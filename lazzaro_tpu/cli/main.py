@@ -188,6 +188,8 @@ def parse_args(argv):
 
 
 def main() -> None:
+    from lazzaro_tpu.utils.compile_cache import place_compile_cache
+    place_compile_cache()
     interactive_chat(parse_args(sys.argv[1:]))
 
 

@@ -34,6 +34,10 @@ from flax import linen as nn
 
 from lazzaro_tpu.models.tokenizer import HashTokenizer, PAD_ID
 
+# Tokens per encoder forward (``TextEncoder.encode_batch``): 128 rows at
+# L=512, where the bge-base forward takes 2.0 GB of temporaries on a v5e.
+_FORWARD_TOKENS = 65_536
+
 
 @dataclass(frozen=True)
 class EncoderConfig:
@@ -254,9 +258,17 @@ class TextEncoder:
         ids = np.asarray(
             self.tokenizer.batch_encode(list(texts), self.cfg.max_len),
             np.int32)
-        n = ids.shape[0]
-        out = self._apply(self.params, jnp.asarray(pad_to_pow2(ids)))
-        return np.asarray(out[:n], np.float32)
+        # One forward holds [rows, heads, L, L] attention logits: 1,024 rows
+        # of the bge-base geometry (L=512, f32) need 15.8 GB of temporaries
+        # and a 16 GB chip refuses the program, so a big batch goes through
+        # in forwards of at most _FORWARD_TOKENS tokens each.
+        rows = max(1, _FORWARD_TOKENS // self.cfg.max_len)
+        outs = []
+        for i in range(0, ids.shape[0], rows):
+            chunk = ids[i:i + rows]
+            outs.append(self._apply(self.params,
+                                    jnp.asarray(pad_to_pow2(chunk)))[:len(chunk)])
+        return np.concatenate([np.asarray(o, np.float32) for o in outs])
 
     def encode(self, text: str) -> np.ndarray:
         return self.encode_batch([text])[0]

@@ -44,6 +44,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from lazzaro_tpu.core import state as S
@@ -64,7 +65,6 @@ from lazzaro_tpu.utils.batching import (LRUKernelCache, bucket_size,
                                         fetch_packed, next_pow2,
                                         pad_to_bucket, pad_to_pow2,
                                         unpack_retrieval)
-from lazzaro_tpu.utils.compat import trace_annotation
 from lazzaro_tpu.utils.telemetry import (default_registry, peak_bytes,
                                          record_device_counters)
 
@@ -589,7 +589,7 @@ class ShardedMemoryIndex:
                                       with_ivf=with_ivf, with_pq=with_pq)
         tel = self.telemetry
         t0 = time.perf_counter()
-        with trace_annotation("lz.ingest.pod_fused"):
+        with TraceAnnotation("lz.ingest.pod_fused"):
             with self._state_lock:
                 arena, edges = self._arena, self._edge_state
                 shadow = self._int8_shadow if with_shadow else None
@@ -1741,7 +1741,7 @@ class ShardedMemoryIndex:
         # admission plan missed; serve_requests answers with one replan.
         faults.fire("plan.oom", mode=f"pod_{mode}", batch=pad_n)
         t0 = time.perf_counter()
-        with trace_annotation(f"lz.serve.pod_{mode}"):
+        with TraceAnnotation(f"lz.serve.pod_{mode}"):
             if boost_on.any():
                 now_rel = time.time() - self.epoch
                 with self._state_lock:

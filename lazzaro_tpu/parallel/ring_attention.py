@@ -17,7 +17,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from lazzaro_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 NEG = -1e30

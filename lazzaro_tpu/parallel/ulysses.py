@@ -15,7 +15,7 @@ TPU-native capabilities of the in-tree LM stack.
 from __future__ import annotations
 
 import jax
-from lazzaro_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

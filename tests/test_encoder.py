@@ -33,9 +33,14 @@ def test_encoder_deterministic_across_instances():
     assert np.allclose(a, b, atol=1e-6)
 
 
-def test_batch_matches_single(enc):
+@pytest.mark.parametrize("forward_tokens", [None, 64])
+def test_batch_matches_single(enc, forward_tokens, monkeypatch):
+    if forward_tokens:       # 2 rows per forward: the batch spans 2 forwards
+        from lazzaro_tpu.models import encoder
+        monkeypatch.setattr(encoder, "_FORWARD_TOKENS", forward_tokens)
     texts = ["alpha beta", "gamma delta", "epsilon"]
     batch = enc.encode_batch(texts)
+    assert batch.shape == (3, enc.dim)
     for i, t in enumerate(texts):
         assert np.allclose(batch[i], enc.encode(t), atol=1e-5)
 
@@ -59,7 +64,14 @@ def _load_graft():
     return m
 
 
-def test_graft_entry_compiles():
+@pytest.fixture()
+def cache_placed_outside(monkeypatch, tmp_path):
+    """With the variable set the hooks leave jax's config alone (jax read it
+    at import, before this fixture), so the suite keeps compiling uncached."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+
+
+def test_graft_entry_compiles(cache_placed_outside):
     import jax
     m = _load_graft()
     fn, args = m.entry()
@@ -67,5 +79,5 @@ def test_graft_entry_compiles():
     assert out.shape[-1] >= 259  # vocab logits
 
 
-def test_dryrun_multichip_8():
+def test_dryrun_multichip_8(cache_placed_outside):
     _load_graft().dryrun_multichip(8)

@@ -25,6 +25,7 @@ from lazzaro_tpu.core.index import build_host_csr, split_csr
 from lazzaro_tpu.parallel.index import ShardedMemoryIndex
 from lazzaro_tpu.parallel.mesh import make_mesh, shard_stacked
 from lazzaro_tpu.serve import QueryScheduler, RetrievalRequest
+from lazzaro_tpu.utils.batching import unpack_retrieval
 
 D = 16
 CAP = 127          # cap+1 = 128 divides both mesh shapes
@@ -83,12 +84,30 @@ def _shard_csr(indptr, nbr, mesh):
 _TAIL = (jnp.float32(1000.0), jnp.float32(0.4), jnp.float32(0.05),
          jnp.float32(0.02))
 
+# A shard-local scan and the whole-arena scan reduce the same products in a
+# different order, so cosines differ in the last bits of a UNIT-scale f32
+# (the error accrues at the scale of the partial sums, not of the result):
+# 2 ULP of a value in [0.5, 1).
+_SCORE_ATOL = float(np.finfo(np.float32).eps)
+
+
+def _assert_packed_parity(p1, p2, k=K):
+    """Rows, gate verdicts and the counter tail exact; scores to
+    ``_SCORE_ATOL``."""
+    u1, u2 = (unpack_retrieval(np.asarray(p), k) for p in (p1, p2))
+    for a, b, exact in zip(u1, u2, (False, True, False, True, True, True)):
+        if exact:
+            np.testing.assert_array_equal(a, b)
+        else:
+            np.testing.assert_allclose(a, b, rtol=0, atol=_SCORE_ATOL)
+
 
 @pytest.mark.parametrize("n_dev", [2, 4])
 def test_exact_mode_bit_identical_to_single_chip(n_dev):
     """Packed readback AND post-serve boost columns (salience, access
-    counts, freshness) must match the single-chip ``search_fused`` bit for
-    bit — gate verdicts, neighbor dedup, and multi-tenant masks included."""
+    counts, freshness) must match the single-chip ``search_fused`` — rows,
+    gate verdicts, neighbor dedup, multi-tenant masks and boost columns bit
+    for bit, scores to ``_SCORE_ATOL``."""
     mesh = _mesh(n_dev)
     st, emb, indptr, nbr = _arena()
     qv, q_valid, tq, gate_on, boost_on = _queries()
@@ -100,7 +119,7 @@ def test_exact_mode_bit_identical_to_single_chip(n_dev):
                                 mode="exact")
     ish, nsh = _shard_csr(indptr, nbr, mesh)
     st2, p2 = kern.serve_copy(_shard_state(st, mesh), (), ish, nsh, *args)
-    np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
+    _assert_packed_parity(p1, p2)
     for col in ("salience", "access_count", "last_accessed"):
         np.testing.assert_array_equal(np.asarray(getattr(st1, col)),
                                       np.asarray(getattr(st2, col)))
@@ -122,7 +141,7 @@ def test_read_twin_matches_and_mutates_nothing():
     r2 = kern.read(st_sh, (), ish, nsh, jnp.asarray(qv),
                    jnp.asarray(q_valid), jnp.asarray(tq),
                    jnp.asarray(gate_on), jnp.float32(0.4))
-    np.testing.assert_array_equal(np.asarray(r1), np.asarray(r2))
+    _assert_packed_parity(r1, r2)
     np.testing.assert_array_equal(sal_before, np.asarray(st_sh.salience))
 
 
