@@ -369,17 +369,25 @@ class MemoryConfig:
     lifecycle_busy_load: int = 0
 
     # --- serving telemetry (ISSUE 6) ---------------------------------------
-    # Host spans + device counters: every request records enqueue→flush
+    # Host spans + device counters: every region the program times goes
+    # through ``Telemetry.span`` (README "Observability" lists them:
+    # scheduler wait and demux, index pack / stage / decode, dispatch
+    # launch / readback, the conversation API, the write path, journals,
+    # store and each file operation), every request records enqueue→flush
     # queue wait (per-tenant label), every coalesced batch records pad
-    # inflation, device dispatch wall time and readback-decode time, and
-    # the fused kernels append an int32 counter tail (gate hit/miss, top-k
-    # shortfall, dedup hits, boost-scatter rows, link-pool occupancy/
-    # overflow) to the packed readback that already exists — bytes, not
-    # dispatches. Off = the registry stays empty but the readback layout
+    # inflation, and the fused kernels append an int32 counter tail (gate
+    # hit/miss, top-k shortfall, dedup hits, boost-scatter rows, link-pool
+    # occupancy/overflow) to the packed readback that already exists —
+    # bytes, not dispatches. Off = the registry stays empty; the spans'
+    # profiler annotations stay (they cost a check while no profile is
+    # being taken, and ARE the trace when one is) and the readback layout
     # is unchanged (the tail always rides; decoding it is nearly free).
     serve_telemetry: bool = True
-    # Telemetry ring-buffer window per timer series (percentiles are
-    # computed over at most this many recent samples).
+    # Telemetry ring-buffer window per timer SERIES (percentiles are
+    # computed over at most this many recent samples). A labelled timer
+    # has one ring per label set: serve.queue_wait_ms{tenant} drops a hot
+    # tenant's (and "~other"'s) oldest samples in a long window —
+    # serve.queue_wait_us / serve.requests is the untruncated mean.
     serve_telemetry_window: int = 10_000
     # AOT-lower each fused serving geometry's read twin ONCE to record its
     # compiled ``memory_analysis()`` peak-HBM gauge

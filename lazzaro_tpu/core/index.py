@@ -22,7 +22,6 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.profiler import TraceAnnotation
 
 from lazzaro_tpu.core import state as S
 from lazzaro_tpu.core.paging import PageAllocator
@@ -1596,8 +1595,8 @@ class MemoryIndex:
         kind = ("sharded_fused"
                 if self.ingest_sharded and self.mesh is not None
                 else "fused")
-        t0 = time.perf_counter()
-        with TraceAnnotation(f"lz.ingest.{kind}"):
+        with self.telemetry.span("ingest." + kind, timer="ingest.dispatch_ms",
+                                 labels={"kind": kind}):
             (link_flat, shadow_fresh, ivf_fresh, pq_fresh,
              page_mirror) = self._apply_fused(
                 jnp.asarray(padded), jnp.asarray(emb),
@@ -1630,9 +1629,6 @@ class MemoryIndex:
                 self.tiering.on_rows_written(rows)
 
             host = fetch_packed(*link_flat)    # the ONE readback
-        self.telemetry.record("ingest.dispatch_ms",
-                              (time.perf_counter() - t0) * 1e3,
-                              labels={"kind": kind})
         # Device-side ingest counters riding the same readback (ISSUE 6):
         # overflow flag + accepted-link count + pool-slot occupancy are the
         # trailing broadcast leaves after the per-mode triples (the online
@@ -1929,8 +1925,8 @@ class MemoryIndex:
                 if self.ingest_sharded and self.mesh is not None
                 else "dedup_fused")
         self._maybe_record_ingest_hbm(dev_args, k_eff, shard_modes, b)
-        t0 = time.perf_counter()
-        with TraceAnnotation(f"lz.ingest.{kind}"):
+        with self.telemetry.span("ingest." + kind, timer="ingest.dispatch_ms",
+                                 labels={"kind": kind}):
             (flat, shadow_fresh, ivf_fresh, pq_fresh,
              page_mirror) = self._apply_dedup_fused(
                 *dev_args, k=k_eff, shard_modes=shard_modes,
@@ -1943,9 +1939,6 @@ class MemoryIndex:
                 self._pq_encode_rows(rows)
             self._emb_gen += 1
             host = fetch_packed(*flat)         # the ONE readback
-        self.telemetry.record("ingest.dispatch_ms",
-                              (time.perf_counter() - t0) * 1e3,
-                              labels={"kind": kind})
         # Device counters riding the same readback: dedup verdicts are the
         # first wide leaf; the link counters trail the per-mode triples,
         # the online-IVF leaves (assign, member pos, 4 counters —
@@ -3017,77 +3010,183 @@ class MemoryIndex:
         results = [RetrievalResult() for _ in range(nq)]
         if not self.id_to_row:
             return results
-        st = self.state
-        cap = st.capacity
-        dim = self.dim
-        ragged = self.serve_ragged
-        if ragged:
-            # Static per-mode k CEILING (ISSUE 7): every request clamps to
-            # it, so the kernel key never depends on the batch's k mix —
-            # one compiled program per (mode × geometry) serves k∈{4..128}
-            # in one dispatch. Per-request k rides as device data below.
-            k_bucket = int(min(max(self.serve_k_max, cap_take, 1), cap))
-        else:
-            k_eff = max(cap_take, max((min(int(r.k), cap) for r in reqs),
-                                      default=1), 1)
-            k_bucket = min(max(next_pow2(k_eff), 1), cap)
-        q = np.zeros((nq, dim), np.float32)
-        valid = np.zeros((nq,), bool)
-        tenants = np.full((nq,), -1, np.int32)
-        gate_on = np.zeros((nq,), bool)
-        boost_on = np.zeros((nq,), bool)
-        k_arr = np.zeros((nq,), np.int32)
-        cap_arr = np.zeros((nq,), np.int32)
-        for i, r in enumerate(reqs):
-            v = np.asarray(r.query, np.float32).reshape(-1)
-            tid = self._tenants.get(r.tenant)
-            if v.size != dim or tid is None:
-                continue
-            q[i] = v
-            valid[i] = True
-            tenants[i] = tid
-            gate_on[i] = bool(r.gate_enabled)
-            boost_on[i] = bool(r.boost)
-            if ragged:
-                # k_q ≥ cap so the boosted prefix is always live (the
-                # non-ragged path guaranteed the same via k_eff ≥ cap_take)
-                k_arr[i] = min(max(int(r.k), cap_take, 1), k_bucket)
-                rc = getattr(r, "cap_take", None)
-                cap_arr[i] = min(int(rc) if rc else cap_take, cap_take,
-                                 k_bucket)
-        if not valid.any():
-            return results
-        # Ragged batches pad to a LINEAR granularity bucket instead of the
-        # next power of two: worst-case padded waste drops from ~50% of
-        # the dispatch to granularity-1 slots (the pow2 padding tax this
-        # PR kills), with jit specializations still bounded.
-        qp = (pad_to_bucket(q, self.serve_pad_granularity) if ragged
-              else pad_to_pow2(q))
-        pad_n = qp.shape[0]
         tel = self.telemetry
-        # Coalesce/pad inflation: padded kernel slots vs live requests and
-        # the kernel k (per-batch max-k bucket, or the ragged ceiling).
-        tel.bump("serve.live_requests", nq)
-        tel.bump("serve.padded_slots", pad_n)
-        tel.gauge("serve.batch_occupancy", nq / pad_n)
-        tel.record("serve.k_bucket", k_bucket)
-        if ragged:
-            for kv in k_arr[valid]:
-                tel.record("serve.k_request", float(kv))
+        with tel.span("index.pack"):
+            st = self.state
+            cap = st.capacity
+            dim = self.dim
+            ragged = self.serve_ragged
+            if ragged:
+                # Static per-mode k CEILING (ISSUE 7): every request clamps to
+                # it, so the kernel key never depends on the batch's k mix —
+                # one compiled program per (mode × geometry) serves k∈{4..128}
+                # in one dispatch. Per-request k rides as device data below.
+                k_bucket = int(min(max(self.serve_k_max, cap_take, 1), cap))
+            else:
+                k_eff = max(cap_take, max((min(int(r.k), cap) for r in reqs),
+                                          default=1), 1)
+                k_bucket = min(max(next_pow2(k_eff), 1), cap)
+            q = np.zeros((nq, dim), np.float32)
+            valid = np.zeros((nq,), bool)
+            tenants = np.full((nq,), -1, np.int32)
+            gate_on = np.zeros((nq,), bool)
+            boost_on = np.zeros((nq,), bool)
+            k_arr = np.zeros((nq,), np.int32)
+            cap_arr = np.zeros((nq,), np.int32)
+            for i, r in enumerate(reqs):
+                v = np.asarray(r.query, np.float32).reshape(-1)
+                tid = self._tenants.get(r.tenant)
+                if v.size != dim or tid is None:
+                    continue
+                q[i] = v
+                valid[i] = True
+                tenants[i] = tid
+                gate_on[i] = bool(r.gate_enabled)
+                boost_on[i] = bool(r.boost)
+                if ragged:
+                    # k_q ≥ cap so the boosted prefix is always live (the
+                    # non-ragged path guaranteed the same via k_eff ≥ cap_take)
+                    k_arr[i] = min(max(int(r.k), cap_take, 1), k_bucket)
+                    rc = getattr(r, "cap_take", None)
+                    cap_arr[i] = min(int(rc) if rc else cap_take, cap_take,
+                                     k_bucket)
+            if not valid.any():
+                return results
+            # Ragged batches pad to a LINEAR granularity bucket instead of the
+            # next power of two: worst-case padded waste drops from ~50% of
+            # the dispatch to granularity-1 slots (the pow2 padding tax this
+            # PR kills), with jit specializations still bounded.
+            qp = (pad_to_bucket(q, self.serve_pad_granularity) if ragged
+                  else pad_to_pow2(q))
+            pad_n = qp.shape[0]
+        with tel.span("index.stage"):
+            # Coalesce/pad inflation: padded kernel slots vs live requests.
+            tel.bump("serve.live_requests", nq)
+            tel.bump("serve.padded_slots", pad_n)
+            tel.gauge("serve.batch_occupancy", nq / pad_n)
 
-        def padb(arr, fill=False, dt=bool):
-            out = np.full((pad_n,), fill, dt)
-            out[:nq] = arr
-            return out
+            def padb(arr, fill=False, dt=bool):
+                out = np.full((pad_n,), fill, dt)
+                out[:nq] = arr
+                return out
 
-        indptr, nbr = self._csr_for(st)
-        # Tiered memory (ISSUE 8): with any row demoted, serving routes
-        # through the tier-aware program — int8 coarse scan over the
-        # full-corpus shadow, exact in-kernel rescore for hot rows, ONE
-        # bounded finish dispatch for queries whose candidates touch cold
-        # rows. Hot-only turns stay ONE dispatch + ONE readback.
-        tm = self.tiering
-        tiered = tm is not None and tm.cold_count > 0
+            indptr, nbr = self._csr_for(st)
+            # Tiered memory (ISSUE 8): with any row demoted, serving routes
+            # through the tier-aware program — int8 coarse scan over the
+            # full-corpus shadow, exact in-kernel rescore for hot rows, ONE
+            # bounded finish dispatch for queries whose candidates touch cold
+            # rows. Hot-only turns stay ONE dispatch + ONE readback.
+            tm = self.tiering
+            tiered = tm is not None and tm.cold_count > 0
+            if self.mesh is None:
+                args = (indptr, nbr, jnp.asarray(qp),
+                        jnp.asarray(padb(valid)),
+                        jnp.asarray(padb(tenants, -1, np.int32)),
+                        jnp.asarray(padb(gate_on)))
+                statics = dict(k=k_bucket, cap_take=min(cap_take, k_bucket),
+                               max_nbr=max_nbr)
+                # Quantized fused serving (ISSUE 3): with the int8 shadow active the
+                # SAME single-dispatch program streams the int8 codes for the
+                # coarse top-(k+slack), exactly rescores the survivors from the
+                # master, and runs the gate/CSR/boost tail unchanged — the fused
+                # path no longer steps aside for int8 mode. Only the arena is
+                # donated; the shadow is a read-only replica that the boost scatter
+                # (salience/access/freshness only) can never invalidate.
+                use_quant = (bool(self.int8_serving) and self.mesh is None
+                             and not tiered)
+                # Fused IVF serving (ISSUE 4): with a coarse build published,
+                # the single-dispatch program starts from the centroid prefilter +
+                # member gather instead of a whole-arena stream — candidate HBM
+                # traffic ~(C + nprobe·N/C)·d per query — and ``ivf_nprobe > 0``
+                # no longer opts out of fusion. With int8 ALSO on, the candidate
+                # scan itself is two-stage (int8 gathered coarse + exact rescore).
+                # With cold rows present IVF now COMPOSES with tiering (ISSUE 12
+                # — the PR 8 dense-fallback is gone): hot candidates come from the
+                # member gather (demoted rows dropped from the tables and masked
+                # by residency), cold rows from the residency-masked int8 shadow
+                # coarse scan, merged at the k+slack window for the same bounded
+                # cold finish.
+                ivf_tabs = self._ivf_fused_pack(k_bucket)
+                # Fused PQ serving (ISSUE 16): with a complete (book, codes) pack
+                # published, the coarse stage is the m-byte ADC member scan — the
+                # flat LUT built in-kernel from the query and codebook, codes
+                # gathered for the visited clusters' members, exact f32 rescore
+                # of the top-(k+slack) survivors from the master — and the gate/
+                # CSR/boost tail rides unchanged: the last serving mode joins the
+                # ONE-dispatch contract. With cold rows present PQ composes with
+                # tiering the same way IVF does, except the cold coarse scan
+                # reads the PQ slab (m bytes/row) instead of the int8 shadow.
+                pq_tabs = self._pq_fused_pack(k_bucket)
+                ivf_tiered = tiered and ivf_tabs is not None
+                pq_tiered = tiered and pq_tabs is not None
+                coarse_tabs = pq_tabs if pq_tabs is not None else ivf_tabs
+                if coarse_tabs is not None:
+                    statics["nprobe"] = coarse_tabs[3]
+                    statics["slack"] = self.coarse_slack
+                elif use_quant or tiered:
+                    statics["slack"] = self.coarse_slack
+                mode = ("pq_tiered" if pq_tiered
+                        else "ivf_tiered" if ivf_tiered
+                        else "tiered" if tiered
+                        else "pq" if pq_tabs is not None
+                        else "ivf" if ivf_tabs is not None
+                        else "quant" if use_quant else "exact")
+                # Ragged sidecar device columns (ISSUE 7): per-query k / cap /
+                # nprobe as int32 DATA next to the query batch. Pad rows carry 0
+                # (their top-k masks fully dead; they were q_valid=False anyway).
+                k_dev = capq_dev = npq_dev = None
+                if ragged:
+                    np.minimum(cap_arr, statics["cap_take"], out=cap_arr)
+                    k_dev = jnp.asarray(padb(k_arr, 0, np.int32))
+                    capq_dev = jnp.asarray(padb(cap_arr, 0, np.int32))
+                    if coarse_tabs is not None:
+                        ceil_np = coarse_tabs[3]
+                        np_arr = np.zeros((nq,), np.int32)
+                        for i, r in enumerate(reqs):
+                            rn = getattr(r, "nprobe", None)
+                            np_arr[i] = (min(max(int(rn), 1), ceil_np) if rn
+                                         else ceil_np)
+                        np_arr[~valid] = 0
+                        npq_dev = jnp.asarray(padb(np_arr, 0, np.int32))
+                if ragged and scan_chunk:
+                    # Planner streaming-width override (ISSUE 11): the scan
+                    # chunks the arena stream tighter — smaller [chunk, rows]
+                    # score tile, SAME single dispatch, bit-identical results.
+                    statics["scan_chunk"] = int(scan_chunk)
+                # Semantic query cache (ISSUE 20): the ring probe, hit
+                # substitution with per-query scan early-out, and the miss
+                # writeback all ride INSIDE this one dispatch; the hit verdict
+                # comes back in the packed readback's semantic counter. Skipped
+                # when the batch's candidate window outgrows the ring width
+                # (non-ragged k-buckets past serve_k_max).
+                semh = self._sem_host
+                sem_kw = {}
+                if semh is not None and mode in S.SEM_MODE_IDS:
+                    win = k_bucket + (statics.get("slack", 0)
+                                      if mode in ("tiered", "ivf_tiered",
+                                                  "pq_tiered") else 0)
+                    if win <= semh.width:
+                        statics["sem_block"] = semh.block
+                        sem_kw = {"sem": semh.tuple_for(mode)}
+                self._note_serve_kernel(mode, statics, ragged)
+                # pq_tiered never touches the int8 shadow — the cold coarse scan
+                # reads the PQ slab already in pq_tabs; only the residency mask
+                # rides in the tier pack there
+                tier_pack = (None if not tiered
+                             else (tm.cold_mask_dev(),) if pq_tiered
+                             else (*self._int8_shadow_for(st), tm.cold_mask_dev()))
+                self._maybe_record_hbm(mode, st, args, statics, super_gate,
+                                       ivf_tabs, use_quant, ragged=ragged,
+                                       k_dev=k_dev, npq_dev=npq_dev,
+                                       tier_pack=tier_pack, pq_tabs=pq_tabs)
+                # Fault point "plan.oom" (ISSUE 11): an HBM allocation failure the
+                # admission plan missed; the wrapper answers with one replan.
+                faults.fire("plan.oom", mode=mode, batch=pad_n)
+                if sem_kw and not boost_on.any():
+                    # the read twins take the ring operand as a plain kwarg next
+                    # to their statics; the boost branch passes it explicitly
+                    # beside its donated state
+                    statics = dict(statics, **sem_kw)
         if self.mesh is not None:
             mode = ("sharded_tiered" if tiered
                     else "sharded_quant" if self.int8_serving
@@ -3108,20 +3207,19 @@ class MemoryIndex:
             # failure the admission plan missed — recovery is ONE replan
             # into split sub-dispatches through the copy twins.
             faults.fire("plan.oom", mode=mode, batch=pad_n)
-            t0 = time.perf_counter()
-            with TraceAnnotation(f"lz.serve.{mode}"):
-                packed = self._dispatch_fused_sharded(
-                    st, indptr, nbr, qp, padb, valid, tenants, gate_on,
-                    boost_on, k_bucket, cap_take, max_nbr, super_gate,
-                    acc_boost, nbr_boost, now, ragged=ragged,
-                    k_arr=k_arr, cap_arr=cap_arr, tiered=tiered,
-                    force_copy=force_copy, sem=sem_state)
-                if sem_state is not None:
-                    sem_ring2, packed = packed
-                host = np.asarray(packed)      # the ONE readback
-            tel.record("serve.dispatch_ms",
-                       (time.perf_counter() - t0) * 1e3,
-                       labels={"mode": mode})
+            with tel.span("serve." + mode, timer="serve.dispatch_ms",
+                          labels={"mode": mode}):
+                with tel.span("dispatch.launch"):
+                    packed = self._dispatch_fused_sharded(
+                        st, indptr, nbr, qp, padb, valid, tenants, gate_on,
+                        boost_on, k_bucket, cap_take, max_nbr, super_gate,
+                        acc_boost, nbr_boost, now, ragged=ragged,
+                        k_arr=k_arr, cap_arr=cap_arr, tiered=tiered,
+                        force_copy=force_copy, sem=sem_state)
+                    if sem_state is not None:
+                        sem_ring2, packed = packed
+                with tel.span("dispatch.readback"):
+                    host = np.asarray(packed)      # the ONE readback
             tel.bump("serve.dispatches", labels={"mode": mode})
             if tiered:
                 from lazzaro_tpu.tier.serve import tiered_decode_and_finish
@@ -3134,7 +3232,7 @@ class MemoryIndex:
                         host[:nq], k_unpack)
                     semh.note_readback(sem_ring2, ctr[:, 4], valid[:nq],
                                        tenants[:nq], g_s, g_r, a_s, a_r)
-                with tel.span("serve.decode_ms"):
+                with tel.span("index.decode", timer="serve.decode_ms"):
                     return tiered_decode_and_finish(
                         self, tm, reqs, results, valid, boost_on, q,
                         tenants, host, k_bucket=k_bucket,
@@ -3142,7 +3240,7 @@ class MemoryIndex:
                         acc_boost=acc_boost, nbr_boost=nbr_boost,
                         now_rel=now_rel, ragged=ragged,
                         cap_arr=(cap_arr if ragged else None), tel=tel)
-            with tel.span("serve.decode_ms"):
+            with tel.span("index.decode", timer="serve.decode_ms"):
                 gate_s, gate_r, ann_s, ann_r, fast, counters = \
                     unpack_retrieval(host[:nq], k_bucket)
                 out = self._demux_fused(reqs, results, valid, boost_on,
@@ -3150,348 +3248,240 @@ class MemoryIndex:
                                         cap,
                                         lengths=(counters[:, 0] if ragged
                                                  else None))
-            if sem_state is not None:
-                semh.note_readback(sem_ring2, counters[:, 4], valid[:nq],
-                                   tenants[:nq], gate_s, gate_r, ann_s,
-                                   ann_r)
-            record_device_counters(
-                tel, counters, fast, gate_on[:nq], valid[:nq],
-                np.asarray([min(int(r.k), cap) for r in reqs]),
-                sem_active=sem_state is not None)
+                if sem_state is not None:
+                    semh.note_readback(sem_ring2, counters[:, 4],
+                                       valid[:nq], tenants[:nq], gate_s,
+                                       gate_r, ann_s, ann_r)
+                record_device_counters(
+                    tel, counters, fast, gate_on[:nq], valid[:nq],
+                    np.asarray([min(int(r.k), cap) for r in reqs]),
+                    sem_active=sem_state is not None)
             return out
-        args = (indptr, nbr, jnp.asarray(qp),
-                jnp.asarray(padb(valid)),
-                jnp.asarray(padb(tenants, -1, np.int32)),
-                jnp.asarray(padb(gate_on)))
-        statics = dict(k=k_bucket, cap_take=min(cap_take, k_bucket),
-                       max_nbr=max_nbr)
-        # Quantized fused serving (ISSUE 3): with the int8 shadow active the
-        # SAME single-dispatch program streams the int8 codes for the
-        # coarse top-(k+slack), exactly rescores the survivors from the
-        # master, and runs the gate/CSR/boost tail unchanged — the fused
-        # path no longer steps aside for int8 mode. Only the arena is
-        # donated; the shadow is a read-only replica that the boost scatter
-        # (salience/access/freshness only) can never invalidate.
-        use_quant = (bool(self.int8_serving) and self.mesh is None
-                     and not tiered)
-        # Fused IVF serving (ISSUE 4): with a coarse build published,
-        # the single-dispatch program starts from the centroid prefilter +
-        # member gather instead of a whole-arena stream — candidate HBM
-        # traffic ~(C + nprobe·N/C)·d per query — and ``ivf_nprobe > 0``
-        # no longer opts out of fusion. With int8 ALSO on, the candidate
-        # scan itself is two-stage (int8 gathered coarse + exact rescore).
-        # With cold rows present IVF now COMPOSES with tiering (ISSUE 12
-        # — the PR 8 dense-fallback is gone): hot candidates come from the
-        # member gather (demoted rows dropped from the tables and masked
-        # by residency), cold rows from the residency-masked int8 shadow
-        # coarse scan, merged at the k+slack window for the same bounded
-        # cold finish.
-        ivf_tabs = self._ivf_fused_pack(k_bucket)
-        # Fused PQ serving (ISSUE 16): with a complete (book, codes) pack
-        # published, the coarse stage is the m-byte ADC member scan — the
-        # flat LUT built in-kernel from the query and codebook, codes
-        # gathered for the visited clusters' members, exact f32 rescore
-        # of the top-(k+slack) survivors from the master — and the gate/
-        # CSR/boost tail rides unchanged: the last serving mode joins the
-        # ONE-dispatch contract. With cold rows present PQ composes with
-        # tiering the same way IVF does, except the cold coarse scan
-        # reads the PQ slab (m bytes/row) instead of the int8 shadow.
-        pq_tabs = self._pq_fused_pack(k_bucket)
-        ivf_tiered = tiered and ivf_tabs is not None
-        pq_tiered = tiered and pq_tabs is not None
-        coarse_tabs = pq_tabs if pq_tabs is not None else ivf_tabs
-        if coarse_tabs is not None:
-            statics["nprobe"] = coarse_tabs[3]
-            statics["slack"] = self.coarse_slack
-        elif use_quant or tiered:
-            statics["slack"] = self.coarse_slack
-        mode = ("pq_tiered" if pq_tiered
-                else "ivf_tiered" if ivf_tiered
-                else "tiered" if tiered
-                else "pq" if pq_tabs is not None
-                else "ivf" if ivf_tabs is not None
-                else "quant" if use_quant else "exact")
-        # Ragged sidecar device columns (ISSUE 7): per-query k / cap /
-        # nprobe as int32 DATA next to the query batch. Pad rows carry 0
-        # (their top-k masks fully dead; they were q_valid=False anyway).
-        k_dev = capq_dev = npq_dev = None
-        if ragged:
-            np.minimum(cap_arr, statics["cap_take"], out=cap_arr)
-            k_dev = jnp.asarray(padb(k_arr, 0, np.int32))
-            capq_dev = jnp.asarray(padb(cap_arr, 0, np.int32))
-            if coarse_tabs is not None:
-                ceil_np = coarse_tabs[3]
-                np_arr = np.zeros((nq,), np.int32)
-                for i, r in enumerate(reqs):
-                    rn = getattr(r, "nprobe", None)
-                    np_arr[i] = (min(max(int(rn), 1), ceil_np) if rn
-                                 else ceil_np)
-                np_arr[~valid] = 0
-                npq_dev = jnp.asarray(padb(np_arr, 0, np.int32))
-        if ragged and scan_chunk:
-            # Planner streaming-width override (ISSUE 11): the scan
-            # chunks the arena stream tighter — smaller [chunk, rows]
-            # score tile, SAME single dispatch, bit-identical results.
-            statics["scan_chunk"] = int(scan_chunk)
-        # Semantic query cache (ISSUE 20): the ring probe, hit
-        # substitution with per-query scan early-out, and the miss
-        # writeback all ride INSIDE this one dispatch; the hit verdict
-        # comes back in the packed readback's semantic counter. Skipped
-        # when the batch's candidate window outgrows the ring width
-        # (non-ragged k-buckets past serve_k_max).
-        semh = self._sem_host
-        sem_kw = {}
-        if semh is not None and mode in S.SEM_MODE_IDS:
-            win = k_bucket + (statics.get("slack", 0)
-                              if mode in ("tiered", "ivf_tiered",
-                                          "pq_tiered") else 0)
-            if win <= semh.width:
-                statics["sem_block"] = semh.block
-                sem_kw = {"sem": semh.tuple_for(mode)}
-        self._note_serve_kernel(mode, statics, ragged)
-        # pq_tiered never touches the int8 shadow — the cold coarse scan
-        # reads the PQ slab already in pq_tabs; only the residency mask
-        # rides in the tier pack there
-        tier_pack = (None if not tiered
-                     else (tm.cold_mask_dev(),) if pq_tiered
-                     else (*self._int8_shadow_for(st), tm.cold_mask_dev()))
-        self._maybe_record_hbm(mode, st, args, statics, super_gate,
-                               ivf_tabs, use_quant, ragged=ragged,
-                               k_dev=k_dev, npq_dev=npq_dev,
-                               tier_pack=tier_pack, pq_tabs=pq_tabs)
-        # Fault point "plan.oom" (ISSUE 11): an HBM allocation failure the
-        # admission plan missed; the wrapper answers with one replan.
-        faults.fire("plan.oom", mode=mode, batch=pad_n)
-        if sem_kw and not boost_on.any():
-            # the read twins take the ring operand as a plain kwarg next
-            # to their statics; the boost branch passes it explicitly
-            # beside its donated state
-            statics = dict(statics, **sem_kw)
-        t0 = time.perf_counter()
-        with TraceAnnotation(f"lz.serve.{mode}"):
-            if boost_on.any():
-                del st  # a live snapshot would trip the sole-owner gate
-                now_rel = ((now if now is not None else time.time())
-                           - self.epoch)
-                with self._state_lock:
-                    cur = self._state
-                    scalars = (jnp.float32(now_rel),
-                               jnp.float32(super_gate),
-                               jnp.float32(acc_boost),
-                               jnp.float32(nbr_boost))
-                    boost_dev = jnp.asarray(padb(boost_on))
-                    # force_copy: a post-OOM replan always dispatches
-                    # through the non-donating twin (ISSUE 11)
-                    sole = (not force_copy
-                            and sys.getrefcount(cur) <= self._SOLE_REFS)
-                    # Each branch picks the (donated, copying) twin pair
-                    # and the per-mode leading operands; ONE guarded call
-                    # at the end executes it donation-safe (ISSUE 10):
-                    # a transient failure retries through the copying
-                    # twin, a consumed input raises typed ArenaPoisoned.
-                    if pq_tiered:
-                        # PQ × tiering (ISSUE 16): exact member gather for
-                        # hot, residency-masked ADC coarse over the code
-                        # slab for cold — the codes/tables are read-only
-                        # replicas, so only the residency mask is taken
-                        # fresh here
-                        cold_dev = tm.cold_mask_dev()
-                        cent, members, extras, _, book_cent, codes = \
-                            pq_tabs
-                        pre = (book_cent, codes, cold_dev, cent, members,
-                               extras)
-                        if ragged:
-                            twins = (S.search_fused_pq_tiered_ragged,
-                                     S.search_fused_pq_tiered_ragged_copy)
-                            boost_args = (boost_dev, k_dev, capq_dev,
-                                          npq_dev) + scalars
+        with tel.span("serve." + mode, timer="serve.dispatch_ms",
+                      labels={"mode": mode}):
+            with tel.span("dispatch.launch"):
+                if boost_on.any():
+                    del st  # a live snapshot would trip the sole-owner gate
+                    now_rel = ((now if now is not None else time.time())
+                               - self.epoch)
+                    with self._state_lock:
+                        cur = self._state
+                        scalars = (jnp.float32(now_rel),
+                                   jnp.float32(super_gate),
+                                   jnp.float32(acc_boost),
+                                   jnp.float32(nbr_boost))
+                        boost_dev = jnp.asarray(padb(boost_on))
+                        # force_copy: a post-OOM replan always dispatches
+                        # through the non-donating twin (ISSUE 11)
+                        sole = (not force_copy
+                                and sys.getrefcount(cur) <= self._SOLE_REFS)
+                        # Each branch picks the (donated, copying) twin pair
+                        # and the per-mode leading operands; ONE guarded call
+                        # at the end executes it donation-safe (ISSUE 10):
+                        # a transient failure retries through the copying
+                        # twin, a consumed input raises typed ArenaPoisoned.
+                        if pq_tiered:
+                            # PQ × tiering (ISSUE 16): exact member gather for
+                            # hot, residency-masked ADC coarse over the code
+                            # slab for cold — the codes/tables are read-only
+                            # replicas, so only the residency mask is taken
+                            # fresh here
+                            cold_dev = tm.cold_mask_dev()
+                            cent, members, extras, _, book_cent, codes = \
+                                pq_tabs
+                            pre = (book_cent, codes, cold_dev, cent, members,
+                                   extras)
+                            if ragged:
+                                twins = (S.search_fused_pq_tiered_ragged,
+                                         S.search_fused_pq_tiered_ragged_copy)
+                                boost_args = (boost_dev, k_dev, capq_dev,
+                                              npq_dev) + scalars
+                            else:
+                                twins = (S.search_fused_pq_tiered,
+                                         S.search_fused_pq_tiered_copy)
+                                boost_args = (boost_dev,) + scalars
+                        elif pq_tabs is not None:
+                            # Fused PQ serving (ISSUE 16): ADC member scan +
+                            # exact shortlist rescore, then the same tail
+                            cent, members, extras, _, book_cent, codes = \
+                                pq_tabs
+                            pre = (book_cent, codes, cent, members, extras)
+                            if ragged:
+                                twins = (S.search_fused_pq_ragged,
+                                         S.search_fused_pq_ragged_copy)
+                                boost_args = (boost_dev, k_dev, capq_dev,
+                                              npq_dev) + scalars
+                            else:
+                                twins = (S.search_fused_pq,
+                                         S.search_fused_pq_copy)
+                                boost_args = (boost_dev,) + scalars
+                        elif ivf_tiered:
+                            # IVF × tiering (ISSUE 12): member gather for hot,
+                            # residency-masked shadow coarse for cold — all
+                            # taken against ``cur`` under the lock
+                            q8, scale = self._int8_shadow_for(cur)
+                            cold_dev = tm.cold_mask_dev()
+                            cent, members, extras, _ = ivf_tabs
+                            pre = (q8, scale, cold_dev, cent, members, extras)
+                            if ragged:
+                                twins = (S.search_fused_ivf_tiered_ragged,
+                                         S.search_fused_ivf_tiered_ragged_copy)
+                                boost_args = (boost_dev, k_dev, capq_dev,
+                                              npq_dev) + scalars
+                            else:
+                                twins = (S.search_fused_ivf_tiered,
+                                         S.search_fused_ivf_tiered_copy)
+                                boost_args = (boost_dev,) + scalars
+                        elif tiered:
+                            # (arena, shadow, residency) all taken against
+                            # ``cur`` under the lock — the triple never tears
+                            q8, scale = self._int8_shadow_for(cur)
+                            cold_dev = tm.cold_mask_dev()
+                            pre = (q8, scale, cold_dev)
+                            if ragged:
+                                twins = (S.search_fused_tiered_ragged,
+                                         S.search_fused_tiered_ragged_copy)
+                                boost_args = (boost_dev, k_dev,
+                                              capq_dev) + scalars
+                            else:
+                                twins = (S.search_fused_tiered,
+                                         S.search_fused_tiered_copy)
+                                boost_args = (boost_dev,) + scalars
+                        elif ivf_tabs is not None:
+                            cent, members, extras, _ = ivf_tabs
+                            # shadow (when int8 is on too) taken against ``cur``
+                            # under the lock — the (arena, codes) pair never
+                            # tears
+                            shadow = (self._int8_shadow_for(cur) if use_quant
+                                      else None)
+                            pre = (shadow, cent, members, extras)
+                            if ragged:
+                                twins = (S.search_fused_ivf_ragged,
+                                         S.search_fused_ivf_ragged_copy)
+                                boost_args = (boost_dev, k_dev, capq_dev,
+                                              npq_dev) + scalars
+                            else:
+                                twins = (S.search_fused_ivf,
+                                         S.search_fused_ivf_copy)
+                                boost_args = (boost_dev,) + scalars
+                        elif use_quant:
+                            # shadow taken against ``cur`` under the lock, so
+                            # the (arena, codes) pair can never tear across a
+                            # racing writer (re-entrant RLock; rebuild is
+                            # dispatch-only)
+                            q8, scale = self._int8_shadow_for(cur)
+                            pre = (q8, scale)
+                            if ragged:
+                                twins = (S.search_fused_quant_ragged,
+                                         S.search_fused_quant_ragged_copy)
+                                boost_args = (boost_dev, k_dev,
+                                              capq_dev) + scalars
+                            else:
+                                twins = (S.search_fused_quant,
+                                         S.search_fused_quant_copy)
+                                boost_args = (boost_dev,) + scalars
                         else:
-                            twins = (S.search_fused_pq_tiered,
-                                     S.search_fused_pq_tiered_copy)
-                            boost_args = (boost_dev,) + scalars
-                    elif pq_tabs is not None:
-                        # Fused PQ serving (ISSUE 16): ADC member scan +
-                        # exact shortlist rescore, then the same tail
-                        cent, members, extras, _, book_cent, codes = \
-                            pq_tabs
-                        pre = (book_cent, codes, cent, members, extras)
-                        if ragged:
-                            twins = (S.search_fused_pq_ragged,
-                                     S.search_fused_pq_ragged_copy)
-                            boost_args = (boost_dev, k_dev, capq_dev,
-                                          npq_dev) + scalars
+                            pre = ()
+                            if ragged:
+                                twins = (S.search_fused_ragged,
+                                         S.search_fused_ragged_copy)
+                                boost_args = (boost_dev, k_dev,
+                                              capq_dev) + scalars
+                            else:
+                                twins = (S.search_fused, S.search_fused_copy)
+                                boost_args = (boost_dev,) + scalars
+                        out = self._guarded(
+                            lambda fn: fn(cur, *pre, *args, *boost_args,
+                                          **sem_kw, **statics),
+                            twins[0], twins[1], sole, (cur,),
+                            "serve_" + mode)
+                        if sem_kw:
+                            new_state, sem_ring2, packed = out
                         else:
-                            twins = (S.search_fused_pq,
-                                     S.search_fused_pq_copy)
-                            boost_args = (boost_dev,) + scalars
-                    elif ivf_tiered:
-                        # IVF × tiering (ISSUE 12): member gather for hot,
-                        # residency-masked shadow coarse for cold — all
-                        # taken against ``cur`` under the lock
-                        q8, scale = self._int8_shadow_for(cur)
-                        cold_dev = tm.cold_mask_dev()
-                        cent, members, extras, _ = ivf_tabs
-                        pre = (q8, scale, cold_dev, cent, members, extras)
-                        if ragged:
-                            twins = (S.search_fused_ivf_tiered_ragged,
-                                     S.search_fused_ivf_tiered_ragged_copy)
-                            boost_args = (boost_dev, k_dev, capq_dev,
-                                          npq_dev) + scalars
-                        else:
-                            twins = (S.search_fused_ivf_tiered,
-                                     S.search_fused_ivf_tiered_copy)
-                            boost_args = (boost_dev,) + scalars
-                    elif tiered:
-                        # (arena, shadow, residency) all taken against
-                        # ``cur`` under the lock — the triple never tears
-                        q8, scale = self._int8_shadow_for(cur)
-                        cold_dev = tm.cold_mask_dev()
-                        pre = (q8, scale, cold_dev)
-                        if ragged:
-                            twins = (S.search_fused_tiered_ragged,
-                                     S.search_fused_tiered_ragged_copy)
-                            boost_args = (boost_dev, k_dev,
-                                          capq_dev) + scalars
-                        else:
-                            twins = (S.search_fused_tiered,
-                                     S.search_fused_tiered_copy)
-                            boost_args = (boost_dev,) + scalars
-                    elif ivf_tabs is not None:
-                        cent, members, extras, _ = ivf_tabs
-                        # shadow (when int8 is on too) taken against ``cur``
-                        # under the lock — the (arena, codes) pair never
-                        # tears
-                        shadow = (self._int8_shadow_for(cur) if use_quant
-                                  else None)
-                        pre = (shadow, cent, members, extras)
-                        if ragged:
-                            twins = (S.search_fused_ivf_ragged,
-                                     S.search_fused_ivf_ragged_copy)
-                            boost_args = (boost_dev, k_dev, capq_dev,
-                                          npq_dev) + scalars
-                        else:
-                            twins = (S.search_fused_ivf,
-                                     S.search_fused_ivf_copy)
-                            boost_args = (boost_dev,) + scalars
-                    elif use_quant:
-                        # shadow taken against ``cur`` under the lock, so
-                        # the (arena, codes) pair can never tear across a
-                        # racing writer (re-entrant RLock; rebuild is
-                        # dispatch-only)
-                        q8, scale = self._int8_shadow_for(cur)
-                        pre = (q8, scale)
-                        if ragged:
-                            twins = (S.search_fused_quant_ragged,
-                                     S.search_fused_quant_ragged_copy)
-                            boost_args = (boost_dev, k_dev,
-                                          capq_dev) + scalars
-                        else:
-                            twins = (S.search_fused_quant,
-                                     S.search_fused_quant_copy)
-                            boost_args = (boost_dev,) + scalars
+                            new_state, packed = out
+                        del cur
+                        self.state = new_state
+                elif pq_tiered:
+                    cold_dev = tm.cold_mask_dev()
+                    cent, members, extras, _, book_cent, codes = pq_tabs
+                    if ragged:
+                        packed = S.search_fused_pq_tiered_ragged_read(
+                            st, book_cent, codes, cold_dev, cent, members,
+                            extras, *args, k_dev, npq_dev,
+                            jnp.float32(super_gate), **statics)
                     else:
-                        pre = ()
-                        if ragged:
-                            twins = (S.search_fused_ragged,
-                                     S.search_fused_ragged_copy)
-                            boost_args = (boost_dev, k_dev,
-                                          capq_dev) + scalars
-                        else:
-                            twins = (S.search_fused, S.search_fused_copy)
-                            boost_args = (boost_dev,) + scalars
-                    out = self._guarded(
-                        lambda fn: fn(cur, *pre, *args, *boost_args,
-                                      **sem_kw, **statics),
-                        twins[0], twins[1], sole, (cur,),
-                        "serve_" + mode)
-                    if sem_kw:
-                        new_state, sem_ring2, packed = out
+                        packed = S.search_fused_pq_tiered_read(
+                            st, book_cent, codes, cold_dev, cent, members,
+                            extras, *args, jnp.float32(super_gate), **statics)
+                elif pq_tabs is not None:
+                    cent, members, extras, _, book_cent, codes = pq_tabs
+                    if ragged:
+                        packed = S.search_fused_pq_ragged_read(
+                            st, book_cent, codes, cent, members, extras,
+                            *args, k_dev, npq_dev, jnp.float32(super_gate),
+                            **statics)
                     else:
-                        new_state, packed = out
-                    del cur
-                    self.state = new_state
-            elif pq_tiered:
-                cold_dev = tm.cold_mask_dev()
-                cent, members, extras, _, book_cent, codes = pq_tabs
-                if ragged:
-                    packed = S.search_fused_pq_tiered_ragged_read(
-                        st, book_cent, codes, cold_dev, cent, members,
-                        extras, *args, k_dev, npq_dev,
-                        jnp.float32(super_gate), **statics)
+                        packed = S.search_fused_pq_read(
+                            st, book_cent, codes, cent, members, extras,
+                            *args, jnp.float32(super_gate), **statics)
+                elif ivf_tiered:
+                    q8, scale = self._int8_shadow_for(st)
+                    cold_dev = tm.cold_mask_dev()
+                    cent, members, extras, _ = ivf_tabs
+                    if ragged:
+                        packed = S.search_fused_ivf_tiered_ragged_read(
+                            st, q8, scale, cold_dev, cent, members, extras,
+                            *args, k_dev, npq_dev, jnp.float32(super_gate),
+                            **statics)
+                    else:
+                        packed = S.search_fused_ivf_tiered_read(
+                            st, q8, scale, cold_dev, cent, members, extras,
+                            *args, jnp.float32(super_gate), **statics)
+                elif tiered:
+                    q8, scale = self._int8_shadow_for(st)
+                    cold_dev = tm.cold_mask_dev()
+                    if ragged:
+                        packed = S.search_fused_tiered_ragged_read(
+                            st, q8, scale, cold_dev, *args, k_dev,
+                            jnp.float32(super_gate), **statics)
+                    else:
+                        packed = S.search_fused_tiered_read(
+                            st, q8, scale, cold_dev, *args,
+                            jnp.float32(super_gate), **statics)
+                elif ivf_tabs is not None:
+                    cent, members, extras, _ = ivf_tabs
+                    shadow = self._int8_shadow_for(st) if use_quant else None
+                    if ragged:
+                        packed = S.search_fused_ivf_ragged_read(
+                            st, shadow, cent, members, extras, *args, k_dev,
+                            npq_dev, jnp.float32(super_gate), **statics)
+                    else:
+                        packed = S.search_fused_ivf_read(
+                            st, shadow, cent, members, extras, *args,
+                            jnp.float32(super_gate), **statics)
+                elif use_quant:
+                    q8, scale = self._int8_shadow_for(st)
+                    if ragged:
+                        packed = S.search_fused_quant_ragged_read(
+                            st, q8, scale, *args, k_dev,
+                            jnp.float32(super_gate), **statics)
+                    else:
+                        packed = S.search_fused_quant_read(
+                            st, q8, scale, *args, jnp.float32(super_gate),
+                            **statics)
                 else:
-                    packed = S.search_fused_pq_tiered_read(
-                        st, book_cent, codes, cold_dev, cent, members,
-                        extras, *args, jnp.float32(super_gate), **statics)
-            elif pq_tabs is not None:
-                cent, members, extras, _, book_cent, codes = pq_tabs
-                if ragged:
-                    packed = S.search_fused_pq_ragged_read(
-                        st, book_cent, codes, cent, members, extras,
-                        *args, k_dev, npq_dev, jnp.float32(super_gate),
-                        **statics)
-                else:
-                    packed = S.search_fused_pq_read(
-                        st, book_cent, codes, cent, members, extras,
-                        *args, jnp.float32(super_gate), **statics)
-            elif ivf_tiered:
-                q8, scale = self._int8_shadow_for(st)
-                cold_dev = tm.cold_mask_dev()
-                cent, members, extras, _ = ivf_tabs
-                if ragged:
-                    packed = S.search_fused_ivf_tiered_ragged_read(
-                        st, q8, scale, cold_dev, cent, members, extras,
-                        *args, k_dev, npq_dev, jnp.float32(super_gate),
-                        **statics)
-                else:
-                    packed = S.search_fused_ivf_tiered_read(
-                        st, q8, scale, cold_dev, cent, members, extras,
-                        *args, jnp.float32(super_gate), **statics)
-            elif tiered:
-                q8, scale = self._int8_shadow_for(st)
-                cold_dev = tm.cold_mask_dev()
-                if ragged:
-                    packed = S.search_fused_tiered_ragged_read(
-                        st, q8, scale, cold_dev, *args, k_dev,
-                        jnp.float32(super_gate), **statics)
-                else:
-                    packed = S.search_fused_tiered_read(
-                        st, q8, scale, cold_dev, *args,
-                        jnp.float32(super_gate), **statics)
-            elif ivf_tabs is not None:
-                cent, members, extras, _ = ivf_tabs
-                shadow = self._int8_shadow_for(st) if use_quant else None
-                if ragged:
-                    packed = S.search_fused_ivf_ragged_read(
-                        st, shadow, cent, members, extras, *args, k_dev,
-                        npq_dev, jnp.float32(super_gate), **statics)
-                else:
-                    packed = S.search_fused_ivf_read(
-                        st, shadow, cent, members, extras, *args,
-                        jnp.float32(super_gate), **statics)
-            elif use_quant:
-                q8, scale = self._int8_shadow_for(st)
-                if ragged:
-                    packed = S.search_fused_quant_ragged_read(
-                        st, q8, scale, *args, k_dev,
-                        jnp.float32(super_gate), **statics)
-                else:
-                    packed = S.search_fused_quant_read(
-                        st, q8, scale, *args, jnp.float32(super_gate),
-                        **statics)
-            else:
-                if ragged:
-                    packed = S.search_fused_ragged_read(
-                        st, *args, k_dev, jnp.float32(super_gate),
-                        **statics)
-                else:
-                    packed = S.search_fused_read(st, *args,
-                                                 jnp.float32(super_gate),
-                                                 **statics)
-            if sem_kw and not boost_on.any():
-                sem_ring2, packed = packed
-            host = np.asarray(packed)          # the ONE readback
-        tel.record("serve.dispatch_ms", (time.perf_counter() - t0) * 1e3,
-                   labels={"mode": mode})
+                    if ragged:
+                        packed = S.search_fused_ragged_read(
+                            st, *args, k_dev, jnp.float32(super_gate),
+                            **statics)
+                    else:
+                        packed = S.search_fused_read(st, *args,
+                                                     jnp.float32(super_gate),
+                                                     **statics)
+                if sem_kw and not boost_on.any():
+                    sem_ring2, packed = packed
+            with tel.span("dispatch.readback"):
+                host = np.asarray(packed)          # the ONE readback
         tel.bump("serve.dispatches", labels={"mode": mode})
         if tiered:
             from lazzaro_tpu.tier.serve import tiered_decode_and_finish
@@ -3500,38 +3490,40 @@ class MemoryIndex:
             except NameError:
                 pass                       # boost path already dropped it
             now_rel = (now if now is not None else time.time()) - self.epoch
-            with tel.span("serve.decode_ms"):
+            with tel.span("index.decode", timer="serve.decode_ms"):
                 out = tiered_decode_and_finish(
                     self, tm, reqs, results, valid, boost_on, q, tenants,
                     host, k_bucket=k_bucket, cap_take=statics["cap_take"],
                     max_nbr=max_nbr, acc_boost=acc_boost,
                     nbr_boost=nbr_boost, now_rel=now_rel, ragged=ragged,
                     cap_arr=(cap_arr if ragged else None), tel=tel)
-            k_unpack = (host.shape[1] - 8) // 2
-            g_s, g_r, a_s, a_r, fast_np, counters = unpack_retrieval(
-                host[:nq], k_unpack)
-            if sem_kw:
-                semh.note_readback(sem_ring2, counters[:, 4], valid[:nq],
-                                   tenants[:nq], g_s, g_r, a_s, a_r)
-            record_device_counters(
-                tel, counters, fast_np, gate_on[:nq], valid[:nq],
-                np.asarray([min(int(r.k), cap) for r in reqs]),
-                sem_active=bool(sem_kw))
+                k_unpack = (host.shape[1] - 8) // 2
+                g_s, g_r, a_s, a_r, fast_np, counters = unpack_retrieval(
+                    host[:nq], k_unpack)
+                if sem_kw:
+                    semh.note_readback(sem_ring2, counters[:, 4],
+                                       valid[:nq], tenants[:nq], g_s, g_r,
+                                       a_s, a_r)
+                record_device_counters(
+                    tel, counters, fast_np, gate_on[:nq], valid[:nq],
+                    np.asarray([min(int(r.k), cap) for r in reqs]),
+                    sem_active=bool(sem_kw))
             return out
-        with tel.span("serve.decode_ms"):
+        with tel.span("index.decode", timer="serve.decode_ms"):
             gate_s, gate_r, ann_s, ann_r, fast, counters = unpack_retrieval(
                 host[:nq], k_bucket)
             out = self._demux_fused(reqs, results, valid, boost_on, gate_s,
                                     gate_r, ann_s, ann_r, fast, cap,
                                     lengths=(counters[:, 0] if ragged
                                              else None))
-        if sem_kw:
-            semh.note_readback(sem_ring2, counters[:, 4], valid[:nq],
-                               tenants[:nq], gate_s, gate_r, ann_s, ann_r)
-        record_device_counters(
-            tel, counters, fast, gate_on[:nq], valid[:nq],
-            np.asarray([min(int(r.k), cap) for r in reqs]),
-            sem_active=bool(sem_kw))
+            if sem_kw:
+                semh.note_readback(sem_ring2, counters[:, 4], valid[:nq],
+                                   tenants[:nq], gate_s, gate_r, ann_s,
+                                   ann_r)
+            record_device_counters(
+                tel, counters, fast, gate_on[:nq], valid[:nq],
+                np.asarray([min(int(r.k), cap) for r in reqs]),
+                sem_active=bool(sem_kw))
         return out
 
     def _note_serve_kernel(self, mode: str, statics: dict,

@@ -25,7 +25,8 @@ import os
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, Iterator, List, Optional, Set, Tuple
+from typing import (Any, Dict, Iterator, List, Optional, Sequence, Set,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -160,7 +161,15 @@ class MemorySystem:
             dim = len(self.embedder.embed("dimension probe"))
         self.embed_dim = dim
 
-        self.store = store if store is not None else ArrowStore(db_dir)
+        # Serving telemetry (ISSUE 6): one registry per system — the index,
+        # the query scheduler, the store's and journals' file operations and
+        # the chat/consolidation paths all record into it;
+        # ``metrics_summary()`` / the dashboard ``/metrics`` endpoint read
+        # it out.
+        self.telemetry = Telemetry(cfg.serve_telemetry_window,
+                                   enabled=cfg.serve_telemetry)
+        self.store = (store if store is not None
+                      else ArrowStore(db_dir, telemetry=self.telemetry))
         self.vector_store = self.store  # back-compat alias (reference :110)
 
         self.shards: Dict[str, MemoryShard] = {}
@@ -176,12 +185,6 @@ class MemorySystem:
         self.buffer = BufferGraph(self.shards, self.super_nodes)
         self.profile = Profile()
         self.mesh = mesh
-        # Serving telemetry (ISSUE 6): one registry per system — the index,
-        # the query scheduler, and the chat/consolidation paths all record
-        # into it; ``metrics_summary()`` / the dashboard ``/metrics``
-        # endpoint read it out.
-        self.telemetry = Telemetry(cfg.serve_telemetry_window,
-                                   enabled=cfg.serve_telemetry)
         self.index = MemoryIndex(dim, capacity=cfg.initial_capacity,
                                  edge_capacity=cfg.max_edges,
                                  dtype=jnp.dtype(cfg.dtype), mesh=mesh,
@@ -350,17 +353,19 @@ class MemorySystem:
         from lazzaro_tpu.native import WriteAheadLog
 
         path = f"{journal_dir}/journal__{quote(self.user_id, safe='')}.wal"
-        self._journal = WriteAheadLog(path, fsync=self.config.journal_fsync)
+        self._journal = WriteAheadLog(path, fsync=self.config.journal_fsync,
+                                      telemetry=self.telemetry)
         if not replay:
             return
         recovered = []
-        for payload in self._journal.replay():
-            try:
-                turn = json.loads(payload.decode("utf-8"))
-            except (UnicodeDecodeError, json.JSONDecodeError):
-                continue
-            if isinstance(turn, dict) and turn.get("content"):
-                recovered.append(turn)
+        with self.telemetry.span("journal.setup"):
+            for payload in self._journal.replay():
+                try:
+                    turn = json.loads(payload.decode("utf-8"))
+                except (UnicodeDecodeError, json.JSONDecodeError):
+                    continue
+                if isinstance(turn, dict) and turn.get("content"):
+                    recovered.append(turn)
         if recovered:
             self.short_term_memory = recovered
             self.conversation_active = True
@@ -370,10 +375,11 @@ class MemorySystem:
 
     def _journal_turn(self, turn: Dict) -> None:
         if self._journal is not None:
-            try:
-                self._journal.append(json.dumps(turn).encode("utf-8"))
-            except OSError as e:
-                self._log(f"⚠ Journal append failed: {e}")
+            with self.telemetry.span("journal.turn"):
+                try:
+                    self._journal.append(json.dumps(turn).encode("utf-8"))
+                except OSError as e:
+                    self._log(f"⚠ Journal append failed: {e}")
 
     def _journal_sync(self) -> None:
         """Rewrite the WAL to the current not-yet-durable turn set. Callers
@@ -386,12 +392,13 @@ class MemorySystem:
             turns.extend(batch.get("memories", []))
         if self.conversation_active:
             turns.extend(self.short_term_memory)
-        try:
-            self._journal.reset()
-            for t in turns:
-                self._journal.append(json.dumps(t).encode("utf-8"))
-        except OSError:
-            pass
+        with self.telemetry.span("journal.sync"):
+            try:
+                self._journal.reset()
+                for t in turns:
+                    self._journal.append(json.dumps(t).encode("utf-8"))
+            except OSError:
+                pass
 
     # -------------------------------------------------------- ingest journal
     #
@@ -414,15 +421,19 @@ class MemorySystem:
         from lazzaro_tpu.reliability import IngestJournal
 
         path = f"{journal_dir}/ingest__{quote(self.user_id, safe='')}.wal"
-        try:
-            self._ingest_journal = IngestJournal(
-                path, fsync=self.config.ingest_journal_fsync)
-        except OSError as e:
-            self._log(f"⚠ Ingest journal unavailable: {e}")
-            return
-        if not replay:
-            return
-        pending = self._ingest_journal.pending()
+        # the span ends before the replay below: that one runs the ingest
+        # and the store under their own names
+        with self.telemetry.span("journal.setup"):
+            try:
+                self._ingest_journal = IngestJournal(
+                    path, fsync=self.config.ingest_journal_fsync,
+                    telemetry=self.telemetry)
+            except OSError as e:
+                self._log(f"⚠ Ingest journal unavailable: {e}")
+                return
+            if not replay:
+                return
+            pending = self._ingest_journal.pending()
         if not pending:
             return
         n_facts = sum(len(f) for _, f in pending)
@@ -431,8 +442,12 @@ class MemorySystem:
         for _seq, facts in pending:
             self._ingest_facts(facts)
         self.telemetry.bump("reliability.journal_replayed", n_facts)
-        self._ingest_journal.commit(self._ingest_journal.last_seq)
+        self._commit_ingest_journal(self._ingest_journal.last_seq)
         self._save_to_persistence()
+
+    def _commit_ingest_journal(self, seq: int) -> None:
+        with self.telemetry.span("journal.commit"):
+            self._ingest_journal.commit(seq)
 
     # ------------------------------------------------------------------ util
     def _log(self, msg: str) -> None:
@@ -622,18 +637,19 @@ class MemorySystem:
 
     # --------------------------------------------------------------- session
     def start_conversation(self) -> str:
-        if self._recovered_turns and self.conversation_active and self.short_term_memory:
-            # Crash-recovered turns must not be discarded by the normal
-            # "/start clears the buffer" flow — consolidate them first.
-            self._log("🛟 Consolidating recovered turns before new conversation...")
-            self.end_conversation()
-        self._recovered_turns = False
-        self.conversation_active = True
-        self.short_term_memory = []
-        self.conversation_history = []
-        with self._mutex:
-            self._journal_sync()       # drops abandoned-conversation turns
-        return "✓ Conversation started"
+        with self.telemetry.span("api.start_conversation"):
+            if self._recovered_turns and self.conversation_active and self.short_term_memory:
+                # Crash-recovered turns must not be discarded by the normal
+                # "/start clears the buffer" flow — consolidate them first.
+                self._log("🛟 Consolidating recovered turns before new conversation...")
+                self.end_conversation()
+            self._recovered_turns = False
+            self.conversation_active = True
+            self.short_term_memory = []
+            self.conversation_history = []
+            with self._mutex:
+                self._journal_sync()   # drops abandoned-conversation turns
+            return "✓ Conversation started"
 
     def add_to_short_term(self, content: str, memory_type: str = "semantic",
                           salience: float = 0.5) -> None:
@@ -664,7 +680,12 @@ class MemorySystem:
             self.conversation_active = False
             self._recovered_turns = False
             return "✓ Conversation ended. No memories to consolidate."
+        # one span per conversation that consolidates: the per-conversation
+        # metrics divide by their number
+        with self.telemetry.span("api.end_conversation"):
+            return self._end_conversation()
 
+    def _end_conversation(self) -> str:
         results = []
         n_turns = len(self.short_term_memory)
         with self._mutex:
@@ -689,7 +710,7 @@ class MemorySystem:
             nodes, edges = self.buffer.size()
             self._status(results, f"✓ Consolidation complete. Memory: {nodes} nodes, {edges} edges")
 
-        with self._mutex:
+        with self.telemetry.span("write.decay"), self._mutex:
             # Deferred cache-hit boosts land BEFORE the decay sweep, so the
             # batched flush reproduces the classic boost-then-decay order.
             self._flush_pending_boosts_locked()
@@ -1303,38 +1324,40 @@ Return JSON: {"memories": [{"content": "...", "type": "semantic|episodic|procedu
         start_time = time.time()
         self._log(f"🔄 Processing {len(all_memories)} memories in background...")
 
-        conv_text = json.dumps(all_memories)
-        response = self._call_llm(
-            [{"role": "system", "content": self._EXTRACTION_PROMPT},
-             {"role": "user", "content": conv_text}],
-            response_format={"type": "json_object"})
+        with self.telemetry.span("write.extract"):
+            conv_text = json.dumps(all_memories)
+            response = self._call_llm(
+                [{"role": "system", "content": self._EXTRACTION_PROMPT},
+                 {"role": "user", "content": conv_text}],
+                response_format={"type": "json_object"})
 
-        try:
-            data = json.loads(_extract_json_object(response))
-            if isinstance(data, dict):
-                memories = data.get("memories", [])
-            elif isinstance(data, list):
-                memories = data
-            else:
-                self._log(f"⚠ Unexpected data type: {type(data)}")
+            try:
+                data = json.loads(_extract_json_object(response))
+                if isinstance(data, dict):
+                    memories = data.get("memories", [])
+                elif isinstance(data, list):
+                    memories = data
+                else:
+                    self._log(f"⚠ Unexpected data type: {type(data)}")
+                    self._requeue_inflight()
+                    return
+            except json.JSONDecodeError as e:
+                self._log(f"⚠ Parse error: {e}")
                 self._requeue_inflight()
                 return
-        except json.JSONDecodeError as e:
-            self._log(f"⚠ Parse error: {e}")
-            self._requeue_inflight()
-            return
 
-        memories = [m for m in memories if isinstance(m, dict)]
+            memories = [m for m in memories if isinstance(m, dict)]
         self._log(f"✓ Extracted {len(memories)} memory candidates")
         # Durable ingest journal (ISSUE 10): the facts become durable the
         # moment extraction returns — BEFORE the coalescer buffers them —
         # so a crash anywhere between here and the fused dispatch loses
         # nothing (startup replay + dedup probe make recovery idempotent).
         if self._ingest_journal is not None and memories:
-            try:
-                self._ingest_journal.append(memories)
-            except OSError as e:
-                self._log(f"⚠ Ingest journal append failed: {e}")
+            with self.telemetry.span("journal.append"):
+                try:
+                    self._ingest_journal.append(memories)
+                except OSError as e:
+                    self._log(f"⚠ Ingest journal append failed: {e}")
         # Fault point "ingest.worker" (ISSUE 10): a raise here models the
         # consolidation worker dying between extraction and ingest.
         from lazzaro_tpu.reliability import faults as _faults
@@ -1401,21 +1424,23 @@ Return JSON: {"memories": [{"content": "...", "type": "semantic|episodic|procedu
         if self._ingest_journal is not None:
             # append → dispatch → COMMIT: every drained fact is durable in
             # the arena + store now, so the journal can retire them.
-            self._ingest_journal.commit(commit_to)
+            self._commit_ingest_journal(commit_to)
 
     def _ingest_facts(self, memories: List[Dict]) -> List[Tuple[str, str]]:
         """Stage, dedup, and ingest one mega-batch of extracted facts;
         returns the (node_id, shard_key) pairs created."""
-        contents = [m.get("content", "") for m in memories if m.get("content")]
-        embeddings = self._batch_embed(contents)
-        try:
-            # one bulk list→array conversion for the whole batch (per-fact
-            # np.asarray over float lists was ~30% of ingest host time)
-            emb_rows = np.asarray(embeddings, np.float32)
-            if emb_rows.ndim != 2:
-                raise ValueError
-        except (ValueError, TypeError):        # ragged/failed rows: per-item
-            emb_rows = None
+        with self.telemetry.span("write.embed"):
+            contents = [m.get("content", "") for m in memories
+                        if m.get("content")]
+            embeddings = self._batch_embed(contents)
+            try:
+                # one bulk list→array conversion for the whole batch (per-fact
+                # np.asarray over float lists was ~30% of ingest host time)
+                emb_rows = np.asarray(embeddings, np.float32)
+                if emb_rows.ndim != 2:
+                    raise ValueError
+            except (ValueError, TypeError):    # ragged/failed rows: per-item
+                emb_rows = None
 
         with self._mutex:
             # Stage valid facts, then resolve near-duplicates with two
@@ -1449,202 +1474,181 @@ Return JSON: {"memories": [{"content": "...", "type": "semantic|episodic|procedu
                 # device program instead of paying its own dispatch.
                 return self._ingest_facts_dedup_fused(staged)
 
-            probe: List[Tuple[Optional[str], float]] = [(None, 0.0)] * len(staged)
-            probeable = [i for i, (_, _, e) in enumerate(staged)
-                         if e.size == self.embed_dim]
-            if probeable:
-                qs = np.stack([staged[i][2] for i in probeable])
-                res = self.index.search_batch(qs, self.user_id, k=1,
-                                              super_filter=-1, exact=True)
-                for i, (ids, scores) in zip(probeable, res):
-                    if ids:
-                        probe[i] = (ids[0].partition(":")[2], scores[0])
-            intra_best_col = intra_best_sim = None
-            if len(probeable) >= 2:
-                M = np.stack([staged[i][2] for i in probeable])
-                norms = np.linalg.norm(M, axis=1, keepdims=True)
-                norms[norms == 0] = 1.0
-                M = M / norms
-                intra = M @ M.T
-                # Per row, the best match among EARLIER batch rows — one
-                # vectorized masked argmax instead of an O(B²) Python scan.
-                n_p = len(probeable)
-                tril = np.where(np.tri(n_p, k=-1, dtype=bool), intra, -np.inf)
-                intra_best_col = np.argmax(tril, axis=1)
-                intra_best_sim = tril[np.arange(n_p), intra_best_col]
-            pos_in_probeable = {i: j for j, i in enumerate(probeable)}
+            with self.telemetry.span("write.prepare"):
+                probe: List[Tuple[Optional[str], float]] = [(None, 0.0)] * len(staged)
+                probeable = [i for i, (_, _, e) in enumerate(staged)
+                             if e.size == self.embed_dim]
+                if probeable:
+                    qs = np.stack([staged[i][2] for i in probeable])
+                    res = self.index.search_batch(qs, self.user_id, k=1,
+                                                  super_filter=-1, exact=True)
+                    for i, (ids, scores) in zip(probeable, res):
+                        if ids:
+                            probe[i] = (ids[0].partition(":")[2], scores[0])
+                intra_best_col = intra_best_sim = None
+                if len(probeable) >= 2:
+                    M = np.stack([staged[i][2] for i in probeable])
+                    norms = np.linalg.norm(M, axis=1, keepdims=True)
+                    norms[norms == 0] = 1.0
+                    M = M / norms
+                    intra = M @ M.T
+                    # Per row, the best match among EARLIER batch rows — one
+                    # vectorized masked argmax instead of an O(B²) Python scan.
+                    n_p = len(probeable)
+                    tril = np.where(np.tri(n_p, k=-1, dtype=bool), intra, -np.inf)
+                    intra_best_col = np.argmax(tril, axis=1)
+                    intra_best_sim = tril[np.arange(n_p), intra_best_col]
+                pos_in_probeable = {i: j for j, i in enumerate(probeable)}
 
-            new_nodes: List[Tuple[str, str]] = []
-            new_nodes_data: List[Dict] = []
-            created: List[Node] = []
-            created_embs: List[np.ndarray] = []
-            merge_ids: List[str] = []
-            merge_sals: List[float] = []
-            fact_target: List[Optional[str]] = []  # node id each fact resolved to
-            for fi, (mem, content, new_emb) in enumerate(staged):
-                shard_key = mem.get("topic") or self._infer_shard_key(content)
-                if shard_key == "other":
-                    shard_key = self._infer_shard_key(content)
-                shard = self._get_or_create_shard(shard_key)
+                new_nodes: List[Tuple[str, str]] = []
+                new_nodes_data: List[Dict] = []
+                created: List[Node] = []
+                created_embs: List[np.ndarray] = []
+                merge_ids: List[str] = []
+                merge_sals: List[float] = []
+                fact_target: List[Optional[str]] = []  # node id each fact resolved to
+                for fi, (mem, content, new_emb) in enumerate(staged):
+                    shard_key = mem.get("topic") or self._infer_shard_key(content)
+                    if shard_key == "other":
+                        shard_key = self._infer_shard_key(content)
+                    shard = self._get_or_create_shard(shard_key)
 
-                # Best match: pre-batch arena probe vs earlier-in-batch fact.
-                target_id, best = probe[fi]
-                if intra_best_sim is not None and fi in pos_in_probeable:
-                    row = pos_in_probeable[fi]
-                    sim = float(intra_best_sim[row])
-                    if sim > best:
-                        t = fact_target[probeable[int(intra_best_col[row])]]
-                        if t is not None:
-                            target_id, best = t, sim
-                existing_node = (self.buffer.get_node(target_id)
-                                 if target_id is not None
-                                 and best > self.config.dedup_similarity
-                                 else None)
+                    # Best match: pre-batch arena probe vs earlier-in-batch fact.
+                    target_id, best = probe[fi]
+                    if intra_best_sim is not None and fi in pos_in_probeable:
+                        row = pos_in_probeable[fi]
+                        sim = float(intra_best_sim[row])
+                        if sim > best:
+                            t = fact_target[probeable[int(intra_best_col[row])]]
+                            if t is not None:
+                                target_id, best = t, sim
+                    existing_node = (self.buffer.get_node(target_id)
+                                     if target_id is not None
+                                     and best > self.config.dedup_similarity
+                                     else None)
 
-                if existing_node is not None:
-                    cand_sal = float(mem.get("salience", 0.5))
-                    existing_node.salience = max(existing_node.salience, cand_sal)
-                    existing_node.last_accessed = time.time()
-                    existing_node.access_count += 1
-                    merge_ids.append(existing_node.id)
-                    merge_sals.append(cand_sal)
-                    self._mark_dirty(existing_node.id)
-                    fact_target.append(existing_node.id)
-                    self._log(f"   (Merged semantic duplicate into {existing_node.id})")
-                    continue
+                    if existing_node is not None:
+                        cand_sal = float(mem.get("salience", 0.5))
+                        existing_node.salience = max(existing_node.salience, cand_sal)
+                        existing_node.last_accessed = time.time()
+                        existing_node.access_count += 1
+                        merge_ids.append(existing_node.id)
+                        merge_sals.append(cand_sal)
+                        self._mark_dirty(existing_node.id)
+                        fact_target.append(existing_node.id)
+                        self._log(f"   (Merged semantic duplicate into {existing_node.id})")
+                        continue
 
-                node_id = self._generate_node_id()
-                # The arena owns the vector (embedding=None on the host);
-                # keeping a Python float-list per node is what made 1M-node
-                # host graphs impossible. Persistence gathers on demand.
-                node = Node(
-                    id=node_id,
-                    content=content,
-                    embedding=None,
-                    type=mem.get("type", "semantic"),
-                    salience=float(mem.get("salience", 0.5)),
-                    shard_key=shard_key,
-                )
-                shard.add_node(node)
-                created.append(node)
-                created_embs.append(new_emb)
-                fact_target.append(node_id)
-                new_nodes.append((node_id, shard_key))
-                if new_emb.size != self.embed_dim:
-                    # wrong-dim/missing vector: the rare irregular row goes
-                    # through the dict path (vector omitted = NULL)
-                    new_nodes_data.append({
-                        "id": node_id,
-                        "content": content,
-                        "type": node.type,
-                        "salience": node.salience,
-                        "shard_key": node.shard_key,
-                        "timestamp": node.timestamp,
-                        "decay_pass": self._decay_pass,
-                    })
+                    node_id = self._generate_node_id()
+                    # The arena owns the vector (embedding=None on the host);
+                    # keeping a Python float-list per node is what made 1M-node
+                    # host graphs impossible. Persistence gathers on demand.
+                    node = Node(
+                        id=node_id,
+                        content=content,
+                        embedding=None,
+                        type=mem.get("type", "semantic"),
+                        salience=float(mem.get("salience", 0.5)),
+                        shard_key=shard_key,
+                    )
+                    shard.add_node(node)
+                    created.append(node)
+                    created_embs.append(new_emb)
+                    fact_target.append(node_id)
+                    new_nodes.append((node_id, shard_key))
+                    if new_emb.size != self.embed_dim:
+                        # wrong-dim/missing vector: the rare irregular row goes
+                        # through the dict path (vector omitted = NULL)
+                        new_nodes_data.append({
+                            "id": node_id,
+                            "content": content,
+                            "type": node.type,
+                            "salience": node.salience,
+                            "shard_key": node.shard_key,
+                            "timestamp": node.timestamp,
+                            "decay_pass": self._decay_pass,
+                        })
 
-            # ONE arena scatter for every new node, ONE touch for all merges
-            # — and with ingest_fused, the link scan and edge insert ride in
-            # the SAME donated device program.
-            arena_new = [(n, e) for n, e in zip(created, created_embs)
-                         if e.size == self.embed_dim]
-            # stacked once, shared by the arena scatter AND the store write
-            emb_matrix = (np.stack([e for _, e in arena_new])
-                          if arena_new else None)
-            chain_edges = self._chain_edges(new_nodes)
-            use_fused = bool(self.config.ingest_fused and arena_new)
-            fused_created = None
-            if use_fused:
-                arena_ids = {n.id for n, _ in arena_new}
-                chain_pairs = [(self._q(e.source), self._q(e.target))
-                               for e in chain_edges
-                               if e.source in arena_ids and e.target in arena_ids]
-                _rows, _cands, fused_created = self.index.ingest_batch(
-                    ids=[self._q(n.id) for n, _ in arena_new],
-                    embeddings=emb_matrix,
-                    saliences=[n.salience for n, _ in arena_new],
-                    timestamps=[n.timestamp for n, _ in arena_new],
-                    types=[n.type for n, _ in arena_new],
-                    shard_keys=[n.shard_key or "default" for n, _ in arena_new],
-                    tenant=self.user_id,
-                    is_super=[n.is_super_node for n, _ in arena_new],
-                    merge_ids=[self._q(i) for i in merge_ids],
-                    merge_saliences=merge_sals,
-                    chain_pairs=chain_pairs,
-                    chain_weight=self.config.chain_link_weight,
-                    link_k=self.config.cross_link_top_k,
-                    link_gate=self.config.link_gate,
-                    link_scale=self.config.link_weight_scale,
-                    shard_modes=(1, 0),
-                    link_accept_hint=self.config.link_accept_hint)
-            else:
-                if arena_new:
-                    self.index.add(
-                        [self._q(n.id) for n, _ in arena_new],
-                        emb_matrix,
-                        [n.salience for n, _ in arena_new],
-                        [n.timestamp for n, _ in arena_new],
-                        [n.type for n, _ in arena_new],
-                        [n.shard_key or "default" for n, _ in arena_new],
-                        self.user_id,
-                        [n.is_super_node for n, _ in arena_new])
-                if merge_ids:
-                    self.index.merge_touch([self._q(i) for i in merge_ids],
-                                           merge_sals)
-
-            # Persist fresh nodes: columnar bulk path when the store has it
-            # (one flat embedding buffer, no per-row dicts) — ingest hot
-            # path; dict rows for protocol-parity stores and irregular rows.
-            # arena_new is exactly the full-dim subset: arena and store can
-            # never disagree about which nodes carry vectors.
-            regular = arena_new
-            if regular:
-                if hasattr(self.store, "add_nodes_columns"):
-                    self.store.add_nodes_columns(
-                        ids=[n.id for n, _ in regular],
-                        contents=[n.content for n, _ in regular],
+                # ONE arena scatter for every new node, ONE touch for all merges
+                # — and with ingest_fused, the link scan and edge insert ride in
+                # the SAME donated device program.
+                arena_new = [(n, e) for n, e in zip(created, created_embs)
+                             if e.size == self.embed_dim]
+                # stacked once, shared by the arena scatter AND the store write
+                emb_matrix = (np.stack([e for _, e in arena_new])
+                              if arena_new else None)
+                chain_edges = self._chain_edges(new_nodes)
+                use_fused = bool(self.config.ingest_fused and arena_new)
+                fused_created = None
+                if use_fused:
+                    arena_ids = {n.id for n, _ in arena_new}
+                    chain_pairs = [(self._q(e.source), self._q(e.target))
+                                   for e in chain_edges
+                                   if e.source in arena_ids and e.target in arena_ids]
+                    _rows, _cands, fused_created = self.index.ingest_batch(
+                        ids=[self._q(n.id) for n, _ in arena_new],
                         embeddings=emb_matrix,
-                        types=[n.type for n, _ in regular],
-                        saliences=[n.salience for n, _ in regular],
-                        timestamps=[n.timestamp for n, _ in regular],
-                        shard_keys=[n.shard_key or "" for n, _ in regular],
-                        decay_pass=self._decay_pass,
-                        user_id=self.user_id)
+                        saliences=[n.salience for n, _ in arena_new],
+                        timestamps=[n.timestamp for n, _ in arena_new],
+                        types=[n.type for n, _ in arena_new],
+                        shard_keys=[n.shard_key or "default" for n, _ in arena_new],
+                        tenant=self.user_id,
+                        is_super=[n.is_super_node for n, _ in arena_new],
+                        merge_ids=[self._q(i) for i in merge_ids],
+                        merge_saliences=merge_sals,
+                        chain_pairs=chain_pairs,
+                        chain_weight=self.config.chain_link_weight,
+                        link_k=self.config.cross_link_top_k,
+                        link_gate=self.config.link_gate,
+                        link_scale=self.config.link_weight_scale,
+                        shard_modes=(1, 0),
+                        link_accept_hint=self.config.link_accept_hint)
                 else:
-                    new_nodes_data.extend({
-                        "id": n.id, "content": n.content,
-                        "embedding": e.tolist(), "type": n.type,
-                        "salience": n.salience, "shard_key": n.shard_key,
-                        "timestamp": n.timestamp,
-                        "decay_pass": self._decay_pass,
-                    } for n, e in regular)
-            if new_nodes_data:
-                self.store.add_nodes(new_nodes_data, user_id=self.user_id)
+                    if arena_new:
+                        self.index.add(
+                            [self._q(n.id) for n, _ in arena_new],
+                            emb_matrix,
+                            [n.salience for n, _ in arena_new],
+                            [n.timestamp for n, _ in arena_new],
+                            [n.type for n, _ in arena_new],
+                            [n.shard_key or "default" for n, _ in arena_new],
+                            self.user_id,
+                            [n.is_super_node for n, _ in arena_new])
+                    if merge_ids:
+                        self.index.merge_touch([self._q(i) for i in merge_ids],
+                                               merge_sals)
 
-            if use_fused:
-                # The device already inserted every chain + gate-passing
-                # link edge inside the fused dispatch; only the host
-                # bookkeeping (shard placement, Edge objects, dirty marks)
-                # runs here — no second device round trip.
-                def _unq(qid: str) -> str:
-                    return qid.partition(":")[2]
+            with self.telemetry.span("write.apply"):
+                # Persist fresh nodes. arena_new is exactly the full-dim
+                # subset; the rare irregular rows ride along as dicts.
+                if arena_new or new_nodes_data:
+                    self._store_fresh_nodes(arena_new, emb_matrix,
+                                            new_nodes_data)
 
-                sim_edges = [Edge(source=_unq(s), target=_unq(t), weight=w)
-                             for sm in (1, 0)
-                             for s, t, w in fused_created.get(sm, [])]
-                self._register_edges_host(chain_edges + sim_edges)
-                n_cross = len(fused_created.get(0, []))
-                if n_cross:
-                    self._log(f"✓ Created {n_cross} cross-conversation links")
-            else:
-                # Both link scans (same-shard + any-shard) in one round trip.
-                link_cands = self.index.link_candidates_multi(
-                    [self._q(n) for n, _ in new_nodes], self.user_id,
-                    k=self.config.cross_link_top_k,
-                    shard_modes=(1, 0)) if new_nodes else {1: {}, 0: {}}
-                self._link_within_shards(new_nodes, link_cands[1],
-                                         chain=chain_edges)
-                self._link_to_existing_memories(new_nodes, link_cands[0])
+                if use_fused:
+                    # The device already inserted every chain + gate-passing
+                    # link edge inside the fused dispatch; only the host
+                    # bookkeeping (shard placement, Edge objects, dirty marks)
+                    # runs here — no second device round trip.
+                    def _unq(qid: str) -> str:
+                        return qid.partition(":")[2]
+
+                    sim_edges = [Edge(source=_unq(s), target=_unq(t), weight=w)
+                                 for sm in (1, 0)
+                                 for s, t, w in fused_created.get(sm, [])]
+                    self._register_edges_host(chain_edges + sim_edges)
+                    n_cross = len(fused_created.get(0, []))
+                    if n_cross:
+                        self._log(f"✓ Created {n_cross} cross-conversation links")
+                else:
+                    # Both link scans (same-shard + any-shard) in one round trip.
+                    link_cands = self.index.link_candidates_multi(
+                        [self._q(n) for n, _ in new_nodes], self.user_id,
+                        k=self.config.cross_link_top_k,
+                        shard_modes=(1, 0)) if new_nodes else {1: {}, 0: {}}
+                    self._link_within_shards(new_nodes, link_cands[1],
+                                             chain=chain_edges)
+                    self._link_to_existing_memories(new_nodes, link_cands[0])
         return new_nodes
 
     def _ingest_facts_dedup_fused(
@@ -1688,96 +1692,117 @@ Return JSON: {"memories": [{"content": "...", "type": "semantic|episodic|procedu
         only finishes id bookkeeping afterwards. Node ids are assigned from
         the readback's dup verdicts, so the counter advances exactly like
         the classic path (which only names surviving facts)."""
-        cfg = self.config
-        now = time.time()
-        shard_keys: List[str] = []
-        for mem, content, _ in staged:
-            sk = mem.get("topic") or self._infer_shard_key(content)
-            if sk == "other":
-                sk = self._infer_shard_key(content)
-            shard_keys.append(sk)
-        emb_matrix = np.stack([e for _, _, e in staged]).astype(np.float32)
-        saliences = [float(m.get("salience", 0.5)) for m, _, _ in staged]
-        types = [m.get("type", "semantic") for m, _, _ in staged]
-        pending = self.index.ingest_batch_dedup(
-            emb_matrix, saliences, [now] * len(staged), types, shard_keys,
-            tenant=self.user_id, dedup_gate=cfg.dedup_similarity,
-            chain_weight=cfg.chain_link_weight,
-            link_k=cfg.cross_link_top_k, link_gate=cfg.link_gate,
-            link_scale=cfg.link_weight_scale, shard_modes=(1, 0), now=now,
-            link_accept_hint=cfg.link_accept_hint)
+        # prepare: per-fact host work up to and with the ONE fused dispatch
+        # (index.ingest_batch_dedup: its lz.ingest.dedup_fused span lies
+        # inside); apply: the host maps, nodes, shards and the store after
+        # its readback
+        with self.telemetry.span("write.prepare"):
+            cfg = self.config
+            now = time.time()
+            shard_keys: List[str] = []
+            for mem, content, _ in staged:
+                sk = mem.get("topic") or self._infer_shard_key(content)
+                if sk == "other":
+                    sk = self._infer_shard_key(content)
+                shard_keys.append(sk)
+            emb_matrix = np.stack([e for _, _, e in staged]).astype(np.float32)
+            saliences = [float(m.get("salience", 0.5)) for m, _, _ in staged]
+            types = [m.get("type", "semantic") for m, _, _ in staged]
+            pending = self.index.ingest_batch_dedup(
+                emb_matrix, saliences, [now] * len(staged), types, shard_keys,
+                tenant=self.user_id, dedup_gate=cfg.dedup_similarity,
+                chain_weight=cfg.chain_link_weight,
+                link_k=cfg.cross_link_top_k, link_gate=cfg.link_gate,
+                link_scale=cfg.link_weight_scale, shard_modes=(1, 0), now=now,
+                link_accept_hint=cfg.link_accept_hint)
         if pending is None:
             return []
-        dup = pending["dup"]
-        ids = [None if dup[i] else self._q(self._generate_node_id())
-               for i in range(len(staged))]
-        _cands, created, merges, chains = \
-            self.index.commit_ingest_dedup(pending, ids)
+        with self.telemetry.span("write.apply"):
+            dup = pending["dup"]
+            ids = [None if dup[i] else self._q(self._generate_node_id())
+                   for i in range(len(staged))]
+            _cands, created, merges, chains = \
+                self.index.commit_ingest_dedup(pending, ids)
 
-        def _unq(qid: str) -> str:
-            return qid.partition(":")[2]
+            def _unq(qid: str) -> str:
+                return qid.partition(":")[2]
 
-        new_nodes: List[Tuple[str, str]] = []
-        survivors: List[Tuple[Node, np.ndarray]] = []
-        for i, (mem, content, e) in enumerate(staged):
-            if dup[i]:
-                continue
-            node = Node(
-                id=_unq(ids[i]),
-                content=content,
-                embedding=None,          # the arena owns the vector
-                type=types[i],
-                salience=saliences[i],
-                timestamp=now,
-                shard_key=shard_keys[i],
-            )
-            self._get_or_create_shard(shard_keys[i]).add_node(node)
-            survivors.append((node, e))
-            new_nodes.append((node.id, shard_keys[i]))
-        # Device-merged duplicates: mirror the arena's merge touch on the
-        # host copy (max salience, access+1, fresh last_accessed).
-        for i, target_qid in merges:
-            tgt = (self.buffer.get_node(_unq(target_qid))
-                   if target_qid else None)
-            if tgt is None:
-                continue
-            tgt.salience = max(tgt.salience, saliences[i])
-            tgt.last_accessed = now
-            tgt.access_count += 1
-            self._mark_dirty(tgt.id)
-            self._log(f"   (Merged semantic duplicate into {tgt.id})")
-        if survivors:
-            s_matrix = np.stack([e for _, e in survivors])
-            if hasattr(self.store, "add_nodes_columns"):
+            new_nodes: List[Tuple[str, str]] = []
+            survivors: List[Tuple[Node, np.ndarray]] = []
+            for i, (mem, content, e) in enumerate(staged):
+                if dup[i]:
+                    continue
+                node = Node(
+                    id=_unq(ids[i]),
+                    content=content,
+                    embedding=None,          # the arena owns the vector
+                    type=types[i],
+                    salience=saliences[i],
+                    timestamp=now,
+                    shard_key=shard_keys[i],
+                )
+                self._get_or_create_shard(shard_keys[i]).add_node(node)
+                survivors.append((node, e))
+                new_nodes.append((node.id, shard_keys[i]))
+            # Device-merged duplicates: mirror the arena's merge touch on the
+            # host copy (max salience, access+1, fresh last_accessed).
+            for i, target_qid in merges:
+                tgt = (self.buffer.get_node(_unq(target_qid))
+                       if target_qid else None)
+                if tgt is None:
+                    continue
+                tgt.salience = max(tgt.salience, saliences[i])
+                tgt.last_accessed = now
+                tgt.access_count += 1
+                self._mark_dirty(tgt.id)
+                self._log(f"   (Merged semantic duplicate into {tgt.id})")
+            if survivors:
+                self._store_fresh_nodes(survivors)
+            # Edges the device already inserted — host bookkeeping only.
+            chain_edges = [Edge(source=_unq(s), target=_unq(t),
+                                weight=cfg.chain_link_weight)
+                           for s, t in chains]
+            sim_edges = [Edge(source=_unq(s), target=_unq(t), weight=w)
+                         for sm in (1, 0) for s, t, w in created.get(sm, [])]
+            self._register_edges_host(chain_edges + sim_edges)
+            n_cross = len(created.get(0, []))
+            if n_cross:
+                self._log(f"✓ Created {n_cross} cross-conversation links")
+        return new_nodes
+
+    def _store_fresh_nodes(self, fresh: List[Tuple[Node, np.ndarray]],
+                           emb_matrix: Optional[np.ndarray] = None,
+                           irregular: Sequence[Dict] = ()) -> None:
+        """One mega-batch's new nodes to the store. ``fresh`` are the
+        full-width ones (exactly those the arena holds, so arena and store
+        never disagree about which nodes carry vectors): the columnar bulk
+        path when the store has it (one flat embedding buffer, no per-row
+        dicts — ingest hot path), dict rows for protocol-parity stores.
+        ``irregular`` are ready dict rows (wrong-width or missing vector)."""
+        with self.telemetry.span("store.add"):
+            rows = list(irregular)
+            if fresh and hasattr(self.store, "add_nodes_columns"):
                 self.store.add_nodes_columns(
-                    ids=[n.id for n, _ in survivors],
-                    contents=[n.content for n, _ in survivors],
-                    embeddings=s_matrix,
-                    types=[n.type for n, _ in survivors],
-                    saliences=[n.salience for n, _ in survivors],
-                    timestamps=[n.timestamp for n, _ in survivors],
-                    shard_keys=[n.shard_key or "" for n, _ in survivors],
+                    ids=[n.id for n, _ in fresh],
+                    contents=[n.content for n, _ in fresh],
+                    embeddings=(emb_matrix if emb_matrix is not None
+                                else np.stack([e for _, e in fresh])),
+                    types=[n.type for n, _ in fresh],
+                    saliences=[n.salience for n, _ in fresh],
+                    timestamps=[n.timestamp for n, _ in fresh],
+                    shard_keys=[n.shard_key or "" for n, _ in fresh],
                     decay_pass=self._decay_pass,
                     user_id=self.user_id)
             else:
-                self.store.add_nodes([{
+                rows.extend({
                     "id": n.id, "content": n.content,
                     "embedding": e.tolist(), "type": n.type,
                     "salience": n.salience, "shard_key": n.shard_key,
                     "timestamp": n.timestamp,
                     "decay_pass": self._decay_pass,
-                } for n, e in survivors], user_id=self.user_id)
-        # Edges the device already inserted — host bookkeeping only.
-        chain_edges = [Edge(source=_unq(s), target=_unq(t),
-                            weight=cfg.chain_link_weight)
-                       for s, t in chains]
-        sim_edges = [Edge(source=_unq(s), target=_unq(t), weight=w)
-                     for sm in (1, 0) for s, t, w in created.get(sm, [])]
-        self._register_edges_host(chain_edges + sim_edges)
-        n_cross = len(created.get(0, []))
-        if n_cross:
-            self._log(f"✓ Created {n_cross} cross-conversation links")
-        return new_nodes
+                } for n, e in fresh)
+            if rows:
+                self.store.add_nodes(rows, user_id=self.user_id)
 
     def _finish_consolidation(self, new_nodes: List[Tuple[str, str]],
                               start_time: float) -> None:
@@ -2188,16 +2213,17 @@ Example: {"preferences": "User prefers Python for data science.", "knowledge_dom
             self.background_executor.submit(lambda: None).result()
 
     def switch_user(self, new_user_id: str) -> None:
-        if self.conversation_active:
-            self.end_conversation()       # saves after consolidation
-            self._drain_background()
-        else:
-            self._drain_background()
-            self._save_to_persistence()
-        self.user_id = new_user_id
-        self._load_from_persistence()
-        self._setup_journal()          # per-user journal; replays crashed turns
-        self._setup_ingest_journal()   # per-user fact journal + replay
+        with self.telemetry.span("api.switch_user"):
+            if self.conversation_active:
+                self.end_conversation()       # saves after consolidation
+                self._drain_background()
+            else:
+                self._drain_background()
+                self._save_to_persistence()
+            self.user_id = new_user_id
+            self._load_from_persistence()
+            self._setup_journal()        # per-user journal; replays crashed turns
+            self._setup_ingest_journal()   # per-user fact journal + replay
         self._log(f"👤 Switched context to user: {new_user_id}")
 
     def get_all_users(self) -> List[str]:
@@ -2306,7 +2332,7 @@ Example: {"preferences": "User prefers Python for data science.", "knowledge_dom
         conversation touched, not graph size. Fallback path (injected/
         protocol-parity stores, or before the first sync): the reference's
         full delete-all + re-insert (memory_system.py:1275-1302)."""
-        with self._mutex:
+        with self.telemetry.span("store.save"), self._mutex:
             # queued boosts must land before _sync_from_arena pulls rows,
             # or boosted host copies get overwritten with stale values
             self._flush_pending_boosts_locked()
@@ -2443,7 +2469,7 @@ Example: {"preferences": "User prefers Python for data science.", "knowledge_dom
         }
 
     def _load_from_persistence(self) -> None:
-        with self._mutex:
+        with self.telemetry.span("store.load"), self._mutex:
             # Drop stale arena rows for this tenant, then rebuild host + arena.
             stale = list(self.index.tenant_nodes.get(self.user_id, set()))
             if stale:
@@ -3253,7 +3279,7 @@ STORAGE:
                 drained.extend(self._ingest_facts(facts))
             self._finish_consolidation(drained, start)
             if self._ingest_journal is not None:
-                self._ingest_journal.commit(commit_to)
+                self._commit_ingest_journal(commit_to)
         if getattr(self, "_pending_boosts", None):
             self._flush_pending_boosts()
         if hasattr(self, "store") and self.store is not None:

@@ -1645,29 +1645,36 @@ def _ingest_dedup_fused(
     probe_excl = jnp.arange(cap + 1) == cap
     link_excl = (jnp.zeros((cap + 1,), bool).at[rows].set(True)
                  | probe_excl)
-    flat = _ingest_scan_core(arena, qd, shard_id, probe_excl, link_excl,
-                             tenant, k, shard_modes)
-    p_s, p_r = flat[0][:, 0], flat[1][:, 0]
-    link_flat = flat[2:]
+    # named scopes: compile-time metadata for the device trace, as in
+    # ``_exact_two_tier`` (lz.link is the shared probe + link scan)
+    with jax.named_scope("lz.link"):
+        flat = _ingest_scan_core(arena, qd, shard_id, probe_excl, link_excl,
+                                 tenant, k, shard_modes)
+        p_s, p_r = flat[0][:, 0], flat[1][:, 0]
+        link_flat = flat[2:]
 
-    target, dup, chain_src = _dedup_resolve(qf, rows, valid, chain_gid,
-                                            p_s, p_r, dedup_gate, cap)
+    with jax.named_scope("lz.dedup"):
+        target, dup, chain_src = _dedup_resolve(qf, rows, valid, chain_gid,
+                                                p_s, p_r, dedup_gate, cap)
 
-    live_new = valid & ~dup
-    add_rows = jnp.where(live_new, rows, cap)
-    arena = _arena_add(arena, add_rows, emb, salience, timestamp, type_id,
-                       shard_id, tenant_id, is_super)
-    shadow = _shadow_scatter(shadow, add_rows, qd)
-    pq = _pq_scatter(pq, add_rows, qd)
-    touch_rows = jnp.where(dup, target, cap)
-    arena = _arena_merge_touch(arena, touch_rows, salience, now)
-    chain_live = chain_src >= 0
-    edges = _edges_add(edges, chain_slots, chain_src, rows,
-                       jnp.broadcast_to(chain_w, (b,)),
-                       jnp.ones((b,), jnp.int32), now, tenant, chain_live)
-    edges, outs = _gated_link_insert(edges, link_flat, link_pool, pool_len,
-                                     rows, live_new, now, tenant, link_gate,
-                                     link_scale, shard_modes)
+    with jax.named_scope("lz.scatter"):
+        live_new = valid & ~dup
+        add_rows = jnp.where(live_new, rows, cap)
+        arena = _arena_add(arena, add_rows, emb, salience, timestamp,
+                           type_id, shard_id, tenant_id, is_super)
+        shadow = _shadow_scatter(shadow, add_rows, qd)
+        pq = _pq_scatter(pq, add_rows, qd)
+        touch_rows = jnp.where(dup, target, cap)
+        arena = _arena_merge_touch(arena, touch_rows, salience, now)
+        chain_live = chain_src >= 0
+        edges = _edges_add(edges, chain_slots, chain_src, rows,
+                           jnp.broadcast_to(chain_w, (b,)),
+                           jnp.ones((b,), jnp.int32), now, tenant,
+                           chain_live)
+        edges, outs = _gated_link_insert(edges, link_flat, link_pool,
+                                         pool_len, rows, live_new, now,
+                                         tenant, link_gate, link_scale,
+                                         shard_modes)
     if ivf is not None:
         # Online IVF maintenance (ISSUE 12): the SAME dispatch scores the
         # surviving facts against the centroids, appends them to their
@@ -2216,14 +2223,16 @@ def _gate_and_boost_rows(state: ArenaState, csr_indptr, csr_nbr, gate_s,
     ``cap_take`` stays the STATIC slice ceiling, and each query's own cap
     masks within it, so one kernel serves mixed per-request caps."""
     cap = state.capacity
-    fast = gate_c & (gate_s > super_gate)
-    do_boost = boost_c & valid_c & ~fast                  # [C]
-    take = (ann_s[:, :cap_take] > NEG_INF / 2) & do_boost[:, None]
-    if cap_c is not None:
-        take = take & (jnp.arange(cap_take)[None, :] < cap_c[:, None])
-    acc_rows = jnp.where(take, ann_r[:, :cap_take], cap)  # [C, cap_take]
-    nbr_rows = _csr_neighbor_rows(state, csr_indptr, csr_nbr, acc_rows,
-                                  tenant_c, max_nbr)
+    with jax.named_scope("lz.gate"):
+        fast = gate_c & (gate_s > super_gate)
+        do_boost = boost_c & valid_c & ~fast              # [C]
+        take = (ann_s[:, :cap_take] > NEG_INF / 2) & do_boost[:, None]
+        if cap_c is not None:
+            take = take & (jnp.arange(cap_take)[None, :] < cap_c[:, None])
+        acc_rows = jnp.where(take, ann_r[:, :cap_take], cap)  # [C, cap_take]
+    with jax.named_scope("lz.csr"):
+        nbr_rows = _csr_neighbor_rows(state, csr_indptr, csr_nbr, acc_rows,
+                                      tenant_c, max_nbr)
     return fast, acc_rows, nbr_rows
 
 
@@ -2239,18 +2248,24 @@ def _exact_two_tier(state: ArenaState, q_c: jax.Array, tenant_c: jax.Array,
     feed BOTH the packed readback and the boost gather chain; without it
     XLA (CPU at least) splits the consumers into two full [C, cap] sorts —
     measured 2.4× on the whole fused program at 65k rows."""
-    qn = normalize(q_c).astype(state.emb.dtype)
-    scores = nt_dot(qn, state.emb)                        # [C, pool rows] f32
-    alive_p = _pool_mask(state, state.alive)
-    ten_p = _pool_col(state, state.tenant_id)
-    alive_t = alive_p[None, :] & (ten_p[None, :] == tenant_c[:, None])
-    sup = _pool_col(state, state.is_super)[None, :]
-    gate_s, gate_r = jax.lax.top_k(
-        jnp.where(alive_t & sup, scores, NEG_INF), k_gate)
-    ann_s, ann_r = jax.lax.top_k(
-        jnp.where(alive_t & ~sup, scores, NEG_INF), k_ann)
-    gate_r = _pool_to_logical(state, gate_r)
-    ann_r = _pool_to_logical(state, ann_r)
+    # The named scopes (here and in the tail below) are compile-time
+    # metadata on the program's operations: a device trace can then say
+    # which phase an operation belongs to. They cost nothing at run time.
+    with jax.named_scope("lz.norms"):
+        qn = normalize(q_c).astype(state.emb.dtype)
+    with jax.named_scope("lz.scan"):
+        scores = nt_dot(qn, state.emb)                    # [C, pool rows] f32
+        alive_p = _pool_mask(state, state.alive)
+        ten_p = _pool_col(state, state.tenant_id)
+        alive_t = alive_p[None, :] & (ten_p[None, :] == tenant_c[:, None])
+        sup = _pool_col(state, state.is_super)[None, :]
+    with jax.named_scope("lz.topk"):
+        gate_s, gate_r = jax.lax.top_k(
+            jnp.where(alive_t & sup, scores, NEG_INF), k_gate)
+        ann_s, ann_r = jax.lax.top_k(
+            jnp.where(alive_t & ~sup, scores, NEG_INF), k_ann)
+        gate_r = _pool_to_logical(state, gate_r)
+        ann_r = _pool_to_logical(state, ann_r)
     return jax.lax.optimization_barrier((gate_s, gate_r, ann_s, ann_r))
 
 
@@ -2607,18 +2622,19 @@ def _pack_retrieval(gate_s, gate_r, ann_s, ann_r, fast, dup=None, acc=None,
     all-off), and the semantic-cache verdict (``sem``; zero when the ring
     is absent)."""
     q = gate_s.shape[0]
-    zeros = jnp.zeros((q,), jnp.int32)
-    n_live = (ann_s > NEG_INF / 2).sum(axis=-1).astype(jnp.int32)
-    dup = zeros if dup is None else dup.astype(jnp.int32)
-    acc = zeros if acc is None else acc.astype(jnp.int32)
-    nbr = zeros if nbr is None else nbr.astype(jnp.int32)
-    sem = zeros if sem is None else sem.astype(jnp.int32)
-    return jnp.concatenate([
-        _bitcast_i32(gate_s)[:, None], gate_r.astype(jnp.int32)[:, None],
-        _bitcast_i32(ann_s), ann_r.astype(jnp.int32),
-        fast.astype(jnp.int32)[:, None],
-        n_live[:, None], dup[:, None], acc[:, None], nbr[:, None],
-        sem[:, None]], axis=1)
+    with jax.named_scope("lz.pack"):
+        zeros = jnp.zeros((q,), jnp.int32)
+        n_live = (ann_s > NEG_INF / 2).sum(axis=-1).astype(jnp.int32)
+        dup = zeros if dup is None else dup.astype(jnp.int32)
+        acc = zeros if acc is None else acc.astype(jnp.int32)
+        nbr = zeros if nbr is None else nbr.astype(jnp.int32)
+        sem = zeros if sem is None else sem.astype(jnp.int32)
+        return jnp.concatenate([
+            _bitcast_i32(gate_s)[:, None], gate_r.astype(jnp.int32)[:, None],
+            _bitcast_i32(ann_s), ann_r.astype(jnp.int32),
+            fast.astype(jnp.int32)[:, None],
+            n_live[:, None], dup[:, None], acc[:, None], nbr[:, None],
+            sem[:, None]], axis=1)
 
 
 def _boost_row_counts(capacity: int, acc_rows: jax.Array,

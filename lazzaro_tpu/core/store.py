@@ -43,6 +43,8 @@ import numpy as np
 import pyarrow as pa
 import pyarrow.parquet as pq
 
+from lazzaro_tpu.utils.telemetry import default_registry, file_op
+
 _NODE_SCHEMA = pa.schema([
     ("id", pa.string()),
     ("user_id", pa.string()),
@@ -90,17 +92,23 @@ _COMPACT_MAX_SEGMENTS = 16
 _COMPACT_MIN_ROWS = 4096
 
 
-def _atomic_write(path: str, data: bytes) -> None:
+def _atomic_write(path: str, data: bytes, telemetry=None) -> None:
+    """The one funnel every durable write of the store goes through (temp
+    file, write, rename): one ``lz.store.io`` span and one
+    ``store.file_ops{op="write"}`` count each."""
+    tel = telemetry if telemetry is not None else default_registry()
     d = os.path.dirname(path)
-    fd, tmp = tempfile.mkstemp(dir=d, prefix=".tmp-")
-    try:
-        with os.fdopen(fd, "wb") as f:
-            f.write(data)
-        os.replace(tmp, path)
-    except BaseException:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
-        raise
+    with file_op(tel, "store", "write"):
+        fd, tmp = tempfile.mkstemp(dir=d, prefix=".tmp-")
+        try:
+            with os.fdopen(fd, "wb") as f:
+                f.write(data)
+            os.replace(tmp, path)
+        except BaseException:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+            raise
+    tel.bump("store.bytes_written", len(data))
 
 
 def _table_bytes(table: pa.Table) -> bytes:
@@ -115,8 +123,12 @@ class ArrowStore:
     atomically-replaced manifest, retrying once if compaction swaps files
     underneath them."""
 
-    def __init__(self, db_dir: str = "db"):
+    def __init__(self, db_dir: str = "db", telemetry=None):
         self.db_dir = db_dir
+        # every file operation below is one ``lz.store.io`` span and one
+        # ``store.file_ops{op}`` count in this registry
+        self.telemetry = (telemetry if telemetry is not None
+                          else default_registry())
         os.makedirs(db_dir, exist_ok=True)
         self._lock = threading.Lock()
         self._closed = False
@@ -143,34 +155,60 @@ class ArrowStore:
     def _version_path(self) -> str:
         return os.path.join(self.db_dir, "VERSION")
 
+    def _io(self, op: str):
+        return file_op(self.telemetry, "store", op)
+
+    def _write(self, path: str, data: bytes) -> None:
+        _atomic_write(path, data, self.telemetry)
+
+    def _read_json(self, path: str, op: str = "read_json"):
+        """The parsed file, or None when it is missing or torn."""
+        with self._io(op):
+            try:
+                with open(path) as f:
+                    return json.load(f)
+            except (FileNotFoundError, json.JSONDecodeError):
+                return None
+
+    def _read_table(self, name: str) -> pa.Table:
+        with self._io("read_table"):
+            return pq.read_table(os.path.join(self.db_dir, name))
+
+    def _unlink(self, path: str) -> None:
+        with self._io("unlink"):
+            try:
+                os.unlink(path)
+            except FileNotFoundError:
+                pass
+
     def _bump_version(self) -> None:
         v = self.get_latest_version() + 1
-        _atomic_write(self._version_path(), str(v).encode())
+        self._write(self._version_path(), str(v).encode())
 
     def get_latest_version(self) -> int:
-        try:
-            with open(self._version_path()) as f:
-                return int(f.read().strip())
-        except (FileNotFoundError, ValueError):
-            return 0
+        with self._io("read_version"):
+            try:
+                with open(self._version_path()) as f:
+                    return int(f.read().strip())
+            except (FileNotFoundError, ValueError):
+                return 0
 
     # ----------------------------------------------------- manifest handling
     def _load_manifest(self, table: str, user_id: str) -> Optional[Dict[str, Any]]:
         """Current manifest, or a synthesized one for the legacy single-file
         layout (``{table}__{user}.parquet`` with no manifest)."""
-        try:
-            with open(self._manifest_path(table, user_id)) as f:
-                return json.load(f)
-        except (FileNotFoundError, json.JSONDecodeError):
-            pass
+        man = self._read_json(self._manifest_path(table, user_id),
+                              "read_manifest")
+        if man is not None:
+            return man
         legacy = self._stem(table, user_id) + ".parquet"
         if os.path.exists(legacy):
             return {"base": os.path.basename(legacy), "segments": [], "gen": 0}
         return None
 
     def _store_manifest(self, table: str, user_id: str, man: Dict[str, Any]) -> None:
-        _atomic_write(self._manifest_path(table, user_id),
-                      json.dumps(man).encode())
+        self._write(self._manifest_path(table, user_id),
+                    json.dumps(man).encode())
 
     def _conform(self, t: pa.Table, schema: pa.Schema) -> pa.Table:
         """Add any missing columns (legacy files predate decay_pass/_deleted)
@@ -292,8 +330,8 @@ class ArrowStore:
                 parts = []
                 names = ([man["base"]] if man.get("base") else []) + man["segments"]
                 for name in names:
-                    t = pq.read_table(os.path.join(self.db_dir, name))
-                    parts.append(self._conform(t, schema))
+                    parts.append(self._conform(self._read_table(name),
+                                               schema))
             except FileNotFoundError as e:
                 last_err = e
                 continue
@@ -311,7 +349,7 @@ class ArrowStore:
         man = self._load_manifest(table, user_id) or {"base": None, "segments": [], "gen": 0}
         gen = int(man["gen"]) + 1
         name = f"{os.path.basename(self._stem(table, user_id))}.seg-{gen:06d}.parquet"
-        _atomic_write(os.path.join(self.db_dir, name), _table_bytes(rows_table))
+        self._write(os.path.join(self.db_dir, name), _table_bytes(rows_table))
         man["segments"].append(name)
         man["gen"] = gen
         self._store_manifest(table, user_id, man)
@@ -320,10 +358,12 @@ class ArrowStore:
 
     def _maybe_compact(self, table: str, user_id: str, man: Dict[str, Any]) -> None:
         def rows_of(name):
-            try:
-                return pq.read_metadata(os.path.join(self.db_dir, name)).num_rows
-            except FileNotFoundError:
-                return 0
+            with self._io("read_meta"):
+                try:
+                    return pq.read_metadata(
+                        os.path.join(self.db_dir, name)).num_rows
+                except FileNotFoundError:
+                    return 0
 
         segs = man["segments"]
         seg_rows = sum(rows_of(name) for name in segs)
@@ -344,8 +384,7 @@ class ArrowStore:
         parts = []
         for name in man["segments"]:
             try:
-                parts.append(self._conform(
-                    pq.read_table(os.path.join(self.db_dir, name)), schema))
+                parts.append(self._conform(self._read_table(name), schema))
             except FileNotFoundError:
                 pass
         if not parts:
@@ -357,15 +396,12 @@ class ArrowStore:
         old = list(man["segments"])
         gen = int(man["gen"]) + 1
         name = f"{os.path.basename(self._stem(table, user_id))}.seg-{gen:06d}.parquet"
-        _atomic_write(os.path.join(self.db_dir, name), _table_bytes(t))
+        self._write(os.path.join(self.db_dir, name), _table_bytes(t))
         man["segments"] = [name]
         man["gen"] = gen
         self._store_manifest(table, user_id, man)
         for old_name in old:
-            try:
-                os.unlink(os.path.join(self.db_dir, old_name))
-            except FileNotFoundError:
-                pass
+            self._unlink(os.path.join(self.db_dir, old_name))
 
     def _compact(self, table: str, user_id: str, man: Dict[str, Any]) -> None:
         merged = self._read_merged(table, user_id)
@@ -375,14 +411,11 @@ class ArrowStore:
             new_man = {"base": None, "segments": [], "gen": gen}
         else:
             name = f"{os.path.basename(self._stem(table, user_id))}.base-{gen:06d}.parquet"
-            _atomic_write(os.path.join(self.db_dir, name), _table_bytes(merged))
+            self._write(os.path.join(self.db_dir, name), _table_bytes(merged))
             new_man = {"base": name, "segments": [], "gen": gen}
         self._store_manifest(table, user_id, new_man)
         for name in old:
-            try:
-                os.unlink(os.path.join(self.db_dir, name))
-            except FileNotFoundError:
-                pass
+            self._unlink(os.path.join(self.db_dir, name))
 
     def compact(self, user_id: str = "default") -> None:
         """Fold all delta segments into fresh bases (both tables)."""
@@ -398,16 +431,10 @@ class ArrowStore:
         man = self._load_manifest(table, user_id)
         if man is not None:
             for name in ([man["base"]] if man.get("base") else []) + man["segments"]:
-                try:
-                    os.unlink(os.path.join(self.db_dir, name))
-                except FileNotFoundError:
-                    pass
+                self._unlink(os.path.join(self.db_dir, name))
         for path in (self._manifest_path(table, user_id),
                      self._stem(table, user_id) + ".parquet"):
-            try:
-                os.unlink(path)
-            except FileNotFoundError:
-                pass
+            self._unlink(path)
 
     # ----------------------------------------------------------------- nodes
     @staticmethod
@@ -675,16 +702,12 @@ class ArrowStore:
         with self._lock:
             payload = json.dumps({"user_id": user_id, "data": profile,
                                   "updated_at": time.time()}).encode()
-            _atomic_write(self._stem("profiles", user_id) + ".json", payload)
+            self._write(self._stem("profiles", user_id) + ".json", payload)
             self._bump_version()
 
     def load_profile(self, user_id: str = "default") -> Optional[Dict[str, Any]]:
-        path = self._stem("profiles", user_id) + ".json"
-        try:
-            with open(path) as f:
-                return json.load(f).get("data")
-        except (FileNotFoundError, json.JSONDecodeError):
-            return None
+        prof = self._read_json(self._stem("profiles", user_id) + ".json")
+        return prof.get("data") if prof is not None else None
 
     # -------------------------------------------------------------- sys meta
     def save_sys_meta(self, meta: Dict[str, Any], user_id: str = "default") -> None:
@@ -692,16 +715,13 @@ class ArrowStore:
         Presence of this method is how the orchestrator detects that the
         store supports incremental persistence."""
         with self._lock:
-            _atomic_write(self._stem("sysmeta", user_id) + ".json",
-                          json.dumps(meta).encode())
+            self._write(self._stem("sysmeta", user_id) + ".json",
+                        json.dumps(meta).encode())
             self._bump_version()
 
     def load_sys_meta(self, user_id: str = "default") -> Dict[str, Any]:
-        try:
-            with open(self._stem("sysmeta", user_id) + ".json") as f:
-                return json.load(f)
-        except (FileNotFoundError, json.JSONDecodeError):
-            return {}
+        meta = self._read_json(self._stem("sysmeta", user_id) + ".json")
+        return meta if meta is not None else {}
 
     # ------------------------------------------------------------------ misc
     def get_all_users(self) -> List[str]:

@@ -25,6 +25,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from lazzaro_tpu.native.build import build, load, so_path  # noqa: F401
+from lazzaro_tpu.utils.telemetry import default_registry, file_op
 
 
 def available() -> bool:
@@ -151,14 +152,31 @@ class WriteAheadLog:
 
     _MAGIC = 0x4C5A5731
 
-    def __init__(self, path: str, fsync: bool = True):
+    def __init__(self, path: str, fsync: bool = True, telemetry=None):
         self.path = path
         self.fsync = fsync
+        # append / replay / reset are the log's file operations: one
+        # ``lz.journal.io`` span and one ``store.file_ops{op}`` count each
+        self.telemetry = (telemetry if telemetry is not None
+                          else default_registry())
         parent = os.path.dirname(path)
         if parent:
             os.makedirs(parent, exist_ok=True)
 
     def append(self, payload: bytes) -> None:
+        with file_op(self.telemetry, "journal", "wal_append"):
+            self._append(payload)
+        self.telemetry.bump("store.bytes_written", len(payload))
+
+    def replay(self) -> List[bytes]:
+        with file_op(self.telemetry, "journal", "wal_replay"):
+            return self._replay()
+
+    def reset(self) -> None:
+        with file_op(self.telemetry, "journal", "wal_reset"):
+            self._reset()
+
+    def _append(self, payload: bytes) -> None:
         lib = load()
         if lib is not None:
             buf = np.frombuffer(payload or b"\0", np.uint8).copy()
@@ -179,7 +197,7 @@ class WriteAheadLog:
             if self.fsync:
                 os.fsync(f.fileno())
 
-    def replay(self) -> List[bytes]:
+    def _replay(self) -> List[bytes]:
         lib = load()
         if lib is not None:
             out_len = ctypes.c_int64()
@@ -215,7 +233,7 @@ class WriteAheadLog:
             pos += 12 + ln
         return records
 
-    def reset(self) -> None:
+    def _reset(self) -> None:
         lib = load()
         if lib is not None:
             rc = lib.lz_wal_reset(self.path.encode())

@@ -44,7 +44,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from lazzaro_tpu.core import state as S
@@ -588,8 +587,8 @@ class ShardedMemoryIndex:
         self._maybe_record_ingest_hbm(kern, dev_args, with_shadow, b,
                                       with_ivf=with_ivf, with_pq=with_pq)
         tel = self.telemetry
-        t0 = time.perf_counter()
-        with TraceAnnotation("lz.ingest.pod_fused"):
+        with tel.span("ingest.pod_fused", timer="ingest.dispatch_ms",
+                      labels={"kind": "pod_fused"}):
             with self._state_lock:
                 arena, edges = self._arena, self._edge_state
                 shadow = self._int8_shadow if with_shadow else None
@@ -631,8 +630,6 @@ class ShardedMemoryIndex:
                 self._arena = new_arena
                 self._edge_state = new_edges
             host = fetch_packed(*flat)          # the ONE readback
-        tel.record("ingest.dispatch_ms", (time.perf_counter() - t0) * 1e3,
-                   labels={"kind": "pod_fused"})
         tel.bump("ingest.dispatches", labels={"kind": "pod_fused"})
         return self._ingest_finish_host(
             ids, rows, host, chain_slot_list, link_pool_list,
@@ -1605,60 +1602,56 @@ class ShardedMemoryIndex:
         nq = len(reqs)
         if nq == 0 or not self.id_to_row:
             return results
-        dim = self.dim
-        ragged = self.serve_ragged and self.serve_fused
-        cap_s = self.cap_take
-        if ragged:
-            # static per-mode k ceiling: the kernel key never depends on
-            # the batch's k mix (ISSUE 7)
-            k_bucket = int(min(max(self.serve_k_max, cap_s, 1),
-                               self.capacity))
-            cap_s = min(self.cap_take, k_bucket)
-        q = np.zeros((nq, dim), np.float32)
-        valid = np.zeros((nq,), bool)
-        tids = np.full((nq,), -1, np.int32)
-        gate_on = np.zeros((nq,), bool)
-        boost_on = np.zeros((nq,), bool)
-        k_arr = np.zeros((nq,), np.int32)
-        cap_arr = np.zeros((nq,), np.int32)
-        for i, r in enumerate(reqs):
-            v = np.asarray(r.query, np.float32).reshape(-1)
-            tid = self._tenants.get(r.tenant)
-            if v.size != dim or tid is None:
-                continue                    # tenant -1 matches no rows
-            q[i] = v
-            valid[i] = True
-            tids[i] = tid
-            gate_on[i] = bool(getattr(r, "gate_enabled", False))
-            boost_on[i] = bool(getattr(r, "boost", False))
-            if ragged:
-                k_arr[i] = min(max(int(r.k), cap_s, 1), k_bucket)
-                rc = getattr(r, "cap_take", None)
-                cap_arr[i] = min(int(rc) if rc else cap_s, cap_s)
-        if not valid.any():
-            return results
-        if not ragged:
-            k_req = max((min(int(r.k), self.capacity)
-                         for i, r in enumerate(reqs) if valid[i]),
-                        default=1)
-            k_eff = max(self.cap_take, k_req, 1)
-            k_bucket = min(max(next_pow2(k_eff), 1), self.capacity)
-        # Ragged batches bucket LINEARLY (granularity slots of worst-case
-        # padding) instead of to the next power of two (~50% worst case —
-        # the pow2 padding tax this PR kills).
-        qp = (pad_to_bucket(q, self.serve_pad_granularity) if ragged
-              else pad_to_pow2(q))
-        pad_n = qp.shape[0]
         tel = self.telemetry
-        # Coalesce/pad inflation: padded kernel slots vs live requests,
-        # kernel k (max-k bucket, or the ragged ceiling).
+        with tel.span("index.pack"):
+            dim = self.dim
+            ragged = self.serve_ragged and self.serve_fused
+            cap_s = self.cap_take
+            if ragged:
+                # static per-mode k ceiling: the kernel key never depends on
+                # the batch's k mix (ISSUE 7)
+                k_bucket = int(min(max(self.serve_k_max, cap_s, 1),
+                                   self.capacity))
+                cap_s = min(self.cap_take, k_bucket)
+            q = np.zeros((nq, dim), np.float32)
+            valid = np.zeros((nq,), bool)
+            tids = np.full((nq,), -1, np.int32)
+            gate_on = np.zeros((nq,), bool)
+            boost_on = np.zeros((nq,), bool)
+            k_arr = np.zeros((nq,), np.int32)
+            cap_arr = np.zeros((nq,), np.int32)
+            for i, r in enumerate(reqs):
+                v = np.asarray(r.query, np.float32).reshape(-1)
+                tid = self._tenants.get(r.tenant)
+                if v.size != dim or tid is None:
+                    continue                    # tenant -1 matches no rows
+                q[i] = v
+                valid[i] = True
+                tids[i] = tid
+                gate_on[i] = bool(getattr(r, "gate_enabled", False))
+                boost_on[i] = bool(getattr(r, "boost", False))
+                if ragged:
+                    k_arr[i] = min(max(int(r.k), cap_s, 1), k_bucket)
+                    rc = getattr(r, "cap_take", None)
+                    cap_arr[i] = min(int(rc) if rc else cap_s, cap_s)
+            if not valid.any():
+                return results
+            if not ragged:
+                k_req = max((min(int(r.k), self.capacity)
+                             for i, r in enumerate(reqs) if valid[i]),
+                            default=1)
+                k_eff = max(self.cap_take, k_req, 1)
+                k_bucket = min(max(next_pow2(k_eff), 1), self.capacity)
+            # Ragged batches bucket LINEARLY (granularity slots of worst-case
+            # padding) instead of to the next power of two (~50% worst case —
+            # the pow2 padding tax this PR kills).
+            qp = (pad_to_bucket(q, self.serve_pad_granularity) if ragged
+                  else pad_to_pow2(q))
+            pad_n = qp.shape[0]
+        # Coalesce/pad inflation: padded kernel slots vs live requests.
         tel.bump("serve.live_requests", nq)
         tel.bump("serve.padded_slots", pad_n)
         tel.gauge("serve.batch_occupancy", nq / pad_n)
-        tel.record("serve.k_bucket", k_bucket)
-        if ragged:
-            for kv in k_arr[valid]:
-                tel.record("serve.k_request", float(kv))
 
         def padb(arr, fill=False, dt=bool):
             out = np.full((pad_n,), fill, dt)
@@ -1669,113 +1662,114 @@ class ShardedMemoryIndex:
             return self._serve_classic(reqs, results, valid, qp, tids,
                                        k_bucket)
 
-        tm = self.tiering
-        tiered = tm is not None and tm.cold_count > 0
-        pq_tabs = None if tiered else self._pq_tables(k_bucket)
-        ivf_tabs = (None if tiered or pq_tabs is not None
-                    else self._ivf_tables(k_bucket))
-        use_quant = self.int8_serving
-        if tiered:
-            # full-corpus int8 coarse scan + tier-aware rescore: the only
-            # structure that still covers demoted rows (ISSUE 8)
-            nprobe = 0
-            mode = "tiered"
-            ivf_tabs = None
-            tables = (*self._int8_shadow_for(), tm.cold_mask_dev())
-        elif pq_tabs is not None:
-            # m-byte ADC coarse over the shared IVF candidate assembly +
-            # exact rescore — the smallest-resident pod mode (ISSUE 16)
-            book_cent, codes_sh, cent, mem_sh, ext_sh, nprobe = pq_tabs
-            mode = "pq"
-            ivf_tabs = pq_tabs       # nprobe sidecar routing below
-            tables = (book_cent, codes_sh, cent, mem_sh, ext_sh)
-        elif ivf_tabs is not None:
-            cent, mem_sh, ext_sh, nprobe = ivf_tabs
-            mode = "ivf_quant" if use_quant else "ivf"
-            tables = ((*self._int8_shadow_for(), cent, mem_sh, ext_sh)
-                      if use_quant else (cent, mem_sh, ext_sh))
-        else:
-            nprobe = 0
-            mode = "quant" if use_quant else "exact"
-            tables = self._int8_shadow_for() if use_quant else ()
-        # Semantic query cache (ISSUE 20): the replicated ring rides the
-        # SAME distributed dispatch. Tiered pods cache the k+slack
-        # candidate window, so their guard adds the slack.
-        semh = self._sem_host
-        sem_state = None
-        if semh is not None and mode in S.SEM_MODE_IDS:
-            win = k_bucket + (self.coarse_slack if tiered else 0)
-            if win <= semh.width:
-                sem_state = semh.tuple_for(mode)
-        sem_tail = () if sem_state is None else (sem_state,)
-        kern = self._fused_kernels(mode, k_bucket, nprobe, ragged=ragged,
-                                   scan_chunk=scan_chunk,
-                                   sem=sem_state is not None)
-        csr_i, csr_n = self._csr_sharded()
-        args = (tables, csr_i, csr_n, jnp.asarray(qp),
-                jnp.asarray(padb(valid)),
-                jnp.asarray(padb(tids, -1, np.int32)),
-                jnp.asarray(padb(gate_on)))
-        if ragged:
-            # per-query sidecar columns (replicated over the mesh): k,
-            # retrieval cap, and — for the IVF modes — probe width
-            k_dev = jnp.asarray(padb(k_arr, 0, np.int32))
-            capq_dev = jnp.asarray(padb(cap_arr, 0, np.int32))
-            if ivf_tabs is not None:
-                np_arr = np.zeros((nq,), np.int32)
-                for i, r in enumerate(reqs):
-                    rn = getattr(r, "nprobe", None)
-                    np_arr[i] = (min(max(int(rn), 1), nprobe) if rn
-                                 else nprobe)
-                np_arr[~valid] = 0
+        with tel.span("index.stage"):
+            tm = self.tiering
+            tiered = tm is not None and tm.cold_count > 0
+            pq_tabs = None if tiered else self._pq_tables(k_bucket)
+            ivf_tabs = (None if tiered or pq_tabs is not None
+                        else self._ivf_tables(k_bucket))
+            use_quant = self.int8_serving
+            if tiered:
+                # full-corpus int8 coarse scan + tier-aware rescore: the only
+                # structure that still covers demoted rows (ISSUE 8)
+                nprobe = 0
+                mode = "tiered"
+                ivf_tabs = None
+                tables = (*self._int8_shadow_for(), tm.cold_mask_dev())
+            elif pq_tabs is not None:
+                # m-byte ADC coarse over the shared IVF candidate assembly +
+                # exact rescore — the smallest-resident pod mode (ISSUE 16)
+                book_cent, codes_sh, cent, mem_sh, ext_sh, nprobe = pq_tabs
+                mode = "pq"
+                ivf_tabs = pq_tabs       # nprobe sidecar routing below
+                tables = (book_cent, codes_sh, cent, mem_sh, ext_sh)
+            elif ivf_tabs is not None:
+                cent, mem_sh, ext_sh, nprobe = ivf_tabs
+                mode = "ivf_quant" if use_quant else "ivf"
+                tables = ((*self._int8_shadow_for(), cent, mem_sh, ext_sh)
+                          if use_quant else (cent, mem_sh, ext_sh))
             else:
-                np_arr = np.zeros((nq,), np.int32)
-            npq_dev = jnp.asarray(padb(np_arr, 0, np.int32))
-            read_extra = (k_dev, npq_dev, jnp.float32(self.super_gate))
-        else:
-            read_extra = (jnp.float32(self.super_gate),)
-        self._maybe_record_hbm(mode, kern, args, k_bucket,
-                               read_extra=read_extra + sem_tail,
-                               ragged=ragged)
-        # Fault point "plan.oom" (ISSUE 11): an HBM allocation failure the
-        # admission plan missed; serve_requests answers with one replan.
-        faults.fire("plan.oom", mode=f"pod_{mode}", batch=pad_n)
-        t0 = time.perf_counter()
-        with TraceAnnotation(f"lz.serve.pod_{mode}"):
-            if boost_on.any():
-                now_rel = time.time() - self.epoch
-                with self._state_lock:
-                    cur = self._arena
-                    sole = (not force_copy
-                            and sys.getrefcount(cur) <= self._SOLE_REFS)
-                    boost_extra = ((jnp.asarray(padb(boost_on)), k_dev,
-                                    capq_dev, npq_dev) if ragged
-                                   else (jnp.asarray(padb(boost_on)),))
-                    out = self._guarded(
-                        lambda fn: self._dispatch(
-                            fn, cur, *args, *boost_extra,
-                            jnp.float32(now_rel),
-                            jnp.float32(self.super_gate),
-                            jnp.float32(self.acc_boost),
-                            jnp.float32(self.nbr_boost), *sem_tail),
-                        kern.serve, kern.serve_copy, sole, (cur,),
-                        "serve_pod")
-                    if sem_state is not None:
-                        new_state, sem_ring2, packed = out
-                    else:
-                        new_state, packed = out
-                    del cur
-                    self.state = new_state
-            else:
-                out = self._dispatch(kern.read, self.state, *args,
-                                     *read_extra, *sem_tail)
-                if sem_state is not None:
-                    sem_ring2, packed = out
+                nprobe = 0
+                mode = "quant" if use_quant else "exact"
+                tables = self._int8_shadow_for() if use_quant else ()
+            # Semantic query cache (ISSUE 20): the replicated ring rides the
+            # SAME distributed dispatch. Tiered pods cache the k+slack
+            # candidate window, so their guard adds the slack.
+            semh = self._sem_host
+            sem_state = None
+            if semh is not None and mode in S.SEM_MODE_IDS:
+                win = k_bucket + (self.coarse_slack if tiered else 0)
+                if win <= semh.width:
+                    sem_state = semh.tuple_for(mode)
+            sem_tail = () if sem_state is None else (sem_state,)
+            kern = self._fused_kernels(mode, k_bucket, nprobe, ragged=ragged,
+                                       scan_chunk=scan_chunk,
+                                       sem=sem_state is not None)
+            csr_i, csr_n = self._csr_sharded()
+            args = (tables, csr_i, csr_n, jnp.asarray(qp),
+                    jnp.asarray(padb(valid)),
+                    jnp.asarray(padb(tids, -1, np.int32)),
+                    jnp.asarray(padb(gate_on)))
+            if ragged:
+                # per-query sidecar columns (replicated over the mesh): k,
+                # retrieval cap, and — for the IVF modes — probe width
+                k_dev = jnp.asarray(padb(k_arr, 0, np.int32))
+                capq_dev = jnp.asarray(padb(cap_arr, 0, np.int32))
+                if ivf_tabs is not None:
+                    np_arr = np.zeros((nq,), np.int32)
+                    for i, r in enumerate(reqs):
+                        rn = getattr(r, "nprobe", None)
+                        np_arr[i] = (min(max(int(rn), 1), nprobe) if rn
+                                     else nprobe)
+                    np_arr[~valid] = 0
                 else:
-                    packed = out
-            host = np.asarray(packed)          # the ONE readback
-        tel.record("serve.dispatch_ms", (time.perf_counter() - t0) * 1e3,
-                   labels={"mode": f"pod_{mode}"})
+                    np_arr = np.zeros((nq,), np.int32)
+                npq_dev = jnp.asarray(padb(np_arr, 0, np.int32))
+                read_extra = (k_dev, npq_dev, jnp.float32(self.super_gate))
+            else:
+                read_extra = (jnp.float32(self.super_gate),)
+            self._maybe_record_hbm(mode, kern, args, k_bucket,
+                                   read_extra=read_extra + sem_tail,
+                                   ragged=ragged)
+            # Fault point "plan.oom" (ISSUE 11): an HBM allocation failure the
+            # admission plan missed; serve_requests answers with one replan.
+            faults.fire("plan.oom", mode=f"pod_{mode}", batch=pad_n)
+        with tel.span("serve.pod_" + mode, timer="serve.dispatch_ms",
+                      labels={"mode": "pod_" + mode}):
+            with tel.span("dispatch.launch"):
+                if boost_on.any():
+                    now_rel = time.time() - self.epoch
+                    with self._state_lock:
+                        cur = self._arena
+                        sole = (not force_copy
+                                and sys.getrefcount(cur) <= self._SOLE_REFS)
+                        boost_extra = ((jnp.asarray(padb(boost_on)), k_dev,
+                                        capq_dev, npq_dev) if ragged
+                                       else (jnp.asarray(padb(boost_on)),))
+                        out = self._guarded(
+                            lambda fn: self._dispatch(
+                                fn, cur, *args, *boost_extra,
+                                jnp.float32(now_rel),
+                                jnp.float32(self.super_gate),
+                                jnp.float32(self.acc_boost),
+                                jnp.float32(self.nbr_boost), *sem_tail),
+                            kern.serve, kern.serve_copy, sole, (cur,),
+                            "serve_pod")
+                        if sem_state is not None:
+                            new_state, sem_ring2, packed = out
+                        else:
+                            new_state, packed = out
+                        del cur
+                        self.state = new_state
+                else:
+                    out = self._dispatch(kern.read, self.state, *args,
+                                         *read_extra, *sem_tail)
+                    if sem_state is not None:
+                        sem_ring2, packed = out
+                    else:
+                        packed = out
+            with tel.span("dispatch.readback"):
+                host = np.asarray(packed)          # the ONE readback
         if tiered:
             from lazzaro_tpu.tier.serve import tiered_decode_and_finish
             if sem_state is not None:
@@ -1784,7 +1778,7 @@ class ShardedMemoryIndex:
                                                               k_unpack)
                 semh.note_readback(sem_ring2, ctr[:, 4], valid, tids,
                                    g_s, g_r, a_s, a_r)
-            with tel.span("serve.decode_ms"):
+            with tel.span("index.decode", timer="serve.decode_ms"):
                 return tiered_decode_and_finish(
                     self, tm, reqs, results, valid, boost_on, q, tids,
                     host, k_bucket=k_bucket, cap_take=cap_s,
@@ -1792,7 +1786,7 @@ class ShardedMemoryIndex:
                     nbr_boost=self.nbr_boost,
                     now_rel=time.time() - self.epoch, ragged=ragged,
                     cap_arr=(cap_arr if ragged else None), tel=tel)
-        with tel.span("serve.decode_ms"):
+        with tel.span("index.decode", timer="serve.decode_ms"):
             gate_s, gate_r, ann_s, ann_r, fast, counters = unpack_retrieval(
                 host[:nq], k_bucket)
             for i, r in enumerate(reqs):
@@ -1809,13 +1803,13 @@ class ShardedMemoryIndex:
                     res.gate_score = float(gate_s[i])
                 res.fast = bool(fast[i])
                 res.boosted = bool(boost_on[i] and not fast[i])
-        if sem_state is not None:
-            semh.note_readback(sem_ring2, counters[:, 4], valid, tids,
-                               gate_s, gate_r, ann_s, ann_r)
-        record_device_counters(
-            tel, counters, fast, gate_on, valid,
-            np.asarray([min(int(r.k), self.capacity) for r in reqs]),
-            sem_active=sem_state is not None)
+            if sem_state is not None:
+                semh.note_readback(sem_ring2, counters[:, 4], valid, tids,
+                                   gate_s, gate_r, ann_s, ann_r)
+            record_device_counters(
+                tel, counters, fast, gate_on, valid,
+                np.asarray([min(int(r.k), self.capacity) for r in reqs]),
+                sem_active=sem_state is not None)
         return results
 
     def _maybe_record_hbm(self, mode: str, kern, args, k_bucket,

@@ -61,9 +61,9 @@ class IngestJournal:
     """Append/commit journal of extracted-fact batches (one per
     conversation), built on the CRC-framed WAL."""
 
-    def __init__(self, path: str, fsync: bool = False):
+    def __init__(self, path: str, fsync: bool = False, telemetry=None):
         self.path = path
-        self._wal = WriteAheadLog(path, fsync=fsync)
+        self._wal = WriteAheadLog(path, fsync=fsync, telemetry=telemetry)
         self._lock = threading.Lock()
         self._pending: Dict[int, List[dict]] = {}
         # seq -> append wall-time (in-memory only; staleness observability

@@ -316,7 +316,9 @@ class QueryScheduler:
 
     def _serve_loop(self) -> None:
         while True:
-            with self._cond:
+            # the worker's wait for work, up to the batch being admitted:
+            # the window less these spans is the time the worker was busy
+            with self.telemetry.span("sched.idle"), self._cond:
                 while True:
                     now = time.time()
                     oldest = self._pending[0][2] if self._pending else None
@@ -406,10 +408,18 @@ class QueryScheduler:
                else min(req.nprobe, self.degrade_nprobe))
         return dataclasses.replace(req, cap_take=cap, nprobe=npr)
 
-    def _execute(self, batch) -> None:
+    def _account(self, batch):
+        """(requests to dispatch, their summed queue wait in seconds, the
+        armed watchdog timer or None, its timed-out flag)."""
         reqs = [req for req, _, _ in batch]
         flush_t = time.time()
+        waited_s = 0.0
         for req, _, enq in batch:
+            waited_s += flush_t - enq
+            # a ring of ``window`` samples PER TENANT series (256 tenants,
+            # then "~other"): a series that overflows keeps its newest
+            # samples only, so percentiles of this timer lean to the
+            # window's end — serve.queue_wait_us is the whole sum
             self.telemetry.record("serve.queue_wait_ms",
                                   (flush_t - enq) * 1e3,
                                   labels={"tenant": req.tenant})
@@ -433,6 +443,13 @@ class QueryScheduler:
             timer = threading.Timer(self.dispatch_timeout_s, _deadline)
             timer.daemon = True
             timer.start()
+        return reqs, waited_s, timer, timed_out
+
+    def _execute(self, batch) -> None:
+        # the worker's own bookkeeping before the dispatch: one labelled
+        # queue-wait sample per request, the breaker, the watchdog
+        with self.telemetry.span("sched.account"):
+            reqs, waited_s, timer, timed_out = self._account(batch)
         try:
             # one mega-batch == one profiler step, so TPU captures line up
             # with the host spans batch-for-batch
@@ -460,12 +477,19 @@ class QueryScheduler:
         self.requests_served += len(batch)
         self.telemetry.bump("serve.requests", len(batch))
         self.telemetry.bump("serve.batches")
+        # summed over the served requests, so it divides by serve.requests
+        self.telemetry.bump("serve.queue_wait_us", int(waited_s * 1e6))
+        if len(batch) == 1:
+            # a whole dispatch (a full arena pass) for one answer
+            self.telemetry.bump("serve.lone_batches")
         self.telemetry.record("serve.batch_requests", len(batch))
         self.batch_sizes.append(len(batch))
         if len(self.batch_sizes) > 1024:
             del self.batch_sizes[:512]
-        for (_, fut, _), res in zip(batch, results):
-            _set_future(fut, res)
+        # the callers' done-callbacks run here, on the worker thread
+        with self.telemetry.span("sched.demux"):
+            for (_, fut, _), res in zip(batch, results):
+                _set_future(fut, res)
 
     def load(self) -> int:
         """Instantaneous queue depth + in-flight dispatches — the

@@ -24,7 +24,6 @@ row-sharded arena with a replicated flat CSR) and
 from __future__ import annotations
 
 import sys
-import time
 from typing import List, Optional
 
 import numpy as np
@@ -120,31 +119,30 @@ def tiered_decode_and_finish(index, tm, reqs, results, valid, boost_on,
     k_dec = min(int(k_bucket), k_unpack)
     any_boost = bool(boost2.any())
     dev = lambda a: jnp.asarray(a)       # noqa: E731
-    t0 = time.perf_counter()
-    if any_boost:
-        indptr_f, nbr_f = index._flat_csr_for()
-        with index._state_lock:
-            cur = index.state
-            sole = sys.getrefcount(cur) <= index._SOLE_REFS
-            new_state, packed2 = index._guarded(
-                lambda fn: fn(
-                    cur, indptr_f, nbr_f, dev(q2), dev(ten2), dev(rows2),
-                    dev(s2), dev(m2), dev(vecs2), dev(gs2), dev(gr2),
-                    dev(fast2), dev(boost2), dev(capq2),
-                    jnp.float32(now_rel), jnp.float32(acc_boost),
-                    jnp.float32(nbr_boost), k=k_dec, cap_take=cap_take,
-                    max_nbr=max_nbr),
-                S.tier_cold_finish, S.tier_cold_finish_copy, sole, (cur,),
-                "serve_tiered_cold")
-            del cur
-            index.state = new_state
-    else:
-        packed2 = S.tier_cold_rescore(
-            dev(q2), dev(rows2), dev(s2), dev(m2), dev(vecs2), dev(gs2),
-            dev(gr2), dev(fast2), k=k_dec, sentinel=cap)
-    host2 = np.asarray(packed2)          # the ONE finish readback
-    tel.record("serve.dispatch_ms", (time.perf_counter() - t0) * 1e3,
-               labels={"mode": "tiered_cold"})
+    with tel.span("serve.tiered_cold", timer="serve.dispatch_ms",
+                  labels={"mode": "tiered_cold"}):
+        if any_boost:
+            indptr_f, nbr_f = index._flat_csr_for()
+            with index._state_lock:
+                cur = index.state
+                sole = sys.getrefcount(cur) <= index._SOLE_REFS
+                new_state, packed2 = index._guarded(
+                    lambda fn: fn(
+                        cur, indptr_f, nbr_f, dev(q2), dev(ten2), dev(rows2),
+                        dev(s2), dev(m2), dev(vecs2), dev(gs2), dev(gr2),
+                        dev(fast2), dev(boost2), dev(capq2),
+                        jnp.float32(now_rel), jnp.float32(acc_boost),
+                        jnp.float32(nbr_boost), k=k_dec, cap_take=cap_take,
+                        max_nbr=max_nbr),
+                    S.tier_cold_finish, S.tier_cold_finish_copy, sole, (cur,),
+                    "serve_tiered_cold")
+                del cur
+                index.state = new_state
+        else:
+            packed2 = S.tier_cold_rescore(
+                dev(q2), dev(rows2), dev(s2), dev(m2), dev(vecs2), dev(gs2),
+                dev(gr2), dev(fast2), k=k_dec, sentinel=cap)
+        host2 = np.asarray(packed2)          # the ONE finish readback
     tel.bump("serve.dispatches", labels={"mode": "tiered_cold"})
     _, _, ann_s2, ann_r2, _, counters2 = unpack_retrieval(host2[:c2],
                                                           k_dec)

@@ -1,3 +1,3 @@
-from lazzaro_tpu.utils.telemetry import Telemetry, timed
+from lazzaro_tpu.utils.telemetry import Telemetry
 
-__all__ = ["Telemetry", "timed"]
+__all__ = ["Telemetry"]

@@ -8,7 +8,13 @@ the one sink every serving-path measurement flows into:
 - **timers** — ring-buffered latency samples with percentile summaries
   (``record`` / ``span``): queue wait per request, device dispatch wall
   time per mega-batch, readback decode, chat retrieval, consolidation;
--- **counters** — monotonic totals (``bump``): requests, dispatches per
+- **spans** — ``Telemetry.span(name)`` is the ONE way the program times a
+  region: it enters ``jax.profiler.TraceAnnotation("lz." + name)`` (so the
+  region lies on the profiler's clock beside the device's ``XLA Ops``
+  whenever a trace is being taken, and costs one cheap check when none
+  is: ~2 us a span all told), names its enclosing span on a thread-local stack, and records
+  its length under a timer. "Off" is the profiler not started;
+- **counters** — monotonic totals (``bump``): requests, dispatches per
   mode, the device-side counters decoded from the packed readback tail
   (gate hits, top-k shortfall, dedup hits, boost-scatter rows, link-pool
   occupancy/overflow);
@@ -32,16 +38,17 @@ systems in one process (tests, multi-user benches) never mix samples.
 
 from __future__ import annotations
 
-import logging
 import threading
 import time
 from collections import defaultdict, deque
-from contextlib import contextmanager
-from typing import Deque, Dict, Optional
+from typing import Deque, Dict, List, Optional
 
+import jax.monitoring
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-logger = logging.getLogger("lazzaro_tpu.telemetry")
+SPAN_PREFIX = "lz."
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 # Per-metric bound on distinct label COMBINATIONS. Overflowing values are
 # folded into one "~other" series, so a tenant explosion degrades to a
@@ -61,6 +68,70 @@ def split_key(key: str):
     if i < 0:
         return key, ""
     return key[:i], key[i:]
+
+
+# The spans open on each thread, innermost last. One stack for the whole
+# process (not one per registry): the compile listener below has to find
+# the span of the thread that compiled, whichever registry opened it.
+_OPEN = threading.local()
+
+
+def _open_spans() -> List["Span"]:
+    try:
+        return _OPEN.stack
+    except AttributeError:
+        _OPEN.stack = []
+        return _OPEN.stack
+
+
+def current_span() -> Optional["Span"]:
+    """The innermost span open on the calling thread, or None."""
+    stack = _open_spans()
+    return stack[-1] if stack else None
+
+
+class Span(TraceAnnotation):
+    """One timed region of the program (``Telemetry.span`` makes them).
+
+    Entering it starts the profiler annotation ``lz.<name>`` and pushes the
+    span on the calling thread's stack; ``parent`` is then the name of the
+    span that was innermost before — the one that caused this one. All
+    spans of one dispatch lie inside one ``lz.serve.batch`` step, all spans
+    of one conversation inside one ``lz.api.end_conversation``. Leaving it
+    records its length in milliseconds under ``timer`` unless the registry
+    is disabled; the annotation is kept either way."""
+
+    def __init__(self, tel: "Telemetry", name: str, timer: str,
+                 labels: Optional[Dict]):
+        TraceAnnotation.__init__(self, SPAN_PREFIX + name)
+        self.tel = tel
+        self.name = name
+        self.timer = timer
+        self.labels = labels
+        self.parent: Optional[str] = None
+        self._t0 = 0.0
+
+    def __enter__(self) -> "Span":
+        stack = _open_spans()
+        if stack:
+            self.parent = stack[-1].name
+        stack.append(self)
+        self._t0 = time.perf_counter()
+        TraceAnnotation.__enter__(self)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        TraceAnnotation.__exit__(self, exc_type, exc, tb)
+        ms = (time.perf_counter() - self._t0) * 1e3
+        _OPEN.stack.pop()
+        tel = self.tel
+        if tel.enabled:
+            if self.labels is None:
+                # what ``record`` does for an unlabelled timer, without its
+                # key lookup: a dozen spans ride every dispatch
+                tel.timers[self.timer].append(ms)
+            else:
+                tel.record(self.timer, ms, self.labels)
 
 
 class Telemetry:
@@ -115,13 +186,11 @@ class Telemetry:
             return
         self.gauges[self._key(name, labels)] = float(value)
 
-    @contextmanager
-    def span(self, name: str, labels: Optional[Dict] = None):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(name, (time.perf_counter() - t0) * 1e3, labels)
+    def span(self, name: str, *, timer: Optional[str] = None,
+             labels: Optional[Dict] = None) -> Span:
+        """``with tel.span("index.pack"):`` — the profiler annotation
+        ``lz.index.pack`` and the timer ``index.pack_ms`` (or ``timer``)."""
+        return Span(self, name, timer or name + "_ms", labels)
 
     # --------------------------------------------------------------- readers
     def counter_total(self, name: str) -> int:
@@ -141,22 +210,6 @@ class Telemetry:
         for k, v in list(self.timers.items()):
             if split_key(k)[0] == name:
                 out.extend(v)
-        return out
-
-    def summary(self) -> Dict[str, Dict[str, float]]:
-        out: Dict[str, Dict[str, float]] = {}
-        for name, values in list(self.timers.items()):
-            arr = np.asarray(values)
-            if arr.size:
-                out[name] = {
-                    "count": int(arr.size),
-                    "avg_ms": float(arr.mean()),
-                    "p50_ms": float(np.percentile(arr, 50)),
-                    "p95_ms": float(np.percentile(arr, 95)),
-                }
-        with self._lock:
-            for name, count in self.counters.items():
-                out[name] = {"count": count}
         return out
 
     def snapshot(self) -> Dict[str, Dict]:
@@ -236,6 +289,32 @@ def default_registry() -> Telemetry:
     return REGISTRY
 
 
+def file_op(tel: Telemetry, layer: str, op: str) -> Span:
+    """One file operation at one of the funnels of the store (``layer``
+    "store") or of a write-ahead log ("journal"): the span
+    ``lz.<layer>.io`` around it and one ``store.file_ops{op}`` count."""
+    tel.bump("store.file_ops", labels={"op": op})
+    return tel.span(layer + ".io")
+
+
+def _on_compile(event: str, duration_s: float, **_) -> None:
+    """jax calls this on the thread that compiled, so that thread's
+    innermost span says which part of the program paid: an operator alerts
+    on ``lazzaro_compile_events_total`` after warm-up, a builder reads the
+    ``span`` label. Outside any span the default registry counts it."""
+    if event != COMPILE_EVENT:
+        return
+    span = current_span()
+    tel = span.tel if span is not None else REGISTRY
+    tel.bump("compile.events",
+             labels={"span": span.name if span is not None else "none"})
+    tel.record("compile.ms", duration_s * 1e3)
+
+
+# One listener for the process, whatever number of registries it holds.
+jax.monitoring.register_event_duration_secs_listener(_on_compile)
+
+
 def record_device_counters(tel: Telemetry, counters, fast, gate_on, valid,
                            k_req, sem_active: bool = False) -> None:
     """Fold one fused readback's device-counter tail into the registry —
@@ -282,16 +361,3 @@ def peak_bytes(memory_stats) -> Optional[float]:
                      - memory_stats.alias_size_in_bytes)
     except AttributeError:
         return None
-
-
-@contextmanager
-def timed(label: str, sink=None):
-    t0 = time.perf_counter()
-    yield
-    ms = (time.perf_counter() - t0) * 1e3
-    if sink is not None:
-        sink.record(label, ms)
-    else:
-        # library users silence this via the standard logging config
-        # instead of the old unconditional print
-        logger.info("[%s %s: %.1fms]", Telemetry.tier(ms), label, ms)

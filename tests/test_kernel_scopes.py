@@ -1,0 +1,99 @@
+"""Kernel phases are named for the device trace (PR 25; tier-1, CPU): the
+exact ragged serving core and the fused dedup ingest carry ``jax.named_scope``
+names in their operations' metadata — compile-time only, the results are the
+program's own."""
+
+import re
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from lazzaro_tpu.core import state as S
+from lazzaro_tpu.core.index import MemoryIndex
+
+D, Q = 16, 8
+
+
+def _index():
+    idx = MemoryIndex(dim=D, capacity=64, edge_capacity=255)
+    rng = np.random.default_rng(3)
+    emb = rng.standard_normal((12, D)).astype(np.float32)
+    idx.add([f"n{i}" for i in range(12)], emb, [0.5] * 12, [0.0] * 12,
+            ["semantic"] * 12, ["default"] * 12, "a")
+    idx.add_edges([("n0", "n1", 0.7), ("n0", "n2", 0.7)], "a")
+    return idx
+
+
+def _serve_args(idx):
+    st = idx.state
+    indptr, nbr = idx._csr_for(st)
+    tid = idx._tenants["a"]
+    return (st, indptr, nbr, jnp.ones((Q, D), jnp.float32),
+            jnp.ones((Q,), bool), jnp.full((Q,), tid, jnp.int32),
+            jnp.zeros((Q,), bool))
+
+
+def _scopes(hlo_text):
+    return set(re.findall(r'op_name="[^"]*?(lz\.[a-z]+)', hlo_text))
+
+
+def test_read_core_names_its_phases():
+    idx = _index()
+    args = _serve_args(idx)
+    low = S.search_fused_ragged_read.lower(
+        *args, jnp.full((Q,), 5, jnp.int32), jnp.float32(0.4),
+        k=8, cap_take=5, max_nbr=4)
+    # the read twin drops the neighbour gather (nothing reads it)
+    assert _scopes(low.compile().as_text()) == {
+        "lz.norms", "lz.scan", "lz.topk", "lz.gate", "lz.pack"}
+
+
+def test_boosting_twin_of_the_same_core_names_the_csr_gather_too():
+    idx = _index()
+    args = _serve_args(idx)
+    low = S.search_fused_ragged_copy.lower(
+        *args, jnp.ones((Q,), bool), jnp.full((Q,), 5, jnp.int32),
+        jnp.full((Q,), 5, jnp.int32), jnp.float32(1.0), jnp.float32(0.4),
+        jnp.float32(0.05), jnp.float32(0.02), k=8, cap_take=5, max_nbr=4)
+    assert _scopes(low.compile().as_text()) >= {
+        "lz.norms", "lz.scan", "lz.topk", "lz.gate", "lz.csr", "lz.pack"}
+
+
+def test_fused_dedup_ingest_names_its_phases(monkeypatch):
+    idx = _index()
+    seen = {}
+    real = idx._apply_dedup_fused
+
+    def spy(*dev_args, **kw):
+        fn = S.ingest_dedup_fused_copy
+        low = fn.lower(idx.state, idx.edge_state, None, None, None, None,
+                       *dev_args, k=kw["k"], shard_modes=kw["shard_modes"])
+        seen["scopes"] = _scopes(low.compile().as_text())
+        return real(*dev_args, **kw)
+
+    monkeypatch.setattr(idx, "_apply_dedup_fused", spy)
+    rng = np.random.default_rng(5)
+    emb = rng.standard_normal((6, D)).astype(np.float32)
+    pending = idx.ingest_batch_dedup(
+        emb, [0.5] * 6, [0.0] * 6, ["semantic"] * 6, ["default"] * 6,
+        tenant="a", dedup_gate=0.95, chain_weight=0.5, link_k=3,
+        link_gate=0.5, link_scale=1.0, shard_modes=(1, 0), now=0.0)
+    assert pending is not None
+    assert seen["scopes"] >= {"lz.link", "lz.dedup", "lz.scatter"}
+
+
+def test_scopes_leave_the_results_alone():
+    """Same inputs, same packed readback as a scan written without scopes."""
+    idx = _index()
+    st, indptr, nbr, q, valid, tenant, gate = _serve_args(idx)
+    packed = np.asarray(S.search_fused_ragged_read(
+        st, indptr, nbr, q, valid, tenant, gate, jnp.full((Q,), 5, jnp.int32),
+        jnp.float32(0.4), k=8, cap_take=5, max_nbr=4))
+    qn = S.normalize(q).astype(st.emb.dtype)
+    scores = np.array(S.nt_dot(qn, st.emb), np.float32)
+    live = np.asarray(st.alive) & (np.asarray(st.tenant_id) == int(tenant[0]))
+    scores[:, ~live] = -np.inf
+    want = np.sort(scores[0])[::-1][:5]
+    got = packed[0, 2:2 + 5].view(np.float32)
+    np.testing.assert_array_equal(got, want)

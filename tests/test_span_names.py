@@ -1,0 +1,154 @@
+"""The program's span taxonomy (PR 25; tier-1, CPU, debug geometry): one
+conversation through the API and one dispatch through the scheduler open
+exactly the spans PERF.md section 3 lists, nested as listed. A recorder
+stands where ``Span`` is made, so the test reads names and parents from the
+helper's own thread-local stack, not from a profile."""
+
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from lazzaro_tpu.config import MemoryConfig
+from lazzaro_tpu.core.memory_system import MemorySystem
+from lazzaro_tpu.serve import RetrievalRequest
+from lazzaro_tpu.utils import telemetry as T
+from tests.test_fused_ingest import ClusteredEmb, D, QueueLLM
+
+# span -> the spans it may open directly
+CONVERSATION = {
+    None: {"api.switch_user", "api.start_conversation", "journal.turn",
+           "api.end_conversation"},
+    "api.switch_user": {"store.save", "store.load", "journal.setup"},
+    "api.start_conversation": {"journal.sync"},
+    "api.end_conversation": {
+        "write.extract", "journal.append", "write.embed", "write.prepare",
+        "write.apply", "store.save", "journal.sync", "journal.commit",
+        "write.decay"},
+    "write.prepare": {"ingest.dedup_fused"},
+    "write.apply": {"store.add"},
+    "store.save": {"store.io"}, "store.load": {"store.io"},
+    "store.add": {"store.io"},
+    "journal.setup": {"journal.io"}, "journal.sync": {"journal.io"},
+    "journal.turn": {"journal.io"}, "journal.append": {"journal.io"},
+    "journal.commit": {"journal.io"},
+}
+DISPATCH = {
+    None: {"sched.idle", "sched.account", "index.pack", "index.stage",
+           "serve.exact", "index.decode", "sched.demux"},
+    "serve.exact": {"dispatch.launch", "dispatch.readback"},
+}
+# the names accepted metrics select by prefix (index.host_p50_ms.lat,
+# kernel.ingest_dev_ms): a new span under them would redefine the metric
+ACCEPTED = {"serve.exact", "ingest.dedup_fused"}
+SUMMED = ("journal.turn", "journal.sync", "journal.append", "journal.commit",
+          "journal.setup", "store.save", "store.load", "store.add")
+
+
+@pytest.fixture()
+def opened(monkeypatch):
+    log = []
+
+    class Recorded(T.Span):
+        def __enter__(self):
+            super().__enter__()
+            chain = [s.name for s in T._open_spans()]
+            log.append((threading.current_thread().name, self.name,
+                        self.parent, chain[:-1]))
+            return self
+
+    monkeypatch.setattr(T, "Span", Recorded)
+    return log
+
+
+@pytest.fixture()
+def system(tmp_path):
+    ms = MemorySystem(
+        enable_async=False, db_dir=str(tmp_path / "db"), verbose=False,
+        load_from_disk=False, llm_provider=QueueLLM(20),
+        embedding_provider=ClusteredEmb(), auto_prune=False,
+        max_buffer_size=10_000,
+        config=MemoryConfig(auto_consolidate=False, enable_hierarchy=False))
+    yield ms
+    ms.close()
+
+
+def _converse(ms, user, n):
+    ms.switch_user(user)
+    ms.start_conversation()
+    ms.add_to_short_term(f"conv {n}", "episodic", 0.7)
+    ms.end_conversation()
+
+
+def _tree(log):
+    tree = {}
+    for _, name, parent, _ in log:
+        tree.setdefault(parent, set()).add(name)
+    return tree
+
+
+def test_one_conversation_opens_exactly_the_listed_spans(system, opened):
+    _converse(system, "alice", 0)
+    del opened[:]
+    _converse(system, "bob", 1)
+    assert {t for t, *_ in opened} == {"MainThread"}     # synchronous writer
+    assert _tree(opened) == CONVERSATION
+    names = [n for _, n, _, _ in opened]
+    assert names.count("api.end_conversation") == 1
+    # a conversation passes the persist three times today (after the
+    # consolidation, at its end, and at the next switch_user): shown, not
+    # changed, here
+    assert names.count("store.save") == 3
+    assert names.count("store.load") == names.count("store.add") == 1
+    # the per-conversation metrics SUM these: none may lie inside another
+    for _, name, _, chain in opened:
+        if name in SUMMED:
+            assert not set(chain) & set(SUMMED), (name, chain)
+    # and every file operation lies inside one of them
+    for _, name, parent, _ in opened:
+        if name in ("store.io", "journal.io"):
+            assert parent in SUMMED
+
+
+def test_file_operations_per_conversation_repeat(system, opened):
+    _converse(system, "alice", 0)
+    counts = []
+    for n, user in enumerate(("bob", "carol", "dave"), 1):
+        del opened[:]
+        _converse(system, user, n)
+        counts.append(sum(name in ("store.io", "journal.io")
+                          for _, name, _, _ in opened))
+    assert len(set(counts)) == 1 and counts[0] > 0
+
+
+def test_one_dispatch_opens_at_most_twelve_spans(system, opened):
+    _converse(system, "alice", 0)
+    sched = system._ensure_scheduler()
+    req = RetrievalRequest(query=np.ones(D, np.float32), tenant="alice", k=5)
+    assert sched.submit(req).result(timeout=60).ids     # warm: compiles
+    time.sleep(0.05)            # the worker is back in its wait
+    del opened[:]
+    assert sched.submit(req).result(timeout=60).ids
+    deadline = time.time() + 10
+    while (not any(n == "sched.idle" for _, n, _, _ in opened)
+           and time.time() < deadline):
+        time.sleep(0.005)
+    mine = [e for e in opened if e[0] != "MainThread"]
+    assert len({t for t, *_ in mine}) == 1              # the one worker
+    assert _tree(mine) == DISPATCH
+    assert len(mine) == 9 <= 12
+    order = [n for _, n, _, _ in mine]
+    assert order == ["sched.account", "index.pack", "index.stage", "serve.exact",
+                     "dispatch.launch", "dispatch.readback", "index.decode",
+                     "sched.demux", "sched.idle"]
+    assert system.telemetry.counter_total("serve.lone_batches") == 2
+    assert system.telemetry.counter_total("serve.queue_wait_us") > 0
+
+
+def test_no_new_name_falls_under_an_accepted_metrics_prefix():
+    names = {n for tree in (CONVERSATION, DISPATCH)
+             for kids in tree.values() for n in kids}
+    taken = [n for n in names - ACCEPTED
+             if n.startswith(("serve.", "ingest."))]
+    assert not taken

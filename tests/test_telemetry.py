@@ -27,7 +27,7 @@ import pytest
 
 from lazzaro_tpu.core.index import MemoryIndex
 from lazzaro_tpu.serve import QueryScheduler, RetrievalRequest
-from lazzaro_tpu.utils.telemetry import Telemetry, split_key, timed
+from lazzaro_tpu.utils.telemetry import Telemetry, split_key
 from tests.test_fused_retrieval import (_count_dispatches, _ingest,
                                         _system)
 
@@ -85,17 +85,6 @@ def test_disabled_registry_is_a_noop():
     tel.record("t", 1.0)
     tel.gauge("g", 2.0)
     assert tel.snapshot() == {"timers": {}, "counters": {}, "gauges": {}}
-
-
-def test_timed_routes_through_logging(capsys, caplog):
-    """Satellite: ``timed()`` without a sink logs instead of printing, so
-    library users silence it with standard logging config."""
-    import logging
-    with caplog.at_level(logging.INFO, logger="lazzaro_tpu.telemetry"):
-        with timed("unit-test-label"):
-            pass
-    assert capsys.readouterr().out == ""
-    assert any("unit-test-label" in r.getMessage() for r in caplog.records)
 
 
 # ----------------------------------------------------- fixtures (tiny arena)
