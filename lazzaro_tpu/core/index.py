@@ -3169,6 +3169,7 @@ class MemoryIndex:
                         statics["sem_block"] = semh.block
                         sem_kw = {"sem": semh.tuple_for(mode)}
                 self._note_serve_kernel(mode, statics, ragged)
+                self._note_select_core(mode, st)
                 # pq_tiered never touches the int8 shadow — the cold coarse scan
                 # reads the PQ slab already in pq_tabs; only the residency mask
                 # rides in the tier pack there
@@ -3198,6 +3199,7 @@ class MemoryIndex:
             # the FAMILY mode id, so they never cross serving modes.
             semh = self._sem_host
             fam = mode[len("sharded_"):]
+            self._note_select_core(fam, st)
             sem_state = None
             if semh is not None and fam in S.SEM_MODE_IDS:
                 win = k_bucket + (self.coarse_slack if tiered else 0)
@@ -3525,6 +3527,20 @@ class MemoryIndex:
                 np.asarray([min(int(r.k), cap) for r in reqs]),
                 sem_active=bool(sem_kw))
         return out
+
+    def _note_select_core(self, mode: str, st) -> None:
+        """``serve.select{core}``: which form of the exact core this
+        dispatch runs (ISSUE 26) — ``blocked`` when a block tiles the pool
+        (each chip's slice of it under a mesh) and the selection follows
+        the stream, ``whole_pool`` when the pool is ONE block of the same
+        code (smaller than a block, or a row count no block divides)."""
+        if mode != "exact":
+            return
+        from lazzaro_tpu.ops.pallas_topk import block_tiles
+        blocked = block_tiles(st.emb.shape[0] // self._n_parts,
+                              st.emb.shape[1], st.emb.dtype.itemsize)
+        self.telemetry.bump("serve.select", labels={
+            "core": "blocked" if blocked else "whole_pool"})
 
     def _note_serve_kernel(self, mode: str, statics: dict,
                            ragged: bool) -> None:

@@ -142,9 +142,10 @@ class EdgeState:
         return self.src.shape[0] - 1
 
 
-# Pallas top-k geometry: arenas at/above the dispatch threshold allocate row
-# counts in TOPK_BLOCK multiples so the blocked kernel never needs a padded
-# copy of the embedding matrix (extra rows are ordinary free capacity).
+# Blocked-scan geometry: arenas of a block or more allocate row counts in
+# TOPK_BLOCK multiples (== ops/pallas_topk.SELECT_BLOCK) so the blocked
+# select-while-scanning core tiles them without a padded copy of the
+# embedding matrix (extra rows are ordinary free capacity).
 TOPK_BLOCK = 4096
 PALLAS_TOPK_MIN_ROWS = 262_144
 
@@ -740,12 +741,12 @@ def arena_search(
     fast-path scan (memory_system.py:464-470) — same kernel, different mask.
 
     Dispatch (all static at trace time): big block-aligned arenas on TPU
-    take the blocked Pallas kernel — it streams the matrix through VMEM
-    with per-block top-k, so no [Q, N] f32 score tensor ever lands in HBM
-    (4 GB per 1k queries at 1M rows) and the final sort runs over
-    nblocks·k candidates instead of N. Everything else takes the
-    one-matmul XLA path. Callers with a row-sharded arena must pass
-    ``impl="xla"``
+    take the blocked select-while-scanning kernel as its one-mask case
+    (``ops/pallas_topk.masked_topk``, the fused serving core's kernel) —
+    it streams the matrix through VMEM with a running top-k, so no [Q, N]
+    f32 score tensor ever lands in HBM (4 GB per 1k queries at 1M rows).
+    Everything else takes the one-matmul XLA path. Callers with a
+    row-sharded arena must pass ``impl="xla"``
     (pallas_call has no GSPMD partitioning rule) or go through the
     shard_map composition in ``ops/topk.make_sharded_topk``."""
     q = normalize(jnp.atleast_2d(query)).astype(state.emb.dtype)
@@ -760,15 +761,13 @@ def arena_search(
     # paged arenas scan the emb POOL: the logical mask re-indexes into pool
     # space (free slots masked off) and survivors map back to logical rows
     mask = _pool_mask(state, lmask)
-    n, nq = state.emb.shape[0], q.shape[0]
+    from lazzaro_tpu.ops.pallas_topk import block_tiles, masked_topk
+    n, d = state.emb.shape
     use_pallas = impl == "pallas" or (
-        impl == "auto"
-        and on_tpu()
-        and n >= PALLAS_TOPK_MIN_ROWS and n % TOPK_BLOCK == 0
-        and nq <= 128 and k <= 16)
+        impl == "auto" and on_tpu() and n >= PALLAS_TOPK_MIN_ROWS
+        and block_tiles(n, d, state.emb.dtype.itemsize))
     if use_pallas:
-        from lazzaro_tpu.ops.pallas_topk import masked_topk_arena
-        top_scores, top_rows = masked_topk_arena(state.emb, mask, q, k)
+        top_scores, top_rows = masked_topk(state.emb, mask, q, k)
     else:
         def chunk(q_c):
             scores = nt_dot(q_c, state.emb)                       # [C, pool]
@@ -2237,33 +2236,41 @@ def _gate_and_boost_rows(state: ArenaState, csr_indptr, csr_nbr, gate_s,
 
 
 def _exact_two_tier(state: ArenaState, q_c: jax.Array, tenant_c: jax.Array,
-                    k_gate: int, k_ann: int):
-    """Masked super top-``k_gate`` + masked main top-``k_ann`` over ONE
-    score matrix (the arena streams from HBM once; the two retrieval tiers
-    are just different masks, same trick as the multi-mode link scan).
-    The shard-local core of the exact fused scan: single-chip callers pass
-    the whole arena, the sharded program passes each chip's local slice.
+                    k_ann: int, k_c=None):
+    """Masked super top-1 + masked main top-``k_ann`` of every query over
+    its own tenant's rows, SELECTED WHILE THE POOL STREAMS from HBM once
+    (ISSUE 26; ``ops/pallas_topk.blocked_two_tier``): per block the scores,
+    the per-query mask, the gate's running top-1 and the main tier's running
+    top-k stay on chip, so no ``[C, rows]`` score tile exists and the
+    selection work follows each query's own k — ``k_c`` ([C] i32 device
+    data; None for the static callers, which run to ``k_ann``) — not the
+    static ceiling ``k_ann`` the shapes are compiled to. Slots past a
+    query's k, or past its tenant's live rows, hold ``(NEG_INF, capacity)``
+    (what ``_ragged_topk_mask`` writes). The shard-local core of the exact
+    fused scan: single-chip callers pass the whole arena, the sharded
+    program passes each chip's local slice; a paged pool scans in pool
+    space and maps the survivors back.
 
     The trailing barrier is the PR 2 consumer-split fix: the top-k results
     feed BOTH the packed readback and the boost gather chain; without it
-    XLA (CPU at least) splits the consumers into two full [C, cap] sorts —
-    measured 2.4× on the whole fused program at 65k rows."""
-    # The named scopes (here and in the tail below) are compile-time
-    # metadata on the program's operations: a device trace can then say
-    # which phase an operation belongs to. They cost nothing at run time.
+    XLA (CPU at least) splits the consumers and runs the core twice."""
+    from lazzaro_tpu.ops.pallas_topk import ROW_DEAD, blocked_two_tier
+
+    # The named scopes (here, in the core and in the tail below) are
+    # compile-time metadata on the program's operations: a device trace can
+    # then say which phase an operation belongs to. They cost nothing at
+    # run time.
     with jax.named_scope("lz.norms"):
         qn = normalize(q_c).astype(state.emb.dtype)
     with jax.named_scope("lz.scan"):
-        scores = nt_dot(qn, state.emb)                    # [C, pool rows] f32
         alive_p = _pool_mask(state, state.alive)
-        ten_p = _pool_col(state, state.tenant_id)
-        alive_t = alive_p[None, :] & (ten_p[None, :] == tenant_c[:, None])
-        sup = _pool_col(state, state.is_super)[None, :]
+        ten_p = _pool_col(state, state.tenant_id).astype(jnp.int32)
+        sup = _pool_col(state, state.is_super)
+        row_main = jnp.where(alive_p & ~sup, ten_p, ROW_DEAD)
+        row_gate = jnp.where(alive_p & sup, ten_p, ROW_DEAD)
+    gate_s, gate_r, ann_s, ann_r = blocked_two_tier(
+        state.emb, qn, row_main, row_gate, tenant_c, k_ann, k_c)
     with jax.named_scope("lz.topk"):
-        gate_s, gate_r = jax.lax.top_k(
-            jnp.where(alive_t & sup, scores, NEG_INF), k_gate)
-        ann_s, ann_r = jax.lax.top_k(
-            jnp.where(alive_t & ~sup, scores, NEG_INF), k_ann)
         gate_r = _pool_to_logical(state, gate_r)
         ann_r = _pool_to_logical(state, ann_r)
     return jax.lax.optimization_barrier((gate_s, gate_r, ann_s, ann_r))
@@ -2494,28 +2501,25 @@ def _search_fused_scan(state: ArenaState, csr_indptr: jax.Array,
     (``capacity`` is the sentinel row index).
 
     With ``k_q``/``cap_q`` ([Q] i32 device sidecars) the scan is RAGGED:
-    ``k`` and ``cap_take`` become the static batch ceilings the compute
-    runs to, and each query masks at its own top-k boundary
-    (``_ragged_topk_mask``) — per-request shapes are data, not trace
-    constants.
+    ``k`` and ``cap_take`` become the static ceilings the SHAPES are
+    compiled to, and each query's own k rides into the core as data
+    (``_exact_two_tier``'s ``k_c``): the selection runs to what the batch
+    asks, and slots past a query's k come back as (NEG_INF, capacity) —
+    per-request shapes are data, not trace constants.
 
     ``scan_chunk > 0`` (ISSUE 11) overrides the default ``QUERY_CHUNK``
-    streaming width: the HBM planner shrinks the ``[chunk, rows]`` score
-    tile — the dominant transient of the dispatch — to fit a throttled
-    budget WITHOUT splitting the turn. Results are bit-identical (the
-    per-query computation never sees the chunk boundary); only the
-    streaming granularity, and therefore the peak footprint, changes."""
+    streaming width. In this family it no longer bounds a ``[chunk, rows]``
+    score tile — none exists since ISSUE 26 — only the queries one pass of
+    the pool carries (``[chunk, block]`` scores and ``[chunk, k]`` lists at
+    a time), so a throttled budget gains little from it here; results stay
+    bit-identical (the per-query computation never sees the chunk
+    boundary)."""
     ragged = k_q is not None
 
     def chunk(q_c, valid_c, tenant_c, gate_c, boost_c, *rag):
+        k_c, cap_c = rag if ragged else (None, None)
         gate_s, gate_r, ann_s, ann_r = _exact_two_tier(state, q_c, tenant_c,
-                                                       1, k)
-        gate_s, gate_r = gate_s[:, 0], gate_r[:, 0]
-        cap_c = None
-        if ragged:
-            k_c, cap_c = rag
-            ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, k_c,
-                                             state.capacity)
+                                                       k, k_c)
         fast, acc_rows, nbr_rows = _gate_and_boost_rows(
             state, csr_indptr, csr_nbr, gate_s, gate_r, ann_s, ann_r,
             valid_c, tenant_c, gate_c, boost_c, super_gate, cap_take,
@@ -4712,9 +4716,11 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
     ``scan_chunk > 0`` (ISSUE 17 satellite — the pod twin of the ISSUE 11
     single-chip override) narrows every chip's shard-local streaming tile:
     the planner can fit an over-budget pod geometry by shrinking the
-    ``[chunk, local_rows]`` score transient instead of splitting the turn
-    into extra dispatches. Bit-identical results — only the streaming
-    granularity changes — and still ONE distributed dispatch.
+    ``[chunk, local_rows]`` score transient of the quant / tiered cores
+    (the exact core holds no such tile since ISSUE 26) instead of
+    splitting the turn into extra dispatches. Bit-identical results —
+    only the streaming granularity changes — and still ONE distributed
+    dispatch.
 
     ``sem=True`` (ISSUE 20) threads the semantic query-cache ring through
     the distributed program: every call signature gains a trailing
@@ -4771,13 +4777,14 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
             mem_l, ext_l = mem2[0], ext2[0]
 
         def core(q_c, tenant_c, *rag):
-            nprobe_c = rag[0] if rag else None
             zeros = jnp.zeros((q_c.shape[0],), jnp.int32)
             off = jnp.zeros((q_c.shape[0],), bool)
             if mode == "exact":
-                g_s, g_r, a_s, a_r = _exact_two_tier(arena, q_c, tenant_c,
-                                                     1, k_l)
-                return g_s, g_r, a_s, a_r, zeros, off
+                # the one ragged sidecar of the exact core: each query's k
+                g_s, g_r, a_s, a_r = _exact_two_tier(
+                    arena, q_c, tenant_c, k_l, rag[0] if rag else None)
+                return g_s[:, None], g_r[:, None], a_s, a_r, zeros, off
+            nprobe_c = rag[0] if rag else None
             if mode == "quant":
                 g_s, g_r, a_s, a_r = _quant_two_tier(
                     arena, q8_l, scale_l, q_c, tenant_c, k_l, slack)
@@ -4798,8 +4805,10 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
             return g_s[:, None], g_r[:, None], a_s, a_r, n_dup, off
 
         arrays = (q, tenant)
-        if nprobe_q is not None and (mode.startswith("ivf")
-                                     or mode == "pq"):
+        if mode == "exact" and k_q is not None:
+            arrays = arrays + (k_q,)
+        elif nprobe_q is not None and (mode.startswith("ivf")
+                                       or mode == "pq"):
             arrays = arrays + (nprobe_q,)
         g_s, g_r, a_s, a_r, dup_l, cold_l_q = chunked_map_multi(
             core, arrays, chunk=chunk)

@@ -1,21 +1,38 @@
-"""Pallas TPU kernel: fused masked cosine scoring + two-stage exact top-k.
+"""The blocked select-while-scanning core: masked cosine scoring and exact
+top-k in ONE pass over the embedding pool, with no ``[queries, rows]`` score
+matrix in HBM (ISSUE 26).
 
-The retrieval hot op (SURVEY §7.2). The XLA path materializes a [Q, N] f32
-score matrix in HBM and runs a full-width ``lax.top_k`` over N (sort-network
-heavy at N=1M). This kernel streams the embedding matrix through VMEM once,
-blocks of BLK rows at a time: each grid step computes the block's scores on
-the MXU, applies the alive/tenant mask additively, and keeps only the block's
-top-K (iterative max-and-suppress on the VPU) — so HBM traffic is the
-embedding read plus a tiny [nblocks, Q, K] candidate tensor, and the final
-exact top-k runs over nblocks·K ≪ N candidates.
+The pool streams from HBM once, ``block`` rows at a time. For each block,
+on chip: the ``[C, block]`` scores on the MXU, the per-QUERY row mask (each
+query sees only its own tenant's rows — ``row_main`` / ``row_gate`` carry,
+per pool row, the tenant that may read it in that tier, or ``ROW_DEAD``),
+the gate tier's running top-1, and the main tier's running top-k. The
+running top-k is a sorted ``[C, K]`` list that stays on chip across blocks:
+a block's best remaining score is inserted while it beats the query's
+current k-th best, so the selection work follows what the data and each
+query's own ``k`` ask for — a block that holds none of a query's rows, or
+nothing better than it already has, costs one max over the block. The
+loop's bound is DEVICE data (the batch's largest ``k``), never the static
+ceiling ``K`` the shapes are compiled to.
 
-Use ``interpret=True`` (automatic on CPU) for tests.
+Exactness: bf16 rows × bf16-rounded query into an f32 accumulate (what
+``nt_dot`` does); equal scores resolve to the lowest pool row (first argmax
+inside a block, earlier blocks ahead in the list) — ``lax.top_k``'s order;
+slots past a query's ``k``, or past its tenant's live rows, hold
+``(NEG, sentinel)``.
+
+Two vehicles run the same step (``_select_step``): a Pallas TPU kernel
+(grid over blocks, the pipeline's DMA of block b+1 under the compute of
+block b, everything else in VMEM) and a plain-JAX ``fori_loop`` over
+``dynamic_slice``d blocks (every other backend, and any pool the block does
+not tile: then ONE whole-pool block of the same code). PERF.md §6 (PR 26)
+holds the chip measurements that chose between them.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,153 +41,315 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lazzaro_tpu.ops.backend import on_tpu
+from lazzaro_tpu.ops.chunking import nt_dot
 
 NEG = -1e30
+# a pool row no query may read in a tier; no tenant id (>= -1) equals it,
+# and the pad queries the kernel adds carry ROW_DEAD + 1
+ROW_DEAD = int(np.iinfo(np.int32).min)
+
+SELECT_BLOCK = 4096
+# one embedding block's VMEM budget (it is double-buffered beside the
+# [C, block] f32 score tile)
+_BLOCK_BYTES = 8 * 1024 * 1024
+# queries one kernel call holds on chip; wider batches stream in pieces
+_MAX_QUERIES = 128
 
 
-def _topk_block_kernel(k: int):
-    def kernel(q_ref, emb_ref, madd_ref, out_s_ref, out_i_ref):
-        blk_idx = pl.program_id(0)
-        emb_blk = emb_ref[:]                        # [BLK, d]
-        q = q_ref[:]                                # [Q, d]
-        scores = jax.lax.dot_general(
-            q, emb_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [Q, BLK]
-        scores = scores + madd_ref[:]               # additive mask [1, BLK]
-        blk = scores.shape[1]
-        col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        base = blk_idx * blk
-        for t in range(k):                          # iterative max-and-suppress
-            m = jnp.max(scores, axis=1, keepdims=True)           # [Q, 1]
-            hit = scores == m
-            idx = jnp.min(jnp.where(hit, col, blk), axis=1,
-                          keepdims=True)                          # first argmax
-            out_s_ref[0, :, t] = m[:, 0]
-            out_i_ref[0, :, t] = idx[:, 0] + base
-            scores = jnp.where(col == idx, NEG, scores)
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("k", "block_rows", "interpret"))
-def pallas_masked_topk(emb: jax.Array, madd: jax.Array, queries: jax.Array,
-                       k: int = 10, block_rows: int = 4096,
-                       interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """emb [N, d] (L2-normalized, N % block_rows == 0), madd [N] additive mask
-    (0 alive / -1e30 dead), queries [Q, d]. Returns (scores [Q,k], rows [Q,k]).
-    """
-    n, d = emb.shape
-    assert n % block_rows == 0, f"N={n} must be a multiple of {block_rows}"
-    nblocks = n // block_rows
-    q = queries.astype(emb.dtype)
-    nq = q.shape[0]
-    madd2 = madd.reshape(1, n).astype(jnp.float32)
-
-    grid_spec = pl.GridSpec(
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((nq, d), lambda b: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_rows, d), lambda b: (b, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_rows), lambda b: (0, b),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, nq, k), lambda b: (b, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, nq, k), lambda b: (b, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-    )
-    block_s, block_i = pl.pallas_call(
-        _topk_block_kernel(k),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((nblocks, nq, k), jnp.float32),
-            jax.ShapeDtypeStruct((nblocks, nq, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(q, emb, madd2)
-
-    # Stage 2: exact top-k over the nblocks*k candidates per query.
-    cand_s = jnp.moveaxis(block_s, 0, 1).reshape(nq, nblocks * k)
-    cand_i = jnp.moveaxis(block_i, 0, 1).reshape(nq, nblocks * k)
-    top_s, pos = jax.lax.top_k(cand_s, k)
-    top_i = jnp.take_along_axis(cand_i, pos, axis=1)
-    return top_s, top_i
-
-
-@functools.partial(jax.jit, static_argnames=("k", "block_rows", "interpret"))
-def pallas_masked_topk_ragged(emb: jax.Array, madd: jax.Array,
-                              queries: jax.Array, k_q: jax.Array,
-                              k: int = 10, block_rows: int = 4096,
-                              interpret: bool = False
-                              ) -> Tuple[jax.Array, jax.Array]:
-    """Ragged-K variant of the blocked scan (ISSUE 7): ``k`` is the STATIC
-    batch ceiling the VMEM-streaming kernel computes to — the per-block
-    max-and-suppress loop and the stage-2 candidate sort are compiled
-    once per (geometry, ceiling) — and ``k_q`` ([Q] i32 device data) is
-    each query's own k. Positions at or past a query's k come back as
-    (NEG, -1), exactly the per-query ``top_k(k_i)`` result because the
-    ceiling output is score-sorted. One compiled kernel therefore serves
-    any mix of request k's ≤ the ceiling; mixed-k fleets stop burning a
-    compile-cache entry per distinct k."""
-    top_s, top_i = pallas_masked_topk(emb, madd, queries, k=k,
-                                      block_rows=block_rows,
-                                      interpret=interpret)
-    live = jnp.arange(k)[None, :] < k_q[:, None]
-    return jnp.where(live, top_s, NEG), jnp.where(live, top_i, -1)
-
-
-def masked_topk_arena_ragged(emb: jax.Array, mask: jax.Array,
-                             queries: jax.Array, k_q: jax.Array,
-                             k: int = 10) -> Tuple[jax.Array, jax.Array]:
-    """Ragged twin of :func:`masked_topk_arena`: boolean mask → additive
-    mask, block size fitted to VMEM, per-query k as data against the
-    static ``k`` ceiling."""
-    n, d = emb.shape
-    blk = fit_block_rows(n, d, emb.dtype.itemsize)
-    assert blk, f"arena rows {n} have no VMEM-fitting block divisor >= 512"
-    madd = jnp.where(mask, 0.0, NEG).astype(jnp.float32)
-    return pallas_masked_topk_ragged(emb, madd, queries.astype(emb.dtype),
-                                     k_q, k=k, block_rows=blk,
-                                     interpret=not on_tpu())
-
-
-def masked_topk_auto(emb, madd, queries, k=10, block_rows=4096):
-    """Dispatch: pallas on TPU, interpret-mode pallas elsewhere."""
-    return pallas_masked_topk(emb, madd, queries, k=k, block_rows=block_rows,
-                              interpret=not on_tpu())
-
-
-# One embedding block's VMEM budget: blocks are double-buffered and the
-# scoped-vmem ceiling is 16 MB, so ~6 MB per block leaves room for the
-# [Q, blk] f32 score tile and outputs (blk=8192 at d=768 OOMs — measured).
-_BLOCK_BYTES = 6 * 1024 * 1024
-
-
-def fit_block_rows(n: int, d: int, itemsize: int) -> int:
-    """Largest power-of-two block ≤ 4096 that fits the VMEM budget AND
-    divides ``n``; 0 when no block ≥ 512 divides n (caller falls back to the
-    XLA path). Shared by the single-chip arena dispatch and the shard_map
-    per-shard dispatch, whose local row counts are N/n_shards."""
-    blk = 4096
+def select_block_rows(n: int, d: int, itemsize: int) -> int:
+    """Rows per block for a pool of ``n`` rows: the largest power of two
+    ≤ ``SELECT_BLOCK`` (and ≥ 512) that fits the VMEM budget and divides
+    ``n`` — or ``n`` itself (ONE whole-pool block) when none does."""
+    blk = SELECT_BLOCK
     while blk > 512 and blk * d * itemsize > _BLOCK_BYTES:
         blk //= 2
     while blk >= 512 and n % blk != 0:
         blk //= 2
-    return blk if blk >= 512 else 0
+    return blk if 512 <= blk < n else n
 
 
-def masked_topk_arena(emb: jax.Array, mask: jax.Array, queries: jax.Array,
-                      k: int = 10) -> Tuple[jax.Array, jax.Array]:
-    """The ``arena_search`` serving path: boolean mask → additive mask, block
-    size fitted to VMEM for the embedding dtype/width. Requires
-    ``emb.shape[0] %% block == 0`` — arenas allocate row counts in
-    ``state.TOPK_BLOCK`` multiples precisely so no padded copy of the matrix
-    is ever made here."""
+def block_tiles(n: int, d: int, itemsize: int) -> bool:
+    """Whether a block tiles a pool of ``n`` rows — what the Pallas
+    vehicle needs (several blocks; a block is a power of two ≥ 512, so
+    lane-aligned)."""
+    return select_block_rows(n, d, itemsize) < n
+
+
+def _kth(r_s, lane, k_c):
+    """Each query's current k-th best score ([C, 1]; NEG until its list
+    holds k entries, and for a query that asks nothing)."""
+    return jnp.max(jnp.where(lane == k_c - 1, r_s, NEG), axis=1,
+                   keepdims=True)
+
+
+def _wants(m, r_s, lane, k_c):
+    """Per query ([C, 1] bool): the block's best remaining score ``m``
+    would enter its list."""
+    return (m > _kth(r_s, lane, k_c)) & (k_c > 0)
+
+
+def _any(flags):
+    """Scalar i32: some query's flag is set (a while-loop carry)."""
+    return jnp.max(jnp.where(flags, 1, 0))
+
+
+def _select_step(s, m, col, lane, base, r_s, r_r, k_c, roll):
+    """One insertion per query: the block's best remaining score ``m``
+    ([C, 1], the row max of ``s``) enters the sorted list where it beats
+    the query's k-th best; its column is suppressed in ``s``. Returns the
+    new ``(s, m, r_s, r_r, go)``; ``go`` says whether any query still has
+    a score in this block that would enter."""
+    blk = s.shape[1]
+    act = _wants(m, r_s, lane, k_c)
+    idx = jnp.min(jnp.where(s == m, col, blk), axis=1, keepdims=True)
+    # entries >= m stay ahead (they came from lower rows): m lands at `pos`
+    pos = jnp.sum(jnp.where(r_s >= m, 1.0, 0.0), axis=1,
+                  keepdims=True).astype(jnp.int32)
+    ins_s = jnp.where(lane < pos, r_s,
+                      jnp.where(lane == pos, m, roll(r_s)))
+    ins_r = jnp.where(lane < pos, r_r,
+                      jnp.where(lane == pos, idx + base, roll(r_r)))
+    r_s = jnp.where(act, ins_s, r_s)
+    r_r = jnp.where(act, ins_r, r_r)
+    s = jnp.where(col == idx, NEG, s)
+    m = jnp.max(s, axis=1, keepdims=True)
+    return s, m, r_s, r_r, _any(_wants(m, r_s, lane, k_c))
+
+
+def _gate_step(scores, row_gate, tenant_c, col, base, g_s, g_r):
+    """The gate tier's running top-1 ([C, 1] each): a later block wins
+    only with a strictly better score, so ties keep the lowest row."""
+    blk = scores.shape[1]
+    sg = jnp.where(row_gate == tenant_c, scores, NEG)
+    mg = jnp.max(sg, axis=1, keepdims=True)
+    ig = jnp.min(jnp.where(sg == mg, col, blk), axis=1, keepdims=True)
+    upd = mg > g_s
+    return jnp.where(upd, mg, g_s), jnp.where(upd, ig + base, g_r)
+
+
+# ---------------------------------------------------------------- plain JAX
+
+
+def _scan_jax(emb, qn, row_main, row_gate, tenant_c, k_c, kmax, kp: int,
+              block: int, sentinel: int):
+    """The blocked core in plain JAX. Every argument is already padded:
+    ``tenant_c`` / ``k_c`` are [C, 1], the lists are ``kp`` wide."""
+    n = emb.shape[0]
+    c = qn.shape[0]
+    nblocks = n // block
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, block), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (c, kp), 1)
+    roll = functools.partial(jnp.roll, shift=1, axis=1)
+
+    def one_block(b, carry):
+        r_s, r_r, g_s, g_r = carry
+        base = b * block
+        with jax.named_scope("lz.scan"):
+            emb_b = jax.lax.dynamic_slice_in_dim(emb, base, block, 0)
+            rm = jax.lax.dynamic_slice_in_dim(row_main, base, block, 0)
+            rg = jax.lax.dynamic_slice_in_dim(row_gate, base, block, 0)
+            scores = nt_dot(qn, emb_b)                     # [C, block] f32
+            s = jnp.where(rm[None, :] == tenant_c, scores, NEG)
+        with jax.named_scope("lz.topk"):
+            g_s, g_r = _gate_step(scores, rg[None, :], tenant_c, col, base,
+                                  g_s, g_r)
+            m = jnp.max(s, axis=1, keepdims=True)
+            go = _any(_wants(m, r_s, lane, k_c))
+
+            def body(cy):
+                t, s, m, r_s, r_r, _ = cy
+                return (t + 1,) + _select_step(s, m, col, lane, base, r_s,
+                                               r_r, k_c, roll)
+
+            out = jax.lax.while_loop(
+                lambda cy: (cy[5] > 0) & (cy[0] < kmax), body,
+                (jnp.int32(0), s, m, r_s, r_r, go))
+        return out[3], out[4], g_s, g_r
+
+    init = (jnp.full((c, kp), NEG, jnp.float32),
+            jnp.full((c, kp), sentinel, jnp.int32),
+            jnp.full((c, 1), NEG, jnp.float32),
+            jnp.full((c, 1), sentinel, jnp.int32))
+    if nblocks == 1:
+        return one_block(0, init)
+    return jax.lax.fori_loop(0, nblocks, one_block, init)
+
+
+# ------------------------------------------------------------------- Pallas
+
+
+def _select_kernel(block: int, kp: int, sentinel: int):
+    def kernel(kmax_ref, hasg_ref, q_ref, tq_ref, kq_ref, emb_ref, rm_ref,
+               rg_ref, rs_ref, rr_ref, gs_ref, gr_ref, s_ref):
+        b = pl.program_id(0)
+        c = q_ref.shape[0]
+
+        @pl.when(b == 0)
+        def _():
+            rs_ref[...] = jnp.full((c, kp), NEG, jnp.float32)
+            rr_ref[...] = jnp.full((c, kp), sentinel, jnp.int32)
+            gs_ref[...] = jnp.full((c, 1), NEG, jnp.float32)
+            gr_ref[...] = jnp.full((c, 1), sentinel, jnp.int32)
+
+        scores = jax.lax.dot_general(
+            q_ref[...], emb_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [C, block]
+        tq = tq_ref[...]
+        kq = kq_ref[...]
+        base = b * block
+        col = jax.lax.broadcasted_iota(jnp.int32, (c, block), 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (c, kp), 1)
+        roll = functools.partial(pltpu.roll, shift=1, axis=1)
+
+        @pl.when(hasg_ref[b] > 0)       # most blocks hold no super row
+        def _():
+            g_s, g_r = _gate_step(scores, rg_ref[...], tq, col, base,
+                                  gs_ref[...], gr_ref[...])
+            gs_ref[...] = g_s
+            gr_ref[...] = g_r
+
+        s = jnp.where(rm_ref[...] == tq, scores, NEG)
+        s_ref[...] = s
+        m = jnp.max(s, axis=1, keepdims=True)
+        go = _any(_wants(m, rs_ref[...], lane, kq))
+        kmax = kmax_ref[0]
+
+        def body(cy):
+            t, m, _ = cy
+            s, m, r_s, r_r, go = _select_step(
+                s_ref[...], m, col, lane, base, rs_ref[...], rr_ref[...],
+                kq, roll)
+            s_ref[...] = s
+            rs_ref[...] = r_s
+            rr_ref[...] = r_r
+            return t + 1, m, go
+
+        jax.lax.while_loop(lambda cy: (cy[2] > 0) & (cy[0] < kmax), body,
+                           (jnp.int32(0), m, go))
+
+    return kernel
+
+
+def _scan_pallas(emb, qn, row_main, row_gate, tenant_c, k_c, kmax, kp: int,
+                 block: int, sentinel: int, interpret: bool):
+    """The blocked core as one Pallas TPU kernel over the grid of blocks.
+    Same arguments and results as :func:`_scan_jax`."""
     n, d = emb.shape
-    blk = fit_block_rows(n, d, emb.dtype.itemsize)
-    assert blk, f"arena rows {n} have no VMEM-fitting block divisor >= 512"
-    madd = jnp.where(mask, 0.0, NEG).astype(jnp.float32)
-    return pallas_masked_topk(emb, madd, queries.astype(emb.dtype),
-                              k=k, block_rows=blk, interpret=not on_tpu())
+    c = qn.shape[0]
+    nblocks = n // block
+    has_gate = (row_gate.reshape(nblocks, block) != ROW_DEAD).any(
+        axis=1).astype(jnp.int32)
+    fixed = lambda b, *_: (0, 0)                           # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(nblocks,),
+        in_specs=[
+            pl.BlockSpec((c, d), fixed),
+            pl.BlockSpec((c, 1), fixed),
+            pl.BlockSpec((c, 1), fixed),
+            pl.BlockSpec((block, d), lambda b, *_: (b, 0)),
+            pl.BlockSpec((1, block), lambda b, *_: (0, b)),
+            pl.BlockSpec((1, block), lambda b, *_: (0, b)),
+        ],
+        out_specs=[
+            pl.BlockSpec((c, kp), fixed),
+            pl.BlockSpec((c, kp), fixed),
+            pl.BlockSpec((c, 1), fixed),
+            pl.BlockSpec((c, 1), fixed),
+        ],
+        scratch_shapes=[pltpu.VMEM((c, block), jnp.float32)],
+    )
+    vmem = (2 * block * d * emb.dtype.itemsize + 6 * c * block * 4
+            + 8 * 1024 * 1024)
+    return pl.pallas_call(
+        _select_kernel(block, kp, sentinel),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((c, kp), jnp.float32),
+            jax.ShapeDtypeStruct((c, kp), jnp.int32),
+            jax.ShapeDtypeStruct((c, 1), jnp.float32),
+            jax.ShapeDtypeStruct((c, 1), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=int(vmem)),
+        interpret=interpret,
+        name="lz_select_scan",
+    )(kmax.reshape(1), has_gate, qn, tenant_c, k_c, emb,
+      row_main.reshape(1, n), row_gate.reshape(1, n))
+
+
+# ----------------------------------------------------------------- dispatch
+
+
+def blocked_two_tier(emb: jax.Array, qn: jax.Array, row_main: jax.Array,
+                     row_gate: jax.Array, tenant_c: jax.Array, k: int,
+                     k_c: Optional[jax.Array] = None, impl: str = "auto"
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Gate top-1 + main top-``k`` of every query over its own tenant's
+    rows, selected while the pool streams once.
+
+    ``emb`` [n, d] (rows L2-normalized), ``qn`` [C, d] (normalized, in
+    ``emb``'s dtype), ``row_main`` / ``row_gate`` [n] i32 (the tenant that
+    may read the row in that tier, ``ROW_DEAD`` for none), ``tenant_c`` [C]
+    i32, ``k`` the static list width, ``k_c`` [C] i32 each query's own k
+    (None: all ``k``). Returns ``(gate_s [C], gate_r [C], ann_s [C, k],
+    ann_r [C, k])`` with POOL rows; an empty slot is ``(NEG, n - 1)``.
+
+    ``impl``: "auto" takes the Pallas kernel on a TPU when the block tiles
+    the pool, the plain-JAX loop otherwise; "pallas" / "jax" force one
+    (Pallas runs in interpret mode off the TPU)."""
+    n, d = emb.shape
+    c = qn.shape[0]
+    sentinel = n - 1
+    block = select_block_rows(n, d, emb.dtype.itemsize)
+    tiles = block < n
+    if impl == "pallas" and not tiles:
+        raise ValueError(f"no block tiles a pool of {n} rows")
+    use_pallas = impl == "pallas" or (impl == "auto" and on_tpu() and tiles)
+    if k_c is None:
+        k_c = jnp.full((c,), k, jnp.int32)
+    k_c = jnp.clip(k_c.astype(jnp.int32), 0, k)
+    if c > _MAX_QUERIES:
+        from lazzaro_tpu.ops.chunking import chunked_map_multi
+        return chunked_map_multi(
+            lambda q_p, t_p, k_p: blocked_two_tier(
+                emb, q_p, row_main, row_gate, t_p, k, k_p, impl),
+            (qn, tenant_c, k_c), chunk=_MAX_QUERIES)
+    # the kernel's tiles: bf16 queries are 16 deep, the lists 128 lanes wide
+    cp = -(-c // 16) * 16 if use_pallas else c
+    kp = -(-k // 128) * 128 if use_pallas else k
+    pad = cp - c
+    t2 = jnp.pad(tenant_c.astype(jnp.int32), (0, pad),
+                 constant_values=ROW_DEAD + 1)[:, None]
+    k2 = jnp.pad(k_c, (0, pad))[:, None]
+    q2 = jnp.pad(qn, ((0, pad), (0, 0)))
+    args = (emb, q2, row_main, row_gate, t2, k2, jnp.max(k_c))
+    if use_pallas:
+        r_s, r_r, g_s, g_r = _scan_pallas(
+            *args, kp=kp, block=block, sentinel=sentinel,
+            interpret=not on_tpu())
+    else:
+        r_s, r_r, g_s, g_r = _scan_jax(*args, kp, block, sentinel)
+    with jax.named_scope("lz.topk"):
+        # an insertion shifts older entries past a query's own k: cut there
+        live = jnp.arange(k)[None, :] < k_c[:, None]
+        ann_s = jnp.where(live, r_s[:c, :k], NEG)
+        ann_r = jnp.where(live, r_r[:c, :k], sentinel)
+    return g_s[:c, 0], g_r[:c, 0], ann_s, ann_r
+
+
+def masked_topk(emb: jax.Array, mask: jax.Array, queries: jax.Array, k: int,
+                k_q: Optional[jax.Array] = None, impl: str = "pallas"
+                ) -> Tuple[jax.Array, jax.Array]:
+    """The core's ONE-mask case — the classic ``arena_search`` and the
+    per-shard scorer of ``ops.topk.make_sharded_topk``: every query reads
+    the rows ``mask`` [n] allows, one main tier, no gate. ``emb`` [n, d]
+    must be tiled by a block (:func:`block_tiles`). Returns ``(scores
+    [Q, k], rows [Q, k])``; an empty slot is ``(NEG, n - 1)``."""
+    n = emb.shape[0]
+    _, _, top_s, top_r = blocked_two_tier(
+        emb, queries.astype(emb.dtype),
+        jnp.where(mask, 0, ROW_DEAD).astype(jnp.int32),
+        jnp.full((n,), ROW_DEAD, jnp.int32),
+        jnp.zeros((queries.shape[0],), jnp.int32), k, k_q, impl)
+    return top_s, top_r

@@ -138,22 +138,19 @@ def make_sharded_topk(mesh: Mesh, axis: str = "data", k: int = 10,
     def local_candidates(emb_l, mask_l, query):
         # emb_l: [N/n, d], mask_l: [N/n], query: [Q, d] (replicated)
         from lazzaro_tpu.core.state import PALLAS_TOPK_MIN_ROWS
-        from lazzaro_tpu.ops.pallas_topk import fit_block_rows, pallas_masked_topk
+        from lazzaro_tpu.ops.pallas_topk import block_tiles, masked_topk
 
         local_n = emb_l.shape[0]
         k_eff = min(k, local_n)
-        blk = fit_block_rows(local_n, emb_l.shape[1], emb_l.dtype.itemsize)
         # same auto gate as the single-chip dispatch (state.arena_search),
         # with the row threshold scaled to the per-shard slice
-        use_pallas = blk > 0 and k_eff <= 16 and query.shape[0] <= 128 and (
+        use_pallas = block_tiles(local_n, emb_l.shape[1],
+                                 emb_l.dtype.itemsize) and (
             impl == "pallas"
             or (impl == "auto" and on_tpu()
                 and local_n >= PALLAS_TOPK_MIN_ROWS // n_shards))
         if use_pallas:
-            madd = jnp.where(mask_l, 0.0, NEG_INF).astype(jnp.float32)
-            return pallas_masked_topk(emb_l, madd, query.astype(emb_l.dtype),
-                                      k=k_eff, block_rows=blk,
-                                      interpret=not on_tpu())
+            return masked_topk(emb_l, mask_l, query, k_eff)
         from lazzaro_tpu.ops.chunking import nt_dot
         scores = nt_dot(query.astype(emb_l.dtype), emb_l)
         scores = jnp.where(mask_l[None, :], scores, NEG_INF)

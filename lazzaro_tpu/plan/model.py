@@ -9,10 +9,12 @@ from buffer accounting of what the fused kernels actually allocate:
 
 - the RESIDENT live set every dispatch carries (arena columns + int8
   shadow + IVF tables + edge arena + CSR),
-- the TRANSIENT high-water mark of the scan itself, dominated by the
-  ``[min(batch, scan_chunk), rows]`` f32 score tile the chunked-map
-  structure bounds (``ops/chunking.py``), plus query/readback/top-k
-  workspace terms linear in the batch.
+- the TRANSIENT high-water mark of the scan itself: for the exact family
+  one block's ``[chunk, block]`` tile, the tier columns and the running
+  top-k lists of the select-while-scanning core (``ops/pallas_topk.py``);
+  for the other dense families the ``[min(batch, scan_chunk), rows]`` f32
+  score tile the chunked-map structure bounds (``ops/chunking.py``); plus
+  query/readback/top-k workspace terms linear in the batch.
 
 The model is deliberately conservative and then CALIBRATED against the
 measured truth: every AOT ``memory_analysis()`` gauge the PR 6/PR 9
@@ -63,6 +65,22 @@ _DEFAULT_MULTIPLIER = 1.25
 # constant term instead (ISSUE 18: surfaced by the replica bench's
 # 706-row ingest gauges).
 DISPATCH_WORKSPACE_BYTES = 2 << 20
+
+# Mirrors ops/pallas_topk (SELECT_BLOCK, _BLOCK_BYTES, select_block_rows),
+# as literals for the same reason as the chunks above.
+SELECT_BLOCK = 4096
+_SELECT_BLOCK_BYTES = 8 * 1024 * 1024
+
+
+def select_block_rows(n: int, d: int, itemsize: int) -> int:
+    """Rows per block of the exact serving core for a pool of ``n`` rows
+    (``n`` itself: one whole-pool block)."""
+    blk = SELECT_BLOCK
+    while blk > 512 and blk * d * itemsize > _SELECT_BLOCK_BYTES:
+        blk //= 2
+    while blk >= 512 and n % blk != 0:
+        blk //= 2
+    return blk if 512 <= blk < n else n
 
 
 @dataclass(frozen=True)
@@ -293,6 +311,18 @@ class CostModel:
                 # the in-dispatch batch encode (ISSUE 16): [batch, m,
                 # 256] sub-distance tile against the frozen codebook
                 tile += g.batch * max(1, g.dim // 8) * 256 * 4
+        elif fam == "exact":
+            # the blocked select-while-scanning core (ISSUE 26): no
+            # [chunk, rows] tile — ONE block's scores with their masked
+            # copy and compare workspace, the two per-row tenant columns
+            # the tiers mask on, and the running [chunk, k] lists (score
+            # + row, carried and updated). A pool no block tiles is one
+            # whole-pool block, and the first term is the old tile.
+            block = select_block_rows(scan_rows_pc, g.dim,
+                                      g.dtype_bytes)
+            tile = chunk * block * 4 * 3
+            tile += (scan_rows_pc + 1) * 4 * 2
+            tile += chunk * (-(-g.k // 128) * 128) * 8 * 2
         else:
             # dense scan: [chunk, rows] f32 scores + the two mask tiles
             # and the top-k workspace XLA materializes beside them

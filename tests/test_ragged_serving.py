@@ -251,26 +251,27 @@ def test_sharded_ragged_one_kernel_mixed_k():
 
 
 def test_ragged_pallas_topk_matches_per_k():
-    """The ragged-K blocked scan: ceiling compute + per-query boundary mask
-    equals per-k ``lax.top_k`` results for every k in the batch."""
-    from lazzaro_tpu.ops.pallas_topk import pallas_masked_topk_ragged
+    """The ragged-K blocked scan: each query's own k as device data under
+    the static ceiling equals per-k ``lax.top_k`` results for every k in
+    the batch; slots past a query's k hold (NEG, last row)."""
+    from lazzaro_tpu.ops.pallas_topk import masked_topk
 
     rng = np.random.default_rng(0)
-    emb = jnp.asarray(rng.standard_normal((512, D)).astype(np.float32))
-    madd = jnp.where(jnp.arange(512) % 7 == 0, -1e30, 0.0
-                     ).astype(jnp.float32)
+    n = 3 * 512
+    emb = jnp.asarray(rng.standard_normal((n, D)).astype(np.float32))
+    mask = jnp.arange(n) % 7 != 0
     q = jnp.asarray(rng.standard_normal((4, D)).astype(np.float32))
     k_q = jnp.asarray([2, 8, 1, 5], jnp.int32)
-    s, i = pallas_masked_topk_ragged(emb, madd, q, k_q, k=8,
-                                     block_rows=128, interpret=True)
-    scores = q @ emb.T + madd[None, :]
+    s, i = masked_topk(emb, mask, q, 8, k_q, impl="pallas")
+    scores = jnp.where(mask[None, :], q @ emb.T, -1e30)
     for qi, kk in enumerate([2, 8, 1, 5]):
         ts, ti = jax.lax.top_k(scores[qi], kk)
         np.testing.assert_allclose(np.asarray(s)[qi, :kk], np.asarray(ts),
                                    rtol=1e-5)
         np.testing.assert_array_equal(np.asarray(i)[qi, :kk],
                                       np.asarray(ti))
-        assert (np.asarray(i)[qi, kk:] == -1).all()
+        assert (np.asarray(i)[qi, kk:] == n - 1).all()
+        assert (np.asarray(s)[qi, kk:] == np.float32(-1e30)).all()
 
 
 # --------------------------------------------------- continuous batching

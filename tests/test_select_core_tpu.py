@@ -1,0 +1,91 @@
+"""The select-while-scanning kernel compiles for the chip at the benchmark's
+real geometries (ISSUE 26; tier-1, no chip: the TPU compiler is installed
+here and compiles for a v5e that is described, not attached). What interpret
+mode cannot show — tiling, VMEM, what Mosaic lowers — at no chip time. A
+compile that passes is not a chip run. The topology is described inside a
+fixture, never at import (one process at a time may load libtpu)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from lazzaro_tpu.core import state as S
+from lazzaro_tpu.ops import pallas_topk as PT
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # noqa: BLE001 — any failure is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("rows,batch,dtype", [
+    (135_168, 24, jnp.bfloat16),        # share131k, a mid batch bucket
+    (5_001_216, 64, jnp.bfloat16),      # lme5m, the full batch
+    (5_001_216, 8, jnp.bfloat16),       # lme5m, the lone dispatch
+    (1_048_576, 128, jnp.float32),      # an f32 arena, the widest call
+])
+def test_kernel_compiles_for_v5e(one_chip, rows, batch, dtype):
+    d, k = 768, 128
+    item = jnp.dtype(dtype).itemsize
+    block = PT.select_block_rows(rows, d, item)
+    assert PT.block_tiles(rows, d, item)
+    c = -(-batch // 16) * 16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def core(emb, qn, rm, rg, t, kc, kmax):
+        return PT._scan_pallas(emb, qn, rm, rg, t, kc, kmax, kp=k,
+                               block=block, sentinel=rows - 1,
+                               interpret=False)
+
+    comp = jax.jit(core).lower(
+        sds((rows, d), dtype), sds((c, d), dtype), sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32), sds((c, 1), jnp.int32),
+        sds((c, 1), jnp.int32), sds((), jnp.int32)).compile()
+    text = comp.as_text()
+    assert "tpu_custom_call" in text
+    assert f"f32[{c},{rows}]" not in text
+
+
+def test_pod_exact_program_compiles_for_four_chips(topo, monkeypatch):
+    """``make_fused_sharded``'s exact mode on a 2x2 mesh at 20M rows (each
+    chip's slice is `lme5m`'s pool): the kernel sits inside the
+    ``shard_map`` and the candidate merge is the one ``all_gather``. The
+    program asks ``on_tpu()`` which vehicle to take; here the test says."""
+    monkeypatch.setattr(PT, "on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+    n, d, c, edges = 4 * 1221 * 4096, 768, 64, 4096
+
+    def sds(shape, dt, spec=None):
+        spec = spec if spec is not None else P(*([None] * len(shape)))
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, spec))
+
+    st = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype,
+                      P("data", None) if a.ndim == 2 else P("data")),
+        jax.eval_shape(lambda: S.init_arena(n - 1, d, jnp.bfloat16)))
+    kern = S.make_fused_sharded(mesh, "data", k=128, cap_take=5, max_nbr=8,
+                                mode="exact", ragged=True)
+    text = kern.read.lower(
+        st, (), sds((4, n // 4 + 1), jnp.int32, P("data", None)),
+        sds((4, edges), jnp.int32, P("data", None)), sds((c, d), jnp.float32),
+        sds((c,), jnp.bool_), sds((c,), jnp.int32), sds((c,), jnp.bool_),
+        sds((c,), jnp.int32), sds((c,), jnp.int32),
+        sds((), jnp.float32)).compile().as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
+    assert f"f32[{c},{n // 4}]" not in text

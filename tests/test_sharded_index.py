@@ -72,17 +72,17 @@ def test_decay_tenant_scoped(mesh):
 
 def test_pallas_topk_interpret():
     import jax.numpy as jnp
-    from lazzaro_tpu.ops.pallas_topk import pallas_masked_topk
+    from lazzaro_tpu.ops.pallas_topk import masked_topk
     N, d, Q, K = 4096 * 2, 128, 8, 10
     rng = np.random.RandomState(3)
     emb = rng.randn(N, d).astype(np.float32)
     emb /= np.linalg.norm(emb, axis=1, keepdims=True)
-    madd = np.zeros(N, np.float32)
-    madd[::5] = -1e30
+    mask = np.ones(N, bool)
+    mask[::5] = False
     qs = rng.randn(Q, d).astype(np.float32)
-    s, i = pallas_masked_topk(jnp.asarray(emb), jnp.asarray(madd),
-                              jnp.asarray(qs), k=K, interpret=True)
+    s, i = masked_topk(jnp.asarray(emb), jnp.asarray(mask), jnp.asarray(qs),
+                       K, impl="pallas")
     i = np.asarray(i)
-    ref = qs @ emb.T + madd[None, :]
+    ref = np.where(mask[None, :], qs @ emb.T, -np.inf)
     for r in range(Q):
-        assert set(i[r]) == set(np.argsort(-ref[r])[:K])
+        assert list(i[r]) == list(np.argsort(-ref[r], kind="stable")[:K])

@@ -37,7 +37,7 @@ def _arena(n, d, seed=0):
 def test_pallas_local_matches_xla_local_and_oracle(mesh):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    n, d = 8 * 4096, 64          # local shards are block-alignable (4096)
+    n, d = 8 * 2 * 4096, 64      # each local shard is two blocks of 4096
     emb, mask, q = _arena(n, d)
     emb_s = jax.device_put(emb, NamedSharding(mesh, P("data", None)))
     mask_s = jax.device_put(mask, NamedSharding(mesh, P("data")))
