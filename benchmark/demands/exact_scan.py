@@ -11,8 +11,8 @@ def need(cfg: dict, batch: float) -> dict:
     """One dispatch of ``batch`` queries: every arena row is read once (the
     whole batch shares the pass) with its tenant and alive columns, every
     query is scored against every row, and ``k`` ids and scores come back per
-    query. The [batch, rows] score tile and the top-k's own traffic are the
-    implementation's, not the algorithm's: not counted."""
+    query. Whatever else an implementation moves (a block's score tile, the
+    selection's running lists) is its own, not the algorithm's: not counted."""
     rows, dim = cfg["rows"], cfg["dim"]
     item = _ITEM[cfg["dtype"]]
     k = cfg["k"]

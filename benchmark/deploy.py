@@ -6,22 +6,30 @@ installed through ``MemoryIndex``'s ``state`` setter with the host maps filled
 as ``MemoryIndex.add`` fills them — never millions of facts through
 ``end_conversation``. Warm-up drives the program's own ``warmup_serving`` /
 conversation API with the shapes the cell's traffic will use and no others.
+
+A configuration may name its layout, ``"mesh": {"axes": ["data"], "shape":
+[4]}``: the system is then built over that mesh of the first devices and its
+arena is made and filled shard by shard, never whole on one chip. Without the
+key nothing here touches a mesh.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 import os
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
 
 from lazzaro_tpu import MemorySystem
 from lazzaro_tpu.config import MemoryConfig
 from lazzaro_tpu.core import state as S
+from lazzaro_tpu.parallel.mesh import make_mesh
 from lazzaro_tpu.serve.scheduler import RetrievalRequest
 from lazzaro_tpu.utils.batching import bucket_size
 from lazzaro_tpu.utils.compile_cache import place_compile_cache
@@ -47,12 +55,23 @@ def place_cache() -> str:
     return path
 
 
+def mesh_of(cfg: dict) -> Optional[Mesh]:
+    """The mesh a configuration names, over the first devices jax has (too
+    few of them is ``make_mesh``'s error); None where it names none."""
+    if "mesh" not in cfg:
+        return None
+    axes, shape = cfg["mesh"]["axes"], cfg["mesh"]["shape"]
+    return make_mesh(tuple(axes), tuple(shape),
+                     devices=jax.devices()[:math.prod(shape)])
+
+
 def build_system(cfg: dict, work_dir: str, embedder=None, llm=None
                  ) -> MemorySystem:
     """One ``MemorySystem`` of the configuration's deployment: its
     ``memory_config`` is the program's ``MemoryConfig``, field for field, so a
     configuration switches a serving mode on by naming the field. Only what
-    belongs to this run is set here (where it writes, that it is quiet)."""
+    belongs to this run is set here (where it writes, that it is quiet, and
+    the mesh the configuration names)."""
     mc = cfg["memory_config"]
     if (mc["embed_dim"], mc["dtype"]) != (cfg["dim"], cfg["dtype"]):
         raise ValueError("memory_config's embed_dim and dtype have to be the "
@@ -60,14 +79,61 @@ def build_system(cfg: dict, work_dir: str, embedder=None, llm=None
     kw = {}
     if embedder is not None:
         kw.update(embedding_provider=embedder, llm_provider=llm)
-    return MemorySystem(
-        config=MemoryConfig(**mc, db_dir=os.path.join(work_dir, "db")),
-        verbose=False, **kw)
+    mesh = mesh_of(cfg)
+    if mesh is None:
+        return MemorySystem(
+            config=MemoryConfig(**mc, db_dir=os.path.join(work_dir, "db")),
+            verbose=False, **kw)
+    # MemoryIndex makes its arena whole on ONE chip and reshards it afterwards
+    # (S.init_arena in __init__, S.grow_arena), so a deployment larger than a
+    # chip cannot be constructed as configured (PERF.md, Open questions). The
+    # system is built one select block large and handed the arena it would
+    # have made, every column made in its shards.
+    small = dict(mc, initial_capacity=S.TOPK_BLOCK - 1)
+    ms = MemorySystem(
+        config=MemoryConfig(**small, db_dir=os.path.join(work_dir, "db")),
+        verbose=False, mesh=mesh, **kw)
+    ms.config.initial_capacity = mc["initial_capacity"]
+    _arena_in_shards(ms.index, mc["initial_capacity"])
+    return ms
+
+
+def _arena_in_shards(idx, capacity: int) -> None:
+    """An empty arena of ``capacity`` rows (rounded as the index rounds it)
+    in the index's own shardings, each shard made on its chip."""
+    cap = idx._round_capacity(capacity)
+    make = functools.partial(S.init_arena, cap, idx.dim, idx.dtype)
+    shardings = jax.tree_util.tree_map(
+        lambda a: idx._mat_sharding if a.ndim == 2 else idx._row_sharding,
+        jax.eval_shape(make))
+    idx.state = jax.jit(make, out_shardings=shardings)()
+    idx._free_rows = list(range(cap - 1, -1, -1))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _place(emb, block, row0):
     return jax.lax.dynamic_update_slice(emb, block, (row0, jnp.int32(0)))
+
+
+def _place_in_shards(mesh, axis: str):
+    """``_place`` for an arena row-sharded over ``axis``: every chip writes
+    the part of the block that falls into its own rows, in place, and no chip
+    ever holds another's (left to GSPMD, ``_place`` all-gathers the arena).
+    A block may straddle two shards or miss a shard altogether."""
+    def local(emb, block, row0):
+        n, b = emb.shape[0], block.shape[0]
+        off = row0 - jax.lax.axis_index(axis) * n     # the block's first row, here
+        at = jnp.clip(off, 0, n - b)
+        j = jnp.arange(b, dtype=jnp.int32) + (at - off)   # block row of emb[at + i]
+        cur = jax.lax.dynamic_slice(emb, (at, jnp.int32(0)), block.shape)
+        new = jnp.where(((j >= 0) & (j < b))[:, None],
+                        jnp.roll(block, off - at, axis=0), cur)
+        return jax.lax.dynamic_update_slice(emb, new, (at, jnp.int32(0)))
+
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(axis, None), P(), P()),
+                                 out_specs=P(axis, None)),
+                   donate_argnums=(0,))
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
@@ -118,11 +184,13 @@ def install_rows(ms: MemorySystem, cfg: dict, seed: int, rows: int,
     seed2 = jnp.asarray(corpus.seed_words(seed))
     starts_d = jnp.asarray(starts)
     emb = st.emb
+    place = (_place if idx.mesh is None
+             else _place_in_shards(idx.mesh, idx.shard_axis))
     for row0 in range(0, rows, block):
         blk, _, _ = corpus.block_rows(
             seed2, starts_d, jnp.int32(tenant_first), jnp.int32(row0),
             block=block, dim=cfg["dim"], dtype=cfg["dtype"])
-        emb = _place(emb, blk, jnp.int32(row0))
+        emb = place(emb, blk, jnp.int32(row0))
     cols = _columns(starts_d, jnp.int32(rows), jnp.int32(tids[0]),
                     jnp.int32(S.TYPE_IDS.get("semantic", 0)),
                     jnp.int32(idx.shard_id("default")), n=n)

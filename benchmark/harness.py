@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import functools
 import gc
+import math
 import os
 import shutil
 import sys
@@ -50,9 +51,16 @@ def _with_debug(d: dict, debug: bool) -> dict:
     return out
 
 
+def mesh_chips(cfg: dict) -> int:
+    """Devices the layout a configuration names spans: 1 without ``mesh``."""
+    return math.prod(cfg["mesh"]["shape"]) if "mesh" in cfg else 1
+
+
 def cell_files(workload: str, root: str = ROOT, debug: bool = False
                ) -> Tuple[dict, dict, dict]:
-    """(cell, configuration, mix) of a workload, found by name."""
+    """(cell, configuration, mix) of a workload, found by name. The cell's
+    ``chips`` have to be what the configuration's layout spans (a debug run
+    then lays the ``debug`` block's own small mesh over it)."""
     m = manifest(root)
     cells = [w for w in m["workloads"] if w["name"] == workload]
     if len(cells) != 1:
@@ -60,6 +68,11 @@ def cell_files(workload: str, root: str = ROOT, debug: bool = False
     cell = cells[0]
     conf = [c for c in m["configs"] if c["name"] == cell["config"]][0]
     cfg = load_json(os.path.join(root, conf["file"]))
+    if mesh_chips(cfg) != cell["chips"]:
+        raise ValueError(
+            f"workload {workload!r} asks for {cell['chips']} chip(s), but its "
+            f"configuration {conf['name']!r} is laid out over "
+            f"{mesh_chips(cfg)} ({cfg.get('mesh', 'no mesh')})")
     mix = load_json(os.path.join(root, "benchmark", "mixes",
                                  cell["traffic"] + ".json"))
     return cell, _with_debug(cfg, debug), _with_debug(mix, debug)
@@ -260,13 +273,16 @@ def check_serving(reference, cmp, cfg, plan, samples, seed, starts,
 # --------------------------------------------------------------------------
 
 def require_chips(chips: int, debug: bool) -> None:
+    """``chips`` TPU chips; a debug run takes as many devices of any backend
+    (its configuration's ``debug`` mesh)."""
     devs = jax.devices()
-    if debug:
+    if len(devs) >= chips and (debug or devs[0].platform == "tpu"):
         return
-    if devs[0].platform != "tpu" or len(devs) < chips:
-        raise NoAccelerator(
-            f"needs {chips} TPU chip(s); jax sees {len(devs)} "
-            f"{devs[0].platform} device(s)")
+    what = (f"{chips} device(s) for the configuration's debug mesh (XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={chips} gives the CPU "
+            f"backend as many)" if debug else f"{chips} TPU chip(s)")
+    raise NoAccelerator(f"needs {what}; jax sees {len(devs)} "
+                        f"{devs[0].platform} device(s)")
 
 
 def _trace_options() -> ProfileOptions:
@@ -293,7 +309,8 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     t_start = time.perf_counter() if t_start is None else t_start
     cell, cfg, mix = cell_files(workload, root, debug)
     mix.update(mix_override or {})
-    require_chips(cell["chips"], debug)
+    # the cell's chips; a debug run's are its configuration's debug mesh
+    require_chips(mesh_chips(cfg), debug)
     # a debug run (tests) leaves the process's cache policy alone
     cache_dir = None if debug else deploy.place_cache()
     compiles = Compiles()

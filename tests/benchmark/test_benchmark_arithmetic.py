@@ -281,6 +281,17 @@ def test_demand_of_both_configurations_by_hand():
     assert least["seconds"] == pytest.approx(2.0199e8 / 819e9, rel=1e-3)   # 0.247 ms
 
 
+@pytest.mark.parametrize("batch,want_bytes,want_ops", [
+    (1, 7_705_001_576, 7_680_000_000.0),
+    (64, 7_705_100_864, 491_520_000_000.0)])
+def test_lme5m_demand_is_the_dict_it_was_accepted_with(batch, want_bytes, want_ops):
+    # the file's prose was reworded after PR 26 took the score tile out of
+    # the program; what a dispatch has to move did not change
+    _, lme, _ = harness.cell_files("fill.serve", ROOT)
+    assert files.load_module(lme["demand"], ROOT).need(lme, batch) == {
+        "bytes": want_bytes, "ops": want_ops, "ops_peak": "bf16_flops_per_s"}
+
+
 # -------------------------------------------------------------- comparison
 
 LIMITS = {"score_gap": 1e-4, "rank_errors": 0, "foreign_ids": 0,

@@ -7,53 +7,30 @@ read is a device number."""
 import os
 import sys
 
-import jax.numpy as jnp
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, ROOT)
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+sys.path[0:0] = [ROOT, HERE]
 
+import contracts  # noqa: E402
 from benchmark import harness  # noqa: E402
 
 CELLS = [w["name"] for w in harness.manifest(ROOT)["workloads"]]
-KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 
 
 def run(cell, seed, **kw):
-    return harness.run_cell(cell, seed, kw.pop("seconds", 0.6),
-                            kw.pop("traced", False), debug=True, **kw)
+    return contracts.debug_run(cell, seed, ROOT, **kw)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_is_correct_and_its_line_has_the_contracts_keys(cell):
-    res = run(cell, seed=2**31 + 7)
-    assert list(res)[:5] == KEYS and list(res)[-1] == "compared"
-    assert set(res) == set(KEYS) | {"compared"}
-    assert res["correct"] is True and res["failed"] == 0 < res["attempted"]
-    want = {m["name"] for m in harness.metrics_of(
-        harness.cell_files(cell, ROOT)[0], "end_to_end")}
-    assert set(res["metrics"]) == want and "setup_s" in want
-    assert all(set(v) == {"value", "unit"} and v["value"] > 0
-               for v in res["metrics"].values())
-    assert set(res["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
-    gap = res["compared"]["score_gap"]
-    assert gap["value"] <= 1e-6 < gap["limit"]       # bf16 products are exact
-    assert all(v["value"] <= v["limit"] for v in res["compared"].values())
+    contracts.cell_line(cell, ROOT)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_traced_run_reports_per_layer_metrics_it_can_read(cell):
-    res = run(cell, seed=11, traced=True)
-    assert res["correct"] is True
-    names = {m["name"] for m in harness.metrics_of(
-        harness.cell_files(cell, ROOT)[0], "per_layer")}
-    assert set(res["metrics"]) <= names
-    # counters and spans read on any backend; device-trace metrics need a
-    # device plane and are left out here, never reported as 0
-    assert any(n.startswith("device.compiles") for n in res["metrics"])
-    assert not any(n.startswith(("kernel.", "device.idle")) for n in res["metrics"])
-    assert [v["value"] for n, v in res["metrics"].items()
-            if n.startswith("device.compiles")] == [0.0]
+    contracts.traced_line(cell, ROOT)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -66,8 +43,7 @@ def test_int8_control_in_the_programs_place_is_not_correct(cell, seed):
 
 
 def _wrong_tenant_mask(ms):
-    st = ms.index.state
-    ms.index.state = st.replace(tenant_id=jnp.roll(st.tenant_id, 200))
+    contracts.wrong_tenant_mask(ms)
 
 
 def _misrouted_answers(ms):

@@ -1,168 +1,92 @@
 """BENCHMARK.json against the contract it is checked by, and the harness's
-promise that a later PR adds a cell with files and entries alone."""
+promise that a later PR adds a cell with files and entries alone: every
+assertion is a function of a checkout's root (``contracts.py``), called here
+on the repository and again on a temporary checkout that two cells were added
+to, one of them on four chips over a sharded configuration."""
 
 import json
 import os
-import re
 import shutil
 import subprocess
 import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, ROOT)
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+sys.path[0:0] = [ROOT, HERE]
 
+import contracts  # noqa: E402
 from benchmark import files, harness, peaks  # noqa: E402
 
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 M = harness.manifest(ROOT)
 
 
-def _line(s):
-    return 1 <= len(s) <= 200 and "\n" not in s and "\t" not in s
-
-
 def test_top_level_keys_and_size():
-    assert set(M) == {"command", "paths", "run_seconds", "configs",
-                      "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
-    assert isinstance(M["run_seconds"], int) and 1 <= M["run_seconds"] <= 51
-    assert 1 <= len(M["command"]) <= 32 and all(_line(w) for w in M["command"])
-    assert 1 <= len(M["paths"]) <= 16
-    for p in M["paths"]:
-        assert re.fullmatch(r"[A-Za-z0-9_.\-/]{1,200}", p)
-        assert not p.startswith("/") and ".." not in p.split("/")
-        assert os.path.isdir(os.path.join(ROOT, p))
+    contracts.top_level(ROOT)
 
 
 @pytest.mark.parametrize("c", M["configs"], ids=lambda c: c["name"])
 def test_config_entry(c):
-    assert set(c) == {"name", "source", "file", "reduced", "why"}
-    assert NAME.match(c["name"]) and _line(c["source"]) and _line(c["why"])
-    assert any(c["file"].startswith(p + "/") for p in M["paths"])
-    cfg = harness.load_json(os.path.join(ROOT, c["file"]))
-    assert cfg["name"] == c["name"]
-    assert cfg["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
-    assert all(NAME.match(k) and k in cfg for k in c["reduced"])
-    assert any(w["config"] == c["name"] for w in M["workloads"])
-    assert cfg["guarantees"] and cfg["assumed"]
-    # its reference and its demand are files of the benchmark, found by path
-    for key in ("reference", "demand"):
-        assert any(cfg[key].startswith(p + "/") for p in M["paths"])
-    ref = files.load_module(cfg["reference"], ROOT)
-    ref.Comparison(cfg["limits"])                # every compared number has one
-    need = files.load_module(cfg["demand"], ROOT).need(cfg, 1)
-    assert need["bytes"] > 0 and need["ops"] > 0
-    # what the program is configured with is the program's own field names
-    from lazzaro_tpu.config import MemoryConfig
-    mc = MemoryConfig(**cfg["memory_config"])
-    assert (mc.embed_dim, mc.dtype) == (cfg["dim"], cfg["dtype"])
-    assert mc.initial_capacity >= cfg["rows"]
+    contracts.config_entry(c, ROOT)
 
 
 def test_config_files_and_names_are_distinct():
-    for key in ("configs", "workloads", "end_to_end", "per_layer"):
-        names = [e["name"] for e in M[key]]
-        assert len(set(names)) == len(names)
-    assert len({c["file"] for c in M["configs"]}) == len(M["configs"])
-    metric_names = [m["name"] for m in M["end_to_end"] + M["per_layer"]]
-    assert len(set(metric_names)) == len(metric_names)
+    contracts.names_distinct(ROOT)
 
 
 @pytest.mark.parametrize("w", M["workloads"], ids=lambda w: w["name"])
 def test_cell_resolves_to_files(w):
-    assert set(w) == {"name", "config", "traffic", "chips", "why"}
-    assert all(NAME.match(w[k]) for k in ("name", "config", "traffic"))
-    assert w["chips"] in (1, 4) and _line(w["why"])
-    cell, cfg, mix = harness.cell_files(w["name"], ROOT)
-    assert mix["loop"] in ("open", "closed", "conversations")
-    assert mix["name"] == w["traffic"] and cfg["name"] == w["config"]
-    e2e = [m["name"] for m in harness.metrics_of(cell, "end_to_end", ROOT)]
-    assert "setup_s" in e2e and len(e2e) >= 2
-    assert harness.metrics_of(cell, "per_layer", ROOT)
-    for kind in ("end_to_end", "per_layer"):
-        for m in harness.metrics_of(cell, kind, ROOT):
-            assert callable(harness.reader(m["name"], ROOT))
+    contracts.cell_resolves(w, ROOT)
 
 
 def test_cells_on_four_chips_within_quota():
-    four = sum(w["chips"] == 4 for w in M["workloads"])
-    assert four <= max(1, len(M["workloads"]) // 2)
-    pairs = [(w["config"], w["traffic"]) for w in M["workloads"]]
-    assert len(set(pairs)) == len(pairs)
+    contracts.four_chip_quota(ROOT)
 
 
 @pytest.mark.parametrize("m", M["end_to_end"], ids=lambda m: m["name"])
 def test_end_to_end_metric(m):
-    assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
-                                     "source"}
-    assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-    assert m["better"] in ("lower", "higher")
-    assert m["source"] in ("host_clock", "device_trace")
-    assert 0.01 <= m["bound"] <= 0.25
-    cells = {w["name"] for w in M["workloads"]}
-    assert set(m.get("workloads", cells)) <= cells
+    contracts.end_to_end_metric(m, ROOT)
 
 
 def test_setup_s_is_reported_by_every_cell():
-    setup = [m for m in M["end_to_end"] if m["name"] == "setup_s"]
-    assert len(setup) == 1 and "workloads" not in setup[0]
-    assert setup[0]["bound"] <= 0.25
+    contracts.setup_s(ROOT)
 
 
 @pytest.mark.parametrize("m", M["per_layer"], ids=lambda m: m["name"])
 def test_per_layer_metric(m):
-    assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
-                                     "layer", "moves"}
-    assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-    assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
-    assert _line(m["layer"])
-    moved = [e for e in M["end_to_end"] if e["name"] == m["moves"]]
-    assert len(moved) == 1
-    cells = [w["name"] for w in M["workloads"]]
-    reporting = set(moved[0].get("workloads", cells))
-    assert set(m.get("workloads", reporting)) <= reporting
-    if m["name"].endswith("_roofline") or "mfu" in m["name"]:
-        assert m["unit"] == "%"
+    contracts.per_layer_metric(m, ROOT)
 
 
-def test_a_later_pr_adds_a_cell_with_files_and_entries_alone(tmp_path):
-    root = str(tmp_path / "checkout")
-    os.makedirs(root)
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(ROOT, "benchmark"),
-                    os.path.join(root, "benchmark"))
-    before = {}
-    for d, _, names in os.walk(root):
-        for f in names:
-            p = os.path.join(d, f)
-            if not p.endswith("BENCHMARK.json"):
-                before[p] = open(p, "rb").read()
+# ------------------------------------------------- what a later PR will meet
+
+def _write(root, rel, text):
+    with open(os.path.join(root, rel), "w") as f:
+        f.write(text)
+
+
+def _add_int8_cell(root, m):
+    """Another size, a serving mode of the program switched on by its field
+    name, a reference, a demand, a mix and a metric of the new cell's own."""
     cfg = harness.load_json(os.path.join(root, "benchmark/configs/share131k.json"))
-    # another size, a serving mode of the program switched on by its field
-    # name, and a reference and a demand of the new configuration's own
     cfg.update(name="share262k", rows=262144,
                reference="benchmark/reference_recall.py",
                demand="benchmark/demands/int8_scan.py")
     cfg["memory_config"].update(initial_capacity=262208, int8_serving=True)
     cfg["debug"]["memory_config"].update(semantic_cache=True)
-    json.dump(cfg, open(os.path.join(root, "benchmark/configs/share262k.json"), "w"))
-    with open(os.path.join(root, "benchmark/reference_recall.py"), "w") as f:
-        f.write("from benchmark.reference import *  # noqa: F401,F403\n"
-                "MARK = 'recall'\n")
-    with open(os.path.join(root, "benchmark/demands/int8_scan.py"), "w") as f:
-        f.write("def need(cfg, batch):\n    return {'bytes': cfg['rows'] * "
-                "cfg['dim'], 'ops': 1.0, 'ops_peak': 'int8_ops_per_s'}\n")
+    _write(root, "benchmark/configs/share262k.json", json.dumps(cfg))
+    _write(root, "benchmark/reference_recall.py",
+           "from benchmark.reference import *  # noqa: F401,F403\n"
+           "MARK = 'recall'\n")
+    _write(root, "benchmark/demands/int8_scan.py",
+           "def need(cfg, batch):\n    return {'bytes': cfg['rows'] * "
+           "cfg['dim'], 'ops': 1.0, 'ops_peak': 'int8_ops_per_s'}\n")
     mix = harness.load_json(os.path.join(root, "benchmark/mixes/serve-open-zipf.json"))
     mix.update(name="serve-open-burst", rate_rps=50)
-    json.dump(mix, open(os.path.join(root, "benchmark/mixes/serve-open-burst.json"), "w"))
-    with open(os.path.join(root, "benchmark/metrics/sched.batch_p50.lat.py"), "w") as f:
-        f.write("from benchmark.readers import timer_p50\n\n\n"
-                "def read(run):\n    return timer_p50(run, 'serve.batch_requests')\n")
-    m = harness.manifest(root)
+    _write(root, "benchmark/mixes/serve-open-burst.json", json.dumps(mix))
+    _write(root, "benchmark/metrics/sched.batch_p50.lat.py",
+           "from benchmark.readers import timer_p50\n\n\n"
+           "def read(run):\n    return timer_p50(run, 'serve.batch_requests')\n")
     m["configs"].append({"name": "share262k", "source": "s", "why": "w",
                          "file": "benchmark/configs/share262k.json",
                          "reduced": ["rows", "tenants"]})
@@ -175,7 +99,98 @@ def test_a_later_pr_adds_a_cell_with_files_and_entries_alone(tmp_path):
                            "better": "higher", "source": "program_span",
                            "layer": "scheduler", "moves": "search_p50_ms",
                            "workloads": ["share2.burst"]})
-    json.dump(m, open(os.path.join(root, "BENCHMARK.json"), "w"))
+
+
+def _add_pod_cell(root, m):
+    """A configuration that names its layout, four chips, on a mix the
+    benchmark has, with a span metric, a counter metric and a
+    ``device.compiles.*`` of the cell's own: what ``pod.serve`` will be
+    (under names no real cell will take)."""
+    cfg = harness.load_json(os.path.join(root, "benchmark/configs/lme5m.json"))
+    cfg.update(name="later-mesh4", rows=20000000, tenants=2000,
+               mesh={"axes": ["data"], "shape": [4]},
+               demand="benchmark/demands/later_shard.py")
+    cfg["memory_config"].update(initial_capacity=20004863)
+    cfg["debug"]["mesh"] = {"shape": [4]}
+    _write(root, "benchmark/configs/later-mesh4.json", json.dumps(cfg))
+    _write(root, "benchmark/demands/later_shard.py",
+           "from benchmark.demands.exact_scan import need as whole\n\n\n"
+           "def need(cfg, batch):\n"
+           "    return whole(dict(cfg, rows=cfg['rows'] // 4), batch)\n")
+    readers = {
+        "dispatch.readback_p50_ms.later": (
+            "program_span", "dispatch", "ms", "lower",
+            "from benchmark.span_metrics import span_p50_ms\n\n\n"
+            "def read(run):\n"
+            "    return span_p50_ms(run, 'lz.dispatch.readback')\n"),
+        "sched.lone_dispatch_pct.later": (
+            "program_counter", "scheduler", "%", "lower",
+            "from benchmark.span_metrics import counter_ratio\n\n\n"
+            "def read(run):\n"
+            "    return counter_ratio(run, 'serve.lone_batches', "
+            "'serve.batches', 100.0, marker='serve.queue_wait_us')\n"),
+        "device.compiles.later": (
+            "program_counter", "device", "count", "lower",
+            "from benchmark.readers import compiles\n\n\n"
+            "def read(run):\n    return compiles(run)\n"),
+    }
+    for name, (source, layer, unit, better, text) in readers.items():
+        _write(root, f"benchmark/metrics/{name}.py", text)
+        m["per_layer"].append({"name": name, "unit": unit, "better": better,
+                               "source": source, "layer": layer,
+                               "moves": "search_qps", "workloads": ["later.pod"]})
+    m["configs"].append({"name": "later-mesh4", "source": "s", "why": "w",
+                         "file": "benchmark/configs/later-mesh4.json",
+                         "reduced": ["tenants"]})
+    m["workloads"].append({"name": "later.pod", "config": "later-mesh4",
+                           "traffic": "serve-closed-64", "chips": 4, "why": "w"})
+    for e in m["end_to_end"]:
+        if e["name"] == "search_qps":
+            e["workloads"].append("later.pod")
+
+
+class Later:
+    """A checkout as a later PR leaves it: the repository's manifest and
+    paths, and the two cells above added to them."""
+
+    def __init__(self, root):
+        self.root = root
+        shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+        for p in M["paths"]:
+            shutil.copytree(os.path.join(ROOT, p), os.path.join(root, p),
+                            ignore=shutil.ignore_patterns("__pycache__"))
+        self.before = {}
+        for d, _, names in os.walk(root):
+            for f in names:
+                p = os.path.join(d, f)
+                if not p.endswith("BENCHMARK.json"):
+                    self.before[p] = open(p, "rb").read()
+        m = harness.manifest(root)
+        _add_int8_cell(root, m)
+        _add_pod_cell(root, m)
+        _write(root, "BENCHMARK.json", json.dumps(m))
+
+    def nothing_was_edited(self):
+        for p, content in self.before.items():
+            assert open(p, "rb").read() == content, f"{p} had to be edited"
+        # entries were appended: every list of the manifest starts as it was
+        m = harness.manifest(self.root)
+        for key in ("configs", "workloads", "end_to_end", "per_layer"):
+            for old, new in zip(M[key], m[key]):
+                lists = {k for k in old if isinstance(old[k], list)}
+                assert {k: v for k, v in new.items() if k not in lists} == \
+                    {k: v for k, v in old.items() if k not in lists}
+                assert all(new[k][:len(old[k])] == old[k] for k in lists)
+        assert all(m[k] == M[k] for k in ("command", "paths", "run_seconds"))
+
+
+@pytest.fixture(scope="module")
+def later(tmp_path_factory):
+    return Later(str(tmp_path_factory.mktemp("checkout")))
+
+
+def test_a_later_pr_adds_a_cell_with_files_and_entries_alone(later, tmp_path):
+    root = later.root
     cell, cfg2, mix2 = harness.cell_files("share2.burst", root)
     assert cfg2["rows"] == 262144 and mix2["rate_rps"] == 50
     assert files.load_module(cfg2["reference"], root).MARK == "recall"
@@ -199,8 +214,111 @@ def test_a_later_pr_adds_a_cell_with_files_and_entries_alone(tmp_path):
     assert callable(harness.reader("sched.batch_p50.lat", root))
     e2e = [x["name"] for x in harness.metrics_of(cell, "end_to_end", root)]
     assert e2e == ["search_p50_ms", "search_p95_ms", "setup_s"]
-    for p, content in before.items():
-        assert open(p, "rb").read() == content, f"{p} had to be edited"
+    # the cell on four chips resolves to its own files as well
+    pod, pcfg, pmix = harness.cell_files("later.pod", root)
+    assert pod["chips"] == 4 == harness.mesh_chips(pcfg)
+    assert pmix["name"] == "serve-closed-64" and pcfg["rows"] == 20000000
+    assert files.load_module(pcfg["demand"], root).need(pcfg, 64) == \
+        files.load_module("benchmark/demands/exact_scan.py", root).need(
+            dict(pcfg, rows=5000000), 64)
+    assert [x["name"] for x in harness.metrics_of(pod, "per_layer", root)] == [
+        "dispatch.readback_p50_ms.later", "sched.lone_dispatch_pct.later",
+        "device.compiles.later"]
+    later.nothing_was_edited()
+
+
+def test_a_later_pr_s_manifest_passes_every_manifest_wide_assertion(later):
+    contracts.manifest_wide(later.root)
+    later.nothing_was_edited()
+
+
+def test_a_later_pr_s_four_chip_cell_passes_the_per_cell_contracts(later):
+    contracts.cell_line("later.pod", later.root)
+    contracts.traced_line("later.pod", later.root)
+    later.nothing_was_edited()
+
+
+def test_a_later_pr_s_four_chip_cell_reports_the_span_metrics_it_has(later):
+    mine = contracts.traced_debug_run_reports_span_metrics("later.pod",
+                                                           later.root)
+    assert mine == ["dispatch.readback_p50_ms.later", "sched.lone_dispatch_pct.later"]
+    later.nothing_was_edited()
+
+
+# ----------------------------------------- a configuration names its layout
+
+def test_sharded_cell_fills_its_arena_over_four_devices_and_is_correct(later):
+    seen = {}
+
+    def look(ms):
+        emb = ms.index.state.emb
+        seen.update(devices=len(emb.sharding.device_set),
+                    shards={s.data.shape for s in emb.addressable_shards},
+                    rows=emb.shape[0], mesh=ms.mesh,
+                    capacity=ms.config.initial_capacity)
+    res = contracts.debug_run("later.pod", 31, later.root, sabotage=look)
+    assert res["correct"] is True and res["failed"] == 0 < res["attempted"]
+    assert seen["devices"] == 4 and seen["mesh"].shape == {"data": 4}
+    assert seen["shards"] == {(seen["rows"] // 4, 64)}
+    assert seen["capacity"] == 4160          # as the file states it
+
+
+def test_sharded_cell_with_a_wrong_tenant_mask_is_not_correct(later):
+    res = contracts.debug_run("later.pod", 32, later.root,
+                              sabotage=contracts.wrong_tenant_mask)
+    assert res["correct"] is False
+    v = res["compared"]["foreign_ids"]
+    assert v["value"] > v["limit"]
+
+
+@pytest.mark.parametrize("seed", [3, 4, 5])
+def test_sharded_cell_s_int8_control_is_not_correct(later, seed):
+    res = contracts.debug_run("later.pod", seed, later.root, control="int8",
+                              seconds=0.4)
+    assert res["correct"] is False
+    gap = res["compared"]["score_gap"]
+    assert gap["value"] > 3 * gap["limit"]
+
+
+def test_mesh_that_is_not_the_cell_s_chips_is_refused_with_a_sentence(tmp_path):
+    root = str(tmp_path)
+    shutil.copytree(os.path.join(ROOT, "benchmark"), os.path.join(root, "benchmark"))
+    m = harness.manifest(ROOT)
+    _add_pod_cell(root, m)
+    m["workloads"][-1]["chips"] = 1
+    _write(root, "BENCHMARK.json", json.dumps(m))
+    with pytest.raises(ValueError, match=r"'later.pod' asks for 1 chip\(s\), "
+                       r"but its configuration 'later-mesh4' is laid out over 4"):
+        harness.run_cell("later.pod", 1, 0.4, False, root=root, debug=True)
+    with pytest.raises(ValueError, match="laid out over 4"):
+        harness.cell_files("later.pod", root)
+
+
+def test_debug_mesh_larger_than_the_devices_is_refused_with_a_sentence():
+    with pytest.raises(harness.NoAccelerator, match="needs 64 device"):
+        harness.require_chips(64, debug=True)
+    harness.require_chips(8, debug=True)         # conftest's eight
+
+
+def test_configuration_without_mesh_builds_today_s_system(tmp_path, monkeypatch):
+    from benchmark import deploy
+    _, cfg, _ = harness.cell_files("fill.serve", ROOT, debug=True)
+    assert "mesh" not in cfg and deploy.mesh_of(cfg) is None
+    calls = []
+    real = deploy.MemorySystem
+
+    def spy(*a, **kw):
+        calls.append(kw)
+        return real(*a, **kw)
+    monkeypatch.setattr(deploy, "MemorySystem", spy)
+    ms = deploy.build_system(cfg, str(tmp_path / "work"))
+    try:
+        assert set(calls[0]) == {"config", "verbose"}     # no mesh argument
+        assert ms.mesh is None and ms.index.mesh is None
+        assert ms.config.initial_capacity == 4160
+        assert len(ms.index.state.emb.sharding.device_set) == 1
+    finally:
+        ms.close()
 
 
 def test_peaks_table_known_and_unknown_kind():
