@@ -17,7 +17,8 @@ from __future__ import annotations
 import sys
 import threading
 import time
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import (Dict, Iterable, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -367,6 +368,17 @@ class SemanticCacheHost:
             }
 
 
+class _StagedSharded(NamedTuple):
+    """A pod serving dispatch, staged: the compiled twins and every device
+    operand but the state (and the tables made against it at the gate)."""
+
+    kern: S.FusedShardedKernels
+    sargs: tuple                  # per-shard CSR, queries, per-query columns
+    read_extra: tuple             # the read twin's tail
+    boost_extra: Optional[tuple]  # the serve twins' tail; None: no boost asked
+    sem_tail: tuple               # the semantic ring's operand, or ()
+
+
 class MemoryIndex:
     """Single-chip by default; pass ``mesh`` to row-shard every arena column
     over a mesh axis — the scaling-book recipe: annotate the shardings, let
@@ -583,10 +595,10 @@ class MemoryIndex:
             self._pager = PageAllocator(capacity, pool_slots,
                                         self.page_rows)
         else:
-            self.state = S.init_arena(capacity, dim, dtype)
+            self.state = self._init_rows(self._make_arena, capacity)
             self._ptable = None
             self._pager = None
-        self.edge_state = S.init_edges(edge_capacity)
+        self.edge_state = self._init_rows(S.init_edges, edge_capacity)
         self._free_rows: List[int] = list(range(capacity - 1, -1, -1))
         self._free_edge_slots: List[int] = list(range(edge_capacity - 1, -1, -1))
         self.id_to_row: Dict[str, int] = {}
@@ -764,6 +776,45 @@ class MemoryIndex:
     def _grown_capacity(self, old_capacity: int, block: bool = True) -> int:
         """Doubling that preserves block and mesh alignment of capacity+1."""
         return self._round_capacity((old_capacity + 1) * 2 - 1, block=block)
+
+    def _make_arena(self, capacity: int) -> S.ArenaState:
+        return S.init_arena(capacity, self.dim, self.dtype)
+
+    def _init_rows(self, make, capacity: int):
+        """A fresh arena or edge pool (``make(capacity)``); under a mesh
+        every column is created in its shards, never whole on one chip."""
+        if self.mesh is None:
+            return make(capacity)
+        return S.sharded_init(make, capacity, self.mesh, self.shard_axis)()
+
+    def _grow_rows(self, make, grow, state, new_capacity: int):
+        """``grow(state, new_capacity)``; under a mesh the sharded twin,
+        which moves rows between chips shard by shard (rows keep their global
+        numbers, so the contiguous blocks are cut anew) and is REFUSED, typed,
+        where a chip's free memory cannot hold its new shard beside the old
+        one: a pod deployment preallocates (``initial_capacity``)."""
+        if self.mesh is None:
+            return grow(state, new_capacity)
+        leaves = jax.tree_util.tree_leaves(state)
+        per_row = sum(a.dtype.itemsize * int(np.prod(a.shape[1:]))
+                      for a in leaves)
+        old_rows = leaves[0].shape[0] // self._n_parts
+        # the new shard, one old shard in transit and its shifted copy
+        need = per_row * ((new_capacity + 1) // self._n_parts + 2 * old_rows)
+        for dev in self.mesh.devices.flat:
+            stats = dev.memory_stats() or {}
+            if "bytes_limit" not in stats:      # a backend that reports none
+                continue
+            free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+            if need > free:
+                raise DeviceOom(
+                    f"growing {type(state).__name__} from {leaves[0].shape[0]} "
+                    f"to {new_capacity + 1} rows over {self._n_parts} chips "
+                    f"needs {need} bytes beside the old shard on every chip; "
+                    f"{dev} has {free} free. Preallocate the deployment "
+                    f"(initial_capacity) or shard it over more chips")
+        return S.grow_sharded(make, state, new_capacity, self.mesh,
+                              self.shard_axis)
 
     def _reshard(self, pytree):
         """Constrain every column to its row sharding (the only 2-D leaf,
@@ -1255,7 +1306,8 @@ class MemoryIndex:
                 self.state = S.grow_arena_paged(self.state, new_cap)
                 self._pager.grow_capacity(new_cap)
             else:
-                self.state = S.grow_arena(self.state, new_cap)
+                self.state = self._grow_rows(self._make_arena, S.grow_arena,
+                                             self.state, new_cap)
             self._int8_dirty = True        # logical emb shape changed
             pack = self._pq_pack
             if pack is not None and pack[1] is not None:
@@ -3188,36 +3240,50 @@ class MemoryIndex:
                     # to their statics; the boost branch passes it explicitly
                     # beside its donated state
                     statics = dict(statics, **sem_kw)
+            else:
+                mode = ("sharded_tiered" if tiered
+                        else "sharded_quant" if self.int8_serving
+                        else "sharded_exact")
+                # Semantic query cache (ISSUE 20): the replicated ring rides
+                # the SAME distributed dispatch (substitution-only — the
+                # shard-local scans still run; the probe/substitute/
+                # writeback are replicated arithmetic after the merge).
+                # Entries key on the FAMILY mode id, so they never cross
+                # serving modes.
+                semh = self._sem_host
+                fam = mode[len("sharded_"):]
+                self._note_select_core(fam, st)
+                sem_state = None
+                if semh is not None and fam in S.SEM_MODE_IDS:
+                    win = k_bucket + (self.coarse_slack if tiered else 0)
+                    if win <= semh.width:
+                        sem_state = semh.tuple_for(fam)
+                # every host→device put of the distributed dispatch, as on
+                # one chip: ``dispatch.launch`` is the jitted call alone
+                staged = self._stage_fused_sharded(
+                    st, indptr, nbr, qp, padb, valid, tenants, gate_on,
+                    boost_on, k_bucket, cap_take, max_nbr, super_gate,
+                    acc_boost, nbr_boost, now, ragged=ragged, k_arr=k_arr,
+                    cap_arr=cap_arr, tiered=tiered, sem=sem_state)
+                # Fault point "plan.oom" (ISSUE 11): models an HBM allocation
+                # failure the admission plan missed — recovery is ONE replan
+                # into split sub-dispatches through the copy twins.
+                faults.fire("plan.oom", mode=mode, batch=pad_n)
         if self.mesh is not None:
-            mode = ("sharded_tiered" if tiered
-                    else "sharded_quant" if self.int8_serving
-                    else "sharded_exact")
-            # Semantic query cache (ISSUE 20): the replicated ring rides
-            # the SAME distributed dispatch (substitution-only — the
-            # shard-local scans still run; the probe/substitute/writeback
-            # are replicated arithmetic after the merge). Entries key on
-            # the FAMILY mode id, so they never cross serving modes.
-            semh = self._sem_host
-            fam = mode[len("sharded_"):]
-            self._note_select_core(fam, st)
-            sem_state = None
-            if semh is not None and fam in S.SEM_MODE_IDS:
-                win = k_bucket + (self.coarse_slack if tiered else 0)
-                if win <= semh.width:
-                    sem_state = semh.tuple_for(fam)
-            # Fault point "plan.oom" (ISSUE 11): models an HBM allocation
-            # failure the admission plan missed — recovery is ONE replan
-            # into split sub-dispatches through the copy twins.
-            faults.fire("plan.oom", mode=mode, batch=pad_n)
             with tel.span("serve." + mode, timer="serve.dispatch_ms",
                           labels={"mode": mode}):
                 with tel.span("dispatch.launch"):
-                    packed = self._dispatch_fused_sharded(
-                        st, indptr, nbr, qp, padb, valid, tenants, gate_on,
-                        boost_on, k_bucket, cap_take, max_nbr, super_gate,
-                        acc_boost, nbr_boost, now, ragged=ragged,
-                        k_arr=k_arr, cap_arr=cap_arr, tiered=tiered,
-                        force_copy=force_copy, sem=sem_state)
+                    if staged.boost_extra is None:
+                        packed = staged.kern.read(
+                            st, self._sharded_tables(st, tiered),
+                            *staged.sargs, *staged.read_extra,
+                            *staged.sem_tail)
+                    else:
+                        # a live snapshot would trip the sole-owner gate,
+                        # and this frame's reference is one
+                        st = None
+                        packed = self._serve_fused_sharded(
+                            staged, mode, tiered, force_copy)
                     if sem_state is not None:
                         sem_ring2, packed = packed
                 with tel.span("dispatch.readback"):
@@ -3225,7 +3291,7 @@ class MemoryIndex:
             tel.bump("serve.dispatches", labels={"mode": mode})
             if tiered:
                 from lazzaro_tpu.tier.serve import tiered_decode_and_finish
-                del st                     # the finish may donate the state
+                st = None                  # the finish may donate the state
                 now_rel = ((now if now is not None else time.time())
                            - self.epoch)
                 if sem_state is not None:
@@ -3277,6 +3343,9 @@ class MemoryIndex:
                         # through the non-donating twin (ISSUE 11)
                         sole = (not force_copy
                                 and sys.getrefcount(cur) <= self._SOLE_REFS)
+                        if not sole:
+                            tel.bump("serve.copy_dispatches",
+                                     labels={"mode": mode})
                         # Each branch picks the (donated, copying) twin pair
                         # and the per-mode leading operands; ONE guarded call
                         # at the end executes it donation-safe (ISSUE 10):
@@ -3817,34 +3886,30 @@ class MemoryIndex:
                                  labels={"surface": "fused_sharded"})
         return kern
 
-    def _dispatch_fused_sharded(self, st, indptr, nbr, qp, padb, valid,
-                                tenants, gate_on, boost_on, k_bucket,
-                                cap_take, max_nbr, super_gate, acc_boost,
-                                nbr_boost, now, ragged=False, k_arr=None,
-                                cap_arr=None, tiered=False,
-                                force_copy=False, sem=None):
-        """The pod serving dispatch (ISSUE 5): the full chat-turn program
-        as ONE distributed shard_map dispatch against the row-sharded
-        arena. Exact by default; with ``int8_serving`` the shard-local
-        scan streams the row-sharded int8 shadow (coarse + exact rescore —
-        the same two-stage semantics as single-chip quant mode, so the
-        gate verdict never sees quantization error). ``indptr``/``nbr``
-        are the PER-SHARD CSR slices ``_csr_for`` builds under a mesh.
-        The donation gate is the same refcount contract as every other
-        mutation: donate only when this index provably holds the sole
-        arena reference. ``ragged=True`` threads the per-query (k, cap)
-        sidecars into the ragged distributed program — ``k_bucket`` is
-        then the static ceiling and the kernel cache key is per-mode."""
-        use_quant = bool(self.int8_serving)
-        mode = "tiered" if tiered else ("quant" if use_quant else "exact")
+    def _sharded_tables(self, st, tiered: bool) -> tuple:
+        """The distributed program's extra tables against ``st``: the int8
+        shadow (quant), with the residency mask (tiered), row-sharded like
+        the master; none in exact mode."""
+        if tiered:
+            return (*self._int8_shadow_for(st), self.tiering.cold_mask_dev())
+        return self._int8_shadow_for(st) if self.int8_serving else ()
 
-        def _tables(st_):
-            if tiered:
-                # (shadow, residency) both row-sharded like the master
-                return (*self._int8_shadow_for(st_),
-                        self.tiering.cold_mask_dev())
-            return self._int8_shadow_for(st_) if use_quant else ()
-
+    def _stage_fused_sharded(self, st, indptr, nbr, qp, padb, valid,
+                             tenants, gate_on, boost_on, k_bucket, cap_take,
+                             max_nbr, super_gate, acc_boost, nbr_boost,
+                             now, *, ragged=False, k_arr=None, cap_arr=None,
+                             tiered=False, sem=None) -> _StagedSharded:
+        """Everything the pod serving dispatch (ISSUE 5) needs before its
+        launch, made under ``lz.index.stage`` as on one chip: the compiled
+        program for the batch's geometry and every host→device put.
+        ``indptr``/``nbr`` are the PER-SHARD CSR slices ``_csr_for`` builds
+        under a mesh. ``ragged=True`` threads the per-query (k, cap)
+        sidecars into the ragged distributed program — ``k_bucket`` is then
+        the static ceiling and the kernel cache key is per-mode.
+        ``boost_extra`` is None for a batch that asked for no boost: it
+        takes the read twin."""
+        mode = ("tiered" if tiered
+                else "quant" if self.int8_serving else "exact")
         kern = self._fused_sharded_kernels(mode, k_bucket, cap_take,
                                            max_nbr, ragged=ragged,
                                            sem=sem is not None)
@@ -3852,25 +3917,40 @@ class MemoryIndex:
         sargs = (indptr, nbr, jnp.asarray(qp), jnp.asarray(padb(valid)),
                  jnp.asarray(padb(tenants, -1, np.int32)),
                  jnp.asarray(padb(gate_on)))
+        boosting = bool(boost_on.any())
+        boost_extra = (jnp.asarray(padb(boost_on)),) if boosting else None
         if ragged:
             cap_s = min(cap_take, k_bucket)
             k_dev = jnp.asarray(padb(np.minimum(k_arr, k_bucket), 0,
                                      np.int32))
-            capq_dev = jnp.asarray(padb(np.minimum(cap_arr, cap_s), 0,
-                                        np.int32))
             # dense modes share the ragged ABI; nprobe_q is inert here
             npq_dev = jnp.asarray(np.zeros((qp.shape[0],), np.int32))
             read_extra = (k_dev, npq_dev, jnp.float32(super_gate))
+            if boosting:
+                capq_dev = jnp.asarray(padb(np.minimum(cap_arr, cap_s), 0,
+                                            np.int32))
+                boost_extra += (k_dev, capq_dev, npq_dev)
         else:
             read_extra = (jnp.float32(super_gate),)
+        if boosting:
+            now_rel = (now if now is not None else time.time()) - self.epoch
+            boost_extra += (jnp.float32(now_rel), jnp.float32(super_gate),
+                            jnp.float32(acc_boost), jnp.float32(nbr_boost))
+        # what sharded_topk_merge gathers: every shard's k_merge candidates
+        # of every padded query
+        self.telemetry.bump(
+            "serve.merge_candidates",
+            self._n_parts * int(qp.shape[0])
+            * (k_bucket + (self.coarse_slack if tiered else 0)),
+            labels={"mode": "sharded_" + mode})
         if self.telemetry_hbm and self.telemetry.enabled:
             hkey = ("sharded", mode, ragged, k_bucket, cap_take, max_nbr)
             if hkey not in self._hbm_recorded:
                 self._hbm_recorded.add(hkey)
                 try:
-                    tables = _tables(st)
                     peak = peak_bytes(kern.read.lower(
-                        st, tables, *sargs, *read_extra, *sem_tail
+                        st, self._sharded_tables(st, tiered), *sargs,
+                        *read_extra, *sem_tail
                     ).compile().memory_analysis())
                 except Exception:   # noqa: BLE001 — never fail the serve
                     peak = None
@@ -3892,35 +3972,34 @@ class MemoryIndex:
                                  mesh_parts=self._n_parts,
                                  edge_cap=self.edge_state.capacity),
                         peak)
-        if boost_on.any():
-            del st      # a live snapshot would trip the sole-owner gate
-            now_rel = (now if now is not None else time.time()) - self.epoch
-            with self._state_lock:
-                cur = self._state
-                tables = _tables(cur)
-                sole = (not force_copy
-                        and sys.getrefcount(cur) <= self._SOLE_REFS)
-                boost_extra = ((jnp.asarray(padb(boost_on)), k_dev,
-                                capq_dev, npq_dev) if ragged
-                               else (jnp.asarray(padb(boost_on)),))
-                out = self._guarded(
-                    lambda fn: fn(cur, tables, *sargs, *boost_extra,
-                                  jnp.float32(now_rel),
-                                  jnp.float32(super_gate),
-                                  jnp.float32(acc_boost),
-                                  jnp.float32(nbr_boost), *sem_tail),
-                    kern.serve, kern.serve_copy, sole, (cur,),
-                    "serve_sharded")
-                if sem is not None:
-                    new_state, ring2, packed = out
-                else:
-                    new_state, packed = out
-                del cur
-                self.state = new_state
-            return (ring2, packed) if sem is not None else packed
-        tables = _tables(st)
-        out = kern.read(st, tables, *sargs, *read_extra, *sem_tail)
-        return out
+        return _StagedSharded(kern, sargs, read_extra, boost_extra, sem_tail)
+
+    def _serve_fused_sharded(self, staged: _StagedSharded, mode: str,
+                             tiered: bool, force_copy: bool):
+        """The BOOSTING pod dispatch: the full chat-turn program as ONE
+        distributed shard_map dispatch against the row-sharded arena, its
+        boost scatters shard-local. The donation gate is the same refcount
+        contract as every other mutation: donate only when this index
+        provably holds the sole arena reference — so the caller has dropped
+        its own snapshot before it calls. ``force_copy``: a post-OOM replan
+        always dispatches through the non-donating twin (ISSUE 11)."""
+        with self._state_lock:
+            cur = self._state
+            tables = self._sharded_tables(cur, tiered)
+            sole = (not force_copy
+                    and sys.getrefcount(cur) <= self._SOLE_REFS)
+            if not sole:
+                self.telemetry.bump("serve.copy_dispatches",
+                                    labels={"mode": mode})
+            out = self._guarded(
+                lambda fn: fn(cur, tables, *staged.sargs,
+                              *staged.boost_extra, *staged.sem_tail),
+                staged.kern.serve, staged.kern.serve_copy, sole, (cur,),
+                "serve_sharded")
+            new_state, packed = out[0], out[1:]
+            del cur
+            self.state = new_state
+        return packed if staged.sem_tail else packed[0]
 
     def apply_boosts(self, entries: Dict[str, Tuple[int, int, float]],
                      acc_boost: float, nbr_boost: float) -> None:
@@ -4433,7 +4512,8 @@ class MemoryIndex:
                     old + max(deficit, self.page_rows), block=False)
             else:
                 new = self._grown_capacity(old, block=False)
-            self.edge_state = S.grow_edges(self.edge_state, new)
+            self.edge_state = self._grow_rows(S.init_edges, S.grow_edges,
+                                              self.edge_state, new)
             self._free_edge_slots = list(range(new - 1, old - 1, -1)) + self._free_edge_slots
         return [self._free_edge_slots.pop() for _ in range(n)]
 

@@ -292,6 +292,105 @@ def grow_edges(state: EdgeState, new_capacity: int) -> EdgeState:
 
 
 # ---------------------------------------------------------------------------
+# Row-sharded creation and growth (mesh path). A pod arena is larger than
+# one chip, so it can never be made whole and resharded afterwards: every
+# column is created, and grown, in its shards. ``make(capacity)`` is
+# ``init_arena`` / ``init_edges`` with the widths bound; its fills are the
+# same for every row, so a chip's shard is ``make`` of the shard's rows.
+# ---------------------------------------------------------------------------
+
+
+def _row_specs(tree, axis: str):
+    from jax.sharding import PartitionSpec as P
+
+    return jax.tree_util.tree_map(
+        lambda a: P(axis, None) if a.ndim == 2 else P(axis), tree)
+
+
+def _shard_rows(total: int, mesh, axis: str) -> int:
+    local_n, rest = divmod(total, mesh.shape[axis])
+    if rest:
+        raise ValueError(f"{total} rows do not divide over the "
+                         f"{mesh.shape[axis]} shards of axis {axis!r}")
+    return local_n
+
+
+def sharded_init(make: Callable, capacity: int, mesh, axis: str) -> Callable:
+    """The compiled program that creates ``make(capacity)`` in its row
+    shards: called with nothing, each chip makes the ``(capacity + 1) / n``
+    rows it owns, and no chip ever holds a whole column (one ``shard_map``
+    with no input and no collective)."""
+    from jax import shard_map
+
+    local = functools.partial(make, _shard_rows(capacity + 1, mesh, axis) - 1)
+    specs = _row_specs(jax.eval_shape(local), axis)
+    return jax.jit(shard_map(local, mesh=mesh, in_specs=(), out_specs=specs,
+                             check_vma=False))
+
+
+def grow_sharded(make: Callable, state, new_capacity: int, mesh, axis: str):
+    """The row-sharded twin of ``grow_arena`` / ``grow_edges``: rows
+    ``[0, old capacity)`` keep their GLOBAL numbers (the host's maps, the
+    edges and the CSR all speak them) and the rest are fresh.
+
+    Shards are contiguous row blocks, so a grown arena is blocked anew: new
+    shard ``s`` holds the global rows ``[s·m', (s+1)·m')``, which lay on the
+    old shards ``s`` and after. Growth therefore MOVES rows between chips
+    (a per-shard pad would renumber them): each chip makes its new shard
+    fresh and takes in the old shards that overlap it, one ``ppermute``
+    (chip ``s + d`` to chip ``s``) for each distance ``d`` at which any pair
+    overlaps. A chip holds its old shard, its new shard and one old shard in
+    transit; never a whole column, and there is no ``all-gather``."""
+    from jax import shard_map
+
+    n = mesh.shape[axis]
+    old_total = jax.tree_util.tree_leaves(state)[0].shape[0]
+    old = old_total - 1                       # the old sentinel is not kept
+    assert new_capacity > old
+    m_old = _shard_rows(old_total, mesh, axis)
+    m_new = _shard_rows(new_capacity + 1, mesh, axis)
+
+    def overlap(s: int, j: int) -> bool:      # new shard s, old shard j
+        return (min((s + 1) * m_new, (j + 1) * m_old, old)
+                > max(s * m_new, j * m_old))
+
+    # old shard j lies on new shards <= j (m_new >= m_old): distance j - s
+    hops = {d: pairs for d in range(n)
+            if (pairs := [(s + d, s) for s in range(n - d)
+                          if overlap(s, s + d)])}
+
+    def local(cur):
+        s = jax.lax.axis_index(axis)
+
+        def take(new, blk, d):
+            """``blk`` is old shard ``s + d``: write the rows of it that
+            fall into this new shard (and are not the old sentinel)."""
+            src = s + d
+            off = src * m_old - s * m_new     # blk's first row, in new
+            at = jnp.clip(off, 0, m_new - m_old)
+            j = jnp.arange(m_old, dtype=jnp.int32) + (at - off)
+            ok = ((j >= 0) & (j < m_old) & (src * m_old + j < old)
+                  & (src < n))
+            ok = ok.reshape((-1,) + (1,) * (new.ndim - 1))
+            seg = jax.lax.dynamic_slice_in_dim(new, at, m_old)
+            seg = jnp.where(ok, jnp.roll(blk, off - at, axis=0), seg)
+            return jax.lax.dynamic_update_slice_in_dim(new, seg, at, 0)
+
+        def column(new, old_col):
+            for d, pairs in hops.items():
+                blk = (old_col if d == 0 else
+                       jax.lax.ppermute(old_col, axis, pairs))
+                new = take(new, blk, d)
+            return new
+
+        return jax.tree_util.tree_map(column, make(m_new - 1), cur)
+
+    specs = _row_specs(state, axis)
+    return jax.jit(shard_map(local, mesh=mesh, in_specs=(specs,),
+                             out_specs=specs, check_vma=False))(state)
+
+
+# ---------------------------------------------------------------------------
 # Paged arena (ISSUE 17): pool init/growth + the logical<->physical
 # indirection helpers every kernel routes its emb access through. All
 # helpers are the identity when ``row_map`` is None, so dense arenas trace

@@ -89,3 +89,25 @@ def test_pod_exact_program_compiles_for_four_chips(topo, monkeypatch):
         sds((), jnp.float32)).compile().as_text()
     assert "tpu_custom_call" in text and "all-gather" in text
     assert f"f32[{c},{n // 4}]" not in text
+
+
+def test_pod_arena_is_constructed_in_its_shards_for_four_chips(topo):
+    """PR 29: ``lme20m-mesh4``'s arena (20,004,864 rows x 768 bf16 = 30.7 GB,
+    more than a chip) as ``MemoryIndex(mesh=...)`` now creates it: the compiled
+    program leaves every chip its quarter, 7.68 GB + the columns, holds no
+    whole column and has no collective."""
+    import re
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+    n, d = 4 * 1221 * 4096, 768
+    comp = S.sharded_init(lambda c: S.init_arena(c, d, jnp.bfloat16), n - 1,
+                          mesh, "data").lower().compile()
+    text = comp.as_text()
+    assert not re.search(r"all-gather|all-reduce|collective-permute|all-to-all",
+                         text)
+    assert f"[{n // 4},{d}]" in text and f"[{n}," not in text
+    assert f"[{n}]" not in text
+    mem = comp.memory_analysis()
+    shard = (n // 4) * (d * 2 + 4 * 7 + 2)        # emb + seven words + two flags
+    assert shard <= mem.output_size_in_bytes <= 1.01 * shard < 8e9
+    assert mem.temp_size_in_bytes < 64 * 2**20
