@@ -631,6 +631,11 @@ class MemoryIndex:
         # bench's compile_cache_entries measurement and the
         # kernel.cache_entries{surface="single_fused"} gauge read it.
         self._serve_kernel_keys: set = set()
+        # What two READ dispatches in flight together share on the host
+        # (ISSUE 30: the scheduler overlaps a full batch's host path with
+        # the other batch's pass): the CSR cache, the kernel-key set, the
+        # once-keys of the HBM gauge, the distributed program cache.
+        self._serve_shared_lock = threading.Lock()
         self._mesh_topk_cache = LRUKernelCache(serve_kernel_cache_max)
         # Distributed fused serving programs (ISSUE 5): under a mesh the
         # whole chat-turn program runs as ONE shard_map dispatch
@@ -2854,25 +2859,46 @@ class MemoryIndex:
         edge-topology change. The dirty flag is cleared BEFORE the build,
         so a writer racing past us re-dirties and the next serve rebuilds."""
         n = st.salience.shape[0]
-        cache = self._csr_cache
-        if cache is not None and not self._csr_dirty and cache[0] == n:
-            return cache[1], cache[2]
-        self._csr_dirty = False
-        indptr, nbr = build_host_csr(list(self.edge_slots.keys()),
-                                     self.id_to_row, n,
-                                     min_pad=self._csr_pad_hwm)
-        self._csr_pad_hwm = nbr.shape[0]
-        if self.mesh is not None:
-            # pod path: per-shard CSR slices for the distributed fused
-            # kernel, placed so each chip holds its own rows' lists
-            from lazzaro_tpu.parallel.mesh import shard_stacked
-            sh = shard_stacked(self.mesh, self.shard_axis)
-            dev = tuple(jax.device_put(a, sh)
-                        for a in split_csr(indptr, nbr, self._n_parts))
-        else:
-            dev = (jnp.asarray(indptr), jnp.asarray(nbr))
-        self._csr_cache = (n, dev[0], dev[1])
-        return dev
+        # two read dispatches may come together: one builds, the other
+        # finds its build
+        with self._serve_shared_lock:
+            cache = self._csr_cache
+            if cache is not None and not self._csr_dirty and cache[0] == n:
+                return cache[1], cache[2]
+            self._csr_dirty = False
+            indptr, nbr = build_host_csr(list(self.edge_slots.keys()),
+                                         self.id_to_row, n,
+                                         min_pad=self._csr_pad_hwm)
+            self._csr_pad_hwm = nbr.shape[0]
+            if self.mesh is not None:
+                # pod path: per-shard CSR slices for the distributed fused
+                # kernel, placed so each chip holds its own rows' lists
+                from lazzaro_tpu.parallel.mesh import shard_stacked
+                sh = shard_stacked(self.mesh, self.shard_axis)
+                dev = tuple(jax.device_put(a, sh)
+                            for a in split_csr(indptr, nbr, self._n_parts))
+            else:
+                dev = (jnp.asarray(indptr), jnp.asarray(nbr))
+            self._csr_cache = (n, dev[0], dev[1])
+            return dev
+
+    def reads_may_overlap(self, reqs) -> bool:
+        """The scheduler's overlap predicate (ISSUE 30): may this batch be
+        in flight together with another such batch? Yes only for a batch
+        that takes the non-donating ``*_read`` twins and touches no shared
+        serving state — no boosting request (it would donate the arena
+        the other dispatch reads, or copy it), no semantic ring (every
+        dispatch writes it back), no cold rows (the tiered finish may
+        donate), no HBM budget (the planner's model counts ONE dispatch's
+        temporaries), not poisoned. Read at the time of asking; a read
+        dispatch takes ``self.state`` at its own launch either way."""
+        if (self._poisoned or self._sem_host is not None
+                or (self.planner is not None and self.planner.active)):
+            return False
+        tm = self.tiering
+        if tm is not None and tm.cold_count > 0:
+            return False
+        return not any(r.boost for r in reqs)
 
     # ------------------------------------------------- memory-safe serving
     def _serve_mode_hint(self, cap_take: int, reqs) -> Tuple[str, int]:
@@ -3621,7 +3647,9 @@ class MemoryIndex:
         count ≤ the mode count) read the gauge this maintains."""
         key = (mode, "ragged" if ragged else "classic",
                tuple(sorted(statics.items())))
-        if key not in self._serve_kernel_keys:
+        if key in self._serve_kernel_keys:
+            return
+        with self._serve_shared_lock:
             self._serve_kernel_keys.add(key)
             self.telemetry.gauge("kernel.cache_entries",
                                  len(self._serve_kernel_keys),
@@ -3705,6 +3733,15 @@ class MemoryIndex:
             out[(mode, g)] = ms
         return out
 
+    def _hbm_once(self, key) -> bool:
+        """True for the ONE serving dispatch that records ``key``'s HBM
+        gauge (two read dispatches may ask together)."""
+        with self._serve_shared_lock:
+            if key in self._hbm_recorded:
+                return False
+            self._hbm_recorded.add(key)
+            return True
+
     def _maybe_record_hbm(self, mode: str, st, args, statics, super_gate,
                           ivf_tabs, use_quant, ragged: bool = False,
                           k_dev=None, npq_dev=None,
@@ -3719,9 +3756,8 @@ class MemoryIndex:
         if not self.telemetry_hbm or not self.telemetry.enabled:
             return    # never consume the once-key while warmup mutes the registry
         key = (mode, ragged) + tuple(sorted(statics.items()))
-        if key in self._hbm_recorded:
+        if not self._hbm_once(key):
             return
-        self._hbm_recorded.add(key)
         try:
             if pq_tabs is not None and tier_pack is not None:
                 cold_dev = tier_pack[-1]
@@ -3873,17 +3909,18 @@ class MemoryIndex:
                else (mode, k_bucket, cap_take, max_nbr))
         if sem:
             key = key + ("sem",)
-        kern = self._fused_sharded_cache.get(key)
-        if kern is None:
-            kern = S.make_fused_sharded(
-                self.mesh, self.shard_axis, k=k_bucket,
-                cap_take=min(cap_take, k_bucket), max_nbr=max_nbr,
-                mode=mode, slack=self.coarse_slack, ragged=ragged,
-                sem=sem)
-            self._fused_sharded_cache.put(key, kern)
-            self.telemetry.gauge("kernel.cache_entries",
-                                 len(self._fused_sharded_cache),
-                                 labels={"surface": "fused_sharded"})
+        with self._serve_shared_lock:   # the LRU reorders on every get
+            kern = self._fused_sharded_cache.get(key)
+            if kern is None:
+                kern = S.make_fused_sharded(
+                    self.mesh, self.shard_axis, k=k_bucket,
+                    cap_take=min(cap_take, k_bucket), max_nbr=max_nbr,
+                    mode=mode, slack=self.coarse_slack, ragged=ragged,
+                    sem=sem)
+                self._fused_sharded_cache.put(key, kern)
+                self.telemetry.gauge("kernel.cache_entries",
+                                     len(self._fused_sharded_cache),
+                                     labels={"surface": "fused_sharded"})
         return kern
 
     def _sharded_tables(self, st, tiered: bool) -> tuple:
@@ -3945,8 +3982,7 @@ class MemoryIndex:
             labels={"mode": "sharded_" + mode})
         if self.telemetry_hbm and self.telemetry.enabled:
             hkey = ("sharded", mode, ragged, k_bucket, cap_take, max_nbr)
-            if hkey not in self._hbm_recorded:
-                self._hbm_recorded.add(hkey)
+            if self._hbm_once(hkey):
                 try:
                     peak = peak_bytes(kern.read.lower(
                         st, self._sharded_tables(st, tiered), *sargs,

@@ -1102,9 +1102,11 @@ class MemorySystem:
         return self.config.serve_fused
 
     def _ensure_scheduler(self) -> QueryScheduler:
-        """Lazily spawn the cross-request query scheduler (one worker thread
-        per system; it also keeps donated state mutation single-writer on
-        the serving side)."""
+        """Lazily spawn the cross-request query scheduler (one per system).
+        It keeps donated state mutation single-writer on the serving
+        side: one dispatch at a time, except a full batch of pure reads
+        admitted over a batch of pure reads in flight — which batches
+        those are is the index's word (``reads_may_overlap``)."""
         sched = self.query_scheduler
         if sched is not None and not sched.closed:
             return sched
@@ -1125,7 +1127,8 @@ class MemorySystem:
                     shed_bytes=self.config.serve_shed_bytes,
                     degrade_cap_take=self.config.serve_degrade_cap_take,
                     degrade_nprobe=self.config.serve_degrade_nprobe,
-                    admission_check=self._plan_admission)
+                    admission_check=self._plan_admission,
+                    overlap_check=self._reads_may_overlap)
                 self.query_scheduler = sched
         return sched
 
@@ -1145,6 +1148,11 @@ class MemorySystem:
             self.index._serve_geometry(1, mode, k_bucket),
             chunkable=(self.index.serve_ragged
                        and self.index.mesh is None))
+
+    def _reads_may_overlap(self, reqs) -> bool:
+        """Scheduler overlap predicate: the index that executes the batch
+        vouches for it (looked up per call: a restore swaps the index)."""
+        return self.index.reads_may_overlap(reqs)
 
     def _serve_requests(self, reqs: List[RetrievalRequest]):
         """Scheduler executor: ONE fused device dispatch + ONE packed

@@ -9,8 +9,11 @@ retrievals — across callers, threads, and tenants — into padded mega-batches
 the way Ragged Paged Attention coalesces ragged decode work on TPU:
 
 - callers ``submit()`` a :class:`RetrievalRequest` and block on the returned
-  future; a single worker thread owns the device dispatch (which also keeps
-  the donated state mutation single-writer);
+  future; the scheduler's workers own the device dispatch. One dispatch
+  is in flight at a time — which keeps the donated state mutation
+  single-writer — except that a FULL pending batch of pure reads is
+  admitted over an in-flight batch of pure reads (at most two in flight,
+  see :class:`QueryScheduler`);
 - the flush decision is the shared time/size policy (``utils.batching.
   FlushPolicy``): a full ``max_batch`` flushes immediately, a lone trickle
   request waits at most ``max_wait_us`` before it ships;
@@ -38,14 +41,20 @@ mega-batch. Same coalescing, same policy, different device program.
 Failure model (ISSUE 10) — a request future resolves with a RESULT or a
 TYPED ERROR; it never blocks forever:
 
+Every clause holds PER BATCH, also while two batches are in flight: each
+has its own watchdog and its own futures, and the breaker and the degrade
+rung see every batch once.
+
 - an **executor exception** demuxes to every future of that batch (the
-  PR 2 behavior) and counts a breaker failure;
+  PR 2 behavior) and counts a breaker failure; the other batch in flight
+  is untouched;
 - a **worker-thread death** anywhere outside the demuxed executor call
   fails the admitted batch's futures with :class:`WorkerCrashed` and the
-  worker RESTARTS (``reliability.worker_restarts``) — pending requests
-  stay queued and are served by the restarted worker;
+  thread that died RESTARTS (``reliability.worker_restarts``) — pending
+  requests stay queued and are served by the restarted worker, the other
+  worker's batch is served;
 - a **dispatch deadline** (``dispatch_timeout_s > 0``) arms a watchdog
-  per dispatch: on expiry the batch's futures fail with
+  per dispatch: on expiry that batch's futures fail with
   :class:`DispatchTimeout` while the stuck dispatch is left to finish
   (its late results are discarded) and the breaker records the failure;
 - **sustained pressure** opens the circuit breaker
@@ -150,29 +159,64 @@ def _set_future(fut: Future, res) -> None:
         pass            # watchdog already failed it — late result discarded
 
 
+_Item = Tuple[RetrievalRequest, Future, float]      # (request, future, enqueued)
+
+
+@dataclass(eq=False)
+class _Batch:
+    """One admitted dispatch: its queue items, and what the admission
+    decided about it."""
+    items: List[_Item]
+    overlapped: bool            # admitted while another was in flight
+    seq: int                    # admission order: the profiler's step number
+
+    @property
+    def reqs(self) -> List[RetrievalRequest]:
+        return [req for req, _, _ in self.items]
+
+
+_PARK = object()        # _next_batch_locked: come back as a parked worker
+
+
 class QueryScheduler:
     """Coalesce concurrent retrievals into dense device batches.
 
-    One daemon worker thread pops pending requests and runs ``executor``
-    on them; callers block on per-request futures. ``close()`` drains
-    pending work before returning. The worker is crash-restarting and
+    Daemon worker threads pop pending requests and run ``executor`` on
+    them; callers block on per-request futures. ``close()`` drains
+    pending work before returning. The workers are crash-restarting and
     every failure path resolves futures with a typed error (see the
     module docstring's failure model).
 
     Two batching disciplines (ISSUE 7):
 
     - **continuous** (default): requests admit into the NEXT dispatch the
-      moment the worker is free — the in-flight dispatch is the batching
-      window. A lone request on an idle scheduler ships immediately
-      (latency = dispatch time, never the flush timeout), and arrivals
-      during a dispatch coalesce into the next one without any timer.
-      Per-tenant admission control (``tenant_max_inflight``) caps how
-      many of one tenant's requests enter a single dispatch, walking the
-      queue oldest-first so over-cap requests keep their place for the
-      next batch — one flooding tenant cannot monopolize the device.
+      moment nothing is in flight — the in-flight dispatch is the
+      batching window. A lone request on an idle scheduler ships
+      immediately (latency = dispatch time, never the flush timeout), and
+      arrivals during a dispatch coalesce into the next one without any
+      timer. Per-tenant admission control (``tenant_max_inflight``) caps
+      how many of one tenant's requests enter a single dispatch, walking
+      the queue oldest-first so over-cap requests keep their place for
+      the next batch — one flooding tenant cannot monopolize the device.
     - **flush-boundary** (``continuous=False``, the PR 2–6 policy): a
       batch ships when it holds ``max_batch`` requests or its oldest has
       waited ``max_wait_us`` (default 2 ms). Kept for A/B and fallback.
+
+    The admission rule (ISSUE 30), the whole of it: a batch admits when
+    NOTHING is in flight (the disciplines above), or when exactly ONE
+    dispatch is in flight and the pending queue holds a FULL batch (what
+    ``_select_locked`` picks has ``max_batch`` requests) and
+    ``overlap_check`` says yes to both the batch in flight and the one to
+    admit. A window that is full cannot grow: keeping it closed until the
+    other dispatch returns only idles the device, so its host path (pack,
+    stage, launch) runs while the other batch's pass is on the device. At
+    most two dispatches are ever in flight — one running, one queued
+    behind it: a third could not start sooner and would only take
+    requests out of the window early. A window one short of full waits as
+    before, so a closed loop of callers is never cut into more groups
+    than it has today. ``overlap_check`` belongs to the executor's owner
+    (``MemoryIndex.reads_may_overlap``: pure reads that share no serving
+    state); without one the scheduler has ONE worker and never overlaps.
     """
 
     def __init__(self, executor: Executor, max_batch: int = 64,
@@ -184,8 +228,14 @@ class QueryScheduler:
                  breaker_cooldown_s: float = 5.0,
                  shed_depth: int = 0, shed_bytes: int = 0,
                  degrade_cap_take: int = 1, degrade_nprobe: int = 1,
-                 admission_check: Optional[Callable] = None):
+                 admission_check: Optional[Callable] = None,
+                 overlap_check: Optional[Callable] = None):
         self._executor = executor
+        # The executor owner's word on which batches may run while another
+        # is in flight (ISSUE 30): called with a batch's requests, under
+        # the scheduler's lock, only when a full batch waits behind one
+        # dispatch. None = never overlap.
+        self.overlap_check = overlap_check
         # Memory-safe admission (ISSUE 11): an optional callable invoked
         # with the submitted request group BEFORE it queues; raising
         # PlanInfeasible fails the group's futures typed right here —
@@ -211,9 +261,15 @@ class QueryScheduler:
                            telemetry=self.telemetry, name=name)
             if breaker_threshold > 0 else None)
         self._cond = threading.Condition()
-        self._pending: List[Tuple[RetrievalRequest, Future, float]] = []
+        # where a worker waits that has nothing to admit while a dispatch is
+        # in flight: woken only when a full batch waits behind ONE dispatch
+        # (and on close), so a submit costs it nothing otherwise
+        self._parked = threading.Condition(self._cond)
+        self._idle_held = False              # a worker is in its idle wait
+        self._pending: List[_Item] = []
         self._pending_bytes = 0
-        self._inflight = 0
+        self._inflight_batches: List[_Batch] = []    # never more than two
+        self._dispatch_seq = 0
         self._closed = False
         self.batches_flushed = 0
         self.requests_served = 0
@@ -223,13 +279,19 @@ class QueryScheduler:
         self.watchdog_timeouts = 0
         self.batch_sizes: List[int] = []     # observability (bench reads it)
         self._name = name
-        self._worker = threading.Thread(target=self._run, daemon=True,
-                                        name=name)
-        self._worker.start()
+        # the second worker exists for the overlapped batch alone
+        self._workers: List[Optional[threading.Thread]] = \
+            [None] * (2 if overlap_check is not None else 1)
+        with self._cond:
+            self._ensure_workers_locked()
 
     @property
     def closed(self) -> bool:
         return self._closed
+
+    @property
+    def _inflight(self) -> int:
+        return len(self._inflight_batches)
 
     # ------------------------------------------------------------- submit
     def submit(self, request: RetrievalRequest) -> "Future[RetrievalResult]":
@@ -279,19 +341,25 @@ class QueryScheduler:
             for req, fut in zip(requests, futures):
                 self._pending.append((req, fut, now))
             self._pending_bytes += nbytes
-            self._ensure_worker_locked()
+            self._ensure_workers_locked()
             self._cond.notify()
+            if (self._inflight == 1
+                    and len(self._pending) >= self.policy.max_items):
+                self._parked.notify()
         return futures
 
-    def _ensure_worker_locked(self) -> None:
-        """Respawn the worker if it is gone (belt-and-braces: the restart
-        loop already survives crashes, but a dead thread must never let a
-        future sit unserved)."""
-        if self._closed or self._worker.is_alive():
+    def _ensure_workers_locked(self) -> None:
+        """Spawn the workers, and respawn one that is gone (belt-and-
+        braces: the restart loop already survives crashes, but a dead
+        thread must never let a future sit unserved)."""
+        if self._closed:
             return
-        self._worker = threading.Thread(target=self._run, daemon=True,
-                                        name=self._name)
-        self._worker.start()
+        for i, worker in enumerate(self._workers):
+            if worker is None or not worker.is_alive():
+                self._workers[i] = threading.Thread(
+                    target=self._run, daemon=True,
+                    name=self._name + ("-%d" % (i + 1) if i else ""))
+                self._workers[i].start()
 
     # ------------------------------------------------------------- worker
     def _run(self) -> None:
@@ -316,62 +384,120 @@ class QueryScheduler:
 
     def _serve_loop(self) -> None:
         while True:
-            # the worker's wait for work, up to the batch being admitted:
-            # the window less these spans is the time the worker was busy
+            # the wait of a worker that would admit whatever came, up to
+            # the batch being admitted: the window less these spans is the
+            # time a worker was busy. A worker with nothing to admit while
+            # a dispatch is in flight parks OUTSIDE the span.
             with self.telemetry.span("sched.idle"), self._cond:
-                while True:
-                    now = time.time()
-                    oldest = self._pending[0][2] if self._pending else None
-                    if self._pending and (
-                            self._closed or self.continuous
-                            or self.policy.should_flush(len(self._pending),
-                                                        now, oldest)):
-                        # continuous mode: the worker being free IS the
-                        # flush signal — pending work admits immediately
-                        # (ISSUE 7 lone-request fix: no serve_flush_us
-                        # wait on an idle scheduler).
-                        break
-                    if self._closed:
-                        return
-                    timeout = (self.policy.wait_remaining(now, oldest)
-                               if self._pending else None)
-                    self._cond.wait(timeout)
-                batch = self._admit_locked()
-                self._inflight += 1
+                batch = self._next_batch_locked(parked=False)
+            if batch is _PARK:
+                with self._cond:
+                    batch = self._next_batch_locked(parked=True)
+            if batch is None:
+                return
             try:
                 # Fault point "scheduler.worker" (ISSUE 10): a raise here
                 # models the worker dying OUTSIDE the demuxed executor
                 # call — the pre-ISSUE-10 scheduler would strand these
                 # futures forever.
                 try:
-                    faults.fire("scheduler.worker", batch=len(batch))
+                    faults.fire("scheduler.worker", batch=len(batch.items))
                     self._execute(batch)
                 except BaseException as e:
                     err = WorkerCrashed(
                         f"query-scheduler worker died mid-batch: {e!r}")
-                    for _, fut, _ in batch:
+                    for _, fut, _ in batch.items:
                         _fail_future(fut, err)
                     raise
             finally:
                 with self._cond:
-                    self._inflight -= 1
+                    self._inflight_batches.remove(batch)
                     self._cond.notify_all()
+                    if self._closed:
+                        self._parked.notify_all()
 
-    def _admit_locked(self) -> List[Tuple[RetrievalRequest, Future, float]]:
-        """Pop the next dispatch's batch from the pending queue (caller
-        holds the lock). Oldest-first; at most ``max_batch``; with a
-        tenant cap, at most ``tenant_max_inflight`` requests per tenant
-        admit — over-cap requests KEEP their queue position (fairness:
-        the deferred oldest request is first in line next dispatch)."""
+    def _next_batch_locked(self, parked: bool):
+        """Wait (caller holds the lock) until the admission rule lets this
+        worker take a batch; None on a clean close. A worker in its idle
+        wait (``parked=False``) that finds a dispatch in flight and
+        nothing it may admit over it — or the other worker idle already —
+        returns ``_PARK`` and comes back parked: it then sleeps until a
+        full batch waits behind one dispatch. The worker that finishes a
+        dispatch always looks at the queue itself, so a parked one is
+        never needed for anything else."""
+        while True:
+            batch = self._try_admit_locked()
+            if batch is not None:
+                return batch
+            if self._closed and not self._pending:
+                return None
+            if parked:
+                self._parked.wait()
+                continue
+            if self._inflight or self._idle_held:
+                return _PARK
+            now = time.time()
+            oldest = self._pending[0][2] if self._pending else None
+            timeout = (self.policy.wait_remaining(now, oldest)
+                       if self._pending else None)
+            self._idle_held = True
+            try:
+                self._cond.wait(timeout)
+            finally:
+                self._idle_held = False
+
+    def _try_admit_locked(self) -> Optional["_Batch"]:
+        """The admission rule (class docstring; caller holds the lock):
+        the batch admitted now, or None."""
+        if not self._pending:
+            return None
+        overlapped = False
+        if self._inflight == 0:
+            # continuous mode: nothing in flight IS the flush signal —
+            # pending work admits immediately (ISSUE 7 lone-request fix:
+            # no serve_flush_us wait on an idle scheduler).
+            if not (self._closed or self.continuous
+                    or self.policy.should_flush(len(self._pending),
+                                                time.time(),
+                                                self._pending[0][2])):
+                return None
+            picked = self._select_locked()
+        else:
+            # one dispatch in flight: only a window that cannot grow, and
+            # only if the executor's owner lets both batches run together
+            if (self._inflight != 1 or self.overlap_check is None
+                    or len(self._pending) < self.policy.max_items):
+                return None
+            picked = self._select_locked()
+            if (len(picked[0]) < self.policy.max_items
+                    or not self._may_overlap(self._inflight_batches[0].reqs)
+                    or not self._may_overlap(
+                        [req for req, _, _ in picked[0]])):
+                return None
+            overlapped = True
+        return self._admit_locked(*picked, overlapped)
+
+    def _may_overlap(self, reqs) -> bool:
+        try:
+            return bool(self.overlap_check(reqs))
+        except Exception:       # noqa: BLE001 — a broken predicate says no
+            logger.exception("overlap_check raised; serving serially")
+            return False
+
+    def _select_locked(self):
+        """What the next dispatch would take from the pending queue, and
+        nothing changed yet (caller holds the lock): (batch, queue left
+        behind, requests deferred by the tenant cap). Oldest-first; at
+        most ``max_batch``; with a tenant cap, at most
+        ``tenant_max_inflight`` requests per tenant — over-cap requests
+        KEEP their queue position (fairness: the deferred oldest request
+        is first in line next dispatch)."""
         limit = self.policy.max_items
         cap = self.tenant_max_inflight
         if not cap:
-            batch = self._pending[:limit]
-            del self._pending[:len(batch)]
-            self._note_admitted_locked(batch)
-            return batch
-        batch: List[Tuple[RetrievalRequest, Future, float]] = []
-        kept: List[Tuple[RetrievalRequest, Future, float]] = []
+            return self._pending[:limit], self._pending[limit:], 0
+        batch: List[_Item] = []
+        kept: List[_Item] = []
         counts: dict = {}
         deferred = 0
         for item in self._pending:
@@ -383,11 +509,18 @@ class QueryScheduler:
                 kept.append(item)
                 if len(batch) < limit:
                     deferred += 1        # capped out, not batch-full
+        return batch, kept, deferred
+
+    def _admit_locked(self, items, kept, deferred, overlapped) -> _Batch:
+        """Pop what ``_select_locked`` picked: it is in flight now."""
         self._pending = kept
-        self._note_admitted_locked(batch)
+        self._note_admitted_locked(items)
         if deferred:
             self.requests_deferred += deferred
             self.telemetry.bump("serve.admission_deferred", deferred)
+        self._dispatch_seq += 1
+        batch = _Batch(items, overlapped, self._dispatch_seq)
+        self._inflight_batches.append(batch)
         return batch
 
     def _note_admitted_locked(self, batch) -> None:
@@ -408,13 +541,14 @@ class QueryScheduler:
                else min(req.nprobe, self.degrade_nprobe))
         return dataclasses.replace(req, cap_take=cap, nprobe=npr)
 
-    def _account(self, batch):
+    def _account(self, batch: _Batch):
         """(requests to dispatch, their summed queue wait in seconds, the
         armed watchdog timer or None, its timed-out flag)."""
-        reqs = [req for req, _, _ in batch]
+        items = batch.items
+        reqs = batch.reqs
         flush_t = time.time()
         waited_s = 0.0
-        for req, _, enq in batch:
+        for req, _, enq in items:
             waited_s += flush_t - enq
             # a ring of ``window`` samples PER TENANT series (256 tenants,
             # then "~other"): a series that overflows keeps its newest
@@ -437,31 +571,33 @@ class QueryScheduler:
                     self.breaker.record_failure()
                 err = DispatchTimeout(
                     f"dispatch exceeded the {self.dispatch_timeout_s:.3f}s "
-                    f"watchdog deadline (batch of {len(batch)})")
-                for _, fut, _ in batch:
+                    f"watchdog deadline (batch of {len(items)})")
+                for _, fut, _ in items:
                     _fail_future(fut, err)
             timer = threading.Timer(self.dispatch_timeout_s, _deadline)
             timer.daemon = True
             timer.start()
         return reqs, waited_s, timer, timed_out
 
-    def _execute(self, batch) -> None:
+    def _execute(self, batch: _Batch) -> None:
+        items = batch.items
         # the worker's own bookkeeping before the dispatch: one labelled
         # queue-wait sample per request, the breaker, the watchdog
         with self.telemetry.span("sched.account"):
             reqs, waited_s, timer, timed_out = self._account(batch)
         try:
             # one mega-batch == one profiler step, so TPU captures line up
-            # with the host spans batch-for-batch
-            with StepTraceAnnotation("lz.serve.batch",
-                                     step_num=self.batches_flushed):
+            # with the host spans batch-for-batch. ONE annotation around
+            # the whole executor call, on the thread that runs it: every
+            # such span contains at least its own pass over the arena.
+            with StepTraceAnnotation("lz.serve.batch", step_num=batch.seq):
                 results = self._executor(reqs)
         except Exception as e:                      # noqa: BLE001 — demuxed
             if timer is not None:
                 timer.cancel()
             if self.breaker is not None:
                 self.breaker.record_failure()
-            for _, fut, _ in batch:
+            for _, fut, _ in items:
                 _fail_future(fut, e)
             return
         if timer is not None:
@@ -473,22 +609,26 @@ class QueryScheduler:
             return
         if self.breaker is not None:
             self.breaker.record_success()
-        self.batches_flushed += 1
-        self.requests_served += len(batch)
-        self.telemetry.bump("serve.requests", len(batch))
+        with self._cond:        # two workers may get here together
+            self.batches_flushed += 1
+            self.requests_served += len(items)
+            self.batch_sizes.append(len(items))
+            if len(self.batch_sizes) > 1024:
+                del self.batch_sizes[:512]
+        self.telemetry.bump("serve.requests", len(items))
         self.telemetry.bump("serve.batches")
         # summed over the served requests, so it divides by serve.requests
         self.telemetry.bump("serve.queue_wait_us", int(waited_s * 1e6))
-        if len(batch) == 1:
+        if len(items) == 1:
             # a whole dispatch (a full arena pass) for one answer
             self.telemetry.bump("serve.lone_batches")
-        self.telemetry.record("serve.batch_requests", len(batch))
-        self.batch_sizes.append(len(batch))
-        if len(self.batch_sizes) > 1024:
-            del self.batch_sizes[:512]
+        # a batch admitted while another was in flight (a bump of 0 is
+        # dropped: a run that never overlaps has no such entry)
+        self.telemetry.bump("serve.overlapped_batches", int(batch.overlapped))
+        self.telemetry.record("serve.batch_requests", len(items))
         # the callers' done-callbacks run here, on the worker thread
         with self.telemetry.span("sched.demux"):
-            for (_, fut, _), res in zip(batch, results):
+            for (_, fut, _), res in zip(items, results):
                 _set_future(fut, res)
 
     def load(self) -> int:
@@ -515,7 +655,10 @@ class QueryScheduler:
                 return
             self._closed = True
             self._cond.notify_all()
-        self._worker.join(timeout=30.0)
+            self._parked.notify_all()
+            workers = [w for w in self._workers if w is not None]
+        for worker in workers:
+            worker.join(timeout=30.0)
 
     def stats(self) -> dict:
         with self._cond:

@@ -144,6 +144,61 @@ def test_one_dispatch_opens_at_most_twelve_spans(system, opened):
                      "sched.demux", "sched.idle"]
     assert system.telemetry.counter_total("serve.lone_batches") == 2
     assert system.telemetry.counter_total("serve.queue_wait_us") > 0
+    # nothing overlapped: the counter has no entry at all (a bump of 0)
+    assert "serve.overlapped_batches" not in system.telemetry.counters
+
+
+def test_overlapped_dispatch_opens_the_same_spans_on_the_other_worker(
+        system, opened):
+    """ISSUE 30: a full batch admitted over the dispatch in flight runs the
+    same path under the same names on the second worker; the counter says
+    it happened. The first dispatch is held inside the executor, so the
+    order of events is the test's."""
+    from lazzaro_tpu.serve import QueryScheduler
+    _converse(system, "alice", 0)
+    hold, entered = threading.Event(), threading.Event()
+
+    def executor(reqs):
+        if len(reqs) == 1:
+            entered.set()
+            assert hold.wait(timeout=60)
+        return system._serve_requests(reqs)
+
+    req = RetrievalRequest(query=np.ones(D, np.float32), tenant="alice", k=5)
+    system._serve_requests([req] * 4)                   # warm: compiles
+    sched = QueryScheduler(executor, max_batch=4, telemetry=system.telemetry,
+                           overlap_check=system._reads_may_overlap,
+                           name="lz-overlap")
+    try:
+        del opened[:]
+        first = sched.submit(req)
+        assert entered.wait(timeout=60)
+        rest = sched.submit_many([req] * 4)
+        assert all(f.result(timeout=60).ids for f in rest)   # over the first
+        hold.set()
+        assert first.result(timeout=60).ids
+        sched.flush(timeout=60)
+    finally:
+        hold.set()
+        sched.close()
+    tel = system.telemetry
+    assert tel.counter_total("serve.overlapped_batches") == 1
+    assert tel.counter_total("serve.batches") == 2
+    by_thread = {}
+    for t, name, parent, _ in opened:
+        if t != "MainThread" and name != "sched.idle":
+            by_thread.setdefault(t, []).append((name, parent))
+    assert set(by_thread) == {"lz-overlap", "lz-overlap-2"}
+    path = ["sched.account", "index.pack", "index.stage", "serve.exact",
+            "dispatch.launch", "dispatch.readback", "index.decode",
+            "sched.demux"]
+    for t, spans in by_thread.items():
+        assert [n for n, _ in spans] == path, t
+        tree = {}
+        for n, parent in spans:
+            tree.setdefault(parent, set()).add(n)
+        assert tree == {None: DISPATCH[None] - {"sched.idle"},
+                        "serve.exact": DISPATCH["serve.exact"]}, t
 
 
 def test_no_new_name_falls_under_an_accepted_metrics_prefix():
