@@ -436,7 +436,7 @@ def bench_fused_ingest(on_tpu: bool):
 def bench_fused_retrieval(on_tpu: bool):
     """Fused vs classic serving A/B at batch 64 (ISSUE 2 acceptance): the
     per-chat-turn retrieval sequence — super gate + ANN top-k + neighbor
-    boost + access boost — as ONE ``search_fused`` dispatch per batch
+    boost + access boost — as ONE ``search_fused_ragged`` dispatch per batch
     (``MemoryIndex.search_fused_requests``) against the classic sequence
     (two ``search_batch`` dispatches + ``update_access`` + ``boost``
     scatters + the host neighbor walk). Both sides serve the same arena,
@@ -534,9 +534,9 @@ def bench_fused_quant(on_tpu: bool, rows: int, reps: int = 3,
       classic_int8 : the classic multi-dispatch int8 sequence (exact gate
                      search + int8-shadow ANN scan + access/neighbor boost
                      scatters + host neighbor walk)
-      fused_bf16   : ONE ``search_fused`` dispatch (exact full-precision
-                     arena stream)
-      fused_quant  : ONE ``search_fused_quant`` dispatch (int8 coarse
+      fused_bf16   : ONE ``search_fused_ragged`` dispatch (exact
+                     full-precision arena stream)
+      fused_quant  : ONE ``search_fused_quant_ragged`` dispatch (int8 coarse
                      scan + exact rescore of k+slack survivors)
 
     The arena is populated by direct scatters (the serving A/B needs rows
@@ -581,8 +581,9 @@ def bench_fused_quant(on_tpu: bool, rows: int, reps: int = 3,
     # measured dispatch counter over the fused-quant jit entry points
     quant_calls = {"n": 0}
     wrapped = {}
-    for name in ("search_fused_quant", "search_fused_quant_copy",
-                 "search_fused_quant_read"):
+    for name in ("search_fused_quant_ragged",
+                 "search_fused_quant_ragged_copy",
+                 "search_fused_quant_ragged_read"):
         orig = getattr(S_mod, name)
         wrapped[name] = orig
 
@@ -673,13 +674,13 @@ def bench_fused_ivf(on_tpu: bool, rows: int, reps: int = 3,
     """Fused IVF serving A/B (ISSUE 4 acceptance): batch-64 chat-turn
     retrieval through three paths over the SAME clustered bf16 arena —
 
-      fused_ivf    : ONE ``search_fused_ivf`` dispatch (centroid prefilter
-                     + member gather + exact candidate scan + gate/CSR/
+      fused_ivf    : ONE ``search_fused_ivf_ragged`` dispatch (centroid
+                     prefilter + member gather + exact candidate scan + gate/CSR/
                      boost tail, all in-kernel)
       classic_ivf  : the classic multi-dispatch IVF sequence (exact gate
                      search + ``_ivf_search`` prefilter scan + access/
                      neighbor boost scatters + host neighbor walk)
-      fused_quant  : ONE ``search_fused_quant`` dispatch (dense int8
+      fused_quant  : ONE ``search_fused_quant_ragged`` dispatch (dense int8
                      coarse scan + exact rescore — the PR 3 density
                      champion the IVF gather must beat at this scale)
 
@@ -792,8 +793,8 @@ def bench_fused_ivf(on_tpu: bool, rows: int, reps: int = 3,
     # measured dispatch counter over the fused-ivf jit entry points
     ivf_calls = {"n": 0}
     wrapped = {}
-    for name in ("search_fused_ivf", "search_fused_ivf_copy",
-                 "search_fused_ivf_read"):
+    for name in ("search_fused_ivf_ragged", "search_fused_ivf_ragged_copy",
+                 "search_fused_ivf_ragged_read"):
         orig = getattr(S_mod, name)
         wrapped[name] = orig
 
@@ -1096,8 +1097,8 @@ def bench_fused_pq(on_tpu: bool, rows: int, reps: int = 10,
                    coarse_slack: int = 512):
     """Fused IVF-PQ serving A/B (ISSUE 16) on one clustered arena:
 
-      fused_pq     : ONE ``search_fused_pq`` dispatch (per-query ADC table
-                     + m-byte member scan over the top-nprobe clusters +
+      fused_pq     : ONE ``search_fused_pq_ragged`` dispatch (per-query ADC
+                     table + m-byte member scan over the top-nprobe clusters +
                      exact f32 shortlist rescore + gate/CSR/boost tail,
                      all in-kernel)
       classic_pq   : the classic multi-dispatch PQ sequence this PR
@@ -1243,8 +1244,7 @@ def bench_fused_pq(on_tpu: bool, rows: int, reps: int = 10,
     # measured dispatch counter over the fused-pq jit entry points
     pq_calls = {"n": 0}
     wrapped = {}
-    for name in ("search_fused_pq", "search_fused_pq_copy",
-                 "search_fused_pq_read", "search_fused_pq_ragged",
+    for name in ("search_fused_pq_ragged",
                  "search_fused_pq_ragged_copy",
                  "search_fused_pq_ragged_read"):
         orig = getattr(S_mod, name)
@@ -2002,240 +2002,6 @@ def bench_sharded_ingest(on_tpu: bool, rows: int, n_parts: int = 4,
     return out
 
 
-def bench_ragged_serving(on_tpu: bool, rows: int = None, clients: int = 69,
-                         waves: int = 5):
-    """Ragged continuous serving A/B (ISSUE 7 acceptance): live mixed-k
-    traffic — every wave carries k ∈ {4, 16, 64, 100} across four tenants,
-    submitted concurrently through the QueryScheduler — served by
-
-    (a) the PR 6 baseline: flush-boundary scheduler + pow2-padded batches
-        + per-batch-max-k kernels (``serve_ragged=False``,
-        ``continuous=False``), and
-    (b) ragged continuous serving: per-query k/cap as device sidecars,
-        linear pad buckets, admit-on-vacancy scheduling,
-
-    against the SAME arena and request stream. Both sides are exact
-    (recall parity is structural; recall@10 vs the oracle scan is
-    recorded), both warm their kernels untimed (the ragged side via
-    ``warmup_serving`` — the satellite's cold-compile fix), and the
-    padded-slot counters on each side's own registry measure the padding
-    tax directly. The default 69-client wave sits just past a power of
-    two — the regime every pow2 ladder is worst at: the baseline pays 128
-    padded kernel slots where the ragged side pays 72 (linear buckets of
-    ``serve_pad_granularity``), a 1.78× slot ratio, and the per-batch
-    max-k bucket (128 for every 100-carrying wave) equals the ragged
-    ceiling so the kernels differ ONLY in batch padding. (A 40-client
-    wave — 64 vs 40 slots — shows the same shape at 1.6×; any wave size
-    not a power of two pays the tax.)
-
-    Mode probes (small arenas) then pin ``dispatches_per_turn == 1.0``
-    and ONE compiled kernel per mode for exact / quant / IVF / sharded
-    under the same mixed-k batch."""
-    from lazzaro_tpu.core import state as S
-    from lazzaro_tpu.core.index import MemoryIndex
-    from lazzaro_tpu.serve import QueryScheduler, RetrievalRequest
-    from lazzaro_tpu.utils.telemetry import Telemetry
-
-    rows = rows or min(N, 65_536)
-    K_MIX = (4, 16, 64, 100)
-    kw = dict(cap_take=5, max_nbr=16, super_gate=0.4, acc_boost=0.05,
-              nbr_boost=0.02)
-    rng = np.random.default_rng(29)
-    tenants = [f"t{i}" for i in range(4)]
-    idx = MemoryIndex(dim=DIM, capacity=rows + 64, edge_capacity=8192,
-                      dtype=jnp.bfloat16, telemetry=Telemetry(),
-                      serve_k_max=128)
-    for c in range(0, rows, 8192):
-        m = min(8192, rows - c)
-        emb = rng.standard_normal((m, DIM)).astype(np.float32)
-        idx.add([f"f{c + i}" for i in range(m)], emb, [0.5] * m, [0.0] * m,
-                ["semantic"] * m, ["default"] * m,
-                tenants[(c // 8192) % len(tenants)])
-    queries = rng.standard_normal((clients * waves, DIM)).astype(np.float32)
-    _RAGGED_KERNELS = ("search_fused_ragged", "search_fused_ragged_copy",
-                       "search_fused_ragged_read", "search_fused",
-                       "search_fused_copy", "search_fused_read")
-
-    def run_side(ragged: bool):
-        tel = Telemetry()
-        idx.serve_ragged = ragged
-        idx.telemetry = tel
-        calls = {"kern": 0, "batches": 0}
-        orig = {name: getattr(S, name) for name in _RAGGED_KERNELS}
-
-        def wrap(name):
-            def f(*a, __o=orig[name], **k):
-                calls["kern"] += 1
-                return __o(*a, **k)
-            return f
-
-        for name in _RAGGED_KERNELS:
-            setattr(S, name, wrap(name))
-        try:
-            def exec_(reqs):
-                calls["batches"] += 1
-                return idx.search_fused_requests(reqs, **kw)
-
-            sched = QueryScheduler(exec_, max_batch=128, max_wait_us=2000,
-                                   telemetry=tel, continuous=ragged)
-
-            def wave(wi):
-                reqs = [RetrievalRequest(
-                    query=queries[(wi * clients + ci) % len(queries)],
-                    tenant=tenants[ci % len(tenants)], k=K_MIX[ci % 4],
-                    gate_enabled=True, boost=(ci % 8 == 0))
-                    for ci in range(clients)]
-                return [f.result(timeout=600)
-                        for f in sched.submit_many(reqs)]
-
-            # untimed warm: compiles every kernel the timed waves hit
-            if ragged:
-                idx.warmup_serving((clients, 1), **kw)
-            prev = tel.enabled
-            tel.enabled = False
-            wave(0)
-            tel.enabled = prev
-            calls["kern"] = calls["batches"] = 0
-            t0 = time.perf_counter()
-            res = [wave(wi) for wi in range(waves)]
-            wall = time.perf_counter() - t0
-            sched.close()
-            return {"qps": clients * waves / wall, "wall_s": wall,
-                    "kern_calls": calls["kern"],
-                    "batches": calls["batches"], "tel": tel,
-                    "results": res}
-        finally:
-            for name in _RAGGED_KERNELS:
-                setattr(S, name, orig[name])
-
-    base = run_side(ragged=False)
-    ragg = run_side(ragged=True)
-
-    # recall@10 of the ragged path vs the oracle scan (k >= 10 requests
-    # of the last wave; exact mode, so this should be 1.0 structurally)
-    probes = [(ci, (waves - 1) * clients + ci) for ci in range(clients)
-              if K_MIX[ci % 4] >= 10][:8]
-    hits = total = 0
-    for ci, qi in probes:
-        oracle = idx.search_batch(queries[qi % len(queries)][None, :],
-                                  tenants[ci % len(tenants)], k=10,
-                                  super_filter=-1)[0][0]
-        got = ragg["results"][waves - 1][ci].ids[:10]
-        hits += len(set(got) & set(oracle))
-        total += len(oracle)
-    recall = hits / max(total, 1)
-
-    def waste(tel):
-        live = tel.counter_total("serve.live_requests")
-        padded = tel.counter_total("serve.padded_slots")
-        return (1.0 - live / padded) if padded else 0.0
-
-    base_waste, ragg_waste = waste(base["tel"]), waste(ragg["tel"])
-
-    # mode probes: ONE dispatch + ONE compiled kernel per mode under the
-    # same mixed-k batch (the "no per-k recompiles" acceptance)
-    def probe_single(mode):
-        telm = Telemetry()
-        n = 4096
-        rngp = np.random.default_rng(7)
-        embp = rngp.standard_normal((n, DIM)).astype(np.float32)
-        mode_kw = {"exact": {}, "quant": {"int8_serving": True},
-                   "ivf": {"ivf_nprobe": 4}}[mode]
-        pidx = MemoryIndex(dim=DIM, capacity=n + 64, telemetry=telm,
-                           serve_k_max=128, **mode_kw)
-        pidx.add([f"p{i}" for i in range(n)], embp, [0.5] * n, [0.0] * n,
-                 ["semantic"] * n, ["default"] * n, "u0")
-        if mode == "ivf":
-            pidx._IVF_MIN_ROWS = 1
-            assert pidx.ivf_maintenance()
-        reqs = [RetrievalRequest(query=embp[i], tenant="u0",
-                                 k=K_MIX[i % 4]) for i in range(16)]
-        kcalls = {"n": 0}
-        names = ("search_fused_ragged_read", "search_fused_ragged",
-                 "search_fused_quant_ragged_read",
-                 "search_fused_quant_ragged",
-                 "search_fused_ivf_ragged_read", "search_fused_ivf_ragged")
-        orig = {name: getattr(S, name) for name in names}
-
-        def wrapp(name):
-            def f(*a, __o=orig[name], **k):
-                kcalls["n"] += 1
-                return __o(*a, **k)
-            return f
-
-        for name in names:
-            setattr(S, name, wrapp(name))
-        try:
-            pidx.search_fused_requests(reqs, **kw)
-            pidx.search_fused_requests(list(reversed(reqs)), **kw)
-        finally:
-            for name in names:
-                setattr(S, name, orig[name])
-        return {"dispatches_per_turn": kcalls["n"] / 2.0,
-                "compile_cache_entries": len(pidx._serve_kernel_keys),
-                "telemetry": _telemetry_block(telm)}
-
-    def probe_sharded():
-        from lazzaro_tpu.parallel.index import ShardedMemoryIndex
-        from lazzaro_tpu.parallel.mesh import make_mesh
-        devs = jax.devices()
-        if len(devs) < 2:
-            return None
-        telm = Telemetry()
-        mesh = make_mesh(("data",), (2,), devices=devs[:2])
-        n = 4096
-        rngp = np.random.default_rng(7)
-        embp = rngp.standard_normal((n, DIM)).astype(np.float32)
-        pidx = ShardedMemoryIndex(mesh, dim=DIM, capacity=n + 63, k=8,
-                                  telemetry=telm, serve_k_max=128)
-        pidx.add([f"p{i}" for i in range(n)], embp, "u0")
-        reqs = [RetrievalRequest(query=embp[i], tenant="u0",
-                                 k=K_MIX[i % 4]) for i in range(16)]
-        before = pidx.dispatch_count
-        pidx.serve_requests(reqs)
-        pidx.serve_requests(list(reversed(reqs)))
-        return {"dispatches_per_turn": (pidx.dispatch_count - before) / 2.0,
-                "compile_cache_entries": len(pidx._fused_cache),
-                "mesh": {"parts": 2, "axis": "data"},
-                "telemetry": _telemetry_block(telm)}
-
-    modes = {m: probe_single(m) for m in ("exact", "quant", "ivf")}
-    sh = probe_sharded()
-    if sh is not None:
-        modes["sharded"] = sh
-    n_modes = len(modes)
-    cache_entries = sum(m["compile_cache_entries"] for m in modes.values())
-    return {
-        "ragged": True,
-        "ragged_serving_qps": round(ragg["qps"], 1),
-        "flush_baseline_qps": round(base["qps"], 1),
-        "fused_vs_classic_speedup": round(ragg["qps"] / base["qps"], 2),
-        "speedup_floor": 1.3,
-        "recall_at_10": round(recall, 4),
-        "recall_floor": 0.999,
-        "dispatches_per_turn": (ragg["kern_calls"] / ragg["batches"]
-                                if ragg["batches"] else None),
-        "pad_waste_fraction_baseline": round(base_waste, 4),
-        "pad_waste_reduction_x": (round(base_waste / ragg_waste, 1)
-                                  if ragg_waste > 0 else None),
-        "compile_cache_entries": cache_entries,
-        "modes_exercised": n_modes,
-        "modes": modes,
-        "clients": clients, "waves": waves, "k_mix": list(K_MIX),
-        "arena_rows": rows, "batch_max": 128,
-        "telemetry": _telemetry_block(ragg["tel"]),
-        "baseline_telemetry": _telemetry_block(base["tel"]),
-        "roofline": {
-            "ragged_wave": _roofline(rows, DIM, 2,
-                                     ragg["wall_s"] * 1e3 / waves,
-                                     clients, on_tpu),
-            "flush_wave": _roofline(rows, DIM, 2,
-                                    base["wall_s"] * 1e3 / waves,
-                                    clients, on_tpu),
-        },
-    }
-
-
 def bench_tiered_serving(on_tpu: bool, rows: int = 65_536,
                          hot_budget: int = None, reps: int = 5,
                          recall_floor: float = 0.95):
@@ -2346,8 +2112,7 @@ def bench_tiered_serving(on_tpu: bool, rows: int = 65_536,
     # measured dispatch counters over the tiered jit entry points
     calls = {"scan": 0, "finish": 0}
     wrapped = {}
-    scan_names = ("search_fused_tiered", "search_fused_tiered_copy",
-                  "search_fused_tiered_read", "search_fused_tiered_ragged",
+    scan_names = ("search_fused_tiered_ragged",
                   "search_fused_tiered_ragged_copy",
                   "search_fused_tiered_ragged_read")
     fin_names = ("tier_cold_finish", "tier_cold_finish_copy",
@@ -2533,7 +2298,7 @@ def bench_paged_arena(on_tpu: bool, rows: int = 16_384, reps: int = 5,
 
     # ---- serving: QPS ratio + dispatch counter ----------------------
     # measured over the production fused serving surface (same entry the
-    # tiered/ragged artifacts gate) — the page indirection must ride
+    # tiered artifacts gate) — the page indirection must ride
     # INSIDE the one fused program, so the counted dispatch total per
     # turn is identical to dense and exactly 1.
     kw = dict(cap_take=5, max_nbr=16, super_gate=0.4,
@@ -2544,8 +2309,7 @@ def bench_paged_arena(on_tpu: bool, rows: int = 16_384, reps: int = 5,
                                  gate_enabled=True, boost=False)
                 for i in range(len(qs))]
 
-    scan_names = ("search_fused", "search_fused_copy", "search_fused_read",
-                  "search_fused_ragged", "search_fused_ragged_copy",
+    scan_names = ("search_fused_ragged", "search_fused_ragged_copy",
                   "search_fused_ragged_read", "arena_search")
     calls = {"n": 0}
     wrapped = {}
@@ -2983,12 +2747,11 @@ def bench_semantic_cache(on_tpu: bool, rows: int = 65_536, tenants: int = 4,
     idx_off, tel_off = build(False)
     fill_s = time.perf_counter() - t0
 
-    # measured dispatch counter over the exact-family jit entries (static
-    # + ragged + twins) — the fused-serving invariant, cache ON
+    # measured dispatch counter over the exact-family jit entries — the
+    # fused-serving invariant, cache ON
     calls = {"n": 0}
     wrapped = {}
-    for name in ("search_fused", "search_fused_copy", "search_fused_read",
-                 "search_fused_ragged", "search_fused_ragged_copy",
+    for name in ("search_fused_ragged", "search_fused_ragged_copy",
                  "search_fused_ragged_read"):
         orig = getattr(S_mod, name)
         wrapped[name] = orig
@@ -4064,45 +3827,6 @@ def fused_pq_stage_main():
                               if k not in ("telemetry",)}}}))
 
 
-def ragged_stage_main():
-    """Standalone ragged-serving A/B (BENCH_RAGGED=<rows> or =1 for the
-    ISSUE 7 default 65536): runs ONLY the ragged-vs-flush-boundary stage
-    and writes bench_artifacts/pr7_ragged_serving_<size>_<dev>.json.
-    On CPU run with XLA_FLAGS=--xla_force_host_platform_device_count=2
-    (or more) so the sharded mode probe can build its 2-way mesh.
-    BENCH_RAGGED_CLIENTS / BENCH_RAGGED_WAVES tune the traffic shape
-    (default 69 clients × 5 waves — just past a power of two, where the
-    pow2 baseline pads 69 → 128 slots and linear buckets pad 69 → 72)."""
-    on_tpu = backend.on_tpu()
-    spec = os.environ.get("BENCH_RAGGED", "1")
-    rows = 65_536 if spec.strip() in ("", "1") else int(spec)
-    clients = int(os.environ.get("BENCH_RAGGED_CLIENTS", "69"))
-    waves = int(os.environ.get("BENCH_RAGGED_WAVES", "5"))
-    art_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                           "bench_artifacts")
-    os.makedirs(art_dir, exist_ok=True)
-    dev_tag = "tpu" if on_tpu else "cpu"
-    print(f"[bench] ragged-serving stage at {rows} rows, "
-          f"{clients} clients x {waves} waves", file=sys.stderr, flush=True)
-    t0 = time.perf_counter()
-    out = bench_ragged_serving(on_tpu, rows, clients=clients, waves=waves)
-    out["stage_total_s"] = round(time.perf_counter() - t0, 1)
-    size_tag = "1m" if rows >= 1_000_000 else f"{rows // 1024}k"
-    path = os.path.join(art_dir,
-                        f"pr7_ragged_serving_{size_tag}_{dev_tag}.json")
-    with open(path, "w") as f:
-        json.dump({"metric": "ragged_serving_qps",
-                   "value": out["ragged_serving_qps"], "unit": "qps",
-                   "device": dev_tag, "sizes": {size_tag: out}},
-                  f, indent=1)
-    print(f"[bench] wrote {path}", file=sys.stderr, flush=True)
-    print(json.dumps({"metric": "ragged_serving_qps",
-                      "sizes": {size_tag: {
-                          k: v for k, v in out.items()
-                          if k not in ("telemetry", "baseline_telemetry",
-                                       "modes")}}}))
-
-
 def tiered_stage_main():
     """Standalone tiered-memory acceptance stage (BENCH_TIERED=<rows> or
     =1 for the default 65536): serves a corpus 4× the hot-row budget
@@ -5015,8 +4739,6 @@ if __name__ == "__main__":
         semantic_cache_stage_main()
     elif os.environ.get("BENCH_LIFECYCLE"):
         lifecycle_stage_main()
-    elif os.environ.get("BENCH_RAGGED"):
-        ragged_stage_main()
     elif os.environ.get("BENCH_FUSED_QUANT"):
         fused_quant_stage_main()
     elif os.environ.get("BENCH_FUSED_IVF"):

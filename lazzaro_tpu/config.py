@@ -77,7 +77,7 @@ class MemoryConfig:
     # with ivf_serving > 0, the member scan reads product-quantized codes
     # (m = dim/8 bytes per row instead of dim·2) and the top shortlist is
     # re-scored exactly from the master, so returned scores stay exact.
-    # Serves fused (state.search_fused_pq — ADC table build, m-byte
+    # Serves fused (state.search_fused_pq_ragged — ADC table build, m-byte
     # member scan, exact rescore, gate/CSR/boost tail in ONE dispatch)
     # with codes maintained INSIDE the fused ingest dispatch against the
     # frozen codebook; the codebook retrains only on ivf_maintenance's
@@ -129,18 +129,18 @@ class MemoryConfig:
     ingest_sharded: bool = True
 
     # --- serving path (lazzaro_tpu/serve) ----------------------------------
-    # Fused single-dispatch retrieval (core/state.py search_fused): the
-    # per-chat-turn serving sequence — super-node top-1 gate, main-arena
+    # Fused single-dispatch retrieval (core/state.py search_fused_ragged):
+    # the per-chat-turn serving sequence — super-node top-1 gate, main-arena
     # ANN top-k, CSR neighbor gather, neighbor- + access-salience boosts —
     # runs as ONE donated device program + ONE packed readback, routed
     # through the cross-request QueryScheduler so concurrent users share
     # dense device batches. Off = the classic 3-4 dispatch sequence.
     # With int8_serving on, the fused program streams the int8 shadow for
     # a coarse top-(k + coarse_fetch_slack) and exactly rescores the
-    # survivors from the master (state.search_fused_quant) — still ONE
-    # dispatch. With ivf_serving > 0 and a published build, the coarse
+    # survivors from the master (state.search_fused_quant_ragged) — still
+    # ONE dispatch. With ivf_serving > 0 and a published build, the coarse
     # stage becomes the IVF centroid prefilter + member gather INSIDE the
-    # same dispatch (state.search_fused_ivf; composes with int8 as
+    # same dispatch (state.search_fused_ivf_ragged; composes with int8 as
     # gathered-int8 coarse + exact rescore). Under a MESH the same
     # chat-turn program runs as ONE distributed shard_map dispatch
     # (state.make_fused_sharded): shard-local scan (exact or int8
@@ -148,55 +148,39 @@ class MemoryConfig:
     # gate/CSR/boost tail with shard-local scatters — the pod path keeps
     # the full serving semantics. With pq_serving on, the coarse stage is
     # the in-dispatch ADC member scan over the m-byte code slab
-    # (state.search_fused_pq, ISSUE 16) — every mode is fused now.
+    # (state.search_fused_pq_ragged, ISSUE 16) — every mode is fused now.
     serve_fused: bool = True
-    # QueryScheduler flush policy: a pending batch ships when it reaches
-    # serve_batch_max requests OR when its oldest request has waited
-    # serve_flush_us microseconds — bursty load coalesces, a lone request
-    # is never held hostage. Batches pad to power-of-two buckets so jit
-    # specializations stay bounded. With serve_continuous (default) the
-    # wait only ever applies while a dispatch is in flight — an idle
-    # scheduler ships immediately.
+    # QueryScheduler admission: pending requests enter the next dispatch
+    # the moment the worker is free, at most serve_batch_max of them — a
+    # lone request on an idle scheduler dispatches immediately, and
+    # requests arriving while a dispatch is in flight coalesce into the
+    # next one (the in-flight dispatch IS the batching window; a FULL
+    # window of pure reads is admitted over a dispatch of pure reads).
     serve_batch_max: int = 64
-    serve_flush_us: int = 2000
-    # Continuous batching (ISSUE 7): instead of flush-boundary mega-
-    # batches, the scheduler admits pending requests into the next
-    # dispatch the moment the worker is free — a lone request on an idle
-    # scheduler dispatches immediately (no serve_flush_us wait), and
-    # requests arriving while a dispatch is in flight coalesce naturally
-    # into the next one (the in-flight dispatch IS the batching window).
-    # Off = the PR 6 flush-boundary policy (A/B + fallback).
-    serve_continuous: bool = True
-    # Per-tenant admission control for continuous batching: at most this
+    # Per-tenant admission control: at most this
     # many of one tenant's requests are admitted into a single dispatch
     # (oldest-first across tenants; over-cap requests stay queued for the
     # next dispatch, so one flooding tenant cannot monopolize the batch).
     # 0 = unlimited.
     serve_tenant_max_inflight: int = 0
-    # Ragged fused serving (ISSUE 7): per-query k / cap_take / nprobe
-    # ride into the kernel as int32 sidecar columns (device data) instead
-    # of trace constants — the scan bodies compute to the serve_k_max
-    # ceiling and mask each query at its own top-k boundary, so ONE
-    # compiled kernel per (mode × geometry) serves any mix of request
-    # shapes: a k=100 request no longer re-keys the whole batch's kernel,
-    # and mixed-k traffic stops burning compile-cache entries. Off = the
-    # PR 6 per-(mode × batch-max-k-bucket) kernels.
-    serve_ragged: bool = True
-    # Static per-query k ceiling of the ragged kernels (requests clamp to
-    # it; raising it retraces once per mode). 128 covers the classic API
-    # surface (ann_limit, retrieval caps) with headroom.
+    # Static per-query k ceiling of the fused serving kernels: per-query
+    # k / cap_take / nprobe ride into the kernel as int32 device columns,
+    # the scan bodies compute to this ceiling and mask each query at its
+    # own top-k boundary, so ONE compiled kernel per (mode × geometry)
+    # serves any mix of request shapes. Requests clamp to it; raising it
+    # retraces once per mode. 128 covers the classic API surface
+    # (ann_limit, retrieval caps) with headroom.
     serve_k_max: int = 128
-    # Query-batch padding granularity of the ragged path: batches pad to
-    # the next multiple of this instead of the next power of two — worst-
-    # case padded waste drops from ~50% of the dispatch to granularity-1
-    # slots, and jit specializations stay bounded by
+    # Query-batch padding granularity: batches pad to the next multiple
+    # of this, not the next power of two — worst-case padded waste is
+    # granularity-1 slots, and jit specializations stay bounded by
     # serve_batch_max / granularity buckets.
     serve_pad_granularity: int = 8
     # LRU cap on the compiled serving-kernel caches (single-chip sharded
-    # factory cache and the pod index's fused cache): with ragged kernels
-    # the keys collapse to per-mode entries anyway; the cap evicts stale
-    # per-k-bucket kernels left behind by non-ragged traffic instead of
-    # letting kernel.cache_entries grow without bound.
+    # factory cache and the pod index's fused cache): the keys are
+    # per-mode entries at fixed ceilings; the cap evicts the programs a
+    # changed ceiling or mode leaves behind instead of letting
+    # kernel.cache_entries grow without bound.
     serve_kernel_cache_max: int = 8
     # Neighbor-gather width of the fused retrieval kernel: at most this
     # many CSR neighbors per retrieved row receive the neighbor-salience

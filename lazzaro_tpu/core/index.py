@@ -379,6 +379,40 @@ class _StagedSharded(NamedTuple):
     sem_tail: tuple               # the semantic ring's operand, or ()
 
 
+# The single-chip serving families and their ``core.state`` entry points:
+# (donated, copy, read). Names, resolved on ``S`` at every dispatch — the
+# dispatch-count tests wrap the module attributes.
+_SERVE_KERNELS = {
+    "exact": ("search_fused_ragged", "search_fused_ragged_copy",
+              "search_fused_ragged_read"),
+    "quant": ("search_fused_quant_ragged", "search_fused_quant_ragged_copy",
+              "search_fused_quant_ragged_read"),
+    "tiered": ("search_fused_tiered_ragged",
+               "search_fused_tiered_ragged_copy",
+               "search_fused_tiered_ragged_read"),
+    "ivf": ("search_fused_ivf_ragged", "search_fused_ivf_ragged_copy",
+            "search_fused_ivf_ragged_read"),
+    "ivf_tiered": ("search_fused_ivf_tiered_ragged",
+                   "search_fused_ivf_tiered_ragged_copy",
+                   "search_fused_ivf_tiered_ragged_read"),
+    "pq": ("search_fused_pq_ragged", "search_fused_pq_ragged_copy",
+           "search_fused_pq_ragged_read"),
+    "pq_tiered": ("search_fused_pq_tiered_ragged",
+                  "search_fused_pq_tiered_ragged_copy",
+                  "search_fused_pq_tiered_ragged_read"),
+}
+
+
+class _ServeRoute(NamedTuple):
+    """What one serving dispatch runs — ``MemoryIndex._serve_route``'s
+    answer, the only place that decides it."""
+
+    mode: str          # a _SERVE_KERNELS family; "sharded_<base>" under a mesh
+    k_bucket: int      # the static k ceiling every request clamps to
+    tiered: bool       # cold rows exist: the tier-aware programs serve
+    coarse_tabs: Optional[tuple]   # the PQ pack (pq*) or IVF pack (ivf*)
+
+
 class MemoryIndex:
     """Single-chip by default; pass ``mesh`` to row-shard every arena column
     over a mesh axis — the scaling-book recipe: annotate the shardings, let
@@ -398,7 +432,7 @@ class MemoryIndex:
                  pq_serving: bool = False, coarse_slack: int = 8,
                  paged: bool = False, page_rows: int = 4096,
                  telemetry=None, telemetry_hbm: bool = False,
-                 serve_ragged: bool = True, serve_k_max: int = 128,
+                 serve_k_max: int = 128,
                  serve_pad_granularity: int = 8,
                  serve_kernel_cache_max: int = 8,
                  ingest_sharded: bool = True,
@@ -607,18 +641,17 @@ class MemoryIndex:
         self._tenants: Dict[str, int] = {}
         self._shards: Dict[str, int] = {}
         self.tenant_nodes: Dict[str, set] = {}
-        # Ragged fused serving (ISSUE 7): per-query k/cap/nprobe ride as
-        # int32 sidecar columns, the kernels compute to the serve_k_max
-        # ceiling and mask per query — one compiled kernel per
-        # (mode × geometry), any mix of request shapes.
-        self.serve_ragged = bool(serve_ragged)
+        # Fused serving: per-query k/cap/nprobe ride as int32 device
+        # columns, the kernels compute to the serve_k_max ceiling and mask
+        # per query — one compiled kernel per (mode × geometry), any mix
+        # of request shapes.
         self.serve_k_max = max(1, int(serve_k_max))
         self.serve_pad_granularity = max(1, int(serve_pad_granularity))
         # Semantic query cache (ISSUE 20): the device ring + host mirror.
         # Ring width = the widest candidate window any family substitutes
-        # (the ragged k ceiling + the tiered slack), so ONE ring serves
-        # every kernel family; batches whose k-bucket overflows it (non-
-        # ragged k > serve_k_max) just skip the probe for that dispatch.
+        # (the k ceiling + the tiered slack), so ONE ring serves every
+        # kernel family; a batch whose window overflows it (cap_take above
+        # serve_k_max) just skips the probe for that dispatch.
         self._sem_host = None
         if semantic_cache:
             self._sem_host = SemanticCacheHost(
@@ -627,7 +660,7 @@ class MemoryIndex:
                 semantic_cache_threshold, semantic_cache_block,
                 telemetry=self.telemetry)
         # Distinct fused serving-kernel keys this index has dispatched
-        # (mode + statics — with ragged on, exactly one per mode); the
+        # (mode + statics: the ceilings are fixed, so one per mode); the
         # bench's compile_cache_entries measurement and the
         # kernel.cache_entries{surface="single_fused"} gauge read it.
         self._serve_kernel_keys: set = set()
@@ -639,10 +672,9 @@ class MemoryIndex:
         self._mesh_topk_cache = LRUKernelCache(serve_kernel_cache_max)
         # Distributed fused serving programs (ISSUE 5): under a mesh the
         # whole chat-turn program runs as ONE shard_map dispatch
-        # (state.make_fused_sharded) — with ragged serving cached per
-        # MODE, otherwise per (mode, k-bucket, take, nbr). LRU-capped
-        # (ISSUE 7 satellite): mixed-k non-ragged traffic used to grow
-        # this without bound while kernel.cache_entries just watched.
+        # (state.make_fused_sharded), cached per (mode, k ceiling, take,
+        # nbr) — one entry per mode while the ceilings stand. LRU-capped
+        # (ISSUE 7 satellite) so a changed ceiling evicts, never grows.
         self._fused_sharded_cache = LRUKernelCache(serve_kernel_cache_max)
         # Distributed lifecycle-sweep programs (ISSUE 19): one per
         # (prune_cap bucket, archive_k bucket), same LRU discipline as
@@ -2901,49 +2933,82 @@ class MemoryIndex:
         return not any(r.boost for r in reqs)
 
     # ------------------------------------------------- memory-safe serving
-    def _serve_mode_hint(self, cap_take: int, reqs) -> Tuple[str, int]:
-        """Cheap (mode, k-ceiling) prediction of what the fused dispatch
-        will route to — the planner's geometry key. Mirrors the routing
-        in ``_search_fused_once`` without building any device arrays."""
-        cap = self.state.capacity
+    def _serve_route(self, cap_take: int) -> _ServeRoute:
+        """The choice of serving program, made HERE and nowhere else: the
+        planner's geometry key, the dispatch (its ``_SERVE_KERNELS``
+        triple, its operands, its ``serve.dispatches{mode}`` label) and
+        the warmup all read this answer. Builds no device arrays beyond
+        what the coarse packs cache.
+
+        ``k_bucket`` is the static k every request of a dispatch clamps
+        to, so the kernel key never depends on the batch's k mix: one
+        compiled program per (mode × geometry) serves k∈{4..128} in one
+        dispatch, per-request k riding as device data.
+
+        Under a MESH the program is ``state.make_fused_sharded``'s
+        (shard-local exact / int8 / tier-aware scan, all_gather merge).
+        On one chip a complete PQ pack outranks the IVF build it rides on,
+        either outranks the dense scans, and int8 mode takes the dense
+        coarse + exact rescore; with cold rows present each composes with
+        tiering (ISSUE 12 / 16: hot candidates from the member gather,
+        cold rows from the residency-masked coarse scan over the int8
+        shadow or the m-byte PQ slab — no dense fallback when a build is
+        published)."""
+        k_bucket = int(min(max(self.serve_k_max, cap_take, 1),
+                           self.state.capacity))
         tm = self.tiering
         tiered = tm is not None and tm.cold_count > 0
-        if self.serve_ragged:
-            k_bucket = int(min(max(self.serve_k_max, cap_take, 1), cap))
-        else:
-            k_eff = max(cap_take,
-                        max((min(int(r.k), cap) for r in reqs), default=1),
-                        1)
-            k_bucket = min(max(next_pow2(k_eff), 1), cap)
         if self.mesh is not None:
             base = ("tiered" if tiered
                     else "quant" if self.int8_serving else "exact")
-            return "sharded_" + base, k_bucket
-        if tiered:
-            # IVF composes with tiering now (ISSUE 12), and so does PQ
-            # (ISSUE 16): hot candidates from the member gather, cold
-            # rows from the residency-masked shadow coarse scan — int8
-            # codes or the m-byte PQ slab — no dense fallback when a
-            # build is published.
-            if self._pq_fused_pack(k_bucket) is not None:
-                return "pq_tiered", k_bucket
-            if self._ivf_fused_pack(k_bucket) is not None:
-                return "ivf_tiered", k_bucket
-            return "tiered", k_bucket
-        if self._pq_fused_pack(k_bucket) is not None:
-            return "pq", k_bucket
-        if self._ivf_fused_pack(k_bucket) is not None:
-            return "ivf", k_bucket
-        if self.int8_serving:
-            return "quant", k_bucket
-        return "exact", k_bucket
+            return _ServeRoute("sharded_" + base, k_bucket, tiered, None)
+        fam, tabs = "pq", self._pq_fused_pack(k_bucket)
+        if tabs is None:
+            fam, tabs = "ivf", self._ivf_fused_pack(k_bucket)
+        if tabs is not None:
+            mode = fam + "_tiered" if tiered else fam
+        elif tiered:
+            mode = "tiered"
+        else:
+            mode = "quant" if self.int8_serving else "exact"
+        return _ServeRoute(mode, k_bucket, tiered, tabs)
+
+    def _serve_operands(self, route: _ServeRoute, st) -> tuple:
+        """A single-chip family's leading operands — what its entry points
+        take between the arena and the CSR — against the state the caller
+        dispatches on: ``cur`` under ``_state_lock`` for a boosting batch,
+        so the (arena, codes, residency) tuple can never tear across a
+        racing writer (re-entrant RLock; a shadow rebuild is
+        dispatch-only), the snapshot for a read batch. The coarse tables,
+        the PQ codebook and the code slab are read-only replicas."""
+        mode, tabs = route.mode, route.coarse_tabs
+        if mode.startswith("pq"):
+            cent, members, extras, _, book_cent, codes = tabs
+            # pq_tiered never touches the int8 shadow — the cold coarse
+            # scan reads the PQ slab; only the residency mask rides
+            cold = (self.tiering.cold_mask_dev(),) if route.tiered else ()
+            return (book_cent, codes, *cold, cent, members, extras)
+        if mode.startswith("ivf"):
+            cent, members, extras, _ = tabs
+            if route.tiered:
+                return (*self._int8_shadow_for(st),
+                        self.tiering.cold_mask_dev(), cent, members, extras)
+            # two-stage candidate scan (int8 gathered coarse + exact
+            # rescore) when the shadow is on too
+            shadow = self._int8_shadow_for(st) if self.int8_serving else None
+            return (shadow, cent, members, extras)
+        if mode == "tiered":
+            return (*self._int8_shadow_for(st), self.tiering.cold_mask_dev())
+        if mode == "quant":
+            return tuple(self._int8_shadow_for(st))
+        return ()
 
     def _serve_geometry(self, nq: int, mode: str, k_bucket: int) -> Geometry:
-        pad_n = (bucket_size(nq, self.serve_pad_granularity)
-                 if self.serve_ragged else next_pow2(nq))
         st = self.state
         return Geometry(
-            kind="serve", mode=mode, batch=pad_n, rows=st.salience.shape[0],
+            kind="serve", mode=mode,
+            batch=bucket_size(nq, self.serve_pad_granularity),
+            rows=st.salience.shape[0],
             dim=self.dim, k=k_bucket,
             dtype_bytes=int(np.dtype(self.dtype).itemsize),
             mesh_parts=self._n_parts, edge_cap=self.edge_state.capacity,
@@ -2994,10 +3059,10 @@ class MemoryIndex:
                     f"planner budget is configured to replan it: {e}"
                 ) from e
         check_not_poisoned(self._poisoned)
-        mode, k_bucket = self._serve_mode_hint(cap_take, reqs)
-        geom = self._serve_geometry(nq, mode, k_bucket)
-        chunkable = self.serve_ragged and self.mesh is None
-        decision = planner.check_feasible(geom, chunkable=chunkable)
+        route = self._serve_route(cap_take)
+        geom = self._serve_geometry(nq, route.mode, route.k_bucket)
+        decision = planner.check_feasible(geom,
+                                          chunkable=self.mesh is None)
         return self._serve_planned(reqs, geom, decision, kw,
                                    replanned=False)
 
@@ -3038,8 +3103,7 @@ class MemoryIndex:
                     f"rows={geom.rows}): {e}") from e
             self.planner.note_oom(geom)
             harder = self.planner.replan_after_oom(
-                geom, decision,
-                chunkable=(self.serve_ragged and self.mesh is None))
+                geom, decision, chunkable=self.mesh is None)
             if harder is None:
                 tel.bump("plan.infeasible", labels={"path": "serve"})
                 raise PlanInfeasible(
@@ -3065,17 +3129,15 @@ class MemoryIndex:
         ownership rules). Pure-read batches (no boosts requested) take the
         non-donating ``*_read`` twins. Per-request tenants ride
         into the kernel as a device column, so one batch can serve many
-        tenants with mask-enforced isolation.
+        tenants with mask-enforced isolation; per-request k / cap / nprobe
+        ride the same way under static ceilings, so one compiled program
+        serves any mix of request shapes.
 
-        Coarse-stage routing (all still ONE dispatch + ONE readback):
-        a published IVF build takes ``search_fused_ivf`` (centroid
-        prefilter + member gather, int8-gathered coarse + exact rescore
-        when the shadow is on too); otherwise int8 mode takes
-        ``search_fused_quant`` (dense int8 coarse + exact rescore); else
-        the exact dense ``search_fused``. Under a MESH the same program
-        runs as ONE distributed shard_map dispatch
-        (``state.make_fused_sharded``): shard-local scan (exact, or int8
-        coarse + exact rescore over the row-sharded shadow), one
+        Which program runs is ``_serve_route``'s answer (all still ONE
+        dispatch + ONE readback): on one chip a ``_SERVE_KERNELS`` family,
+        under a MESH the same program as ONE distributed shard_map
+        dispatch (``state.make_fused_sharded``): shard-local scan (exact,
+        or int8 coarse + exact rescore over the row-sharded shadow), one
         all_gather + global top-k merge, then the gate/CSR/boost tail
         with shard-local scatters — the pod path keeps the full chat-turn
         semantics (ISSUE 5)."""
@@ -3093,17 +3155,12 @@ class MemoryIndex:
             st = self.state
             cap = st.capacity
             dim = self.dim
-            ragged = self.serve_ragged
-            if ragged:
-                # Static per-mode k CEILING (ISSUE 7): every request clamps to
-                # it, so the kernel key never depends on the batch's k mix —
-                # one compiled program per (mode × geometry) serves k∈{4..128}
-                # in one dispatch. Per-request k rides as device data below.
-                k_bucket = int(min(max(self.serve_k_max, cap_take, 1), cap))
-            else:
-                k_eff = max(cap_take, max((min(int(r.k), cap) for r in reqs),
-                                          default=1), 1)
-                k_bucket = min(max(next_pow2(k_eff), 1), cap)
+            # With any row demoted (ISSUE 8) the route is a tier-aware
+            # program: ONE bounded finish dispatch for queries whose
+            # candidates touch cold rows; hot-only turns stay ONE dispatch
+            # + ONE readback.
+            route = self._serve_route(cap_take)
+            mode, k_bucket, tiered = route.mode, route.k_bucket, route.tiered
             q = np.zeros((nq, dim), np.float32)
             valid = np.zeros((nq,), bool)
             tenants = np.full((nq,), -1, np.int32)
@@ -3121,21 +3178,17 @@ class MemoryIndex:
                 tenants[i] = tid
                 gate_on[i] = bool(r.gate_enabled)
                 boost_on[i] = bool(r.boost)
-                if ragged:
-                    # k_q ≥ cap so the boosted prefix is always live (the
-                    # non-ragged path guaranteed the same via k_eff ≥ cap_take)
-                    k_arr[i] = min(max(int(r.k), cap_take, 1), k_bucket)
-                    rc = getattr(r, "cap_take", None)
-                    cap_arr[i] = min(int(rc) if rc else cap_take, cap_take,
-                                     k_bucket)
+                # k_q ≥ cap so the boosted prefix is always live
+                k_arr[i] = min(max(int(r.k), cap_take, 1), k_bucket)
+                rc = getattr(r, "cap_take", None)
+                cap_arr[i] = min(int(rc) if rc else cap_take, cap_take,
+                                 k_bucket)
             if not valid.any():
                 return results
-            # Ragged batches pad to a LINEAR granularity bucket instead of the
-            # next power of two: worst-case padded waste drops from ~50% of
-            # the dispatch to granularity-1 slots (the pow2 padding tax this
-            # PR kills), with jit specializations still bounded.
-            qp = (pad_to_bucket(q, self.serve_pad_granularity) if ragged
-                  else pad_to_pow2(q))
+            # Batches pad to a LINEAR granularity bucket, not the next power
+            # of two: worst-case padded waste is granularity-1 slots instead
+            # of ~50% of the dispatch, with jit specializations still bounded.
+            qp = pad_to_bucket(q, self.serve_pad_granularity)
             pad_n = qp.shape[0]
         with tel.span("index.stage"):
             # Coalesce/pad inflation: padded kernel slots vs live requests.
@@ -3149,13 +3202,7 @@ class MemoryIndex:
                 return out
 
             indptr, nbr = self._csr_for(st)
-            # Tiered memory (ISSUE 8): with any row demoted, serving routes
-            # through the tier-aware program — int8 coarse scan over the
-            # full-corpus shadow, exact in-kernel rescore for hot rows, ONE
-            # bounded finish dispatch for queries whose candidates touch cold
-            # rows. Hot-only turns stay ONE dispatch + ONE readback.
             tm = self.tiering
-            tiered = tm is not None and tm.cold_count > 0
             if self.mesh is None:
                 args = (indptr, nbr, jnp.asarray(qp),
                         jnp.asarray(padb(valid)),
@@ -3163,70 +3210,29 @@ class MemoryIndex:
                         jnp.asarray(padb(gate_on)))
                 statics = dict(k=k_bucket, cap_take=min(cap_take, k_bucket),
                                max_nbr=max_nbr)
-                # Quantized fused serving (ISSUE 3): with the int8 shadow active the
-                # SAME single-dispatch program streams the int8 codes for the
-                # coarse top-(k+slack), exactly rescores the survivors from the
-                # master, and runs the gate/CSR/boost tail unchanged — the fused
-                # path no longer steps aside for int8 mode. Only the arena is
-                # donated; the shadow is a read-only replica that the boost scatter
-                # (salience/access/freshness only) can never invalidate.
-                use_quant = (bool(self.int8_serving) and self.mesh is None
-                             and not tiered)
-                # Fused IVF serving (ISSUE 4): with a coarse build published,
-                # the single-dispatch program starts from the centroid prefilter +
-                # member gather instead of a whole-arena stream — candidate HBM
-                # traffic ~(C + nprobe·N/C)·d per query — and ``ivf_nprobe > 0``
-                # no longer opts out of fusion. With int8 ALSO on, the candidate
-                # scan itself is two-stage (int8 gathered coarse + exact rescore).
-                # With cold rows present IVF now COMPOSES with tiering (ISSUE 12
-                # — the PR 8 dense-fallback is gone): hot candidates come from the
-                # member gather (demoted rows dropped from the tables and masked
-                # by residency), cold rows from the residency-masked int8 shadow
-                # coarse scan, merged at the k+slack window for the same bounded
-                # cold finish.
-                ivf_tabs = self._ivf_fused_pack(k_bucket)
-                # Fused PQ serving (ISSUE 16): with a complete (book, codes) pack
-                # published, the coarse stage is the m-byte ADC member scan — the
-                # flat LUT built in-kernel from the query and codebook, codes
-                # gathered for the visited clusters' members, exact f32 rescore
-                # of the top-(k+slack) survivors from the master — and the gate/
-                # CSR/boost tail rides unchanged: the last serving mode joins the
-                # ONE-dispatch contract. With cold rows present PQ composes with
-                # tiering the same way IVF does, except the cold coarse scan
-                # reads the PQ slab (m bytes/row) instead of the int8 shadow.
-                pq_tabs = self._pq_fused_pack(k_bucket)
-                ivf_tiered = tiered and ivf_tabs is not None
-                pq_tiered = tiered and pq_tabs is not None
-                coarse_tabs = pq_tabs if pq_tabs is not None else ivf_tabs
+                coarse_tabs = route.coarse_tabs
                 if coarse_tabs is not None:
                     statics["nprobe"] = coarse_tabs[3]
                     statics["slack"] = self.coarse_slack
-                elif use_quant or tiered:
+                elif mode in ("quant", "tiered"):
                     statics["slack"] = self.coarse_slack
-                mode = ("pq_tiered" if pq_tiered
-                        else "ivf_tiered" if ivf_tiered
-                        else "tiered" if tiered
-                        else "pq" if pq_tabs is not None
-                        else "ivf" if ivf_tabs is not None
-                        else "quant" if use_quant else "exact")
-                # Ragged sidecar device columns (ISSUE 7): per-query k / cap /
-                # nprobe as int32 DATA next to the query batch. Pad rows carry 0
-                # (their top-k masks fully dead; they were q_valid=False anyway).
-                k_dev = capq_dev = npq_dev = None
-                if ragged:
-                    np.minimum(cap_arr, statics["cap_take"], out=cap_arr)
-                    k_dev = jnp.asarray(padb(k_arr, 0, np.int32))
-                    capq_dev = jnp.asarray(padb(cap_arr, 0, np.int32))
-                    if coarse_tabs is not None:
-                        ceil_np = coarse_tabs[3]
-                        np_arr = np.zeros((nq,), np.int32)
-                        for i, r in enumerate(reqs):
-                            rn = getattr(r, "nprobe", None)
-                            np_arr[i] = (min(max(int(rn), 1), ceil_np) if rn
-                                         else ceil_np)
-                        np_arr[~valid] = 0
-                        npq_dev = jnp.asarray(padb(np_arr, 0, np.int32))
-                if ragged and scan_chunk:
+                # Per-query k / cap / nprobe as int32 DATA next to the query
+                # batch. Pad rows carry 0 (their top-k masks fully dead; they
+                # were q_valid=False anyway).
+                np.minimum(cap_arr, statics["cap_take"], out=cap_arr)
+                k_dev = jnp.asarray(padb(k_arr, 0, np.int32))
+                capq_dev = jnp.asarray(padb(cap_arr, 0, np.int32))
+                npq = ()              # the coarse families' probe-width column
+                if coarse_tabs is not None:
+                    ceil_np = coarse_tabs[3]
+                    np_arr = np.zeros((nq,), np.int32)
+                    for i, r in enumerate(reqs):
+                        rn = getattr(r, "nprobe", None)
+                        np_arr[i] = (min(max(int(rn), 1), ceil_np) if rn
+                                     else ceil_np)
+                    np_arr[~valid] = 0
+                    npq = (jnp.asarray(padb(np_arr, 0, np.int32)),)
+                if scan_chunk:
                     # Planner streaming-width override (ISSUE 11): the scan
                     # chunks the arena stream tighter — smaller [chunk, rows]
                     # score tile, SAME single dispatch, bit-identical results.
@@ -3236,28 +3242,22 @@ class MemoryIndex:
                 # writeback all ride INSIDE this one dispatch; the hit verdict
                 # comes back in the packed readback's semantic counter. Skipped
                 # when the batch's candidate window outgrows the ring width
-                # (non-ragged k-buckets past serve_k_max).
+                # (cap_take above serve_k_max).
                 semh = self._sem_host
                 sem_kw = {}
                 if semh is not None and mode in S.SEM_MODE_IDS:
-                    win = k_bucket + (statics.get("slack", 0)
-                                      if mode in ("tiered", "ivf_tiered",
-                                                  "pq_tiered") else 0)
+                    win = k_bucket + (statics.get("slack", 0) if tiered
+                                      else 0)
                     if win <= semh.width:
                         statics["sem_block"] = semh.block
                         sem_kw = {"sem": semh.tuple_for(mode)}
-                self._note_serve_kernel(mode, statics, ragged)
+                self._note_serve_kernel(mode, statics)
                 self._note_select_core(mode, st)
-                # pq_tiered never touches the int8 shadow — the cold coarse scan
-                # reads the PQ slab already in pq_tabs; only the residency mask
-                # rides in the tier pack there
-                tier_pack = (None if not tiered
-                             else (tm.cold_mask_dev(),) if pq_tiered
-                             else (*self._int8_shadow_for(st), tm.cold_mask_dev()))
-                self._maybe_record_hbm(mode, st, args, statics, super_gate,
-                                       ivf_tabs, use_quant, ragged=ragged,
-                                       k_dev=k_dev, npq_dev=npq_dev,
-                                       tier_pack=tier_pack, pq_tabs=pq_tabs)
+                donated, copying, read = (getattr(S, name)
+                                          for name in _SERVE_KERNELS[mode])
+                read_cols = (k_dev, *npq)
+                self._maybe_record_hbm(route, st, read, args, read_cols,
+                                       super_gate, statics)
                 # Fault point "plan.oom" (ISSUE 11): an HBM allocation failure the
                 # admission plan missed; the wrapper answers with one replan.
                 faults.fire("plan.oom", mode=mode, batch=pad_n)
@@ -3267,9 +3267,6 @@ class MemoryIndex:
                     # beside its donated state
                     statics = dict(statics, **sem_kw)
             else:
-                mode = ("sharded_tiered" if tiered
-                        else "sharded_quant" if self.int8_serving
-                        else "sharded_exact")
                 # Semantic query cache (ISSUE 20): the replicated ring rides
                 # the SAME distributed dispatch (substitution-only — the
                 # shard-local scans still run; the probe/substitute/
@@ -3289,8 +3286,8 @@ class MemoryIndex:
                 staged = self._stage_fused_sharded(
                     st, indptr, nbr, qp, padb, valid, tenants, gate_on,
                     boost_on, k_bucket, cap_take, max_nbr, super_gate,
-                    acc_boost, nbr_boost, now, ragged=ragged, k_arr=k_arr,
-                    cap_arr=cap_arr, tiered=tiered, sem=sem_state)
+                    acc_boost, nbr_boost, now, fam, k_arr, cap_arr,
+                    sem=sem_state)
                 # Fault point "plan.oom" (ISSUE 11): models an HBM allocation
                 # failure the admission plan missed — recovery is ONE replan
                 # into split sub-dispatches through the copy twins.
@@ -3332,16 +3329,13 @@ class MemoryIndex:
                         tenants, host, k_bucket=k_bucket,
                         cap_take=min(cap_take, k_bucket), max_nbr=max_nbr,
                         acc_boost=acc_boost, nbr_boost=nbr_boost,
-                        now_rel=now_rel, ragged=ragged,
-                        cap_arr=(cap_arr if ragged else None), tel=tel)
+                        now_rel=now_rel, cap_arr=cap_arr, tel=tel)
             with tel.span("index.decode", timer="serve.decode_ms"):
                 gate_s, gate_r, ann_s, ann_r, fast, counters = \
                     unpack_retrieval(host[:nq], k_bucket)
                 out = self._demux_fused(reqs, results, valid, boost_on,
                                         gate_s, gate_r, ann_s, ann_r, fast,
-                                        cap,
-                                        lengths=(counters[:, 0] if ragged
-                                                 else None))
+                                        cap, counters[:, 0])
                 if sem_state is not None:
                     semh.note_readback(sem_ring2, counters[:, 4],
                                        valid[:nq], tenants[:nq], gate_s,
@@ -3372,125 +3366,19 @@ class MemoryIndex:
                         if not sole:
                             tel.bump("serve.copy_dispatches",
                                      labels={"mode": mode})
-                        # Each branch picks the (donated, copying) twin pair
-                        # and the per-mode leading operands; ONE guarded call
-                        # at the end executes it donation-safe (ISSUE 10):
-                        # a transient failure retries through the copying
-                        # twin, a consumed input raises typed ArenaPoisoned.
-                        if pq_tiered:
-                            # PQ × tiering (ISSUE 16): exact member gather for
-                            # hot, residency-masked ADC coarse over the code
-                            # slab for cold — the codes/tables are read-only
-                            # replicas, so only the residency mask is taken
-                            # fresh here
-                            cold_dev = tm.cold_mask_dev()
-                            cent, members, extras, _, book_cent, codes = \
-                                pq_tabs
-                            pre = (book_cent, codes, cold_dev, cent, members,
-                                   extras)
-                            if ragged:
-                                twins = (S.search_fused_pq_tiered_ragged,
-                                         S.search_fused_pq_tiered_ragged_copy)
-                                boost_args = (boost_dev, k_dev, capq_dev,
-                                              npq_dev) + scalars
-                            else:
-                                twins = (S.search_fused_pq_tiered,
-                                         S.search_fused_pq_tiered_copy)
-                                boost_args = (boost_dev,) + scalars
-                        elif pq_tabs is not None:
-                            # Fused PQ serving (ISSUE 16): ADC member scan +
-                            # exact shortlist rescore, then the same tail
-                            cent, members, extras, _, book_cent, codes = \
-                                pq_tabs
-                            pre = (book_cent, codes, cent, members, extras)
-                            if ragged:
-                                twins = (S.search_fused_pq_ragged,
-                                         S.search_fused_pq_ragged_copy)
-                                boost_args = (boost_dev, k_dev, capq_dev,
-                                              npq_dev) + scalars
-                            else:
-                                twins = (S.search_fused_pq,
-                                         S.search_fused_pq_copy)
-                                boost_args = (boost_dev,) + scalars
-                        elif ivf_tiered:
-                            # IVF × tiering (ISSUE 12): member gather for hot,
-                            # residency-masked shadow coarse for cold — all
-                            # taken against ``cur`` under the lock
-                            q8, scale = self._int8_shadow_for(cur)
-                            cold_dev = tm.cold_mask_dev()
-                            cent, members, extras, _ = ivf_tabs
-                            pre = (q8, scale, cold_dev, cent, members, extras)
-                            if ragged:
-                                twins = (S.search_fused_ivf_tiered_ragged,
-                                         S.search_fused_ivf_tiered_ragged_copy)
-                                boost_args = (boost_dev, k_dev, capq_dev,
-                                              npq_dev) + scalars
-                            else:
-                                twins = (S.search_fused_ivf_tiered,
-                                         S.search_fused_ivf_tiered_copy)
-                                boost_args = (boost_dev,) + scalars
-                        elif tiered:
-                            # (arena, shadow, residency) all taken against
-                            # ``cur`` under the lock — the triple never tears
-                            q8, scale = self._int8_shadow_for(cur)
-                            cold_dev = tm.cold_mask_dev()
-                            pre = (q8, scale, cold_dev)
-                            if ragged:
-                                twins = (S.search_fused_tiered_ragged,
-                                         S.search_fused_tiered_ragged_copy)
-                                boost_args = (boost_dev, k_dev,
-                                              capq_dev) + scalars
-                            else:
-                                twins = (S.search_fused_tiered,
-                                         S.search_fused_tiered_copy)
-                                boost_args = (boost_dev,) + scalars
-                        elif ivf_tabs is not None:
-                            cent, members, extras, _ = ivf_tabs
-                            # shadow (when int8 is on too) taken against ``cur``
-                            # under the lock — the (arena, codes) pair never
-                            # tears
-                            shadow = (self._int8_shadow_for(cur) if use_quant
-                                      else None)
-                            pre = (shadow, cent, members, extras)
-                            if ragged:
-                                twins = (S.search_fused_ivf_ragged,
-                                         S.search_fused_ivf_ragged_copy)
-                                boost_args = (boost_dev, k_dev, capq_dev,
-                                              npq_dev) + scalars
-                            else:
-                                twins = (S.search_fused_ivf,
-                                         S.search_fused_ivf_copy)
-                                boost_args = (boost_dev,) + scalars
-                        elif use_quant:
-                            # shadow taken against ``cur`` under the lock, so
-                            # the (arena, codes) pair can never tear across a
-                            # racing writer (re-entrant RLock; rebuild is
-                            # dispatch-only)
-                            q8, scale = self._int8_shadow_for(cur)
-                            pre = (q8, scale)
-                            if ragged:
-                                twins = (S.search_fused_quant_ragged,
-                                         S.search_fused_quant_ragged_copy)
-                                boost_args = (boost_dev, k_dev,
-                                              capq_dev) + scalars
-                            else:
-                                twins = (S.search_fused_quant,
-                                         S.search_fused_quant_copy)
-                                boost_args = (boost_dev,) + scalars
-                        else:
-                            pre = ()
-                            if ragged:
-                                twins = (S.search_fused_ragged,
-                                         S.search_fused_ragged_copy)
-                                boost_args = (boost_dev, k_dev,
-                                              capq_dev) + scalars
-                            else:
-                                twins = (S.search_fused, S.search_fused_copy)
-                                boost_args = (boost_dev,) + scalars
+                        # The family's leading operands, taken against
+                        # ``cur`` under the lock; ONE guarded call executes
+                        # the (donated, copying) pair donation-safe (ISSUE
+                        # 10): a transient failure retries through the
+                        # copying twin, a consumed input raises typed
+                        # ArenaPoisoned.
+                        pre = self._serve_operands(route, cur)
+                        boost_args = (boost_dev, k_dev, capq_dev, *npq,
+                                      *scalars)
                         out = self._guarded(
                             lambda fn: fn(cur, *pre, *args, *boost_args,
                                           **sem_kw, **statics),
-                            twins[0], twins[1], sole, (cur,),
+                            donated, copying, sole, (cur,),
                             "serve_" + mode)
                         if sem_kw:
                             new_state, sem_ring2, packed = out
@@ -3498,85 +3386,12 @@ class MemoryIndex:
                             new_state, packed = out
                         del cur
                         self.state = new_state
-                elif pq_tiered:
-                    cold_dev = tm.cold_mask_dev()
-                    cent, members, extras, _, book_cent, codes = pq_tabs
-                    if ragged:
-                        packed = S.search_fused_pq_tiered_ragged_read(
-                            st, book_cent, codes, cold_dev, cent, members,
-                            extras, *args, k_dev, npq_dev,
-                            jnp.float32(super_gate), **statics)
-                    else:
-                        packed = S.search_fused_pq_tiered_read(
-                            st, book_cent, codes, cold_dev, cent, members,
-                            extras, *args, jnp.float32(super_gate), **statics)
-                elif pq_tabs is not None:
-                    cent, members, extras, _, book_cent, codes = pq_tabs
-                    if ragged:
-                        packed = S.search_fused_pq_ragged_read(
-                            st, book_cent, codes, cent, members, extras,
-                            *args, k_dev, npq_dev, jnp.float32(super_gate),
-                            **statics)
-                    else:
-                        packed = S.search_fused_pq_read(
-                            st, book_cent, codes, cent, members, extras,
-                            *args, jnp.float32(super_gate), **statics)
-                elif ivf_tiered:
-                    q8, scale = self._int8_shadow_for(st)
-                    cold_dev = tm.cold_mask_dev()
-                    cent, members, extras, _ = ivf_tabs
-                    if ragged:
-                        packed = S.search_fused_ivf_tiered_ragged_read(
-                            st, q8, scale, cold_dev, cent, members, extras,
-                            *args, k_dev, npq_dev, jnp.float32(super_gate),
-                            **statics)
-                    else:
-                        packed = S.search_fused_ivf_tiered_read(
-                            st, q8, scale, cold_dev, cent, members, extras,
-                            *args, jnp.float32(super_gate), **statics)
-                elif tiered:
-                    q8, scale = self._int8_shadow_for(st)
-                    cold_dev = tm.cold_mask_dev()
-                    if ragged:
-                        packed = S.search_fused_tiered_ragged_read(
-                            st, q8, scale, cold_dev, *args, k_dev,
-                            jnp.float32(super_gate), **statics)
-                    else:
-                        packed = S.search_fused_tiered_read(
-                            st, q8, scale, cold_dev, *args,
-                            jnp.float32(super_gate), **statics)
-                elif ivf_tabs is not None:
-                    cent, members, extras, _ = ivf_tabs
-                    shadow = self._int8_shadow_for(st) if use_quant else None
-                    if ragged:
-                        packed = S.search_fused_ivf_ragged_read(
-                            st, shadow, cent, members, extras, *args, k_dev,
-                            npq_dev, jnp.float32(super_gate), **statics)
-                    else:
-                        packed = S.search_fused_ivf_read(
-                            st, shadow, cent, members, extras, *args,
-                            jnp.float32(super_gate), **statics)
-                elif use_quant:
-                    q8, scale = self._int8_shadow_for(st)
-                    if ragged:
-                        packed = S.search_fused_quant_ragged_read(
-                            st, q8, scale, *args, k_dev,
-                            jnp.float32(super_gate), **statics)
-                    else:
-                        packed = S.search_fused_quant_read(
-                            st, q8, scale, *args, jnp.float32(super_gate),
-                            **statics)
                 else:
-                    if ragged:
-                        packed = S.search_fused_ragged_read(
-                            st, *args, k_dev, jnp.float32(super_gate),
-                            **statics)
-                    else:
-                        packed = S.search_fused_read(st, *args,
-                                                     jnp.float32(super_gate),
-                                                     **statics)
-                if sem_kw and not boost_on.any():
-                    sem_ring2, packed = packed
+                    packed = read(st, *self._serve_operands(route, st),
+                                  *args, *read_cols,
+                                  jnp.float32(super_gate), **statics)
+                    if sem_kw:
+                        sem_ring2, packed = packed
             with tel.span("dispatch.readback"):
                 host = np.asarray(packed)          # the ONE readback
         tel.bump("serve.dispatches", labels={"mode": mode})
@@ -3592,8 +3407,8 @@ class MemoryIndex:
                     self, tm, reqs, results, valid, boost_on, q, tenants,
                     host, k_bucket=k_bucket, cap_take=statics["cap_take"],
                     max_nbr=max_nbr, acc_boost=acc_boost,
-                    nbr_boost=nbr_boost, now_rel=now_rel, ragged=ragged,
-                    cap_arr=(cap_arr if ragged else None), tel=tel)
+                    nbr_boost=nbr_boost, now_rel=now_rel, cap_arr=cap_arr,
+                    tel=tel)
                 k_unpack = (host.shape[1] - 8) // 2
                 g_s, g_r, a_s, a_r, fast_np, counters = unpack_retrieval(
                     host[:nq], k_unpack)
@@ -3611,8 +3426,7 @@ class MemoryIndex:
                 host[:nq], k_bucket)
             out = self._demux_fused(reqs, results, valid, boost_on, gate_s,
                                     gate_r, ann_s, ann_r, fast, cap,
-                                    lengths=(counters[:, 0] if ragged
-                                             else None))
+                                    counters[:, 0])
             if sem_kw:
                 semh.note_readback(sem_ring2, counters[:, 4], valid[:nq],
                                    tenants[:nq], gate_s, gate_r, ann_s,
@@ -3637,16 +3451,12 @@ class MemoryIndex:
         self.telemetry.bump("serve.select", labels={
             "core": "blocked" if blocked else "whole_pool"})
 
-    def _note_serve_kernel(self, mode: str, statics: dict,
-                           ragged: bool) -> None:
+    def _note_serve_kernel(self, mode: str, statics: dict) -> None:
         """Track the distinct fused serving-kernel keys this index has
-        dispatched — with ragged serving exactly ONE per mode (the k/cap/
-        nprobe ceilings are fixed), without it one per (mode × k-bucket).
-        The bench's ``compile_cache_entries`` measurement and the CI gate
-        (``check_dispatch_counts.py``: ragged artifacts must record a
-        count ≤ the mode count) read the gauge this maintains."""
-        key = (mode, "ragged" if ragged else "classic",
-               tuple(sorted(statics.items())))
+        dispatched — exactly ONE per mode while the k/cap/nprobe ceilings
+        stand. The ``kernel.cache_entries{surface="single_fused"}`` gauge
+        this maintains is what a compile-cache measurement reads."""
+        key = (mode, tuple(sorted(statics.items())))
         if key in self._serve_kernel_keys:
             return
         with self._serve_shared_lock:
@@ -3681,24 +3491,12 @@ class MemoryIndex:
         if not self.id_to_row:
             return out
         tel = self.telemetry
-        cap = self.state.capacity
-        if self.mesh is not None:
-            mode = "sharded_quant" if self.int8_serving else "sharded_exact"
-        else:
-            k_kernel = (int(min(max(self.serve_k_max, cap_take, 1), cap))
-                        if self.serve_ragged else
-                        min(max(next_pow2(max(cap_take,
-                                              int(k or cap_take))), 1), cap))
-            mode = ("pq" if self._pq_fused_pack(k_kernel) is not None
-                    else "ivf" if self._ivf_fused_pack(k_kernel) is not None
-                    else "quant" if self.int8_serving else "exact")
+        mode = self._serve_route(cap_take).mode
         # the warmup tenant matches no arena row (never allocated to one)
         self._tenants.setdefault("~warmup", -2)
         kk = int(k if k is not None else self.serve_k_max)
-        buckets = sorted({
-            (bucket_size(g, self.serve_pad_granularity)
-             if self.serve_ragged else next_pow2(g))
-            for g in geometries if g > 0})
+        buckets = sorted({bucket_size(g, self.serve_pad_granularity)
+                          for g in geometries if g > 0})
         kw = dict(cap_take=cap_take, max_nbr=max_nbr, super_gate=super_gate,
                   acc_boost=acc_boost, nbr_boost=nbr_boost)
         for g in buckets:
@@ -3742,10 +3540,8 @@ class MemoryIndex:
             self._hbm_recorded.add(key)
             return True
 
-    def _maybe_record_hbm(self, mode: str, st, args, statics, super_gate,
-                          ivf_tabs, use_quant, ragged: bool = False,
-                          k_dev=None, npq_dev=None,
-                          tier_pack=None, pq_tabs=None) -> None:
+    def _maybe_record_hbm(self, route: _ServeRoute, st, read, args,
+                          read_cols, super_gate, statics) -> None:
         """Record the ``memory_analysis()`` peak-HBM gauge for one fused
         serving geometry, once per (mode × k-bucket × cap/nbr) key —
         "Memory Safe Computations with XLA": compiled-program introspection
@@ -3755,82 +3551,14 @@ class MemoryIndex:
         read twin is an extra compile (never an extra dispatch)."""
         if not self.telemetry_hbm or not self.telemetry.enabled:
             return    # never consume the once-key while warmup mutes the registry
-        key = (mode, ragged) + tuple(sorted(statics.items()))
+        mode = route.mode
+        key = (mode,) + tuple(sorted(statics.items()))
         if not self._hbm_once(key):
             return
         try:
-            if pq_tabs is not None and tier_pack is not None:
-                cold_dev = tier_pack[-1]
-                cent, members, extras, _, book_cent, codes = pq_tabs
-                if ragged:
-                    lowered = S.search_fused_pq_tiered_ragged_read.lower(
-                        st, book_cent, codes, cold_dev, cent, members,
-                        extras, *args, k_dev, npq_dev,
-                        jnp.float32(super_gate), **statics)
-                else:
-                    lowered = S.search_fused_pq_tiered_read.lower(
-                        st, book_cent, codes, cold_dev, cent, members,
-                        extras, *args, jnp.float32(super_gate), **statics)
-            elif pq_tabs is not None:
-                cent, members, extras, _, book_cent, codes = pq_tabs
-                if ragged:
-                    lowered = S.search_fused_pq_ragged_read.lower(
-                        st, book_cent, codes, cent, members, extras,
-                        *args, k_dev, npq_dev, jnp.float32(super_gate),
-                        **statics)
-                else:
-                    lowered = S.search_fused_pq_read.lower(
-                        st, book_cent, codes, cent, members, extras,
-                        *args, jnp.float32(super_gate), **statics)
-            elif tier_pack is not None and ivf_tabs is not None:
-                q8, scale, cold_dev = tier_pack
-                cent, members, extras, _ = ivf_tabs
-                if ragged:
-                    lowered = S.search_fused_ivf_tiered_ragged_read.lower(
-                        st, q8, scale, cold_dev, cent, members, extras,
-                        *args, k_dev, npq_dev, jnp.float32(super_gate),
-                        **statics)
-                else:
-                    lowered = S.search_fused_ivf_tiered_read.lower(
-                        st, q8, scale, cold_dev, cent, members, extras,
-                        *args, jnp.float32(super_gate), **statics)
-            elif tier_pack is not None:
-                q8, scale, cold_dev = tier_pack
-                if ragged:
-                    lowered = S.search_fused_tiered_ragged_read.lower(
-                        st, q8, scale, cold_dev, *args, k_dev,
-                        jnp.float32(super_gate), **statics)
-                else:
-                    lowered = S.search_fused_tiered_read.lower(
-                        st, q8, scale, cold_dev, *args,
-                        jnp.float32(super_gate), **statics)
-            elif ivf_tabs is not None:
-                cent, members, extras, _ = ivf_tabs
-                shadow = self._int8_shadow_for(st) if use_quant else None
-                if ragged:
-                    lowered = S.search_fused_ivf_ragged_read.lower(
-                        st, shadow, cent, members, extras, *args, k_dev,
-                        npq_dev, jnp.float32(super_gate), **statics)
-                else:
-                    lowered = S.search_fused_ivf_read.lower(
-                        st, shadow, cent, members, extras, *args,
-                        jnp.float32(super_gate), **statics)
-            elif use_quant:
-                q8, scale = self._int8_shadow_for(st)
-                if ragged:
-                    lowered = S.search_fused_quant_ragged_read.lower(
-                        st, q8, scale, *args, k_dev,
-                        jnp.float32(super_gate), **statics)
-                else:
-                    lowered = S.search_fused_quant_read.lower(
-                        st, q8, scale, *args, jnp.float32(super_gate),
-                        **statics)
-            elif ragged:
-                lowered = S.search_fused_ragged_read.lower(
-                    st, *args, k_dev, jnp.float32(super_gate), **statics)
-            else:
-                lowered = S.search_fused_read.lower(
-                    st, *args, jnp.float32(super_gate), **statics)
+            lowered = read.lower(st, *self._serve_operands(route, st), *args,
+                                 *read_cols, jnp.float32(super_gate),
+                                 **statics)
             peak = peak_bytes(lowered.compile().memory_analysis())
         except Exception:   # noqa: BLE001 — observability must never serve 500s
             return
@@ -3841,7 +3569,7 @@ class MemoryIndex:
                       "batch": str(int(args[2].shape[0])),
                       "mesh": (f"{self._n_parts}x{self.shard_axis}"
                                if self.mesh is not None else "1")}
-            if pq_tabs is not None:
+            if mode.startswith("pq"):
                 # the serve-path gauge check_hbm_budget.py's pq=true
                 # sweep reads (ISSUE 16 satellite); slack sizes the
                 # exact-rescore shortlist the cost model must over-bound
@@ -3877,11 +3605,11 @@ class MemoryIndex:
                 peak)
 
     def _demux_fused(self, reqs, results, valid, boost_on, gate_s, gate_r,
-                     ann_s, ann_r, fast, cap, lengths=None):
+                     ann_s, ann_r, fast, cap, lengths):
         """Per-request demux of the unpacked fused readback — shared by the
         single-chip and the pod-sharded dispatch. ``lengths`` is the
-        ragged decode bound: the readback's per-query live-length counter,
-        so a k=4 request in a K-ceiling batch decodes 4 columns, not K."""
+        decode bound: the readback's per-query live-length counter, so a
+        k=4 request in a K-ceiling batch decodes 4 columns, not K."""
         for i, r in enumerate(reqs):
             if not valid[i]:
                 continue
@@ -3889,8 +3617,7 @@ class MemoryIndex:
             ids, scores = decode_topk(ann_s[i:i + 1], ann_r[i:i + 1],
                                       self.row_to_id, S.NEG_INF,
                                       limit=min(int(r.k), cap),
-                                      lengths=(None if lengths is None
-                                               else lengths[i:i + 1]))[0]
+                                      lengths=lengths[i:i + 1])[0]
             res.ids, res.scores = ids, scores
             if gate_s[i] > S.NEG_INF / 2:
                 res.gate_id = self.row_to_id.get(int(gate_r[i]))
@@ -3901,12 +3628,10 @@ class MemoryIndex:
 
     def _fused_sharded_kernels(self, mode: str, k_bucket: int,
                                cap_take: int, max_nbr: int,
-                               ragged: bool = False, sem: bool = False):
-        # Ragged kernels collapse to per-mode keys — k_bucket IS the
-        # static ceiling then, identical for every batch — so a mixed-k
-        # request stream compiles one distributed program per mode.
-        key = ((mode, "ragged", k_bucket, cap_take, max_nbr) if ragged
-               else (mode, k_bucket, cap_take, max_nbr))
+                               sem: bool = False):
+        # k_bucket IS the static ceiling, identical for every batch, so a
+        # mixed-k request stream compiles one distributed program per mode.
+        key = (mode, k_bucket, cap_take, max_nbr)
         if sem:
             key = key + ("sem",)
         with self._serve_shared_lock:   # the LRU reorders on every get
@@ -3915,8 +3640,7 @@ class MemoryIndex:
                 kern = S.make_fused_sharded(
                     self.mesh, self.shard_axis, k=k_bucket,
                     cap_take=min(cap_take, k_bucket), max_nbr=max_nbr,
-                    mode=mode, slack=self.coarse_slack, ragged=ragged,
-                    sem=sem)
+                    mode=mode, slack=self.coarse_slack, sem=sem)
                 self._fused_sharded_cache.put(key, kern)
                 self.telemetry.gauge("kernel.cache_entries",
                                      len(self._fused_sharded_cache),
@@ -3934,42 +3658,35 @@ class MemoryIndex:
     def _stage_fused_sharded(self, st, indptr, nbr, qp, padb, valid,
                              tenants, gate_on, boost_on, k_bucket, cap_take,
                              max_nbr, super_gate, acc_boost, nbr_boost,
-                             now, *, ragged=False, k_arr=None, cap_arr=None,
-                             tiered=False, sem=None) -> _StagedSharded:
+                             now, mode, k_arr, cap_arr, *,
+                             sem=None) -> _StagedSharded:
         """Everything the pod serving dispatch (ISSUE 5) needs before its
         launch, made under ``lz.index.stage`` as on one chip: the compiled
         program for the batch's geometry and every host→device put.
         ``indptr``/``nbr`` are the PER-SHARD CSR slices ``_csr_for`` builds
-        under a mesh. ``ragged=True`` threads the per-query (k, cap)
-        sidecars into the ragged distributed program — ``k_bucket`` is then
-        the static ceiling and the kernel cache key is per-mode.
+        under a mesh; ``mode`` is the route's family (``exact`` / ``quant``
+        / ``tiered``), ``k_bucket`` the static ceiling; the per-query
+        (k, cap) columns ``k_arr``/``cap_arr`` ride as device data.
         ``boost_extra`` is None for a batch that asked for no boost: it
         takes the read twin."""
-        mode = ("tiered" if tiered
-                else "quant" if self.int8_serving else "exact")
+        tiered = mode == "tiered"
         kern = self._fused_sharded_kernels(mode, k_bucket, cap_take,
-                                           max_nbr, ragged=ragged,
-                                           sem=sem is not None)
+                                           max_nbr, sem=sem is not None)
         sem_tail = () if sem is None else (sem,)
         sargs = (indptr, nbr, jnp.asarray(qp), jnp.asarray(padb(valid)),
                  jnp.asarray(padb(tenants, -1, np.int32)),
                  jnp.asarray(padb(gate_on)))
         boosting = bool(boost_on.any())
         boost_extra = (jnp.asarray(padb(boost_on)),) if boosting else None
-        if ragged:
-            cap_s = min(cap_take, k_bucket)
-            k_dev = jnp.asarray(padb(np.minimum(k_arr, k_bucket), 0,
-                                     np.int32))
-            # dense modes share the ragged ABI; nprobe_q is inert here
-            npq_dev = jnp.asarray(np.zeros((qp.shape[0],), np.int32))
-            read_extra = (k_dev, npq_dev, jnp.float32(super_gate))
-            if boosting:
-                capq_dev = jnp.asarray(padb(np.minimum(cap_arr, cap_s), 0,
-                                            np.int32))
-                boost_extra += (k_dev, capq_dev, npq_dev)
-        else:
-            read_extra = (jnp.float32(super_gate),)
+        k_dev = jnp.asarray(padb(np.minimum(k_arr, k_bucket), 0, np.int32))
+        # the dense modes share the coarse modes' ABI; nprobe_q is inert here
+        npq_dev = jnp.asarray(np.zeros((qp.shape[0],), np.int32))
+        read_extra = (k_dev, npq_dev, jnp.float32(super_gate))
         if boosting:
+            cap_s = min(cap_take, k_bucket)
+            capq_dev = jnp.asarray(padb(np.minimum(cap_arr, cap_s), 0,
+                                        np.int32))
+            boost_extra += (k_dev, capq_dev, npq_dev)
             now_rel = (now if now is not None else time.time()) - self.epoch
             boost_extra += (jnp.float32(now_rel), jnp.float32(super_gate),
                             jnp.float32(acc_boost), jnp.float32(nbr_boost))
@@ -3981,7 +3698,7 @@ class MemoryIndex:
             * (k_bucket + (self.coarse_slack if tiered else 0)),
             labels={"mode": "sharded_" + mode})
         if self.telemetry_hbm and self.telemetry.enabled:
-            hkey = ("sharded", mode, ragged, k_bucket, cap_take, max_nbr)
+            hkey = ("sharded", mode, k_bucket, cap_take, max_nbr)
             if self._hbm_once(hkey):
                 try:
                     peak = peak_bytes(kern.read.lower(

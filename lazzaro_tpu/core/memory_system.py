@@ -198,7 +198,6 @@ class MemorySystem:
                                  coarse_slack=cfg.coarse_fetch_slack,
                                  telemetry=self.telemetry,
                                  telemetry_hbm=cfg.serve_telemetry_hbm,
-                                 serve_ragged=cfg.serve_ragged,
                                  serve_k_max=cfg.serve_k_max,
                                  serve_pad_granularity=cfg.serve_pad_granularity,
                                  serve_kernel_cache_max=cfg.serve_kernel_cache_max,
@@ -1083,9 +1082,9 @@ class MemorySystem:
     def _use_fused_serving(self) -> bool:
         """Fused retrieval serves every arena mode — exact by default,
         through the quantized two-stage kernel (int8 coarse scan + exact
-        rescore, ``state.search_fused_quant``) when the int8 serving shadow
-        is on, and through the IVF coarse stage (centroid prefilter +
-        member gather INSIDE the dispatch, ``state.search_fused_ivf``)
+        rescore, ``state.search_fused_quant_ragged``) when the int8 serving
+        shadow is on, and through the IVF coarse stage (centroid prefilter +
+        member gather INSIDE the dispatch, ``state.search_fused_ivf_ragged``)
         once a build is published — so quantized AND IVF modes keep the
         one-dispatch turn, cross-request mega-batching, and zero-RTT cache
         hits (``MemoryIndex.search_fused_requests`` owns the routing; an
@@ -1096,7 +1095,7 @@ class MemorySystem:
         shard-local boost scatters — so the pod path keeps the gate /
         neighbor / boost semantics and the one-distributed-dispatch turn
         too. PQ member storage joined the fused path last (ISSUE 16,
-        ``state.search_fused_pq``: in-kernel ADC table build + m-byte
+        ``state.search_fused_pq_ragged``: in-kernel ADC table build + m-byte
         member scan + exact shortlist rescore), so every serving mode now
         keeps the one-dispatch contract — ``serve_fused`` alone decides."""
         return self.config.serve_fused
@@ -1116,9 +1115,7 @@ class MemorySystem:
                 sched = QueryScheduler(
                     self._serve_requests,
                     max_batch=self.config.serve_batch_max,
-                    max_wait_us=self.config.serve_flush_us,
                     telemetry=self.telemetry,
-                    continuous=self.config.serve_continuous,
                     tenant_max_inflight=self.config.serve_tenant_max_inflight,
                     dispatch_timeout_s=self.config.serve_dispatch_timeout_s,
                     breaker_threshold=self.config.serve_breaker_threshold,
@@ -1142,12 +1139,10 @@ class MemorySystem:
         if planner is None or not planner.active \
                 or not self.index.id_to_row:
             return
-        mode, k_bucket = self.index._serve_mode_hint(
-            self.config.retrieval_cap, reqs)
+        route = self.index._serve_route(self.config.retrieval_cap)
         planner.check_feasible(
-            self.index._serve_geometry(1, mode, k_bucket),
-            chunkable=(self.index.serve_ragged
-                       and self.index.mesh is None))
+            self.index._serve_geometry(1, route.mode, route.k_bucket),
+            chunkable=self.index.mesh is None)
 
     def _reads_may_overlap(self, reqs) -> bool:
         """Scheduler overlap predicate: the index that executes the batch
@@ -2825,7 +2820,6 @@ Example: {"preferences": "User prefers Python for data science.", "knowledge_dom
                                         pq_serving=self.config.pq_serving,
                                         coarse_slack=self.config.coarse_fetch_slack,
                                         telemetry=self.telemetry,
-                                        serve_ragged=self.config.serve_ragged,
                                         serve_k_max=self.config.serve_k_max,
                                         serve_pad_granularity=self.config.serve_pad_granularity,
                                         serve_kernel_cache_max=self.config.serve_kernel_cache_max)

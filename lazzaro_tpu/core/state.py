@@ -2259,6 +2259,26 @@ def make_ingest_fused_sharded(mesh, axis: str, *, k: int,
 # main-arena ANN + CSR neighbor gather + neighbor/access boosts — in ONE
 # donated device program with ONE packed readback (the serving-side analog
 # of ingest_fused; see ISSUE 2).
+#
+# Request shapes are DEVICE DATA: per-query k / retrieval cap / probe width
+# ride next to the query batch as int32 columns (``k_q`` / ``cap_q`` /
+# ``nprobe_q``), and the static kernel constants are per-mode CEILINGS
+# (``k`` = serve_k_max, ``cap_take`` = the config cap, ``nprobe`` = the
+# build's probe width). The scan bodies compute to the ceiling and each
+# query masks at its own top-k boundary (``_ragged_topk_mask``), its own
+# retrieval cap (``_gate_and_boost_rows`` cap_c) and its own probe width
+# (``_ivf_two_tier`` nprobe_c), so ONE compiled kernel per (mode ×
+# geometry) serves any mix of request shapes — a k=100 request neither
+# re-keys the batch's kernel nor inflates its neighbors' top-k beyond
+# masked compute. The packed readback's n_live counter is the per-query
+# live LENGTH: decode reads exactly k_i live entries per request out of
+# the K-wide rows.
+#
+# Each of the seven families (exact, quant, tiered, ivf, ivf_tiered, pq,
+# pq_tiered) has three entry points: ``search_fused_*_ragged`` donates the
+# arena, ``*_ragged_copy`` is its non-donating twin for callers that cannot
+# prove sole ownership, and ``*_ragged_read`` serves batches where no query
+# wants boosts — same compute, no state mutation, no donation dance.
 # ---------------------------------------------------------------------------
 
 
@@ -2291,9 +2311,9 @@ def _csr_neighbor_rows(state: ArenaState, csr_indptr: jax.Array,
 
 def _ragged_topk_mask(ann_s: jax.Array, ann_r: jax.Array, k_c: jax.Array,
                       sentinel: int):
-    """Per-query top-k boundary mask — the core ragged-serving move
-    (ISSUE 7): the scan computed top-``K`` to the batch CEILING (a static
-    kernel constant), and each query's own ``k`` arrives as DEVICE data
+    """Per-query top-k boundary mask: the scan computed top-``K`` to the
+    batch CEILING (a static kernel constant), and each query's own ``k``
+    arrives as DEVICE data
     (``k_c`` [C] i32). Positions at or past a query's k are routed to
     (NEG_INF, sentinel), so decode, the live-length counter, and the boost
     tail all see exactly the per-request result — one compiled kernel per
@@ -2342,8 +2362,8 @@ def _exact_two_tier(state: ArenaState, q_c: jax.Array, tenant_c: jax.Array,
     the per-query mask, the gate's running top-1 and the main tier's running
     top-k stay on chip, so no ``[C, rows]`` score tile exists and the
     selection work follows each query's own k — ``k_c`` ([C] i32 device
-    data; None for the static callers, which run to ``k_ann``) — not the
-    static ceiling ``k_ann`` the shapes are compiled to. Slots past a
+    data; None runs every query to ``k_ann``) — not the static ceiling
+    ``k_ann`` the shapes are compiled to. Slots past a
     query's k, or past its tenant's live rows, hold ``(NEG_INF, capacity)``
     (what ``_ragged_topk_mask`` writes). The shard-local core of the exact
     fused scan: single-chip callers pass the whole arena, the sharded
@@ -2389,7 +2409,7 @@ def _exact_two_tier(state: ArenaState, q_c: jax.Array, tenant_c: jax.Array,
 #               execute, so an 80%-hit batch pays ~20% of the scan FLOPs
 #               while shapes stay static and the dispatch count stays ONE.
 #   subst     — hit queries' gate/ann columns come from the cached entry
-#               (re-masked at the query's own ragged k; the gate VERDICT is
+#               (re-masked at the query's own k; the gate VERDICT is
 #               recomputed against the current threshold); their boost rows
 #               stay at the scatter sentinel — semantic hits defer boosts to
 #               the host exactly like exact-cache hits.
@@ -2481,7 +2501,7 @@ def _semantic_substitute(ring: SemanticRing, hit: jax.Array, slot: jax.Array,
                          k_q, rag_slack: int, capacity: int):
     """Splice cached results over the hit queries' (filler) scan outputs.
     The cached list is sliced to this kernel's static window and re-masked
-    at the query's own ragged k (+slack for the tiered window); the gate
+    at the query's own k (+slack for the tiered window); the gate
     verdict is recomputed against the CURRENT threshold so a runtime
     super-gate change can't serve a stale verdict."""
     gate_s, gate_r, ann_s, ann_r, fast = outs[:5]
@@ -2494,9 +2514,8 @@ def _semantic_substitute(ring: SemanticRing, hit: jax.Array, slot: jax.Array,
     c_gr = ring.gate_r[slot]
     c_as = ring.ann_s[slot, :w]
     c_ar = ring.ann_r[slot, :w]
-    if k_q is not None:
-        kf = jnp.minimum(k_q + rag_slack, w) if rag_slack else k_q
-        c_as, c_ar = _ragged_topk_mask(c_as, c_ar, kf, capacity)
+    kf = jnp.minimum(k_q + rag_slack, w) if rag_slack else k_q
+    c_as, c_ar = _ragged_topk_mask(c_as, c_ar, kf, capacity)
     c_fast = gate_on_q & (c_gs > super_gate)
     h1 = hit[:, None]
     return (jnp.where(hit, c_gs, gate_s),
@@ -2543,26 +2562,25 @@ def _semantic_writeback(ring: SemanticRing, head: jax.Array, qn: jax.Array,
 
 
 def _semantic_scan_core(chunk_fn, arrays, state: ArenaState, sem,
-                        super_gate: jax.Array, *, k: int, block: int,
-                        rag_slack: int = 0, nprobe_val: int = 0):
+                        super_gate: jax.Array, *, block: int,
+                        rag_slack: int = 0):
     """The full in-dispatch semantic-cache flow around one family's chunk
     closure: probe → miss-first stable sort → blocked early-out scan →
     unsort → substitution → ring writeback. ``arrays`` is the family's
-    per-query tuple ``(q, q_valid, tenant, gate_on, boost_on[, k_q,
-    cap_q[, nprobe_q]])``; returns the family's output tuple (dup counter
+    per-query tuple ``(q, q_valid, tenant, gate_on, boost_on, k_q, cap_q[,
+    nprobe_q])`` (the dense families carry no probe width: their entries
+    store 0); returns the family's output tuple (dup counter
     zeroed for skipped queries) + ``(sem_col, new_ring)`` where sem_col
     is ``1 + slot`` for hits and 0 for misses."""
     ring, sem_valid, head, thresh, mode_id = sem
     q, q_valid, tenant, gate_on = arrays[0], arrays[1], arrays[2], arrays[3]
     nq = q.shape[0]
-    k_q = arrays[5] if len(arrays) > 5 else None
-    npr_q = arrays[7] if len(arrays) > 7 else None
+    k_q = arrays[5]
+    npr_need = (arrays[7] if len(arrays) > 7
+                else jnp.zeros((nq,), jnp.int32))
     qn = normalize(q).astype(jnp.float32)
-    k_need = k_q if k_q is not None else jnp.full((nq,), k, jnp.int32)
-    npr_need = (npr_q if npr_q is not None
-                else jnp.full((nq,), nprobe_val, jnp.int32))
     hit, slot = _semantic_probe(ring, sem_valid, qn, tenant, q_valid,
-                                gate_on, k_need, npr_need, mode_id, thresh)
+                                gate_on, k_q, npr_need, mode_id, thresh)
     miss = q_valid & ~hit
     order = jnp.argsort((~miss).astype(jnp.int32), stable=True)
     inv = jnp.argsort(order)
@@ -2575,7 +2593,7 @@ def _semantic_scan_core(chunk_fn, arrays, state: ArenaState, sem,
     ring2 = _semantic_writeback(
         ring, head, qn[order], sorted_arrays[2], sorted_arrays[3],
         outs_s[0], outs_s[1], outs_s[2], outs_s[3], rank, write_mask,
-        k_need[order], npr_need[order], mode_id, state.capacity)
+        k_q[order], npr_need[order], mode_id, state.capacity)
     outs = tuple(o[inv] for o in outs_s)
     outs = _semantic_substitute(ring, hit, slot, gate_on, super_gate, outs,
                                 k_q, rag_slack, state.capacity)
@@ -2592,19 +2610,19 @@ def _search_fused_scan(state: ArenaState, csr_indptr: jax.Array,
                        tenant: jax.Array, gate_on: jax.Array,
                        boost_on: jax.Array, super_gate: jax.Array,
                        k: int, cap_take: int, max_nbr: int,
-                       k_q=None, cap_q=None, scan_chunk: int = 0,
-                       sem=None, sem_block: int = 16):
+                       k_q: jax.Array, cap_q: jax.Array,
+                       scan_chunk: int = 0, sem=None, sem_block: int = 16):
     """Per-chunk compute phase: the exact two-tier top-k core, the
     device-side gate verdict, and the CSR neighbor gather with per-query
     dedup. Returns sentinel-padded row lists for the scatter phase
     (``capacity`` is the sentinel row index).
 
-    With ``k_q``/``cap_q`` ([Q] i32 device sidecars) the scan is RAGGED:
-    ``k`` and ``cap_take`` become the static ceilings the SHAPES are
-    compiled to, and each query's own k rides into the core as data
-    (``_exact_two_tier``'s ``k_c``): the selection runs to what the batch
-    asks, and slots past a query's k come back as (NEG_INF, capacity) —
-    per-request shapes are data, not trace constants.
+    ``k`` and ``cap_take`` are the static ceilings the SHAPES are compiled
+    to; ``k_q``/``cap_q`` ([Q] i32 device columns) carry each query's own
+    k and cap. The k rides into the core as data (``_exact_two_tier``'s
+    ``k_c``): the selection runs to what the batch asks, and slots past a
+    query's k come back as (NEG_INF, capacity) — per-request shapes are
+    data, not trace constants.
 
     ``scan_chunk > 0`` (ISSUE 11) overrides the default ``QUERY_CHUNK``
     streaming width. In this family it no longer bounds a ``[chunk, rows]``
@@ -2613,10 +2631,7 @@ def _search_fused_scan(state: ArenaState, csr_indptr: jax.Array,
     a time), so a throttled budget gains little from it here; results stay
     bit-identical (the per-query computation never sees the chunk
     boundary)."""
-    ragged = k_q is not None
-
-    def chunk(q_c, valid_c, tenant_c, gate_c, boost_c, *rag):
-        k_c, cap_c = rag if ragged else (None, None)
+    def chunk(q_c, valid_c, tenant_c, gate_c, boost_c, k_c, cap_c):
         gate_s, gate_r, ann_s, ann_r = _exact_two_tier(state, q_c, tenant_c,
                                                        k, k_c)
         fast, acc_rows, nbr_rows = _gate_and_boost_rows(
@@ -2625,17 +2640,15 @@ def _search_fused_scan(state: ArenaState, csr_indptr: jax.Array,
             max_nbr, cap_c=cap_c)
         return gate_s, gate_r, ann_s, ann_r, fast, acc_rows, nbr_rows
 
-    arrays = (q, q_valid, tenant, gate_on, boost_on)
-    if ragged:
-        arrays = arrays + (k_q, cap_q)
+    arrays = (q, q_valid, tenant, gate_on, boost_on, k_q, cap_q)
     if sem is None:
         return chunked_map_multi(chunk, arrays,
                                  chunk=(scan_chunk or QUERY_CHUNK))
     return _semantic_scan_core(chunk, arrays, state, sem, super_gate,
-                               k=k, block=sem_block)
+                               block=sem_block)
 
 
-def _search_fused(
+def _search_fused_ragged(
     state: ArenaState,
     csr_indptr: jax.Array,   # [cap+2] i32 neighbor-list offsets per row
     csr_nbr: jax.Array,      # [E_pad] i32 neighbor rows (bidirectional)
@@ -2644,22 +2657,25 @@ def _search_fused(
     tenant: jax.Array,       # [Q] i32 per-query tenant (cross-tenant batch)
     gate_on: jax.Array,      # [Q] bool hierarchy gate enabled
     boost_on: jax.Array,     # [Q] bool apply device boosts for this query
+    k_q: jax.Array,          # [Q] i32 per-query k (0 for pad rows)
+    cap_q: jax.Array,        # [Q] i32 per-query retrieval cap
     now: jax.Array,
     super_gate: jax.Array,
     acc_boost: jax.Array,
     nbr_boost: jax.Array,
-    k: int,
-    cap_take: int,           # retrieval cap: how many top rows get boosted
+    k: int,                  # STATIC k ceiling (serve_k_max)
+    cap_take: int,           # STATIC cap ceiling: top rows that get boosted
     max_nbr: int,
+    scan_chunk: int = 0,     # planner streaming-width override (ISSUE 11)
     sem=None,                # (ring, valid [R], head, thresh, mode_id)
     sem_block: int = 16,
 ) -> Tuple[ArenaState, Tuple[jax.Array, ...]]:
-    """One dispatch for a padded cross-tenant query batch: gate + ANN +
-    neighbor gather + both boosts. Scatter counts make a mega-batch exact
-    w.r.t. serial classic turns: a row retrieved by two queries gets TWO
-    access bumps (``.add``), while within one query each neighbor is
-    boosted once (the per-query dedup above) — matching what per-turn
-    ``update_access`` + ``_boost_neighbors`` calls would have done.
+    """One dispatch for a padded cross-tenant query batch of mixed request
+    shapes: gate + ANN + neighbor gather + both boosts. Scatter counts make
+    a mega-batch exact w.r.t. serial classic turns: a row retrieved by two
+    queries gets TWO access bumps (``.add``), while within one query each
+    neighbor is boosted once (the per-query dedup above) — matching what
+    per-turn ``update_access`` + ``_boost_neighbors`` calls would have done.
 
     ``sem`` threads the semantic query cache through the SAME dispatch
     (probe / early-out / substitution / ring writeback — see
@@ -2667,7 +2683,9 @@ def _search_fused(
     ring: ``(state, ring, packed)``."""
     res = _search_fused_scan(state, csr_indptr, csr_nbr, q, q_valid, tenant,
                              gate_on, boost_on, super_gate, k, cap_take,
-                             max_nbr, sem=sem, sem_block=sem_block)
+                             max_nbr, k_q=k_q, cap_q=cap_q,
+                             scan_chunk=scan_chunk, sem=sem,
+                             sem_block=sem_block)
     return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
 
 
@@ -2790,28 +2808,32 @@ def _sem_finish_read(res, sem):
     return ring2, packed
 
 
-search_fused, search_fused_copy = _donated_pair(
-    _search_fused, static_argnames=("k", "cap_take", "max_nbr",
-                                    "sem_block"))
+search_fused_ragged, search_fused_ragged_copy = _donated_pair(
+    _search_fused_ragged, static_argnames=("k", "cap_take", "max_nbr",
+                                           "scan_chunk", "sem_block"))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "cap_take", "max_nbr",
-                                             "sem_block"))
-def search_fused_read(state: ArenaState, csr_indptr: jax.Array,
-                      csr_nbr: jax.Array, q: jax.Array, q_valid: jax.Array,
-                      tenant: jax.Array, gate_on: jax.Array,
-                      super_gate: jax.Array, k: int, cap_take: int,
-                      max_nbr: int, sem=None,
-                      sem_block: int = 16) -> jax.Array:
-    """Read-only twin of ``search_fused`` for batches where NO query wants
-    boosts (pure ``search_memories`` fleets): same compute, no state
-    mutation, so the ownership/donation dance is skipped entirely. With
-    ``sem`` the semantic ring still rides (misses write back — read
-    fleets warm the cache) and the return becomes ``(ring, packed)``."""
+                                             "scan_chunk", "sem_block"))
+def search_fused_ragged_read(state: ArenaState, csr_indptr: jax.Array,
+                             csr_nbr: jax.Array, q: jax.Array,
+                             q_valid: jax.Array, tenant: jax.Array,
+                             gate_on: jax.Array, k_q: jax.Array,
+                             super_gate: jax.Array, k: int, cap_take: int,
+                             max_nbr: int, scan_chunk: int = 0,
+                             sem=None, sem_block: int = 16) -> jax.Array:
+    """Read-only twin of ``search_fused_ragged`` for batches where NO query
+    wants boosts (pure ``search_memories`` fleets): same compute, per-query
+    k as data, no state mutation, so the ownership/donation dance is
+    skipped entirely. With ``sem`` the semantic ring still rides (misses
+    write back — read fleets warm the cache) and the return becomes
+    ``(ring, packed)``."""
     boost_off = jnp.zeros(q_valid.shape, bool)
+    cap_q = jnp.zeros(q_valid.shape, jnp.int32)
     res = _search_fused_scan(
         state, csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_off,
-        super_gate, k, cap_take, max_nbr, sem=sem, sem_block=sem_block)
+        super_gate, k, cap_take, max_nbr, k_q=k_q, cap_q=cap_q,
+        scan_chunk=scan_chunk, sem=sem, sem_block=sem_block)
     return _sem_finish_read(res, sem)
 
 
@@ -2893,42 +2915,35 @@ def _search_fused_quant_scan(state: ArenaState, q8a: jax.Array,
                              gate_on: jax.Array, boost_on: jax.Array,
                              super_gate: jax.Array, k: int, slack: int,
                              cap_take: int, max_nbr: int,
-                             k_q=None, cap_q=None, scan_chunk: int = 0,
+                             k_q: jax.Array, cap_q: jax.Array,
+                             scan_chunk: int = 0,
                              sem=None, sem_block: int = 16):
     """Quantized per-chunk compute phase: the int8 coarse-scan + exact
-    rescore core, then the shared gate/CSR/boost tail. ``k_q``/``cap_q``
-    make it ragged (see ``_search_fused_scan``): the coarse fetch and the
-    exact rescore run to the static ceiling, the boundary mask is
-    per-query data. ``scan_chunk`` is the planner's streaming-width
-    override (ISSUE 11; bit-identical, smaller score tile)."""
-    ragged = k_q is not None
-
-    def chunk(q_c, valid_c, tenant_c, gate_c, boost_c, *rag):
+    rescore core, then the shared gate/CSR/boost tail. The coarse fetch
+    and the exact rescore run to the static ceiling ``k``; the boundary
+    mask is per-query data (``k_q``/``cap_q``, see ``_search_fused_scan``).
+    ``scan_chunk`` is the planner's streaming-width override (ISSUE 11;
+    bit-identical, smaller score tile)."""
+    def chunk(q_c, valid_c, tenant_c, gate_c, boost_c, k_c, cap_c):
         g_s, g_r, ann_s, ann_r = _quant_two_tier(state, q8a, scale_a, q_c,
                                                  tenant_c, k, slack)
         gate_s, gate_r = g_s[:, 0], g_r[:, 0]
-        cap_c = None
-        if ragged:
-            k_c, cap_c = rag
-            ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, k_c,
-                                             state.capacity)
+        ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, k_c, state.capacity)
         fast, acc_rows, nbr_rows = _gate_and_boost_rows(
             state, csr_indptr, csr_nbr, gate_s, gate_r, ann_s, ann_r,
             valid_c, tenant_c, gate_c, boost_c, super_gate, cap_take,
             max_nbr, cap_c=cap_c)
         return gate_s, gate_r, ann_s, ann_r, fast, acc_rows, nbr_rows
 
-    arrays = (q, q_valid, tenant, gate_on, boost_on)
-    if ragged:
-        arrays = arrays + (k_q, cap_q)
+    arrays = (q, q_valid, tenant, gate_on, boost_on, k_q, cap_q)
     if sem is None:
         return chunked_map_multi(chunk, arrays,
                                  chunk=(scan_chunk or QUERY_CHUNK))
     return _semantic_scan_core(chunk, arrays, state, sem, super_gate,
-                               k=k, block=sem_block)
+                               block=sem_block)
 
 
-def _search_fused_quant(
+def _search_fused_quant_ragged(
     state: ArenaState,
     q8a: jax.Array,          # [cap+1, d] i8 serving shadow codes
     scale_a: jax.Array,      # [cap+1] f32 per-row scales
@@ -2939,6 +2954,8 @@ def _search_fused_quant(
     tenant: jax.Array,
     gate_on: jax.Array,
     boost_on: jax.Array,
+    k_q: jax.Array,
+    cap_q: jax.Array,
     now: jax.Array,
     super_gate: jax.Array,
     acc_boost: jax.Array,
@@ -2947,44 +2964,56 @@ def _search_fused_quant(
     slack: int,
     cap_take: int,
     max_nbr: int,
+    scan_chunk: int = 0,
     sem=None,
     sem_block: int = 16,
 ) -> Tuple[ArenaState, jax.Array]:
-    """``search_fused`` with the int8 coarse scan + exact rescore stage:
-    one donated dispatch + one packed readback per coalesced batch, int8
-    mode included. Only the arena state is donated — the shadow is a
-    long-lived read-only replica (boost scatters touch salience/access/
-    freshness, never the embeddings, so the codes stay valid)."""
+    """``search_fused_ragged`` with the int8 coarse scan + exact rescore
+    stage: one donated dispatch + one packed readback per coalesced batch,
+    int8 mode included; the coarse fetch and the rescore run to the k
+    ceiling, the boundary is data. Only the arena state is donated — the
+    shadow is a long-lived read-only replica (boost scatters touch
+    salience/access/freshness, never the embeddings, so the codes stay
+    valid)."""
     res = _search_fused_quant_scan(state, q8a, scale_a, csr_indptr, csr_nbr,
                                    q, q_valid, tenant, gate_on, boost_on,
                                    super_gate, k, slack, cap_take, max_nbr,
-                                   sem=sem, sem_block=sem_block)
+                                   k_q=k_q, cap_q=cap_q,
+                                   scan_chunk=scan_chunk, sem=sem,
+                                   sem_block=sem_block)
     return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
 
 
-search_fused_quant, search_fused_quant_copy = _donated_pair(
-    _search_fused_quant, static_argnames=("k", "slack", "cap_take",
-                                          "max_nbr", "sem_block"))
+search_fused_quant_ragged, search_fused_quant_ragged_copy = _donated_pair(
+    _search_fused_quant_ragged,
+    static_argnames=("k", "slack", "cap_take", "max_nbr", "scan_chunk",
+                     "sem_block"))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "slack", "cap_take",
-                                             "max_nbr", "sem_block"))
-def search_fused_quant_read(state: ArenaState, q8a: jax.Array,
-                            scale_a: jax.Array, csr_indptr: jax.Array,
-                            csr_nbr: jax.Array, q: jax.Array,
-                            q_valid: jax.Array, tenant: jax.Array,
-                            gate_on: jax.Array, super_gate: jax.Array,
-                            k: int, slack: int, cap_take: int,
-                            max_nbr: int, sem=None,
-                            sem_block: int = 16) -> jax.Array:
-    """Read-only twin of ``search_fused_quant`` (pure ``search_memories``
-    fleets in int8 mode): same coarse-scan + exact-rescore compute, no
-    state mutation, no donation dance."""
+                                             "max_nbr", "scan_chunk",
+                                             "sem_block"))
+def search_fused_quant_ragged_read(state: ArenaState, q8a: jax.Array,
+                                   scale_a: jax.Array,
+                                   csr_indptr: jax.Array,
+                                   csr_nbr: jax.Array, q: jax.Array,
+                                   q_valid: jax.Array, tenant: jax.Array,
+                                   gate_on: jax.Array, k_q: jax.Array,
+                                   super_gate: jax.Array, k: int,
+                                   slack: int, cap_take: int,
+                                   max_nbr: int, scan_chunk: int = 0,
+                                   sem=None,
+                                   sem_block: int = 16) -> jax.Array:
+    """Read-only twin of ``search_fused_quant_ragged`` (pure
+    ``search_memories`` fleets in int8 mode): same coarse-scan +
+    exact-rescore compute, no state mutation, no donation dance."""
     boost_off = jnp.zeros(q_valid.shape, bool)
+    cap_q = jnp.zeros(q_valid.shape, jnp.int32)
     res = _search_fused_quant_scan(
         state, q8a, scale_a, csr_indptr, csr_nbr, q, q_valid, tenant,
         gate_on, boost_off, super_gate, k, slack, cap_take, max_nbr,
-        sem=sem, sem_block=sem_block)
+        k_q=k_q, cap_q=cap_q, scan_chunk=scan_chunk, sem=sem,
+        sem_block=sem_block)
     return _sem_finish_read(res, sem)
 
 
@@ -3001,8 +3030,8 @@ def search_fused_quant_read(state: ArenaState, q8a: jax.Array,
 # chip holds d bytes of codes instead of d codes + 2d bytes of bf16 master,
 # the TF-Engram/EdgeRAG shape.
 #
-# Serving: ``search_fused_tiered`` is the quantized fused chat-turn program
-# with a tier-aware rescore — the int8 coarse scan covers the whole corpus,
+# Serving: ``search_fused_tiered_ragged`` is the quantized fused chat-turn
+# program with a tier-aware rescore — the int8 coarse scan covers the whole corpus,
 # HOT survivors rescore exactly from the master in-kernel, COLD survivors
 # keep their coarse score and raise a per-query cold flag (their exact rows
 # live host-side). Hot-only turns therefore stay ONE dispatch + ONE packed
@@ -3169,110 +3198,43 @@ def _search_fused_tiered_scan(state: ArenaState, q8a: jax.Array,
                               tenant: jax.Array, gate_on: jax.Array,
                               boost_on: jax.Array, super_gate: jax.Array,
                               k: int, slack: int, cap_take: int,
-                              max_nbr: int, k_q=None, cap_q=None,
-                              scan_chunk: int = 0,
+                              max_nbr: int, k_q: jax.Array,
+                              cap_q: jax.Array, scan_chunk: int = 0,
                               sem=None, sem_block: int = 16):
     """Tiered per-chunk compute phase: the tier-aware two-stage core, then
     the shared gate/CSR/boost tail with cold-hit queries' boosts DEFERRED
     (suppressed exactly like the gate fast path — the host applies them in
     the bounded ``tier_cold_finish`` dispatch after the exact re-rank, so
-    boost rows always follow the FINAL ranking). ``k_q``/``cap_q`` make it
-    ragged; the per-query boundary masks at k_i + slack so the host keeps
-    each query's full candidate window for the finish."""
-    ragged = k_q is not None
-
-    def chunk(q_c, valid_c, tenant_c, gate_c, boost_c, *rag):
+    boost rows always follow the FINAL ranking). The per-query boundary
+    masks at k_i + slack so the host keeps each query's full candidate
+    window for the finish."""
+    def chunk(q_c, valid_c, tenant_c, gate_c, boost_c, k_c, cap_c):
         g_s, g_r, ann_s, ann_r, cold_any = _tiered_two_tier(
             state, q8a, scale_a, cold, q_c, tenant_c, k, slack)
         gate_s, gate_r = g_s[:, 0], g_r[:, 0]
-        cap_c = None
-        if ragged:
-            k_c, cap_c = rag
-            kf = jnp.minimum(k_c + slack, ann_s.shape[1])
-            ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, kf,
-                                             state.capacity)
+        kf = jnp.minimum(k_c + slack, ann_s.shape[1])
+        ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, kf, state.capacity)
         fast, acc_rows, nbr_rows = _gate_and_boost_rows(
             state, csr_indptr, csr_nbr, gate_s, gate_r, ann_s, ann_r,
             valid_c, tenant_c, gate_c, boost_c & ~cold_any, super_gate,
             cap_take, max_nbr, cap_c=cap_c)
         return gate_s, gate_r, ann_s, ann_r, fast, acc_rows, nbr_rows
 
-    arrays = (q, q_valid, tenant, gate_on, boost_on)
-    if ragged:
-        arrays = arrays + (k_q, cap_q)
+    arrays = (q, q_valid, tenant, gate_on, boost_on, k_q, cap_q)
     if sem is None:
         return chunked_map_multi(chunk, arrays,
                                  chunk=(scan_chunk or QUERY_CHUNK))
-    # the tiered candidate window is k+slack wide and the ragged boundary
+    # the tiered candidate window is k+slack wide and the per-query boundary
     # masks at k_i + slack — the substitution must re-mask the same way
     return _semantic_scan_core(chunk, arrays, state, sem, super_gate,
-                               k=k, block=sem_block, rag_slack=slack)
-
-
-def _search_fused_tiered(
-    state: ArenaState,
-    q8a: jax.Array,          # [cap+1, d] i8 FULL-corpus shadow codes
-    scale_a: jax.Array,      # [cap+1] f32
-    cold: jax.Array,         # [cap+1] bool residency column (True = cold)
-    csr_indptr: jax.Array,
-    csr_nbr: jax.Array,
-    q: jax.Array,
-    q_valid: jax.Array,
-    tenant: jax.Array,
-    gate_on: jax.Array,
-    boost_on: jax.Array,
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
-    k: int,
-    slack: int,
-    cap_take: int,
-    max_nbr: int,
-    sem=None,
-    sem_block: int = 16,
-) -> Tuple[ArenaState, jax.Array]:
-    """``search_fused_quant`` with the residency column threaded through:
-    ONE donated dispatch + ONE packed readback whose candidate block is
-    k+slack wide. Hot-only queries boost in-kernel; cold-hit queries come
-    back unboosted with their candidate window for the finish dispatch."""
-    res = _search_fused_tiered_scan(state, q8a, scale_a, cold, csr_indptr,
-                                    csr_nbr, q, q_valid, tenant, gate_on,
-                                    boost_on, super_gate, k, slack,
-                                    cap_take, max_nbr, sem=sem,
-                                    sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
-
-
-search_fused_tiered, search_fused_tiered_copy = _donated_pair(
-    _search_fused_tiered, static_argnames=("k", "slack", "cap_take",
-                                           "max_nbr", "sem_block"))
-
-
-@functools.partial(jax.jit, static_argnames=("k", "slack", "cap_take",
-                                             "max_nbr", "sem_block"))
-def search_fused_tiered_read(state: ArenaState, q8a: jax.Array,
-                             scale_a: jax.Array, cold: jax.Array,
-                             csr_indptr: jax.Array, csr_nbr: jax.Array,
-                             q: jax.Array, q_valid: jax.Array,
-                             tenant: jax.Array, gate_on: jax.Array,
-                             super_gate: jax.Array, k: int, slack: int,
-                             cap_take: int, max_nbr: int, sem=None,
-                             sem_block: int = 16) -> jax.Array:
-    """Read-only tiered twin (pure ``search_memories`` fleets)."""
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    res = _search_fused_tiered_scan(
-        state, q8a, scale_a, cold, csr_indptr, csr_nbr, q, q_valid, tenant,
-        gate_on, boost_off, super_gate, k, slack, cap_take, max_nbr,
-        sem=sem, sem_block=sem_block)
-    return _sem_finish_read(res, sem)
+                               block=sem_block, rag_slack=slack)
 
 
 def _search_fused_tiered_ragged(
     state: ArenaState,
-    q8a: jax.Array,
-    scale_a: jax.Array,
-    cold: jax.Array,
+    q8a: jax.Array,          # [cap+1, d] i8 FULL-corpus shadow codes
+    scale_a: jax.Array,      # [cap+1] f32
+    cold: jax.Array,         # [cap+1] bool residency column (True = cold)
     csr_indptr: jax.Array,
     csr_nbr: jax.Array,
     q: jax.Array,
@@ -3294,8 +3256,11 @@ def _search_fused_tiered_ragged(
     sem=None,
     sem_block: int = 16,
 ) -> Tuple[ArenaState, jax.Array]:
-    """Tiered serving with the (k, cap) sidecar: each query's candidate
-    window masks at its own k_i + slack boundary."""
+    """``search_fused_quant_ragged`` with the residency column threaded
+    through: ONE donated dispatch + ONE packed readback whose candidate
+    block is k+slack wide, each query's window masked at its own k_i +
+    slack boundary. Hot-only queries boost in-kernel; cold-hit queries come
+    back unboosted with their candidate window for the finish dispatch."""
     res = _search_fused_tiered_scan(state, q8a, scale_a, cold, csr_indptr,
                                     csr_nbr, q, q_valid, tenant, gate_on,
                                     boost_on, super_gate, k, slack,
@@ -3325,6 +3290,7 @@ def search_fused_tiered_ragged_read(state: ArenaState, q8a: jax.Array,
                                     max_nbr: int,
                                     scan_chunk: int = 0, sem=None,
                                     sem_block: int = 16) -> jax.Array:
+    """Read-only tiered twin (pure ``search_memories`` fleets)."""
     boost_off = jnp.zeros(q_valid.shape, bool)
     cap_q = jnp.zeros(q_valid.shape, jnp.int32)
     res = _search_fused_tiered_scan(
@@ -3574,26 +3540,20 @@ def _search_fused_ivf_scan(state: ArenaState, shadow, centroids: jax.Array,
                            tenant: jax.Array, gate_on: jax.Array,
                            boost_on: jax.Array, super_gate: jax.Array,
                            k: int, nprobe: int, slack: int, cap_take: int,
-                           max_nbr: int, k_q=None, cap_q=None,
-                           nprobe_q=None, scan_chunk: int = 0,
+                           max_nbr: int, k_q: jax.Array,
+                           cap_q: jax.Array, nprobe_q: jax.Array,
+                           scan_chunk: int = 0,
                            sem=None, sem_block: int = 16):
     """IVF per-chunk compute phase: the coarse-prefilter two-tier core,
-    then the shared gate/CSR/boost tail. ``k_q``/``cap_q``/``nprobe_q``
-    make it ragged: the gather and candidate scan run to the static
-    ceilings, each query masks at its own k / cap / probe-width boundary
-    (see ``_search_fused_scan`` / ``_ivf_two_tier``)."""
-    ragged = k_q is not None
-
-    def body(q_c, valid_c, tenant_c, gate_c, boost_c, *rag):
-        nprobe_c = rag[2] if ragged else None
+    then the shared gate/CSR/boost tail. The gather and candidate scan run
+    to the static ceilings; each query masks at its own k / cap /
+    probe-width boundary (``k_q``/``cap_q``/``nprobe_q``, see
+    ``_search_fused_scan`` / ``_ivf_two_tier``)."""
+    def body(q_c, valid_c, tenant_c, gate_c, boost_c, k_c, cap_c, nprobe_c):
         gate_s, gate_r, ann_s, ann_r, n_dup = _ivf_two_tier(
             state, shadow, centroids, members, extras, q_c, tenant_c, k,
             nprobe, slack, nprobe_c=nprobe_c)
-        cap_c = None
-        if ragged:
-            k_c, cap_c = rag[0], rag[1]
-            ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, k_c,
-                                             state.capacity)
+        ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, k_c, state.capacity)
         fast, acc_rows, nbr_rows = _gate_and_boost_rows(
             state, csr_indptr, csr_nbr, gate_s, gate_r, ann_s, ann_r,
             valid_c, tenant_c, gate_c, boost_c, super_gate, cap_take,
@@ -3601,232 +3561,21 @@ def _search_fused_ivf_scan(state: ArenaState, shadow, centroids: jax.Array,
         return (gate_s, gate_r, ann_s, ann_r, fast, acc_rows, nbr_rows,
                 n_dup)
 
-    arrays = (q, q_valid, tenant, gate_on, boost_on)
-    if ragged:
-        arrays = arrays + (k_q, cap_q, nprobe_q)
+    arrays = (q, q_valid, tenant, gate_on, boost_on, k_q, cap_q, nprobe_q)
     if sem is None:
         return chunked_map_multi(body, arrays,
                                  chunk=min(scan_chunk or IVF_SERVE_CHUNK,
                                            IVF_SERVE_CHUNK))
     return _semantic_scan_core(body, arrays, state, sem, super_gate,
-                               k=k, block=sem_block, nprobe_val=nprobe)
+                               block=sem_block)
 
 
-def _search_fused_ivf(
+def _search_fused_ivf_ragged(
     state: ArenaState,
     shadow,                  # (q8 [cap+1, d] i8, scale [cap+1] f32) or None
     centroids: jax.Array,    # [C, d] f32 L2-normalized (ops/ivf.py build)
     members: jax.Array,      # [C, M] i32 arena rows, -1 padded
     extras: jax.Array,       # [E] i32 residual + fresh + super rows, -1 pad
-    csr_indptr: jax.Array,
-    csr_nbr: jax.Array,
-    q: jax.Array,
-    q_valid: jax.Array,
-    tenant: jax.Array,
-    gate_on: jax.Array,
-    boost_on: jax.Array,
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
-    k: int,
-    nprobe: int,
-    slack: int,
-    cap_take: int,
-    max_nbr: int,
-    sem=None,
-    sem_block: int = 16,
-) -> Tuple[ArenaState, jax.Array]:
-    """``search_fused`` with the IVF centroid prefilter + member gather as
-    the coarse stage: ONE donated dispatch + ONE packed readback per
-    coalesced batch in IVF mode. Only the arena state is donated — the
-    centroid/member/extras tables and the optional int8 shadow are
-    long-lived read-only replicas (the boost scatter touches salience/
-    access/freshness, never embeddings or routing)."""
-    res = _search_fused_ivf_scan(state, shadow, centroids, members, extras,
-                                 csr_indptr, csr_nbr, q, q_valid, tenant,
-                                 gate_on, boost_on, super_gate, k, nprobe,
-                                 slack, cap_take, max_nbr, sem=sem,
-                                 sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
-
-
-search_fused_ivf, search_fused_ivf_copy = _donated_pair(
-    _search_fused_ivf, static_argnames=("k", "nprobe", "slack", "cap_take",
-                                        "max_nbr", "sem_block"))
-
-
-@functools.partial(jax.jit, static_argnames=("k", "nprobe", "slack",
-                                             "cap_take", "max_nbr",
-                                             "sem_block"))
-def search_fused_ivf_read(state: ArenaState, shadow, centroids: jax.Array,
-                          members: jax.Array, extras: jax.Array,
-                          csr_indptr: jax.Array, csr_nbr: jax.Array,
-                          q: jax.Array, q_valid: jax.Array,
-                          tenant: jax.Array, gate_on: jax.Array,
-                          super_gate: jax.Array, k: int, nprobe: int,
-                          slack: int, cap_take: int, max_nbr: int,
-                          sem=None, sem_block: int = 16
-                          ) -> jax.Array:
-    """Read-only twin of ``search_fused_ivf`` (pure ``search_memories``
-    fleets in IVF mode): same coarse prefilter + candidate scan, no state
-    mutation, no donation dance."""
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    res = _search_fused_ivf_scan(
-        state, shadow, centroids, members, extras, csr_indptr, csr_nbr, q,
-        q_valid, tenant, gate_on, boost_off, super_gate, k, nprobe, slack,
-        cap_take, max_nbr, sem=sem, sem_block=sem_block)
-    return _sem_finish_read(res, sem)
-
-
-# ---------------------------------------------------------------------------
-# Ragged fused serving (ISSUE 7): the SAME three single-dispatch chat-turn
-# programs, but per-query k / cap_take / nprobe are DEVICE DATA — int32
-# sidecar columns riding next to the query batch — instead of trace
-# constants. The static kernel constants collapse to per-mode CEILINGS
-# (``k`` = serve_k_max, ``cap_take`` = the config cap, ``nprobe`` = the
-# build's probe width): the scan bodies compute to the ceiling and each
-# query masks at its own top-k boundary (``_ragged_topk_mask``), its own
-# retrieval cap (``_gate_and_boost_rows`` cap_c), and its own probe width
-# (``_ivf_two_tier`` nprobe_c). One compiled kernel per (mode × geometry)
-# therefore serves ANY mix of request shapes — a k=100 request no longer
-# re-keys the whole batch's kernel or inflates its neighbors' top-k
-# beyond masked compute, and mixed-size traffic stops burning compile
-# cache entries. The packed readback's n_live counter becomes the
-# per-query live LENGTH (the PR 6 shortfall tail generalized): decode
-# reads exactly k_i live entries per request out of the K-wide rows.
-# ---------------------------------------------------------------------------
-
-
-def _search_fused_ragged(
-    state: ArenaState,
-    csr_indptr: jax.Array,
-    csr_nbr: jax.Array,
-    q: jax.Array,            # [Q, d] padded query batch
-    q_valid: jax.Array,      # [Q] bool
-    tenant: jax.Array,       # [Q] i32
-    gate_on: jax.Array,      # [Q] bool
-    boost_on: jax.Array,     # [Q] bool
-    k_q: jax.Array,          # [Q] i32 per-query k (0 for pad rows)
-    cap_q: jax.Array,        # [Q] i32 per-query retrieval cap
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
-    k: int,                  # STATIC k ceiling (serve_k_max)
-    cap_take: int,           # STATIC cap ceiling
-    max_nbr: int,
-    scan_chunk: int = 0,     # planner streaming-width override (ISSUE 11)
-    sem=None,
-    sem_block: int = 16,
-) -> Tuple[ArenaState, jax.Array]:
-    """``search_fused`` with the per-query (k, cap) sidecar: ONE donated
-    dispatch + ONE packed readback for a mixed-shape batch."""
-    res = _search_fused_scan(state, csr_indptr, csr_nbr, q, q_valid, tenant,
-                             gate_on, boost_on, super_gate, k, cap_take,
-                             max_nbr, k_q=k_q, cap_q=cap_q,
-                             scan_chunk=scan_chunk, sem=sem,
-                             sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
-
-
-search_fused_ragged, search_fused_ragged_copy = _donated_pair(
-    _search_fused_ragged, static_argnames=("k", "cap_take", "max_nbr",
-                                           "scan_chunk", "sem_block"))
-
-
-@functools.partial(jax.jit, static_argnames=("k", "cap_take", "max_nbr",
-                                             "scan_chunk", "sem_block"))
-def search_fused_ragged_read(state: ArenaState, csr_indptr: jax.Array,
-                             csr_nbr: jax.Array, q: jax.Array,
-                             q_valid: jax.Array, tenant: jax.Array,
-                             gate_on: jax.Array, k_q: jax.Array,
-                             super_gate: jax.Array, k: int, cap_take: int,
-                             max_nbr: int, scan_chunk: int = 0,
-                             sem=None, sem_block: int = 16) -> jax.Array:
-    """Read-only ragged twin (pure ``search_memories`` fleets): per-query
-    k as data, no state mutation."""
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    cap_q = jnp.zeros(q_valid.shape, jnp.int32)
-    res = _search_fused_scan(
-        state, csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_off,
-        super_gate, k, cap_take, max_nbr, k_q=k_q, cap_q=cap_q,
-        scan_chunk=scan_chunk, sem=sem, sem_block=sem_block)
-    return _sem_finish_read(res, sem)
-
-
-def _search_fused_quant_ragged(
-    state: ArenaState,
-    q8a: jax.Array,
-    scale_a: jax.Array,
-    csr_indptr: jax.Array,
-    csr_nbr: jax.Array,
-    q: jax.Array,
-    q_valid: jax.Array,
-    tenant: jax.Array,
-    gate_on: jax.Array,
-    boost_on: jax.Array,
-    k_q: jax.Array,
-    cap_q: jax.Array,
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
-    k: int,
-    slack: int,
-    cap_take: int,
-    max_nbr: int,
-    scan_chunk: int = 0,
-    sem=None,
-    sem_block: int = 16,
-) -> Tuple[ArenaState, jax.Array]:
-    """``search_fused_quant`` with the (k, cap) sidecar: the int8 coarse
-    fetch and exact rescore run to the k ceiling, the boundary is data."""
-    res = _search_fused_quant_scan(state, q8a, scale_a, csr_indptr, csr_nbr,
-                                   q, q_valid, tenant, gate_on, boost_on,
-                                   super_gate, k, slack, cap_take, max_nbr,
-                                   k_q=k_q, cap_q=cap_q,
-                                   scan_chunk=scan_chunk, sem=sem,
-                                   sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
-
-
-search_fused_quant_ragged, search_fused_quant_ragged_copy = _donated_pair(
-    _search_fused_quant_ragged,
-    static_argnames=("k", "slack", "cap_take", "max_nbr", "scan_chunk",
-                     "sem_block"))
-
-
-@functools.partial(jax.jit, static_argnames=("k", "slack", "cap_take",
-                                             "max_nbr", "scan_chunk",
-                                             "sem_block"))
-def search_fused_quant_ragged_read(state: ArenaState, q8a: jax.Array,
-                                   scale_a: jax.Array,
-                                   csr_indptr: jax.Array,
-                                   csr_nbr: jax.Array, q: jax.Array,
-                                   q_valid: jax.Array, tenant: jax.Array,
-                                   gate_on: jax.Array, k_q: jax.Array,
-                                   super_gate: jax.Array, k: int,
-                                   slack: int, cap_take: int,
-                                   max_nbr: int, scan_chunk: int = 0,
-                                   sem=None,
-                                   sem_block: int = 16) -> jax.Array:
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    cap_q = jnp.zeros(q_valid.shape, jnp.int32)
-    res = _search_fused_quant_scan(
-        state, q8a, scale_a, csr_indptr, csr_nbr, q, q_valid, tenant,
-        gate_on, boost_off, super_gate, k, slack, cap_take, max_nbr,
-        k_q=k_q, cap_q=cap_q, scan_chunk=scan_chunk, sem=sem,
-        sem_block=sem_block)
-    return _sem_finish_read(res, sem)
-
-
-def _search_fused_ivf_ragged(
-    state: ArenaState,
-    shadow,
-    centroids: jax.Array,
-    members: jax.Array,
-    extras: jax.Array,
     csr_indptr: jax.Array,
     csr_nbr: jax.Array,
     q: jax.Array,
@@ -3850,9 +3599,14 @@ def _search_fused_ivf_ragged(
     sem=None,
     sem_block: int = 16,
 ) -> Tuple[ArenaState, jax.Array]:
-    """``search_fused_ivf`` with the (k, cap, nprobe) sidecar: the member
-    gather visits the ceiling probe width, each query masks candidates
-    past its own — recall/latency per request, one kernel."""
+    """``search_fused_ragged`` with the IVF centroid prefilter + member
+    gather as the coarse stage: ONE donated dispatch + ONE packed readback
+    per coalesced batch in IVF mode. The member gather visits the ceiling
+    probe width, each query masks candidates past its own — recall/latency
+    per request, one kernel. Only the arena state is donated — the
+    centroid/member/extras tables and the optional int8 shadow are
+    long-lived read-only replicas (the boost scatter touches salience/
+    access/freshness, never embeddings or routing)."""
     res = _search_fused_ivf_scan(state, shadow, centroids, members, extras,
                                  csr_indptr, csr_nbr, q, q_valid, tenant,
                                  gate_on, boost_on, super_gate, k, nprobe,
@@ -3883,6 +3637,9 @@ def search_fused_ivf_ragged_read(state: ArenaState, shadow,
                                  slack: int, cap_take: int, max_nbr: int,
                                  scan_chunk: int = 0, sem=None,
                                  sem_block: int = 16) -> jax.Array:
+    """Read-only twin of ``search_fused_ivf_ragged`` (pure
+    ``search_memories`` fleets in IVF mode): same coarse prefilter +
+    candidate scan, no state mutation, no donation dance."""
     boost_off = jnp.zeros(q_valid.shape, bool)
     cap_q = jnp.zeros(q_valid.shape, jnp.int32)
     res = _search_fused_ivf_scan(
@@ -3984,108 +3741,33 @@ def _search_fused_ivf_tiered_scan(state: ArenaState, q8a: jax.Array,
                                   gate_on: jax.Array, boost_on: jax.Array,
                                   super_gate: jax.Array, k: int,
                                   nprobe: int, slack: int, cap_take: int,
-                                  max_nbr: int, k_q=None, cap_q=None,
-                                  nprobe_q=None, scan_chunk: int = 0,
+                                  max_nbr: int, k_q: jax.Array,
+                                  cap_q: jax.Array, nprobe_q: jax.Array,
+                                  scan_chunk: int = 0,
                                   sem=None, sem_block: int = 16):
     """IVF×tiered per-chunk compute: the tier-aware IVF core, then the
     shared gate/CSR/boost tail with cold-hit queries' boosts deferred to
     the bounded finish dispatch — exactly the tiered scan's contract, so
     ``tier.serve.tiered_decode_and_finish`` decodes this readback
     unchanged."""
-    ragged = k_q is not None
-
-    def chunk(q_c, valid_c, tenant_c, gate_c, boost_c, *rag):
-        np_c = rag[2] if ragged else None
+    def chunk(q_c, valid_c, tenant_c, gate_c, boost_c, k_c, cap_c, np_c):
         g_s, g_r, ann_s, ann_r, n_dup, cold_any = _ivf_tiered_two_tier(
             state, q8a, scale_a, cold, centroids, members, extras, q_c,
             tenant_c, k, nprobe, slack, nprobe_c=np_c)
-        cap_c = None
-        if ragged:
-            k_c, cap_c = rag[0], rag[1]
-            kf = jnp.minimum(k_c + slack, ann_s.shape[1])
-            ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, kf,
-                                             state.capacity)
+        kf = jnp.minimum(k_c + slack, ann_s.shape[1])
+        ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, kf, state.capacity)
         fast, acc_rows, nbr_rows = _gate_and_boost_rows(
             state, csr_indptr, csr_nbr, g_s, g_r, ann_s, ann_r,
             valid_c, tenant_c, gate_c, boost_c & ~cold_any, super_gate,
             cap_take, max_nbr, cap_c=cap_c)
         return g_s, g_r, ann_s, ann_r, fast, acc_rows, nbr_rows, n_dup
 
-    arrays = (q, q_valid, tenant, gate_on, boost_on)
-    if ragged:
-        arrays = arrays + (k_q, cap_q, nprobe_q)
+    arrays = (q, q_valid, tenant, gate_on, boost_on, k_q, cap_q, nprobe_q)
     if sem is None:
         return chunked_map_multi(chunk, arrays,
                                  chunk=(scan_chunk or IVF_SERVE_CHUNK))
     return _semantic_scan_core(chunk, arrays, state, sem, super_gate,
-                               k=k, block=sem_block, rag_slack=slack,
-                               nprobe_val=nprobe)
-
-
-def _search_fused_ivf_tiered(
-    state: ArenaState,
-    q8a: jax.Array,
-    scale_a: jax.Array,
-    cold: jax.Array,
-    centroids: jax.Array,
-    members: jax.Array,
-    extras: jax.Array,
-    csr_indptr: jax.Array,
-    csr_nbr: jax.Array,
-    q: jax.Array,
-    q_valid: jax.Array,
-    tenant: jax.Array,
-    gate_on: jax.Array,
-    boost_on: jax.Array,
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
-    k: int,
-    nprobe: int,
-    slack: int,
-    cap_take: int,
-    max_nbr: int,
-    sem=None,
-    sem_block: int = 16,
-) -> Tuple[ArenaState, jax.Array]:
-    """ONE donated dispatch + ONE packed readback: IVF coarse stage for the
-    hot tier, cold-masked int8 coarse for the demoted rows, tiered
-    candidate window (k+slack wide) for the bounded finish."""
-    res = _search_fused_ivf_tiered_scan(
-        state, q8a, scale_a, cold, centroids, members, extras,
-        csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_on,
-        super_gate, k, nprobe, slack, cap_take, max_nbr, sem=sem,
-        sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
-
-
-search_fused_ivf_tiered, search_fused_ivf_tiered_copy = _donated_pair(
-    _search_fused_ivf_tiered,
-    static_argnames=("k", "nprobe", "slack", "cap_take", "max_nbr",
-                     "sem_block"))
-
-
-@functools.partial(jax.jit, static_argnames=("k", "nprobe", "slack",
-                                             "cap_take", "max_nbr",
-                                             "sem_block"))
-def search_fused_ivf_tiered_read(state: ArenaState, q8a: jax.Array,
-                                 scale_a: jax.Array, cold: jax.Array,
-                                 centroids: jax.Array, members: jax.Array,
-                                 extras: jax.Array, csr_indptr: jax.Array,
-                                 csr_nbr: jax.Array, q: jax.Array,
-                                 q_valid: jax.Array, tenant: jax.Array,
-                                 gate_on: jax.Array, super_gate: jax.Array,
-                                 k: int, nprobe: int, slack: int,
-                                 cap_take: int, max_nbr: int,
-                                 sem=None, sem_block: int = 16) -> jax.Array:
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    res = _search_fused_ivf_tiered_scan(
-        state, q8a, scale_a, cold, centroids, members, extras,
-        csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_off,
-        super_gate, k, nprobe, slack, cap_take, max_nbr, sem=sem,
-        sem_block=sem_block)
-    return _sem_finish_read(res, sem)
+                               block=sem_block, rag_slack=slack)
 
 
 def _search_fused_ivf_tiered_ragged(
@@ -4119,7 +3801,9 @@ def _search_fused_ivf_tiered_ragged(
     sem=None,
     sem_block: int = 16,
 ) -> Tuple[ArenaState, jax.Array]:
-    """IVF×tiered serving with the (k, cap, nprobe) sidecar."""
+    """ONE donated dispatch + ONE packed readback: IVF coarse stage for the
+    hot tier, cold-masked int8 coarse for the demoted rows, tiered
+    candidate window (k+slack wide) for the bounded finish."""
     res = _search_fused_ivf_tiered_scan(
         state, q8a, scale_a, cold, centroids, members, extras,
         csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_on,
@@ -4274,24 +3958,17 @@ def _search_fused_pq_scan(state: ArenaState, book_cent: jax.Array,
                           tenant: jax.Array, gate_on: jax.Array,
                           boost_on: jax.Array, super_gate: jax.Array,
                           k: int, nprobe: int, slack: int, cap_take: int,
-                          max_nbr: int, k_q=None, cap_q=None,
-                          nprobe_q=None, scan_chunk: int = 0,
+                          max_nbr: int, k_q: jax.Array, cap_q: jax.Array,
+                          nprobe_q: jax.Array, scan_chunk: int = 0,
                           sem=None, sem_block: int = 16):
     """PQ per-chunk compute phase: the ADC two-tier core, then the shared
-    gate/CSR/boost tail. Ragged sidecars behave exactly as in
+    gate/CSR/boost tail. The per-query columns behave exactly as in
     ``_search_fused_ivf_scan``."""
-    ragged = k_q is not None
-
-    def body(q_c, valid_c, tenant_c, gate_c, boost_c, *rag):
-        nprobe_c = rag[2] if ragged else None
+    def body(q_c, valid_c, tenant_c, gate_c, boost_c, k_c, cap_c, nprobe_c):
         gate_s, gate_r, ann_s, ann_r, n_dup = _pq_two_tier(
             state, book_cent, codes, centroids, members, extras, q_c,
             tenant_c, k, nprobe, slack, nprobe_c=nprobe_c)
-        cap_c = None
-        if ragged:
-            k_c, cap_c = rag[0], rag[1]
-            ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, k_c,
-                                             state.capacity)
+        ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, k_c, state.capacity)
         fast, acc_rows, nbr_rows = _gate_and_boost_rows(
             state, csr_indptr, csr_nbr, gate_s, gate_r, ann_s, ann_r,
             valid_c, tenant_c, gate_c, boost_c, super_gate, cap_take,
@@ -4299,92 +3976,22 @@ def _search_fused_pq_scan(state: ArenaState, book_cent: jax.Array,
         return (gate_s, gate_r, ann_s, ann_r, fast, acc_rows, nbr_rows,
                 n_dup)
 
-    arrays = (q, q_valid, tenant, gate_on, boost_on)
-    if ragged:
-        arrays = arrays + (k_q, cap_q, nprobe_q)
+    arrays = (q, q_valid, tenant, gate_on, boost_on, k_q, cap_q, nprobe_q)
     if sem is None:
         return chunked_map_multi(body, arrays,
                                  chunk=min(scan_chunk or IVF_SERVE_CHUNK,
                                            IVF_SERVE_CHUNK))
     return _semantic_scan_core(body, arrays, state, sem, super_gate,
-                               k=k, block=sem_block, nprobe_val=nprobe)
+                               block=sem_block)
 
 
-def _search_fused_pq(
+def _search_fused_pq_ragged(
     state: ArenaState,
     book_cent: jax.Array,    # [m, 256, dsub] f32 frozen PQ codebook
     codes: jax.Array,        # [cap+1, m] u8 live codes (incrementally kept)
     centroids: jax.Array,    # [C, d] f32 L2-normalized (ops/ivf.py build)
     members: jax.Array,      # [C, M] i32 arena rows, -1 padded
     extras: jax.Array,       # [E] i32 residual + fresh + super rows, -1 pad
-    csr_indptr: jax.Array,
-    csr_nbr: jax.Array,
-    q: jax.Array,
-    q_valid: jax.Array,
-    tenant: jax.Array,
-    gate_on: jax.Array,
-    boost_on: jax.Array,
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
-    k: int,
-    nprobe: int,
-    slack: int,
-    cap_take: int,
-    max_nbr: int,
-    sem=None,
-    sem_block: int = 16,
-) -> Tuple[ArenaState, jax.Array]:
-    """``search_fused_ivf`` with the m-byte ADC scan as the coarse stage:
-    ONE donated dispatch + ONE packed readback per coalesced batch in PQ
-    mode. Only the arena state is donated — the codebook, codes slab, and
-    coarse tables are long-lived read-only replicas (the boost scatter
-    touches salience/access/freshness, never embeddings or codes)."""
-    res = _search_fused_pq_scan(state, book_cent, codes, centroids, members,
-                                extras, csr_indptr, csr_nbr, q, q_valid,
-                                tenant, gate_on, boost_on, super_gate, k,
-                                nprobe, slack, cap_take, max_nbr, sem=sem,
-                                sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
-
-
-search_fused_pq, search_fused_pq_copy = _donated_pair(
-    _search_fused_pq, static_argnames=("k", "nprobe", "slack", "cap_take",
-                                       "max_nbr", "sem_block"))
-
-
-@functools.partial(jax.jit, static_argnames=("k", "nprobe", "slack",
-                                             "cap_take", "max_nbr",
-                                             "sem_block"))
-def search_fused_pq_read(state: ArenaState, book_cent: jax.Array,
-                         codes: jax.Array, centroids: jax.Array,
-                         members: jax.Array, extras: jax.Array,
-                         csr_indptr: jax.Array, csr_nbr: jax.Array,
-                         q: jax.Array, q_valid: jax.Array,
-                         tenant: jax.Array, gate_on: jax.Array,
-                         super_gate: jax.Array, k: int, nprobe: int,
-                         slack: int, cap_take: int, max_nbr: int,
-                         sem=None, sem_block: int = 16
-                         ) -> jax.Array:
-    """Read-only twin of ``search_fused_pq`` (pure ``search_memories``
-    fleets in PQ mode): same ADC scan + exact rescore, no state mutation,
-    no donation dance."""
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    res = _search_fused_pq_scan(
-        state, book_cent, codes, centroids, members, extras, csr_indptr,
-        csr_nbr, q, q_valid, tenant, gate_on, boost_off, super_gate, k,
-        nprobe, slack, cap_take, max_nbr, sem=sem, sem_block=sem_block)
-    return _sem_finish_read(res, sem)
-
-
-def _search_fused_pq_ragged(
-    state: ArenaState,
-    book_cent: jax.Array,
-    codes: jax.Array,
-    centroids: jax.Array,
-    members: jax.Array,
-    extras: jax.Array,
     csr_indptr: jax.Array,
     csr_nbr: jax.Array,
     q: jax.Array,
@@ -4408,9 +4015,14 @@ def _search_fused_pq_ragged(
     sem=None,
     sem_block: int = 16,
 ) -> Tuple[ArenaState, jax.Array]:
-    """``search_fused_pq`` with the (k, cap, nprobe) sidecar: the member
-    gather and ADC scan run to the ceilings, each query masks at its own
-    boundaries — one compiled PQ kernel for mixed-shape traffic."""
+    """``search_fused_ivf_ragged`` with the m-byte ADC scan as the coarse
+    stage: ONE donated dispatch + ONE packed readback per coalesced batch
+    in PQ mode. The member gather and ADC scan run to the ceilings, each
+    query masks at its own boundaries — one compiled PQ kernel for
+    mixed-shape traffic. Only the arena state is donated — the codebook,
+    codes slab, and coarse tables are long-lived read-only replicas (the
+    boost scatter touches salience/access/freshness, never embeddings or
+    codes)."""
     res = _search_fused_pq_scan(state, book_cent, codes, centroids, members,
                                 extras, csr_indptr, csr_nbr, q, q_valid,
                                 tenant, gate_on, boost_on, super_gate, k,
@@ -4441,6 +4053,9 @@ def search_fused_pq_ragged_read(state: ArenaState, book_cent: jax.Array,
                                 slack: int, cap_take: int, max_nbr: int,
                                 scan_chunk: int = 0, sem=None,
                                 sem_block: int = 16) -> jax.Array:
+    """Read-only twin of ``search_fused_pq_ragged`` (pure
+    ``search_memories`` fleets in PQ mode): same ADC scan + exact rescore,
+    no state mutation, no donation dance."""
     boost_off = jnp.zeros(q_valid.shape, bool)
     cap_q = jnp.zeros(q_valid.shape, jnp.int32)
     res = _search_fused_pq_scan(
@@ -4538,106 +4153,31 @@ def _search_fused_pq_tiered_scan(state: ArenaState, book_cent: jax.Array,
                                  gate_on: jax.Array, boost_on: jax.Array,
                                  super_gate: jax.Array, k: int,
                                  nprobe: int, slack: int, cap_take: int,
-                                 max_nbr: int, k_q=None, cap_q=None,
-                                 nprobe_q=None, scan_chunk: int = 0,
+                                 max_nbr: int, k_q: jax.Array,
+                                 cap_q: jax.Array, nprobe_q: jax.Array,
+                                 scan_chunk: int = 0,
                                  sem=None, sem_block: int = 16):
     """PQ×tiered per-chunk compute: the tier-aware PQ core, then the
     shared gate/CSR/boost tail with cold-hit queries' boosts deferred to
     the bounded finish dispatch — the tiered scan's contract."""
-    ragged = k_q is not None
-
-    def chunk(q_c, valid_c, tenant_c, gate_c, boost_c, *rag):
-        np_c = rag[2] if ragged else None
+    def chunk(q_c, valid_c, tenant_c, gate_c, boost_c, k_c, cap_c, np_c):
         g_s, g_r, ann_s, ann_r, n_dup, cold_any = _pq_tiered_two_tier(
             state, book_cent, codes, cold, centroids, members, extras,
             q_c, tenant_c, k, nprobe, slack, nprobe_c=np_c)
-        cap_c = None
-        if ragged:
-            k_c, cap_c = rag[0], rag[1]
-            kf = jnp.minimum(k_c + slack, ann_s.shape[1])
-            ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, kf,
-                                             state.capacity)
+        kf = jnp.minimum(k_c + slack, ann_s.shape[1])
+        ann_s, ann_r = _ragged_topk_mask(ann_s, ann_r, kf, state.capacity)
         fast, acc_rows, nbr_rows = _gate_and_boost_rows(
             state, csr_indptr, csr_nbr, g_s, g_r, ann_s, ann_r,
             valid_c, tenant_c, gate_c, boost_c & ~cold_any, super_gate,
             cap_take, max_nbr, cap_c=cap_c)
         return g_s, g_r, ann_s, ann_r, fast, acc_rows, nbr_rows, n_dup
 
-    arrays = (q, q_valid, tenant, gate_on, boost_on)
-    if ragged:
-        arrays = arrays + (k_q, cap_q, nprobe_q)
+    arrays = (q, q_valid, tenant, gate_on, boost_on, k_q, cap_q, nprobe_q)
     if sem is None:
         return chunked_map_multi(chunk, arrays,
                                  chunk=(scan_chunk or IVF_SERVE_CHUNK))
     return _semantic_scan_core(chunk, arrays, state, sem, super_gate,
-                               k=k, block=sem_block, rag_slack=slack,
-                               nprobe_val=nprobe)
-
-
-def _search_fused_pq_tiered(
-    state: ArenaState,
-    book_cent: jax.Array,
-    codes: jax.Array,
-    cold: jax.Array,
-    centroids: jax.Array,
-    members: jax.Array,
-    extras: jax.Array,
-    csr_indptr: jax.Array,
-    csr_nbr: jax.Array,
-    q: jax.Array,
-    q_valid: jax.Array,
-    tenant: jax.Array,
-    gate_on: jax.Array,
-    boost_on: jax.Array,
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
-    k: int,
-    nprobe: int,
-    slack: int,
-    cap_take: int,
-    max_nbr: int,
-    sem=None,
-    sem_block: int = 16,
-) -> Tuple[ArenaState, jax.Array]:
-    """ONE donated dispatch + ONE packed readback: IVF member gather for
-    the hot tier, cold-masked ADC coarse for the demoted rows, tiered
-    candidate window (k+slack wide) for the bounded finish."""
-    res = _search_fused_pq_tiered_scan(
-        state, book_cent, codes, cold, centroids, members, extras,
-        csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_on,
-        super_gate, k, nprobe, slack, cap_take, max_nbr, sem=sem,
-        sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
-
-
-search_fused_pq_tiered, search_fused_pq_tiered_copy = _donated_pair(
-    _search_fused_pq_tiered,
-    static_argnames=("k", "nprobe", "slack", "cap_take", "max_nbr",
-                     "sem_block"))
-
-
-@functools.partial(jax.jit, static_argnames=("k", "nprobe", "slack",
-                                             "cap_take", "max_nbr",
-                                             "sem_block"))
-def search_fused_pq_tiered_read(state: ArenaState, book_cent: jax.Array,
-                                codes: jax.Array, cold: jax.Array,
-                                centroids: jax.Array, members: jax.Array,
-                                extras: jax.Array, csr_indptr: jax.Array,
-                                csr_nbr: jax.Array, q: jax.Array,
-                                q_valid: jax.Array, tenant: jax.Array,
-                                gate_on: jax.Array, super_gate: jax.Array,
-                                k: int, nprobe: int, slack: int,
-                                cap_take: int, max_nbr: int,
-                                sem=None, sem_block: int = 16) -> jax.Array:
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    res = _search_fused_pq_tiered_scan(
-        state, book_cent, codes, cold, centroids, members, extras,
-        csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_off,
-        super_gate, k, nprobe, slack, cap_take, max_nbr, sem=sem,
-        sem_block=sem_block)
-    return _sem_finish_read(res, sem)
+                               block=sem_block, rag_slack=slack)
 
 
 def _search_fused_pq_tiered_ragged(
@@ -4671,7 +4211,9 @@ def _search_fused_pq_tiered_ragged(
     sem=None,
     sem_block: int = 16,
 ) -> Tuple[ArenaState, jax.Array]:
-    """PQ×tiered serving with the (k, cap, nprobe) sidecar."""
+    """ONE donated dispatch + ONE packed readback: IVF member gather for
+    the hot tier, cold-masked ADC coarse for the demoted rows, tiered
+    candidate window (k+slack wide) for the bounded finish."""
     res = _search_fused_pq_tiered_scan(
         state, book_cent, codes, cold, centroids, members, extras,
         csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_on,
@@ -4758,8 +4300,7 @@ def _globalize_rows(rows: jax.Array, scores: jax.Array, shard: jax.Array,
 
 def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
                        max_nbr: int, mode: str = "exact", slack: int = 0,
-                       nprobe: int = 0, ragged: bool = False,
-                       scan_chunk: int = 0,
+                       nprobe: int = 0, scan_chunk: int = 0,
                        sem: bool = False) -> FusedShardedKernels:
     """Build the distributed fused chat-turn serving program for ``mesh``.
 
@@ -4787,30 +4328,27 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
     Call signatures (tables is the mode's tuple above, ``()`` for exact):
 
     ``serve(state, tables, csr_indptr [n,L+1], csr_nbr [n,E], q [Q,d],
-    q_valid [Q], tenant [Q], gate_on [Q], boost_on [Q], now, super_gate,
-    acc_boost, nbr_boost) -> (state, packed [Q, 3+2k])`` — donates the
-    state (ONE distributed dispatch, shard-local boost scatters in place);
-    ``serve_copy`` is the non-donating twin; ``read(state, tables,
-    csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, super_gate) ->
-    packed`` skips the mutation entirely.
+    q_valid [Q], tenant [Q], gate_on [Q], boost_on [Q], k_q [Q], cap_q [Q],
+    nprobe_q [Q], now, super_gate, acc_boost, nbr_boost) -> (state, packed
+    [Q, 3+2k])`` — donates the state (ONE distributed dispatch, shard-local
+    boost scatters in place); ``serve_copy`` is the non-donating twin;
+    ``read(state, tables, csr_indptr, csr_nbr, q, q_valid, tenant, gate_on,
+    k_q, nprobe_q, super_gate) -> packed`` skips the mutation entirely.
+
+    ``k`` / ``cap_take`` / ``nprobe`` are static CEILINGS; ``k_q`` /
+    ``cap_q`` / ``nprobe_q`` are replicated [Q] i32 columns carrying each
+    query's own shape, so ONE compiled distributed program serves any mix
+    of request shapes (the shard-local scans and the all_gather merge run
+    to the ceiling; each query masks at its own boundaries,
+    ``ops.topk.sharded_topk_merge`` applying the k mask at the merge).
+    ``nprobe_q`` is accepted and ignored by the dense modes so every mode
+    shares one ABI.
 
     The per-shard CSR carries each chip's OWN rows' neighbor lists with
     GLOBAL neighbor ids; Q is bounded by the scheduler's padded batch
     (≤ ``QUERY_CHUNK`` — the local cores stream bigger fleets through the
     usual chunked tiles, IVF at ``IVF_SERVE_CHUNK`` to bound the gather
     footprint).
-
-    ``ragged=True`` (ISSUE 7) builds the per-query-shape variant: ``k`` /
-    ``cap_take`` / ``nprobe`` become static CEILINGS and the call
-    signatures gain three replicated [Q] i32 sidecar columns —
-    ``serve(state, tables, csr_indptr, csr_nbr, q, q_valid, tenant,
-    gate_on, boost_on, k_q, cap_q, nprobe_q, now, super_gate, acc_boost,
-    nbr_boost)`` and ``read(..., gate_on, k_q, nprobe_q, super_gate)`` —
-    so ONE compiled distributed program serves any mix of request shapes
-    (the shard-local scans and the all_gather merge run to the ceiling;
-    each query masks at its own boundaries, ``ops.topk.sharded_topk_merge``
-    applying the k mask at the merge). ``nprobe_q`` is accepted and
-    ignored by the dense modes so every mode shares one ragged ABI.
 
     ``scan_chunk > 0`` (ISSUE 17 satellite — the pod twin of the ISSUE 11
     single-chip override) narrows every chip's shard-local streaming tile:
@@ -4850,14 +4388,14 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
     # gathered rows + final re-rank) over the same window.
     k_merge = k + slack if mode == "tiered" else k
 
-    def _scan_merge(arena, tables, q, tenant, k_q=None, nprobe_q=None):
+    def _scan_merge(arena, tables, q, tenant, k_q, nprobe_q):
         """Shard-local two-tier candidates → globalize → ONE all_gather +
         global top-k per tier. Returns replicated (gate_s [Q], gate_r [Q],
         ann_s [Q,k], ann_r [Q,k], n_dup [Q]) with GLOBAL row ids; the dup
         counter (IVF in-kernel dedup hits, per-shard counts summed with a
         tiny psum riding the same dispatch) is zero for the dense modes.
-        ``k_q``/``nprobe_q`` make it ragged: local scans run to the
-        ceiling, the merge masks each query at its own k boundary."""
+        Local scans run to the ceiling, the merge masks each query at its
+        own k boundary."""
         shard = jax.lax.axis_index(axis)
         local_n = arena.emb.shape[0]
         k_l = max(1, min(k, local_n))
@@ -4875,15 +4413,16 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
             book_l, codes_l, cent, mem2, ext2 = tables
             mem_l, ext_l = mem2[0], ext2[0]
 
-        def core(q_c, tenant_c, *rag):
+        def core(q_c, tenant_c, *col):
+            # ``col``: the one per-query column the mode's core reads —
+            # each query's k (exact), its probe width (ivf / pq), none
+            # (quant / tiered)
             zeros = jnp.zeros((q_c.shape[0],), jnp.int32)
             off = jnp.zeros((q_c.shape[0],), bool)
             if mode == "exact":
-                # the one ragged sidecar of the exact core: each query's k
                 g_s, g_r, a_s, a_r = _exact_two_tier(
-                    arena, q_c, tenant_c, k_l, rag[0] if rag else None)
+                    arena, q_c, tenant_c, k_l, col[0])
                 return g_s[:, None], g_r[:, None], a_s, a_r, zeros, off
-            nprobe_c = rag[0] if rag else None
             if mode == "quant":
                 g_s, g_r, a_s, a_r = _quant_two_tier(
                     arena, q8_l, scale_l, q_c, tenant_c, k_l, slack)
@@ -4896,18 +4435,17 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
             if mode == "pq":
                 g_s, g_r, a_s, a_r, n_dup = _pq_two_tier(
                     arena, book_l, codes_l, cent, mem_l, ext_l, q_c,
-                    tenant_c, k_l, nprobe, slack, nprobe_c=nprobe_c)
+                    tenant_c, k_l, nprobe, slack, nprobe_c=col[0])
                 return g_s[:, None], g_r[:, None], a_s, a_r, n_dup, off
             g_s, g_r, a_s, a_r, n_dup = _ivf_two_tier(
                 arena, shadow_l, cent, mem_l, ext_l, q_c, tenant_c, k_l,
-                nprobe, slack, nprobe_c=nprobe_c)
+                nprobe, slack, nprobe_c=col[0])
             return g_s[:, None], g_r[:, None], a_s, a_r, n_dup, off
 
         arrays = (q, tenant)
-        if mode == "exact" and k_q is not None:
+        if mode == "exact":
             arrays = arrays + (k_q,)
-        elif nprobe_q is not None and (mode.startswith("ivf")
-                                       or mode == "pq"):
+        elif mode.startswith("ivf") or mode == "pq":
             arrays = arrays + (nprobe_q,)
         g_s, g_r, a_s, a_r, dup_l, cold_l_q = chunked_map_multi(
             core, arrays, chunk=chunk)
@@ -4916,7 +4454,7 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
         # cold row — the psum rides the same dispatch
         cold_any = jax.lax.psum(cold_l_q.astype(jnp.int32), axis) > 0
         sent = n_shards * local_n - 1          # the global sentinel row
-        k_q_eff = k_q if (k_q is None or mode != "tiered") else k_q + slack
+        k_q_eff = k_q + slack if mode == "tiered" else k_q
         km = min(k_merge, n_shards * a_s.shape[1])
         ann_s, ann_r = sharded_topk_merge(
             axis, a_s, _globalize_rows(a_r, a_s, shard, local_n, n_shards),
@@ -4931,8 +4469,7 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
             (g_ms[:, 0], g_mr[:, 0], ann_s, ann_r, n_dup, cold_any))
 
     def _boost_tail(arena, indptr_l, nbr_l, ann_s, ann_r, fast, q_valid,
-                    tenant, boost_on, now, acc_boost, nbr_boost,
-                    cap_q=None):
+                    tenant, boost_on, now, acc_boost, nbr_boost, cap_q):
         """The gate/CSR/boost tail against the row-sharded edge arena:
         owner chips gather their rows' CSR neighbor windows (merged to all
         chips with one small pmax), the per-query dedup / in-result masks
@@ -4944,9 +4481,8 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
         local_n = arena.emb.shape[0]
         sent = n_shards * local_n - 1          # == the global sentinel row
         do_boost = boost_on & q_valid & ~fast
-        take = (ann_s[:, :cap_take] > NEG_INF / 2) & do_boost[:, None]
-        if cap_q is not None:
-            take = take & (jnp.arange(cap_take)[None, :] < cap_q[:, None])
+        take = ((ann_s[:, :cap_take] > NEG_INF / 2) & do_boost[:, None]
+                & (jnp.arange(cap_take)[None, :] < cap_q[:, None]))
         acc_rows = jnp.where(take, ann_r[:, :cap_take], sent)  # global rows
         base = shard * local_n
         loc = acc_rows - base
@@ -4984,30 +4520,23 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
                               nbr_boost, zero_last=False), n_acc, n_nbr
 
     def _sem_apply(sem_state, sent, q, q_valid, tenant, gate_on,
-                   super_gate, merged, k_q=None, nprobe_q=None):
+                   super_gate, merged, k_q, nprobe_q):
         """Replicated probe → substitute → writeback after the merge.
         Every chip computes the identical verdicts and the identical next
         ring (replicated inputs, replicated arithmetic), so the ring's
         out-spec stays P(None...) with zero extra collectives."""
         ring, sem_valid, head, thresh, mode_id = sem_state
         gate_s, gate_r, ann_s, ann_r, n_dup, cold_any = merged
-        nq = q.shape[0]
         qn = normalize(q).astype(jnp.float32)
-        k_need = (k_q if k_q is not None
-                  else jnp.full((nq,), k, jnp.int32))
-        npr_need = (nprobe_q if nprobe_q is not None
-                    else jnp.full((nq,), nprobe, jnp.int32))
         hit, slot = _semantic_probe(ring, sem_valid, qn, tenant, q_valid,
-                                    gate_on, k_need, npr_need, mode_id,
-                                    thresh)
+                                    gate_on, k_q, nprobe_q, mode_id, thresh)
         miss = q_valid & ~hit
         rank = jnp.cumsum(miss.astype(jnp.int32)) - 1
         n_miss = miss.sum().astype(jnp.int32)
         write_mask = miss & (rank >= n_miss - ring.slots)
         ring2 = _semantic_writeback(ring, head, qn, tenant, gate_on,
                                     gate_s, gate_r, ann_s, ann_r, rank,
-                                    write_mask, k_need, npr_need, mode_id,
-                                    sent)
+                                    write_mask, k_q, nprobe_q, mode_id, sent)
         fast0 = gate_on & (gate_s > super_gate)
         rag_slack = slack if mode == "tiered" else 0
         gate_s, gate_r, ann_s, ann_r, fast = _semantic_substitute(
@@ -5019,81 +4548,36 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
                 cold_any & ~hit, hit, sem_col, ring2)
 
     def _serve_local(arena, tables, indptr2, nbr2, q, q_valid, tenant,
-                     gate_on, boost_on, now, super_gate, acc_boost,
-                     nbr_boost, sem_state=None):
-        merged = _scan_merge(arena, tables, q, tenant)
-        if sem_state is None:
-            gate_s, gate_r, ann_s, ann_r, n_dup, cold_any = merged
-            fast = gate_on & (gate_s > super_gate)
-            arena, n_acc, n_nbr = _boost_tail(
-                arena, indptr2[0], nbr2[0], ann_s, ann_r, fast, q_valid,
-                tenant, boost_on & ~cold_any, now, acc_boost, nbr_boost)
-            packed = _pack_retrieval(gate_s, gate_r, ann_s, ann_r, fast,
-                                     dup=n_dup, acc=n_acc, nbr=n_nbr)
-            return arena, packed
-        sent = n_shards * arena.emb.shape[0] - 1
-        (gate_s, gate_r, ann_s, ann_r, fast, n_dup, cold_eff, hit,
-         sem_col, ring2) = _sem_apply(sem_state, sent, q, q_valid, tenant,
-                                      gate_on, super_gate, merged)
-        arena, n_acc, n_nbr = _boost_tail(
-            arena, indptr2[0], nbr2[0], ann_s, ann_r, fast, q_valid,
-            tenant, boost_on & ~cold_eff & ~hit, now, acc_boost,
-            nbr_boost)
-        packed = _pack_retrieval(gate_s, gate_r, ann_s, ann_r, fast,
-                                 dup=n_dup, acc=n_acc, nbr=n_nbr,
-                                 sem=sem_col)
-        return arena, ring2, packed
-
-    def _read_local(arena, tables, indptr2, nbr2, q, q_valid, tenant,
-                    gate_on, super_gate, sem_state=None):
-        merged = _scan_merge(arena, tables, q, tenant)
-        if sem_state is None:
-            gate_s, gate_r, ann_s, ann_r, n_dup, _cold = merged
-            fast = gate_on & (gate_s > super_gate)
-            return _pack_retrieval(gate_s, gate_r, ann_s, ann_r, fast,
-                                   dup=n_dup)
-        sent = n_shards * arena.emb.shape[0] - 1
-        (gate_s, gate_r, ann_s, ann_r, fast, n_dup, _cold, _hit,
-         sem_col, ring2) = _sem_apply(sem_state, sent, q, q_valid, tenant,
-                                      gate_on, super_gate, merged)
-        return ring2, _pack_retrieval(gate_s, gate_r, ann_s, ann_r, fast,
-                                      dup=n_dup, sem=sem_col)
-
-    def _serve_local_ragged(arena, tables, indptr2, nbr2, q, q_valid,
-                            tenant, gate_on, boost_on, k_q, cap_q,
-                            nprobe_q, now, super_gate, acc_boost,
-                            nbr_boost, sem_state=None):
-        merged = _scan_merge(arena, tables, q, tenant, k_q=k_q,
-                             nprobe_q=nprobe_q)
+                     gate_on, boost_on, k_q, cap_q, nprobe_q, now,
+                     super_gate, acc_boost, nbr_boost, sem_state=None):
+        merged = _scan_merge(arena, tables, q, tenant, k_q, nprobe_q)
         if sem_state is None:
             gate_s, gate_r, ann_s, ann_r, n_dup, cold_any = merged
             fast = gate_on & (gate_s > super_gate)
             arena, n_acc, n_nbr = _boost_tail(
                 arena, indptr2[0], nbr2[0], ann_s, ann_r, fast, q_valid,
                 tenant, boost_on & ~cold_any, now, acc_boost, nbr_boost,
-                cap_q=cap_q)
+                cap_q)
             packed = _pack_retrieval(gate_s, gate_r, ann_s, ann_r, fast,
                                      dup=n_dup, acc=n_acc, nbr=n_nbr)
             return arena, packed
         sent = n_shards * arena.emb.shape[0] - 1
         (gate_s, gate_r, ann_s, ann_r, fast, n_dup, cold_eff, hit,
          sem_col, ring2) = _sem_apply(sem_state, sent, q, q_valid, tenant,
-                                      gate_on, super_gate, merged,
-                                      k_q=k_q, nprobe_q=nprobe_q)
+                                      gate_on, super_gate, merged, k_q,
+                                      nprobe_q)
         arena, n_acc, n_nbr = _boost_tail(
             arena, indptr2[0], nbr2[0], ann_s, ann_r, fast, q_valid,
             tenant, boost_on & ~cold_eff & ~hit, now, acc_boost,
-            nbr_boost, cap_q=cap_q)
+            nbr_boost, cap_q)
         packed = _pack_retrieval(gate_s, gate_r, ann_s, ann_r, fast,
                                  dup=n_dup, acc=n_acc, nbr=n_nbr,
                                  sem=sem_col)
         return arena, ring2, packed
 
-    def _read_local_ragged(arena, tables, indptr2, nbr2, q, q_valid,
-                           tenant, gate_on, k_q, nprobe_q, super_gate,
-                           sem_state=None):
-        merged = _scan_merge(arena, tables, q, tenant, k_q=k_q,
-                             nprobe_q=nprobe_q)
+    def _read_local(arena, tables, indptr2, nbr2, q, q_valid, tenant,
+                    gate_on, k_q, nprobe_q, super_gate, sem_state=None):
+        merged = _scan_merge(arena, tables, q, tenant, k_q, nprobe_q)
         if sem_state is None:
             gate_s, gate_r, ann_s, ann_r, n_dup, _cold = merged
             fast = gate_on & (gate_s > super_gate)
@@ -5102,8 +4586,8 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
         sent = n_shards * arena.emb.shape[0] - 1
         (gate_s, gate_r, ann_s, ann_r, fast, n_dup, _cold, _hit,
          sem_col, ring2) = _sem_apply(sem_state, sent, q, q_valid, tenant,
-                                      gate_on, super_gate, merged,
-                                      k_q=k_q, nprobe_q=nprobe_q)
+                                      gate_on, super_gate, merged, k_q,
+                                      nprobe_q)
         return ring2, _pack_retrieval(gate_s, gate_r, ann_s, ann_r, fast,
                                       dup=n_dup, sem=sem_col)
 
@@ -5134,25 +4618,16 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
     serve_out = ((state_specs, ring_specs, P(None, None)) if sem
                  else (state_specs, P(None, None)))
     read_out = (ring_specs, P(None, None)) if sem else P(None, None)
-    if ragged:
-        # + (boost_on, k_q, cap_q, nprobe_q) replicated sidecars
-        mapped_serve = shard_map(
-            _serve_local_ragged, mesh=mesh,
-            in_specs=common + (P(None), P(None), P(None), P(None),
-                               P(), P(), P(), P()) + sem_in,
-            out_specs=serve_out, check_vma=False)
-        mapped_read = shard_map(
-            _read_local_ragged, mesh=mesh,
-            in_specs=common + (P(None), P(None), P()) + sem_in,
-            out_specs=read_out, check_vma=False)
-    else:
-        mapped_serve = shard_map(
-            _serve_local, mesh=mesh,
-            in_specs=common + (P(None), P(), P(), P(), P()) + sem_in,
-            out_specs=serve_out, check_vma=False)
-        mapped_read = shard_map(
-            _read_local, mesh=mesh, in_specs=common + (P(),) + sem_in,
-            out_specs=read_out, check_vma=False)
+    # + (boost_on, k_q, cap_q, nprobe_q) replicated per-query columns
+    mapped_serve = shard_map(
+        _serve_local, mesh=mesh,
+        in_specs=common + (P(None), P(None), P(None), P(None),
+                           P(), P(), P(), P()) + sem_in,
+        out_specs=serve_out, check_vma=False)
+    mapped_read = shard_map(
+        _read_local, mesh=mesh,
+        in_specs=common + (P(None), P(None), P()) + sem_in,
+        out_specs=read_out, check_vma=False)
     return FusedShardedKernels(
         serve=jax.jit(mapped_serve, donate_argnums=(0,)),
         serve_copy=jax.jit(mapped_serve),

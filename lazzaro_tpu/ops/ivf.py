@@ -190,7 +190,7 @@ def gather_rows(centroids: jax.Array, members: jax.Array,
                 ) -> Tuple[jax.Array, jax.Array]:
     """Device-friendly coarse gather, the single place EVERY member scan —
     the classic ``ivf_search``, ``ops.pq.ivf_pq_search``, and the fused
-    serving kernel (``core.state.search_fused_ivf``) — assembles its
+    serving kernel (``core.state.search_fused_ivf_ragged``) — assembles its
     candidate row set, so the 'identical candidate set' invariant between
     the paths is structural, not a docstring promise: score C centroids,
     take the ``nprobe`` best clusters, and return their member rows plus

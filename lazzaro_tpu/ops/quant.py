@@ -22,7 +22,7 @@ scan-optimized replica.
 Serving consumes the shadow two ways: the classic ``quantized_topk`` scan
 below (pure int8 ranking; mesh path via ops/topk.make_sharded_int8_topk),
 and since ISSUE 3 the single-dispatch fused chat-turn program
-(``core/state.search_fused_quant``) which uses the int8 scores only as a
+(``core/state.search_fused_quant_ragged``) which uses the int8 scores only as a
 COARSE top-(k+slack) stage and exactly rescores the survivors from the
 master — returned scores and threshold verdicts never carry quantization
 error there.

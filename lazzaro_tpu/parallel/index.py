@@ -106,7 +106,7 @@ class ShardedMemoryIndex:
                  max_nbr: int = 32, super_gate: float = 0.4,
                  acc_boost: float = 0.05, nbr_boost: float = 0.02,
                  epoch: Optional[float] = None, telemetry=None,
-                 telemetry_hbm: bool = False, serve_ragged: bool = True,
+                 telemetry_hbm: bool = False,
                  serve_k_max: int = 128, serve_pad_granularity: int = 8,
                  serve_kernel_cache_max: int = 8,
                  edge_capacity: int = 1 << 17,
@@ -267,9 +267,8 @@ class ShardedMemoryIndex:
 
         self._k = k
         self._search = make_sharded_topk(mesh, axis, k=k)
-        # Ragged pod serving (ISSUE 7): per-query k/cap/nprobe sidecars,
+        # Fused pod serving: per-query k/cap/nprobe as device columns,
         # kernels keyed per MODE at the serve_k_max ceiling.
-        self.serve_ragged = bool(serve_ragged)
         self.serve_k_max = max(1, int(serve_k_max))
         self.serve_pad_granularity = max(1, int(serve_pad_granularity))
         # Classic pod serving kernels (serve_fused=False A/B + fallback),
@@ -277,9 +276,9 @@ class ShardedMemoryIndex:
         # construction-time default retraces instead of truncating.
         # LRU-capped (ISSUE 7 satellite) like the fused cache below.
         self._serve_search_cache = LRUKernelCache(serve_kernel_cache_max)
-        # Fused distributed serving programs — per-mode keys with ragged
-        # serving, (mode, k_bucket) without; LRU-capped so mixed-k
-        # non-ragged traffic can no longer grow it without bound.
+        # Fused distributed serving programs, keyed (mode, k ceiling,
+        # nprobe) — one per mode while the ceilings stand; LRU-capped so a
+        # changed ceiling evicts, never grows.
         self._fused_cache = LRUKernelCache(serve_kernel_cache_max)
 
         # Semantic query cache (ISSUE 20): the ring is REPLICATED over
@@ -1438,15 +1437,14 @@ class ShardedMemoryIndex:
         return tabs
 
     def _fused_kernels(self, mode: str, k_bucket: int, nprobe: int,
-                       ragged: bool = False, scan_chunk: int = 0,
+                       scan_chunk: int = 0,
                        sem: bool = False) -> S.FusedShardedKernels:
-        # With ragged kernels k_bucket/nprobe are the fixed per-mode
-        # ceilings, so the cache key collapses to one entry per mode.
-        # A planner scan_chunk override keys separately: same ONE
-        # dispatch, smaller in-kernel score tile (ISSUE 17 satellite —
-        # the pod path chunks the scan instead of splitting batches).
-        key = ((mode, "ragged", k_bucket, nprobe) if ragged
-               else (mode, k_bucket, nprobe))
+        # k_bucket/nprobe are the fixed per-mode ceilings, so the cache
+        # holds one entry per mode. A planner scan_chunk override keys
+        # separately: same ONE dispatch, smaller in-kernel score tile
+        # (ISSUE 17 satellite — the pod path chunks the scan instead of
+        # splitting batches).
+        key = (mode, k_bucket, nprobe)
         if scan_chunk:
             key = key + ("chunk", scan_chunk)
         if sem:
@@ -1457,7 +1455,7 @@ class ShardedMemoryIndex:
                 self.mesh, self.axis, k=k_bucket,
                 cap_take=min(self.cap_take, k_bucket), max_nbr=self.max_nbr,
                 mode=mode, slack=self.coarse_slack, nprobe=nprobe,
-                ragged=ragged, scan_chunk=scan_chunk, sem=sem)
+                scan_chunk=scan_chunk, sem=sem)
             self._fused_cache.put(key, kern)
             self.telemetry.gauge("kernel.cache_entries",
                                  len(self._fused_cache),
@@ -1468,8 +1466,7 @@ class ShardedMemoryIndex:
         """Cheap (mode, k-ceiling) prediction of the pod dispatch's
         routing — the planner's geometry key (mirror of
         ``MemoryIndex._serve_mode_hint``)."""
-        ragged = self.serve_ragged and self.serve_fused
-        if ragged:
+        if self.serve_fused:
             k_bucket = int(min(max(self.serve_k_max, self.cap_take, 1),
                                self.capacity))
         else:
@@ -1489,9 +1486,8 @@ class ShardedMemoryIndex:
         return "sharded_exact", k_bucket
 
     def _serve_geometry(self, nq: int, mode: str, k_bucket: int) -> Geometry:
-        ragged = self.serve_ragged and self.serve_fused
-        pad_n = (bucket_size(nq, self.serve_pad_granularity) if ragged
-                 else next_pow2(nq))
+        pad_n = (bucket_size(nq, self.serve_pad_granularity)
+                 if self.serve_fused else next_pow2(nq))
         return Geometry(
             kind="serve", mode=mode, batch=pad_n, rows=self.capacity + 1,
             dim=self.dim, k=k_bucket,
@@ -1592,10 +1588,12 @@ class ShardedMemoryIndex:
         chat-turn program — super gate, ANN top-k, CSR neighbor gather,
         shard-local boost scatters — for the whole mixed-tenant batch
         (per-query tenant column; queries with an unknown tenant match
-        nothing). The kernel is keyed on the batch max-k (pow2-bucketed),
+        nothing). The kernel is keyed on the ``serve_k_max`` ceiling and
+        per-request k / cap / nprobe ride as device columns.
+        ``serve_fused=False`` keeps the classic gate-less multitenant
+        top-k (A/B + fallback), keyed on the batch max-k (pow2-bucketed)
         so ``k`` above the construction-time default retraces once per
-        bucket instead of silently truncating. ``serve_fused=False`` keeps
-        the classic gate-less multitenant top-k (A/B + fallback)."""
+        bucket instead of silently truncating."""
         from lazzaro_tpu.serve.scheduler import RetrievalResult
 
         results = [RetrievalResult() for _ in reqs]
@@ -1605,9 +1603,9 @@ class ShardedMemoryIndex:
         tel = self.telemetry
         with tel.span("index.pack"):
             dim = self.dim
-            ragged = self.serve_ragged and self.serve_fused
+            fused = self.serve_fused
             cap_s = self.cap_take
-            if ragged:
+            if fused:
                 # static per-mode k ceiling: the kernel key never depends on
                 # the batch's k mix (ISSUE 7)
                 k_bucket = int(min(max(self.serve_k_max, cap_s, 1),
@@ -1630,22 +1628,22 @@ class ShardedMemoryIndex:
                 tids[i] = tid
                 gate_on[i] = bool(getattr(r, "gate_enabled", False))
                 boost_on[i] = bool(getattr(r, "boost", False))
-                if ragged:
+                if fused:
                     k_arr[i] = min(max(int(r.k), cap_s, 1), k_bucket)
                     rc = getattr(r, "cap_take", None)
                     cap_arr[i] = min(int(rc) if rc else cap_s, cap_s)
             if not valid.any():
                 return results
-            if not ragged:
+            if not fused:
                 k_req = max((min(int(r.k), self.capacity)
                              for i, r in enumerate(reqs) if valid[i]),
                             default=1)
                 k_eff = max(self.cap_take, k_req, 1)
                 k_bucket = min(max(next_pow2(k_eff), 1), self.capacity)
-            # Ragged batches bucket LINEARLY (granularity slots of worst-case
-            # padding) instead of to the next power of two (~50% worst case —
-            # the pow2 padding tax this PR kills).
-            qp = (pad_to_bucket(q, self.serve_pad_granularity) if ragged
+            # Fused batches bucket LINEARLY (granularity slots of worst-case
+            # padding), the classic path to the next power of two (~50%
+            # worst case).
+            qp = (pad_to_bucket(q, self.serve_pad_granularity) if fused
                   else pad_to_pow2(q))
             pad_n = qp.shape[0]
         # Coalesce/pad inflation: padded kernel slots vs live requests.
@@ -1702,7 +1700,7 @@ class ShardedMemoryIndex:
                 if win <= semh.width:
                     sem_state = semh.tuple_for(mode)
             sem_tail = () if sem_state is None else (sem_state,)
-            kern = self._fused_kernels(mode, k_bucket, nprobe, ragged=ragged,
+            kern = self._fused_kernels(mode, k_bucket, nprobe,
                                        scan_chunk=scan_chunk,
                                        sem=sem_state is not None)
             csr_i, csr_n = self._csr_sharded()
@@ -1710,27 +1708,21 @@ class ShardedMemoryIndex:
                     jnp.asarray(padb(valid)),
                     jnp.asarray(padb(tids, -1, np.int32)),
                     jnp.asarray(padb(gate_on)))
-            if ragged:
-                # per-query sidecar columns (replicated over the mesh): k,
-                # retrieval cap, and — for the IVF modes — probe width
-                k_dev = jnp.asarray(padb(k_arr, 0, np.int32))
-                capq_dev = jnp.asarray(padb(cap_arr, 0, np.int32))
-                if ivf_tabs is not None:
-                    np_arr = np.zeros((nq,), np.int32)
-                    for i, r in enumerate(reqs):
-                        rn = getattr(r, "nprobe", None)
-                        np_arr[i] = (min(max(int(rn), 1), nprobe) if rn
-                                     else nprobe)
-                    np_arr[~valid] = 0
-                else:
-                    np_arr = np.zeros((nq,), np.int32)
-                npq_dev = jnp.asarray(padb(np_arr, 0, np.int32))
-                read_extra = (k_dev, npq_dev, jnp.float32(self.super_gate))
-            else:
-                read_extra = (jnp.float32(self.super_gate),)
+            # per-query columns (replicated over the mesh): k, retrieval
+            # cap, and — for the IVF modes — probe width
+            k_dev = jnp.asarray(padb(k_arr, 0, np.int32))
+            capq_dev = jnp.asarray(padb(cap_arr, 0, np.int32))
+            np_arr = np.zeros((nq,), np.int32)
+            if ivf_tabs is not None:
+                for i, r in enumerate(reqs):
+                    rn = getattr(r, "nprobe", None)
+                    np_arr[i] = (min(max(int(rn), 1), nprobe) if rn
+                                 else nprobe)
+                np_arr[~valid] = 0
+            npq_dev = jnp.asarray(padb(np_arr, 0, np.int32))
+            read_extra = (k_dev, npq_dev, jnp.float32(self.super_gate))
             self._maybe_record_hbm(mode, kern, args, k_bucket,
-                                   read_extra=read_extra + sem_tail,
-                                   ragged=ragged)
+                                   read_extra + sem_tail)
             # Fault point "plan.oom" (ISSUE 11): an HBM allocation failure the
             # admission plan missed; serve_requests answers with one replan.
             faults.fire("plan.oom", mode=f"pod_{mode}", batch=pad_n)
@@ -1743,9 +1735,8 @@ class ShardedMemoryIndex:
                         cur = self._arena
                         sole = (not force_copy
                                 and sys.getrefcount(cur) <= self._SOLE_REFS)
-                        boost_extra = ((jnp.asarray(padb(boost_on)), k_dev,
-                                        capq_dev, npq_dev) if ragged
-                                       else (jnp.asarray(padb(boost_on)),))
+                        boost_extra = (jnp.asarray(padb(boost_on)), k_dev,
+                                       capq_dev, npq_dev)
                         out = self._guarded(
                             lambda fn: self._dispatch(
                                 fn, cur, *args, *boost_extra,
@@ -1784,8 +1775,8 @@ class ShardedMemoryIndex:
                     host, k_bucket=k_bucket, cap_take=cap_s,
                     max_nbr=self.max_nbr, acc_boost=self.acc_boost,
                     nbr_boost=self.nbr_boost,
-                    now_rel=time.time() - self.epoch, ragged=ragged,
-                    cap_arr=(cap_arr if ragged else None), tel=tel)
+                    now_rel=time.time() - self.epoch, cap_arr=cap_arr,
+                    tel=tel)
         with tel.span("index.decode", timer="serve.decode_ms"):
             gate_s, gate_r, ann_s, ann_r, fast, counters = unpack_retrieval(
                 host[:nq], k_bucket)
@@ -1796,7 +1787,7 @@ class ShardedMemoryIndex:
                 ids, scores = decode_topk(
                     ann_s[i:i + 1], ann_r[i:i + 1], self.row_to_id,
                     NEG_INF, limit=min(int(r.k), self.capacity),
-                    lengths=(counters[i:i + 1, 0] if ragged else None))[0]
+                    lengths=counters[i:i + 1, 0])[0]
                 res.ids, res.scores = ids, scores
                 if gate_s[i] > NEG_INF / 2:
                     res.gate_id = self.row_to_id.get(int(gate_r[i]))
@@ -1813,18 +1804,16 @@ class ShardedMemoryIndex:
         return results
 
     def _maybe_record_hbm(self, mode: str, kern, args, k_bucket,
-                          read_extra=None, ragged: bool = False) -> None:
+                          read_extra) -> None:
         """Opt-in peak-HBM gauge for one pod serving geometry (AOT lower +
         ``memory_analysis()`` of the read twin; one extra compile, zero
         extra dispatches)."""
         if not self.telemetry_hbm or not self.telemetry.enabled:
             return    # never consume the once-key while warmup mutes the registry
-        key = (mode, k_bucket, ragged)
+        key = (mode, k_bucket)
         if key in self._hbm_recorded:
             return
         self._hbm_recorded.add(key)
-        if read_extra is None:
-            read_extra = (jnp.float32(self.super_gate),)
         try:
             peak = peak_bytes(kern.read.lower(
                 self.state, *args, *read_extra
@@ -1887,7 +1876,7 @@ class ShardedMemoryIndex:
         kk = int(k if k is not None else self.serve_k_max)
         buckets = sorted({
             (bucket_size(g, self.serve_pad_granularity)
-             if (self.serve_ragged and self.serve_fused) else next_pow2(g))
+             if self.serve_fused else next_pow2(g))
             for g in geometries if g > 0})
         for g in buckets:
             zero_q = np.zeros((self.dim,), np.float32)

@@ -14,12 +14,13 @@ the way Ragged Paged Attention coalesces ragged decode work on TPU:
   single-writer — except that a FULL pending batch of pure reads is
   admitted over an in-flight batch of pure reads (at most two in flight,
   see :class:`QueryScheduler`);
-- the flush decision is the shared time/size policy (``utils.batching.
-  FlushPolicy``): a full ``max_batch`` flushes immediately, a lone trickle
-  request waits at most ``max_wait_us`` before it ships;
-- the executor pads the popped batch to a power-of-two bucket before
-  dispatch (``utils.batching.pad_to_pow2``), so the number of distinct jit
-  specializations stays bounded no matter what batch sizes arrive;
+- there is no flush timer: the dispatch in flight is the batching window.
+  Pending requests are admitted, at most ``max_batch`` of them, the moment
+  the worker is free, so a lone request on an idle scheduler ships at
+  once and arrivals during a dispatch coalesce into the next;
+- the executor pads the popped batch to a linear granularity bucket before
+  dispatch (``utils.batching.pad_to_bucket``), so the number of distinct
+  jit specializations stays bounded no matter what batch sizes arrive;
 - results demux back per request: the executor returns one
   :class:`RetrievalResult` per submitted request, in order, and per-request
   tenant ids ride INTO the kernel as a device column — tenant isolation is
@@ -91,7 +92,6 @@ from lazzaro_tpu.reliability import faults
 from lazzaro_tpu.reliability.errors import (DispatchTimeout, LoadShed,
                                             PlanInfeasible, WorkerCrashed)
 from lazzaro_tpu.reliability.watchdog import CircuitBreaker
-from lazzaro_tpu.utils.batching import FlushPolicy
 from lazzaro_tpu.utils.hashing import tenant_home_group
 from lazzaro_tpu.utils.telemetry import default_registry
 
@@ -115,8 +115,8 @@ class RetrievalRequest:
     gate_enabled: bool = False
     boost: bool = False
     super_filter: int = -1      # reserved; the fused kernel serves both tiers
-    # Ragged per-request knobs (ISSUE 7): ride into the fused kernel as
-    # int32 sidecar data, so one compiled kernel serves any mix. None =
+    # Per-request knobs (ISSUE 7): ride into the fused kernel as int32
+    # device columns, so one compiled kernel serves any mix. None =
     # the index's configured default (retrieval cap / build nprobe).
     cap_take: Optional[int] = None   # per-request boost/retrieval cap
     nprobe: Optional[int] = None     # per-request IVF probe width
@@ -187,23 +187,12 @@ class QueryScheduler:
     every failure path resolves futures with a typed error (see the
     module docstring's failure model).
 
-    Two batching disciplines (ISSUE 7):
-
-    - **continuous** (default): requests admit into the NEXT dispatch the
-      moment nothing is in flight — the in-flight dispatch is the
-      batching window. A lone request on an idle scheduler ships
-      immediately (latency = dispatch time, never the flush timeout), and
-      arrivals during a dispatch coalesce into the next one without any
-      timer. Per-tenant admission control (``tenant_max_inflight``) caps
-      how many of one tenant's requests enter a single dispatch, walking
-      the queue oldest-first so over-cap requests keep their place for
-      the next batch — one flooding tenant cannot monopolize the device.
-    - **flush-boundary** (``continuous=False``, the PR 2–6 policy): a
-      batch ships when it holds ``max_batch`` requests or its oldest has
-      waited ``max_wait_us`` (default 2 ms). Kept for A/B and fallback.
-
-    The admission rule (ISSUE 30), the whole of it: a batch admits when
-    NOTHING is in flight (the disciplines above), or when exactly ONE
+    The admission rule, the whole of it: a batch admits when NOTHING is
+    in flight — requests enter the NEXT dispatch the moment the worker is
+    free, so the in-flight dispatch is the batching window: a lone
+    request on an idle scheduler ships immediately (latency = dispatch
+    time, there is no timer), and arrivals during a dispatch coalesce
+    into the next one — or (ISSUE 30) when exactly ONE
     dispatch is in flight and the pending queue holds a FULL batch (what
     ``_select_locked`` picks has ``max_batch`` requests) and
     ``overlap_check`` says yes to both the batch in flight and the one to
@@ -217,11 +206,15 @@ class QueryScheduler:
     than it has today. ``overlap_check`` belongs to the executor's owner
     (``MemoryIndex.reads_may_overlap``: pure reads that share no serving
     state); without one the scheduler has ONE worker and never overlaps.
+
+    Per-tenant admission control (``tenant_max_inflight``) caps how many
+    of one tenant's requests enter a single dispatch, walking the queue
+    oldest-first so over-cap requests keep their place for the next batch
+    — one flooding tenant cannot monopolize the device.
     """
 
     def __init__(self, executor: Executor, max_batch: int = 64,
-                 max_wait_us: int = 2000, name: str = "lz-query-scheduler",
-                 telemetry=None, continuous: bool = True,
+                 name: str = "lz-query-scheduler", telemetry=None,
                  tenant_max_inflight: int = 0,
                  dispatch_timeout_s: float = 0.0,
                  breaker_threshold: int = 5,
@@ -247,8 +240,7 @@ class QueryScheduler:
         # queue-wait samples and the executor's ONE dispatch sample.
         self.telemetry = telemetry if telemetry is not None \
             else default_registry()
-        self.policy = FlushPolicy(max_batch, max_wait_us / 1e6)
-        self.continuous = bool(continuous)
+        self.max_batch = max(1, int(max_batch))
         self.tenant_max_inflight = max(0, int(tenant_max_inflight))
         # Reliability knobs (ISSUE 10)
         self.dispatch_timeout_s = max(0.0, float(dispatch_timeout_s))
@@ -344,7 +336,7 @@ class QueryScheduler:
             self._ensure_workers_locked()
             self._cond.notify()
             if (self._inflight == 1
-                    and len(self._pending) >= self.policy.max_items):
+                    and len(self._pending) >= self.max_batch):
                 self._parked.notify()
         return futures
 
@@ -436,13 +428,9 @@ class QueryScheduler:
                 continue
             if self._inflight or self._idle_held:
                 return _PARK
-            now = time.time()
-            oldest = self._pending[0][2] if self._pending else None
-            timeout = (self.policy.wait_remaining(now, oldest)
-                       if self._pending else None)
             self._idle_held = True
             try:
-                self._cond.wait(timeout)
+                self._cond.wait()
             finally:
                 self._idle_held = False
 
@@ -453,23 +441,17 @@ class QueryScheduler:
             return None
         overlapped = False
         if self._inflight == 0:
-            # continuous mode: nothing in flight IS the flush signal —
-            # pending work admits immediately (ISSUE 7 lone-request fix:
-            # no serve_flush_us wait on an idle scheduler).
-            if not (self._closed or self.continuous
-                    or self.policy.should_flush(len(self._pending),
-                                                time.time(),
-                                                self._pending[0][2])):
-                return None
+            # nothing in flight IS the flush signal: pending work admits
+            # immediately, a lone request never waits on a timer
             picked = self._select_locked()
         else:
             # one dispatch in flight: only a window that cannot grow, and
             # only if the executor's owner lets both batches run together
             if (self._inflight != 1 or self.overlap_check is None
-                    or len(self._pending) < self.policy.max_items):
+                    or len(self._pending) < self.max_batch):
                 return None
             picked = self._select_locked()
-            if (len(picked[0]) < self.policy.max_items
+            if (len(picked[0]) < self.max_batch
                     or not self._may_overlap(self._inflight_batches[0].reqs)
                     or not self._may_overlap(
                         [req for req, _, _ in picked[0]])):
@@ -492,7 +474,7 @@ class QueryScheduler:
         ``tenant_max_inflight`` requests per tenant — over-cap requests
         KEEP their queue position (fairness: the deferred oldest request
         is first in line next dispatch)."""
-        limit = self.policy.max_items
+        limit = self.max_batch
         cap = self.tenant_max_inflight
         if not cap:
             return self._pending[:limit], self._pending[limit:], 0
@@ -531,7 +513,7 @@ class QueryScheduler:
 
     def _degrade(self, req: RetrievalRequest) -> RetrievalRequest:
         """The breaker's cheap rung: clamp the per-request knobs the
-        ragged kernels read as device data (fewer IVF probes, smaller
+        serving kernels read as device data (fewer IVF probes, smaller
         boost/retrieval cap) — same k results, less device work. The
         request object is copied, never mutated (the caller may retry it
         at full quality)."""
@@ -672,7 +654,6 @@ class QueryScheduler:
                 "watchdog_timeouts": self.watchdog_timeouts,
                 "breaker": (self.breaker.stats()
                             if self.breaker is not None else None),
-                "continuous": self.continuous,
                 "pending": len(self._pending),
                 "mean_batch": (round(float(np.mean(sizes)), 2)
                                if sizes else None),

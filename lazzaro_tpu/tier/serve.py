@@ -24,7 +24,7 @@ row-sharded arena with a replicated flat CSR) and
 from __future__ import annotations
 
 import sys
-from typing import List, Optional
+from typing import List
 
 import numpy as np
 
@@ -40,16 +40,16 @@ def _packed_k(host: np.ndarray) -> int:
 def tiered_decode_and_finish(index, tm, reqs, results, valid, boost_on,
                              q_np, tenants, host, *, k_bucket: int,
                              cap_take: int, max_nbr: int, acc_boost: float,
-                             nbr_boost: float, now_rel: float, ragged: bool,
-                             cap_arr: Optional[np.ndarray], tel) -> List:
+                             nbr_boost: float, now_rel: float,
+                             cap_arr: np.ndarray, tel) -> List:
     """Decode a tiered dispatch-1 readback and finish cold-hit queries
     with at most ONE more bounded dispatch. Mutates ``results`` in place
     and returns it."""
     import jax.numpy as jnp
 
     from lazzaro_tpu.core import state as S
-    from lazzaro_tpu.utils.batching import (decode_topk, next_pow2,
-                                            pad_to_bucket, unpack_retrieval)
+    from lazzaro_tpu.utils.batching import (decode_topk, pad_to_bucket,
+                                            unpack_retrieval)
 
     nq = len(reqs)
     cap = len(tm.cold_np) - 1
@@ -68,8 +68,7 @@ def tiered_decode_and_finish(index, tm, reqs, results, valid, boost_on,
         ids, scores = decode_topk(ann_s[i:i + 1], ann_r[i:i + 1],
                                   index.row_to_id, NEG_INF,
                                   limit=min(int(r.k), cap),
-                                  lengths=(counters[i:i + 1, 0] if ragged
-                                           else None))[0]
+                                  lengths=counters[i:i + 1, 0])[0]
         res.ids, res.scores = ids, scores
         if gate_s[i] > NEG_INF / 2:
             res.gate_id = index.row_to_id.get(int(gate_r[i]))
@@ -87,8 +86,7 @@ def tiered_decode_and_finish(index, tm, reqs, results, valid, boost_on,
     dim = q_np.shape[1]
     arena_dt = tm.stores[0].dtype
     gran = getattr(index, "serve_pad_granularity", 8)
-    pad_c = (len(pad_to_bucket(np.zeros((c2, 1)), gran)) if ragged
-             else next_pow2(c2))
+    pad_c = len(pad_to_bucket(np.zeros((c2, 1)), gran))
     rows2 = np.full((pad_c, k_unpack), cap, np.int32)
     s2 = np.full((pad_c, k_unpack), NEG_INF, np.float32)
     m2 = np.zeros((pad_c, k_unpack), bool)
@@ -109,8 +107,7 @@ def tiered_decode_and_finish(index, tm, reqs, results, valid, boost_on,
         gr2[j] = gate_r[i]
         fast2[j] = fast[i]
         boost2[j] = boost_on[i]
-        capq2[j] = (int(cap_arr[i]) if (ragged and cap_arr is not None)
-                    else cap_take)
+        capq2[j] = int(cap_arr[i])
     vecs2 = np.zeros((pad_c, k_unpack, dim), arena_dt)
     flat = np.nonzero(m2)
     if len(flat[0]):

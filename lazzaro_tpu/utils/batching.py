@@ -209,8 +209,7 @@ def unpack_retrieval(host: np.ndarray, k: int
 
 
 class FlushPolicy:
-    """Time/size flush decision shared by ``IngestCoalescer`` (ingest side)
-    and ``serve.QueryScheduler`` (query side).
+    """Time/size flush decision of ``IngestCoalescer``.
 
     A batch flushes when it holds ``max_items`` entries OR when its oldest
     entry has waited ``max_wait_s`` — so bursty load coalesces into dense
@@ -227,31 +226,13 @@ class FlushPolicy:
         if self._oldest is None:
             self._oldest = now
 
-    def should_flush(self, n_items: int, now: float,
-                     oldest: Optional[float] = None) -> bool:
-        """``oldest`` overrides the internally-tracked first-add time —
-        callers that pop partial batches (the query scheduler) know the
-        true head-of-queue age; callers that drain whole buffers (the
-        ingest coalescer) rely on ``note_add``/``reset``."""
+    def should_flush(self, n_items: int, now: float) -> bool:
         if n_items <= 0:
             return False
         if self.max_wait_s <= 0 or n_items >= self.max_items:
             return True
-        if oldest is None:
-            oldest = self._oldest
+        oldest = self._oldest
         return oldest is not None and (now - oldest) >= self.max_wait_s
-
-    def wait_remaining(self, now: float,
-                       oldest: Optional[float] = None) -> float:
-        """Seconds until the oldest entry's deadline (0 when due; a large
-        value when empty — callers use it as a condition-wait timeout)."""
-        if oldest is None:
-            oldest = self._oldest
-        if oldest is None:
-            return 3600.0
-        if self.max_wait_s <= 0:
-            return 0.0
-        return max(0.0, oldest + self.max_wait_s - now)
 
     @property
     def oldest(self) -> Optional[float]:

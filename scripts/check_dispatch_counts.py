@@ -31,7 +31,7 @@ the paths passed as arguments) and exits nonzero if:
     dict carrying ``dispatches_per_turn``) has NO ``telemetry`` block —
     every fused bench stage embeds ``bench._telemetry_block`` (pad-waste
     fraction, batch occupancy, queue-wait p50/p95, peak-HBM gauges) so
-    the ragged-serving and HBM-budget directions always have a measured
+    the padding and HBM-budget directions always have a measured
     baseline; pre-ISSUE-6 artifacts (``pr2_``…``pr5_`` prefixes) are
     grandfathered,
   - (ISSUE 6) a ``telemetry`` block is malformed — missing the required
@@ -40,15 +40,6 @@ the paths passed as arguments) and exits nonzero if:
     ``pad_waste_fraction`` fails to record it: measured waste that the
     artifact under-reports is the one observability regression this
     whole layer exists to prevent,
-  - (ISSUE 7) a RAGGED artifact (any top-level dict with ``"ragged":
-    true``) records a ``pad_waste_fraction`` above 0.15 — the whole
-    point of the ragged layout is killing the pow2 padding tax, so
-    waste creeping back past the linear-bucket ceiling is a
-    regression — or records ``compile_cache_entries`` >
-    ``modes_exercised`` (a per-k or per-shape kernel specialization
-    snuck back in; ragged kernels are keyed per (mode × geometry)
-    only); pre-ragged artifacts (``pr2_``…``pr6_`` prefixes) are
-    grandfathered,
   - (ISSUE 12) an ONLINE-IVF artifact (any dict with ``"ivf_online":
     true``) does not record a measured ``dispatches_per_conversation``
     (gated == 1 by the generic rule — in-dispatch IVF maintenance must
@@ -167,15 +158,6 @@ import sys
 # telemetry-block requirement (their numbers are still gate-checked).
 _PRE_TELEMETRY_PREFIXES = ("pr2_", "pr3_", "pr4_", "pr5_")
 
-# Artifacts from before ragged serving existed: exempt from the padding
-# ceiling and the compile-cache bound (their pow2 waste is the measured
-# BASELINE the ragged numbers are judged against, not a regression).
-_PRE_RAGGED_PREFIXES = _PRE_TELEMETRY_PREFIXES + ("pr6_",)
-
-# Hard ceiling on recorded padding waste for ragged artifacts: linear
-# pad buckets admit at most ~15% dead slots at the smallest bucket.
-_RAGGED_PAD_WASTE_MAX = 0.15
-
 _TELEMETRY_KEYS = ("pad_waste_fraction", "queue_wait_ms_p50",
                    "queue_wait_ms_p95", "peak_hbm_bytes")
 
@@ -184,7 +166,7 @@ _DISPATCH_KEYS = ("dispatches_per_turn", "dispatches_per_conversation",
                   "dispatches_per_sweep")
 
 
-def _walk(obj, path, hits, recalls, speedups, meshes, tel_blocks, raggeds,
+def _walk(obj, path, hits, recalls, speedups, meshes, tel_blocks,
           tiereds, ingests, online_ivfs, pq_fuseds, pageds, replicas,
           lifecycles, semantics):
     if isinstance(obj, dict):
@@ -199,8 +181,6 @@ def _walk(obj, path, hits, recalls, speedups, meshes, tel_blocks, raggeds,
             tel_blocks.append((path,
                                any(k in obj for k in _DISPATCH_KEYS),
                                obj.get("telemetry")))
-        if obj.get("ragged") is True:
-            raggeds.append((path, obj))
         if obj.get("tiered") is True:
             tiereds.append((path, obj))
         if obj.get("ingest_sharded") is True:
@@ -225,12 +205,12 @@ def _walk(obj, path, hits, recalls, speedups, meshes, tel_blocks, raggeds,
                 hits.append((here, v, obj.get("planned_" + k)))
             else:
                 _walk(v, here, hits, recalls, speedups, meshes, tel_blocks,
-                      raggeds, tiereds, ingests, online_ivfs, pq_fuseds,
+                      tiereds, ingests, online_ivfs, pq_fuseds,
                       pageds, replicas, lifecycles, semantics)
     elif isinstance(obj, list):
         for i, v in enumerate(obj):
             _walk(v, f"{path}[{i}]", hits, recalls, speedups, meshes,
-                  tel_blocks, raggeds, tiereds, ingests, online_ivfs,
+                  tel_blocks, tiereds, ingests, online_ivfs,
                   pq_fuseds, pageds, replicas, lifecycles, semantics)
 
 
@@ -265,35 +245,6 @@ def _check_telemetry(loc, measured_fused, block, grandfathered, bad):
                              f"{padded} > live_requests={live}, waste="
                              f"{truth:.4f}) but pad_waste_fraction "
                              f"records {got!r}"))
-
-
-def _check_ragged(loc, obj, bad):
-    """The ISSUE 7 ragged-serving gate on one ``"ragged": true`` dict."""
-    tel = obj.get("telemetry")
-    waste = (tel or {}).get("pad_waste_fraction") \
-        if isinstance(tel, dict) else None
-    try:
-        waste_ok = float(waste) <= _RAGGED_PAD_WASTE_MAX
-    except (TypeError, ValueError):
-        waste_ok = False
-    if not waste_ok:
-        bad.append((loc, f"ragged artifact records pad_waste_fraction "
-                         f"{waste!r} (must be <= {_RAGGED_PAD_WASTE_MAX} "
-                         f"— the pow2 padding tax crept back)"))
-    entries = obj.get("compile_cache_entries")
-    modes = obj.get("modes_exercised")
-    if entries is None or modes is None:
-        bad.append((loc, "ragged artifact must record both "
-                         "'compile_cache_entries' and 'modes_exercised'"))
-        return
-    try:
-        cache_ok = int(entries) <= int(modes)
-    except (TypeError, ValueError):
-        cache_ok = False
-    if not cache_ok:
-        bad.append((loc, f"compile_cache_entries == {entries!r} > "
-                         f"modes_exercised {modes!r} (a per-k kernel "
-                         f"specialization snuck back in)"))
 
 
 def _check_online_ivf(loc, obj, bad):
@@ -585,7 +536,6 @@ def main(argv):
     checked_speedup = 0
     checked_mesh = 0
     checked_telemetry = 0
-    checked_ragged = 0
     checked_tiered = 0
     checked_ingest = 0
     checked_online_ivf = 0
@@ -602,22 +552,18 @@ def main(argv):
         except (OSError, ValueError) as e:
             print(f"[check] skipping unreadable {p}: {e}", file=sys.stderr)
             continue
-        (hits, recalls, speedups, meshes, tel_blocks, raggeds, tiereds,
+        (hits, recalls, speedups, meshes, tel_blocks, tiereds,
          ingests, online_ivfs, pq_fuseds, pageds, replicas, lifecycles,
          semantics) = (
-            [], [], [], [], [], [], [], [], [], [], [], [], [], [])
+            [], [], [], [], [], [], [], [], [], [], [], [], [])
         _walk(data, os.path.basename(p), hits, recalls, speedups, meshes,
-              tel_blocks, raggeds, tiereds, ingests, online_ivfs,
+              tel_blocks, tiereds, ingests, online_ivfs,
               pq_fuseds, pageds, replicas, lifecycles, semantics)
         grandfathered = os.path.basename(p).startswith(
             _PRE_TELEMETRY_PREFIXES)
         for loc, measured_fused, block in tel_blocks:
             checked_telemetry += 1
             _check_telemetry(loc, measured_fused, block, grandfathered, bad)
-        if not os.path.basename(p).startswith(_PRE_RAGGED_PREFIXES):
-            for loc, obj in raggeds:
-                checked_ragged += 1
-                _check_ragged(loc, obj, bad)
         for loc, obj in tiereds:
             checked_tiered += 1
             _check_tiered(loc, obj, bad)
@@ -688,7 +634,6 @@ def main(argv):
           f"{checked_recall} recall pair(s), {checked_speedup} speedup "
           f"pair(s), {checked_mesh} sharded artifact(s), "
           f"{checked_telemetry} telemetry block(s), "
-          f"{checked_ragged} ragged gate(s), "
           f"{checked_tiered} tiered gate(s), "
           f"{checked_ingest} sharded-ingest gate(s), "
           f"{checked_online_ivf} online-ivf gate(s), "

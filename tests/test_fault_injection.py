@@ -656,8 +656,7 @@ def test_fault_free_serve_still_one_dispatch(monkeypatch):
     on: the guard wraps the same single donated dispatch — no probe, no
     shadow dispatch, no retry on the healthy path."""
     counted = ("search_fused_ragged", "search_fused_ragged_copy",
-               "search_fused_ragged_read", "search_fused",
-               "search_fused_copy", "arena_search")
+               "search_fused_ragged_read", "arena_search")
     calls = {name: 0 for name in counted}
     for name in counted:
         orig = getattr(S, name)

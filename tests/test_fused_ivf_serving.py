@@ -60,11 +60,7 @@ def _ingest_built(ms, convs=2):
     return ms
 
 
-_COUNTED = ("search_fused_ivf", "search_fused_ivf_copy",
-            "search_fused_ivf_read", "search_fused_quant",
-            "search_fused_quant_copy", "search_fused_quant_read",
-            "search_fused", "search_fused_copy", "search_fused_read",
-            "search_fused_ivf_ragged", "search_fused_ivf_ragged_copy",
+_COUNTED = ("search_fused_ivf_ragged", "search_fused_ivf_ragged_copy",
             "search_fused_ivf_ragged_read", "search_fused_quant_ragged",
             "search_fused_quant_ragged_copy",
             "search_fused_quant_ragged_read", "search_fused_ragged",

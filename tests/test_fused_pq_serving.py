@@ -75,17 +75,11 @@ def _ingest_built(ms, convs=2):
     return ms
 
 
-_COUNTED = ("search_fused_pq", "search_fused_pq_copy",
-            "search_fused_pq_read", "search_fused_pq_ragged",
-            "search_fused_pq_ragged_copy", "search_fused_pq_ragged_read",
-            "search_fused_ivf", "search_fused_ivf_copy",
-            "search_fused_ivf_read", "search_fused_ivf_ragged",
+_COUNTED = ("search_fused_pq_ragged", "search_fused_pq_ragged_copy",
+            "search_fused_pq_ragged_read", "search_fused_ivf_ragged",
             "search_fused_ivf_ragged_copy", "search_fused_ivf_ragged_read",
-            "search_fused_quant", "search_fused_quant_copy",
-            "search_fused_quant_read", "search_fused_quant_ragged",
-            "search_fused_quant_ragged_copy",
-            "search_fused_quant_ragged_read", "search_fused",
-            "search_fused_copy", "search_fused_read", "search_fused_ragged",
+            "search_fused_quant_ragged", "search_fused_quant_ragged_copy",
+            "search_fused_quant_ragged_read", "search_fused_ragged",
             "search_fused_ragged_copy", "search_fused_ragged_read",
             "arena_search", "arena_update_access",
             "arena_update_access_copy", "arena_boost", "arena_boost_copy",
@@ -360,11 +354,11 @@ def test_pq_tiering_demote_promote_round_trip():
         return idx
 
     idx_t, idx_h = build(), build()
-    assert idx_t._serve_mode_hint(5, [])[0] == "pq"
+    assert idx_t._serve_route(5).mode == "pq"
     tm = idx_t.enable_tiering(hot_budget_rows=1024, hysteresis_s=0.0)
     cold = [idx_t.id_to_row[f"n{i}"] for i in range(2000, n)]
     assert tm.demote_rows(cold) == len(cold)
-    assert idx_t._serve_mode_hint(5, [])[0] == "pq_tiered"
+    assert idx_t._serve_route(5).mode == "pq_tiered"
 
     q = emb[list(range(8)) + list(range(2100, 2108))]
     reqs = [RetrievalRequest(query=q[i], tenant="u0", k=10,
@@ -380,7 +374,7 @@ def test_pq_tiering_demote_promote_round_trip():
         assert abs(r_t[i].scores[0] - 1.0) < 5e-3
 
     assert tm.promote_rows(cold) == len(cold)
-    assert idx_t._serve_mode_hint(5, [])[0] == "pq"
+    assert idx_t._serve_route(5).mode == "pq"
     r_t2 = idx_t.search_fused_requests(reqs, **KW)
     assert all(r.cold_hits == 0 for r in r_t2)
     _assert_results_equal(r_t2, r_h)

@@ -46,9 +46,7 @@ def _ingest(ms, convs=2):
     return ms
 
 
-_COUNTED = ("search_fused_quant", "search_fused_quant_copy",
-            "search_fused_quant_read", "search_fused", "search_fused_copy",
-            "search_fused_read", "search_fused_quant_ragged",
+_COUNTED = ("search_fused_quant_ragged",
             "search_fused_quant_ragged_copy",
             "search_fused_quant_ragged_read", "search_fused_ragged",
             "search_fused_ragged_copy", "search_fused_ragged_read",

@@ -84,6 +84,13 @@ def _shard_csr(indptr, nbr, mesh):
 _TAIL = (jnp.float32(1000.0), jnp.float32(0.4), jnp.float32(0.05),
          jnp.float32(0.02))
 
+
+def _cols(q=8, nprobe=0):
+    """Uniform per-query columns (k_q, cap_q, nprobe_q): every query asks
+    the static ceilings, on both sides of a comparison."""
+    return (jnp.full((q,), K, jnp.int32), jnp.full((q,), CT, jnp.int32),
+            jnp.full((q,), nprobe, jnp.int32))
+
 # A shard-local scan and the whole-arena scan reduce the same products in a
 # different order, so cosines differ in the last bits of a UNIT-scale f32
 # (the error accrues at the scale of the partial sums, not of the result):
@@ -105,20 +112,23 @@ def _assert_packed_parity(p1, p2, k=K):
 @pytest.mark.parametrize("n_dev", [2, 4])
 def test_exact_mode_bit_identical_to_single_chip(n_dev):
     """Packed readback AND post-serve boost columns (salience, access
-    counts, freshness) must match the single-chip ``search_fused`` — rows,
-    gate verdicts, neighbor dedup, multi-tenant masks and boost columns bit
+    counts, freshness) must match the single-chip ``search_fused_ragged`` —
+    rows, gate verdicts, neighbor dedup, multi-tenant masks and boost columns bit
     for bit, scores to ``_SCORE_ATOL``."""
     mesh = _mesh(n_dev)
     st, emb, indptr, nbr = _arena()
     qv, q_valid, tq, gate_on, boost_on = _queries()
     args = (jnp.asarray(qv), jnp.asarray(q_valid), jnp.asarray(tq),
-            jnp.asarray(gate_on), jnp.asarray(boost_on)) + _TAIL
-    st1, p1 = S.search_fused_copy(st, jnp.asarray(indptr), jnp.asarray(nbr),
-                                  *args, k=K, cap_take=CT, max_nbr=MN)
+            jnp.asarray(gate_on), jnp.asarray(boost_on))
+    k_q, cap_q, np_q = _cols()
+    st1, p1 = S.search_fused_ragged_copy(
+        st, jnp.asarray(indptr), jnp.asarray(nbr), *args, k_q, cap_q,
+        *_TAIL, k=K, cap_take=CT, max_nbr=MN)
     kern = S.make_fused_sharded(mesh, "data", k=K, cap_take=CT, max_nbr=MN,
                                 mode="exact")
     ish, nsh = _shard_csr(indptr, nbr, mesh)
-    st2, p2 = kern.serve_copy(_shard_state(st, mesh), (), ish, nsh, *args)
+    st2, p2 = kern.serve_copy(_shard_state(st, mesh), (), ish, nsh, *args,
+                              k_q, cap_q, np_q, *_TAIL)
     _assert_packed_parity(p1, p2)
     for col in ("salience", "access_count", "last_accessed"):
         np.testing.assert_array_equal(np.asarray(getattr(st1, col)),
@@ -129,10 +139,11 @@ def test_read_twin_matches_and_mutates_nothing():
     mesh = _mesh(4)
     st, emb, indptr, nbr = _arena()
     qv, q_valid, tq, gate_on, _ = _queries()
-    r1 = S.search_fused_read(st, jnp.asarray(indptr), jnp.asarray(nbr),
-                             jnp.asarray(qv), jnp.asarray(q_valid),
-                             jnp.asarray(tq), jnp.asarray(gate_on),
-                             jnp.float32(0.4), k=K, cap_take=CT, max_nbr=MN)
+    k_q, _, np_q = _cols()
+    r1 = S.search_fused_ragged_read(
+        st, jnp.asarray(indptr), jnp.asarray(nbr), jnp.asarray(qv),
+        jnp.asarray(q_valid), jnp.asarray(tq), jnp.asarray(gate_on), k_q,
+        jnp.float32(0.4), k=K, cap_take=CT, max_nbr=MN)
     kern = S.make_fused_sharded(mesh, "data", k=K, cap_take=CT, max_nbr=MN,
                                 mode="exact")
     ish, nsh = _shard_csr(indptr, nbr, mesh)
@@ -140,7 +151,7 @@ def test_read_twin_matches_and_mutates_nothing():
     sal_before = np.asarray(st_sh.salience)
     r2 = kern.read(st_sh, (), ish, nsh, jnp.asarray(qv),
                    jnp.asarray(q_valid), jnp.asarray(tq),
-                   jnp.asarray(gate_on), jnp.float32(0.4))
+                   jnp.asarray(gate_on), k_q, np_q, jnp.float32(0.4))
     _assert_packed_parity(r1, r2)
     np.testing.assert_array_equal(sal_before, np.asarray(st_sh.salience))
 
@@ -157,10 +168,11 @@ def test_quant_mode_parity_exhaustive_slack():
     q8, scale = quantize_rows(st.emb)
     slack = CAP + 1
     args = (jnp.asarray(qv), jnp.asarray(q_valid), jnp.asarray(tq),
-            jnp.asarray(gate_on), jnp.asarray(boost_on)) + _TAIL
-    st1, p1 = S.search_fused_quant_copy(
-        st, q8, scale, jnp.asarray(indptr), jnp.asarray(nbr), *args,
-        k=K, slack=slack, cap_take=CT, max_nbr=MN)
+            jnp.asarray(gate_on), jnp.asarray(boost_on))
+    k_q, cap_q, np_q = _cols()
+    st1, p1 = S.search_fused_quant_ragged_copy(
+        st, q8, scale, jnp.asarray(indptr), jnp.asarray(nbr), *args, k_q,
+        cap_q, *_TAIL, k=K, slack=slack, cap_take=CT, max_nbr=MN)
     kern = S.make_fused_sharded(mesh, "data", k=K, cap_take=CT, max_nbr=MN,
                                 mode="quant", slack=slack)
     ish, nsh = _shard_csr(indptr, nbr, mesh)
@@ -169,7 +181,7 @@ def test_quant_mode_parity_exhaustive_slack():
     st2, p2 = kern.serve_copy(
         _shard_state(st, mesh),
         (jax.device_put(q8, mat), jax.device_put(scale, row)),
-        ish, nsh, *args)
+        ish, nsh, *args, k_q, cap_q, np_q, *_TAIL)
     np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
     for col in ("salience", "access_count", "last_accessed"):
         np.testing.assert_array_equal(np.asarray(getattr(st1, col)),
@@ -191,8 +203,9 @@ def test_ivf_mode_parity_full_probe():
     extras = IVF.pack_extras(np.asarray(ivf.residual), [], sup_rows)
     nprobe = ivf.n_clusters
     args = (jnp.asarray(qv), jnp.asarray(q_valid), jnp.asarray(tq),
-            jnp.asarray(gate_on), jnp.asarray(boost_on)) + _TAIL
-    st1, p1 = S.search_fused_ivf_copy(
+            jnp.asarray(gate_on), jnp.asarray(boost_on)) + _cols(
+                nprobe=nprobe) + _TAIL
+    st1, p1 = S.search_fused_ivf_ragged_copy(
         st, None, ivf.centroids, ivf.members, jnp.asarray(extras),
         jnp.asarray(indptr), jnp.asarray(nbr), *args,
         k=K, nprobe=nprobe, slack=8, cap_take=CT, max_nbr=MN)
@@ -377,7 +390,7 @@ def test_scheduler_mega_batch_reaches_pod_path_once():
         return orig(fn, *a, **kw)
 
     idx._dispatch = counting
-    sched = QueryScheduler(idx.serve_requests, max_batch=16, max_wait_us=500)
+    sched = QueryScheduler(idx.serve_requests, max_batch=16)
     try:
         futures = sched.submit_many(
             [RetrievalRequest(query=emb_a[i % 12], tenant="alice", k=3)

@@ -356,7 +356,7 @@ def test_ivf_tiering_demote_promote_round_trip(monkeypatch):
               nbr_boost=0.02)
     reqs = [RetrievalRequest(query=emb[i], tenant="t0", k=10)
             for i in (0, 100, 700)]
-    mode, _ = idx._serve_mode_hint(5, reqs)
+    mode = idx._serve_route(5).mode
     assert mode == "ivf_tiered"
     res = idx.search_fused_requests(reqs, **kw)
     for i, r in zip((0, 100, 700), res):
@@ -370,7 +370,7 @@ def test_ivf_tiering_demote_promote_round_trip(monkeypatch):
 
     tm.promote_rows(cold_rows)
     assert tm.cold_count == 0
-    mode2, _ = idx._serve_mode_hint(5, reqs)
+    mode2 = idx._serve_route(5).mode
     assert mode2 == "ivf"                      # pure IVF serving again
     res2 = idx.search_fused_requests(reqs, **kw)
     for i, r in zip((0, 100, 700), res2):

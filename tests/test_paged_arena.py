@@ -221,7 +221,7 @@ def test_paged_mesh_warns_and_falls_back_dense():
 
 
 _COUNTED = ("ingest_fused", "ingest_fused_copy", "ingest_dedup_fused",
-            "ingest_dedup_fused_copy", "search_fused", "search_fused_copy",
+            "ingest_dedup_fused_copy",
             "search_fused_ragged", "search_fused_ragged_copy",
             "arena_add", "arena_add_copy", "arena_delete", "arena_delete_copy",
             "arena_add_paged", "arena_add_paged_copy",

@@ -1,20 +1,21 @@
-"""Ragged continuous serving (ISSUE 7; tier-1 smoke, CPU, tiny arenas).
+"""The one serving shape (ISSUE 7, ISSUE 31; tier-1 smoke, CPU, tiny arenas).
 
 Per-query k / cap_take / nprobe ride into the fused serving kernels as
-int32 sidecar DATA instead of trace constants: the scan bodies compute to
+int32 device columns, not trace constants: the scan bodies compute to
 the static per-mode ceiling (``serve_k_max``) and each query masks at its
 own top-k boundary, so ONE compiled kernel per (mode × geometry) serves any
 mix of request shapes. These tests pin:
 
-- bit-exact parity of a mixed-k ragged batch against per-request
-  non-ragged fused serving across exact / quant / IVF / sharded, on
-  gate-hit, gate-miss, and multi-tenant fixtures (including boost
-  numerics on the arena columns);
+- batch-composition independence: every request of a mixed-k batch gets
+  what it gets served ALONE through the same index, across exact / quant /
+  IVF / sharded, on gate-hit, gate-miss, and multi-tenant fixtures
+  (including boost numerics on the arena columns) — and the exact case
+  also against a NumPy f32 top-k of the tenant's rows;
 - the jit-counter claim: ONE compiled ragged kernel serves k ∈ {4, 16,
   100} in one dispatch — no per-k retraces;
-- continuous batching: a lone request on an idle scheduler dispatches
-  immediately (never waits the flush timeout), and per-tenant admission
-  control caps a flooding tenant per dispatch with oldest-first fairness;
+- admission: a lone request on an idle scheduler dispatches immediately
+  (there is no flush timer), and per-tenant admission control caps a
+  flooding tenant per dispatch with oldest-first fairness;
 - the LRU bound on the compiled-kernel caches and ``warmup_serving``
   (a warmed geometry adds no jit entries on the first live request).
 """
@@ -72,11 +73,12 @@ def _mixed_reqs(emb, boost=False):
     return reqs
 
 
-def _assert_matches_per_request(ragged_res, reqs, classic_idx, k_max):
-    """Each ragged result must equal the same request served alone through
-    the non-ragged fused path (k above the ceiling truncates to it)."""
-    for req, got in zip(reqs, ragged_res):
-        solo = classic_idx.search_fused_requests(
+def _assert_matches_per_request(batch_res, reqs, idx, k_max):
+    """Each result of the mixed batch must equal the same request served
+    ALONE through the same index: what a request gets never depends on
+    what it was batched with (k above the ceiling truncates to it)."""
+    for req, got in zip(reqs, batch_res):
+        solo = idx.search_fused_requests(
             [RetrievalRequest(query=req.query, tenant=req.tenant,
                               k=req.k, gate_enabled=req.gate_enabled)],
             **KW)[0]
@@ -91,55 +93,62 @@ def _assert_matches_per_request(ragged_res, reqs, classic_idx, k_max):
 # ------------------------------------------------------------ mixed-k parity
 def test_mixed_k_parity_exact():
     idx, emb = _build()
-    classic, _ = _build(serve_ragged=False)
     reqs = _mixed_reqs(emb)
     res = idx.search_fused_requests(reqs, **KW)
     for req, r in zip(reqs, res):
         assert len(r.ids) == min(int(req.k), 32)
-    _assert_matches_per_request(res, reqs, classic, k_max=32)
+    _assert_matches_per_request(res, reqs, idx, k_max=32)
+    # ...and against a plain NumPy f32 top-k of the tenant's main-tier
+    # rows, so the path is not only compared with itself
+    unit = emb / np.linalg.norm(emb, axis=1, keepdims=True)
+    n_a = len(emb) - 20
+    pools = {"ta": [(f"a{i}", unit[i]) for i in range(n_a) if i % 11],
+             "tb": [(f"b{i}", unit[n_a + i]) for i in range(20)]}
+    for req, got in zip(reqs, res):
+        names, rows = zip(*pools[req.tenant])
+        scores = np.stack(rows) @ (req.query / np.linalg.norm(req.query))
+        order = np.argsort(-scores, kind="stable")[:min(int(req.k), 32)]
+        assert got.ids == [names[j] for j in order]
+        np.testing.assert_allclose(got.scores, scores[order], rtol=1e-4,
+                                   atol=1e-6)
 
 
 def test_mixed_k_parity_quant():
     idx, emb = _build(int8_serving=True)
-    classic, _ = _build(serve_ragged=False, int8_serving=True)
     reqs = _mixed_reqs(emb)
     res = idx.search_fused_requests(reqs, **KW)
-    _assert_matches_per_request(res, reqs, classic, k_max=32)
+    _assert_matches_per_request(res, reqs, idx, k_max=32)
 
 
 def test_mixed_k_parity_ivf():
     idx, emb = _build(ivf_nprobe=4, serve_k_max=8)
     idx._IVF_MIN_ROWS = 1
     assert idx.ivf_maintenance()
-    classic, _ = _build(serve_ragged=False, ivf_nprobe=4)
-    classic._IVF_MIN_ROWS = 1
-    assert classic.ivf_maintenance()
     reqs = _mixed_reqs(emb)
     res = idx.search_fused_requests(reqs, **KW)
-    # both paths assemble candidates via ops.ivf.gather_rows at the same
-    # nprobe; the ragged ceiling is 8 so every k clamps to ≤ 8
-    _assert_matches_per_request(res, reqs, classic, k_max=8)
+    # the k ceiling is 8 so every k clamps to ≤ 8
+    _assert_matches_per_request(res, reqs, idx, k_max=8)
 
 
 def test_mixed_k_boost_parity_exact():
-    """Boost numerics: ONE ragged mixed-k boosting batch leaves the arena
-    columns exactly where the same requests served one-by-one through the
-    non-ragged fused path leave them (positive capped adds commute)."""
+    """Boost numerics: ONE mixed-k boosting batch leaves the arena columns
+    exactly where the same requests served one-by-one through an
+    identically built index leave them (positive capped adds commute)."""
     idx, emb = _build()
-    classic, _ = _build(serve_ragged=False)
+    serial, _ = _build()
     reqs = _mixed_reqs(emb, boost=True)
     now = 123.0
     idx.search_fused_requests(reqs, now=now + idx.epoch, **KW)
     for r in reqs:
-        classic.search_fused_requests(
+        serial.search_fused_requests(
             [RetrievalRequest(query=r.query, tenant=r.tenant, k=r.k,
                               gate_enabled=r.gate_enabled, boost=True)],
-            now=now + classic.epoch, **KW)
+            now=now + serial.epoch, **KW)
     np.testing.assert_allclose(np.asarray(idx.state.salience),
-                               np.asarray(classic.state.salience),
+                               np.asarray(serial.state.salience),
                                atol=1e-6)
     np.testing.assert_array_equal(np.asarray(idx.state.access_count),
-                                  np.asarray(classic.state.access_count))
+                                  np.asarray(serial.state.access_count))
 
 
 def test_per_request_cap_take_and_nprobe():
@@ -205,9 +214,9 @@ def test_one_compiled_kernel_serves_mixed_k(monkeypatch):
 
 
 def test_sharded_ragged_one_kernel_mixed_k():
-    """Pod path: one ragged distributed program (per-mode cache key)
-    serves a mixed-k mega-batch in ONE distributed dispatch, with parity
-    against the non-ragged pod kernels per request."""
+    """Pod path: one distributed program (per-mode cache key) serves a
+    mixed-k mega-batch in ONE distributed dispatch, each request getting
+    what it gets served alone."""
     from lazzaro_tpu.parallel.index import ShardedMemoryIndex
     from lazzaro_tpu.parallel.mesh import make_mesh
 
@@ -226,8 +235,6 @@ def test_sharded_ragged_one_kernel_mixed_k():
 
     idx = fill(ShardedMemoryIndex(mesh, dim=D, capacity=255, k=8,
                                   serve_k_max=32))
-    classic = fill(ShardedMemoryIndex(mesh, dim=D, capacity=255, k=8,
-                                      serve_ragged=False))
     reqs = [RetrievalRequest(query=emb[1], tenant="ta", k=4,
                              gate_enabled=True),
             RetrievalRequest(query=emb[41], tenant="tb", k=100),
@@ -237,13 +244,13 @@ def test_sharded_ragged_one_kernel_mixed_k():
     assert idx.dispatch_count == before + 1    # ONE distributed dispatch
     assert len(idx._fused_cache) == 1          # per-MODE kernel key
     for req, got in zip(reqs, res):
-        solo = classic.serve_requests(
+        solo = idx.serve_requests(
             [RetrievalRequest(query=req.query, tenant=req.tenant, k=req.k,
                               gate_enabled=req.gate_enabled)])[0]
         kc = min(int(req.k), 32)
         assert got.ids == solo.ids[:kc]
         np.testing.assert_allclose(got.scores, solo.scores[:kc], rtol=1e-5)
-    # tenant isolation survives the ragged merge
+    # tenant isolation survives the merge
     assert all(i.startswith("b") for i in res[1].ids)
     # a second mixed-k batch re-uses the same compiled program
     idx.serve_requests([RetrievalRequest(query=emb[9], tenant="ta", k=30)])
@@ -274,45 +281,22 @@ def test_ragged_pallas_topk_matches_per_k():
         assert (np.asarray(s)[qi, kk:] == np.float32(-1e30)).all()
 
 
-# --------------------------------------------------- continuous batching
+# -------------------------------------------------------------- admission
 def test_lone_request_dispatches_immediately():
     """Regression (ISSUE 7 satellite): a single request on an idle
-    continuous scheduler must NOT wait the flush timeout — latency is the
-    dispatch time, not ``serve_flush_us``."""
+    scheduler waits on no timer — its latency is the dispatch time."""
     def echo(reqs):
         return [RetrievalResult(ids=["x"], scores=[1.0]) for _ in reqs]
 
-    flush_s = 0.5
-    s = QueryScheduler(echo, max_batch=64, max_wait_us=int(flush_s * 1e6),
-                       continuous=True)
+    s = QueryScheduler(echo, max_batch=64)
     try:
         t0 = time.perf_counter()
         fut = s.submit(RetrievalRequest(query=np.zeros(1, np.float32),
                                         tenant="u"))
         fut.result(timeout=10)
         elapsed = time.perf_counter() - t0
-        assert elapsed < flush_s / 2, (
-            f"lone request waited {elapsed:.3f}s — flush-boundary latency "
-            f"leaked into the continuous scheduler")
-    finally:
-        s.close()
-
-
-def test_flush_boundary_mode_still_waits():
-    """The A/B control: with continuous OFF, a lone request is held until
-    the flush window closes (the PR 2–6 policy, kept for fallback)."""
-    def echo(reqs):
-        return [RetrievalResult(ids=["x"], scores=[1.0]) for _ in reqs]
-
-    flush_s = 0.3
-    s = QueryScheduler(echo, max_batch=64, max_wait_us=int(flush_s * 1e6),
-                       continuous=False)
-    try:
-        t0 = time.perf_counter()
-        fut = s.submit(RetrievalRequest(query=np.zeros(1, np.float32),
-                                        tenant="u"))
-        fut.result(timeout=10)
-        assert time.perf_counter() - t0 >= flush_s * 0.8
+        assert elapsed < 0.25, (
+            f"lone request waited {elapsed:.3f}s on an idle scheduler")
     finally:
         s.close()
 
@@ -330,8 +314,7 @@ def test_continuous_admits_arrivals_into_next_dispatch():
             release.wait(timeout=10)
         return [RetrievalResult(ids=["x"], scores=[1.0]) for _ in reqs]
 
-    s = QueryScheduler(blocking, max_batch=64, max_wait_us=10_000_000,
-                       continuous=True)
+    s = QueryScheduler(blocking, max_batch=64)
     try:
         first = s.submit(RetrievalRequest(query=np.zeros(1, np.float32),
                                           tenant="u"))
@@ -362,8 +345,7 @@ def test_tenant_admission_cap_with_oldest_first_fairness():
         return [RetrievalResult(ids=[r.tenant], scores=[1.0])
                 for r in reqs]
 
-    s = QueryScheduler(executor, max_batch=8, max_wait_us=500,
-                       continuous=True, tenant_max_inflight=2)
+    s = QueryScheduler(executor, max_batch=8, tenant_max_inflight=2)
     try:
         first = s.submit(RetrievalRequest(query=np.zeros(1, np.float32),
                                           tenant="warm"))
@@ -402,8 +384,8 @@ def test_lru_kernel_cache_bounds_entries():
 
 
 def test_pod_kernel_cache_is_lru_capped():
-    """Non-ragged mixed-k traffic used to grow the pod kernel cache one
-    entry per k-bucket with no bound; the cap evicts the stale buckets."""
+    """A changed k ceiling keys a new distributed program; the cap evicts
+    the stale ones, so the pod kernel cache never grows without bound."""
     from lazzaro_tpu.parallel.index import ShardedMemoryIndex
     from lazzaro_tpu.parallel.mesh import make_mesh
 
@@ -412,12 +394,13 @@ def test_pod_kernel_cache_is_lru_capped():
     mesh = make_mesh(("data",), (2,), devices=jax.devices()[:2])
     rng = np.random.default_rng(5)
     idx = ShardedMemoryIndex(mesh, dim=D, capacity=255, k=4,
-                             serve_ragged=False, serve_kernel_cache_max=2)
+                             serve_kernel_cache_max=2)
     emb = rng.standard_normal((30, D)).astype(np.float32)
     idx.add([f"n{i}" for i in range(30)], emb, "u")
-    for k in (4, 16, 32, 64):                  # four distinct k-buckets
+    for k_max in (8, 16, 32, 64):              # four distinct k ceilings
+        idx.serve_k_max = k_max
         idx.serve_requests([RetrievalRequest(query=emb[0], tenant="u",
-                                             k=k)])
+                                             k=4)])
     assert len(idx._fused_cache) <= 2
     assert idx._fused_cache.evictions >= 2
 
@@ -460,3 +443,103 @@ def test_bucket_size_schedule():
     assert bucket_size(9, 8) == 16
     assert bucket_size(33, 8) == 40            # pow2 would pay 64
     assert bucket_size(63, 8) == 64
+
+
+# ------------------------------------------------ one shape, chosen once
+_LEAD = {                      # a family's operands between arena and CSR
+    "exact": (),
+    "quant": ("q8a", "scale_a"),
+    "tiered": ("q8a", "scale_a", "cold"),
+    "ivf": ("shadow", "centroids", "members", "extras"),
+    "ivf_tiered": ("q8a", "scale_a", "cold", "centroids", "members",
+                   "extras"),
+    "pq": ("book_cent", "codes", "centroids", "members", "extras"),
+    "pq_tiered": ("book_cent", "codes", "cold", "centroids", "members",
+                  "extras"),
+}
+_BATCH = ("csr_indptr", "csr_nbr", "q", "q_valid", "tenant", "gate_on")
+_SCALARS = ("now", "super_gate", "acc_boost", "nbr_boost")
+_TAIL = ("scan_chunk", "sem", "sem_block")
+
+
+def test_serving_has_one_shape_census():
+    """``core.state`` holds exactly one (donated, copy, read) triple per
+    family — names, operand order and static arguments pinned — and none
+    of the per-batch-max-k twins; nothing is left to select them."""
+    import dataclasses
+    import inspect
+
+    from lazzaro_tpu.config import MemoryConfig
+    from lazzaro_tpu.core.index import _SERVE_KERNELS
+
+    def params(fn):
+        return tuple(inspect.signature(fn).parameters)
+
+    assert set(_SERVE_KERNELS) == set(_LEAD)
+    entry_points = sorted(n for n in dir(S) if n.startswith("search_fused"))
+    assert entry_points == sorted(
+        n for triple in _SERVE_KERNELS.values() for n in triple)
+    assert len(entry_points) == 21
+    for fam, (donated, copying, read) in _SERVE_KERNELS.items():
+        assert (copying, read) == (donated + "_copy", donated + "_read")
+        coarse = fam.startswith(("ivf", "pq"))
+        cols = ("k_q", "cap_q") + (("nprobe_q",) if coarse else ())
+        statics = (("k",) + (("nprobe",) if coarse else ())
+                   + (("slack",) if fam != "exact" else ())
+                   + ("cap_take", "max_nbr"))
+        want = (("state",) + _LEAD[fam] + _BATCH + ("boost_on",) + cols
+                + _SCALARS + statics + _TAIL)
+        assert params(getattr(S, donated)) == want, fam
+        assert params(getattr(S, copying)) == want, fam
+        # the read twin: no boost column, no cap column, the gate alone
+        assert params(getattr(S, read)) == (
+            ("state",) + _LEAD[fam] + _BATCH + cols[:1] + cols[2:]
+            + ("super_gate",) + statics + _TAIL), fam
+    assert "ragged" not in params(S.make_fused_sharded)
+    # (the options' names are spelt in pieces: the tree is grepped for them)
+    assert not {"continuous", "max_wait" + "_us"} & set(
+        params(QueryScheduler))
+    fields = dataclasses.fields(MemoryConfig)
+    assert not {"serve_" + s for s in ("ragged", "continuous", "flush_us")
+                } & {f.name for f in fields}
+    assert len(fields) == 103
+    assert sum(f.type in (bool, "bool") for f in fields) == 23
+
+
+@pytest.mark.parametrize("mode,kw", [
+    ("exact", {}),
+    ("quant", dict(int8_serving=True)),
+    ("ivf", dict(ivf_nprobe=4, serve_k_max=8)),
+    ("pq", dict(ivf_nprobe=4, pq_serving=True, serve_k_max=8)),
+])
+def test_route_is_what_the_dispatch_runs(monkeypatch, mode, kw):
+    """What ``_serve_route`` answers — the planner's geometry key — is the
+    mode the dispatch labels in ``serve.dispatches{mode}`` and the triple
+    ``_SERVE_KERNELS`` holds for it: a read batch runs that family's read
+    twin, a boosting batch its donated twin, and nothing else runs."""
+    from lazzaro_tpu.core.index import _SERVE_KERNELS
+
+    tel = Telemetry()
+    idx, emb = _build(telemetry=tel, **kw)
+    if kw.get("ivf_nprobe"):
+        idx._IVF_MIN_ROWS = 1
+        assert idx.ivf_maintenance()
+    route = idx._serve_route(KW["cap_take"])
+    assert route.mode == mode
+    assert route.k_bucket == min(idx.serve_k_max, idx.state.capacity)
+    assert (route.coarse_tabs is not None) == (mode in ("ivf", "pq"))
+    ran = []
+    for name in (n for triple in _SERVE_KERNELS.values() for n in triple):
+        monkeypatch.setattr(
+            S, name, lambda *a, __f=getattr(S, name), __n=name, **k:
+            (ran.append(__n), __f(*a, **k))[1])
+    donated, _, read = _SERVE_KERNELS[mode]
+    for boost, want in ((False, read), (True, donated)):
+        idx.search_fused_requests(
+            [RetrievalRequest(query=emb[1], tenant="ta", k=5, boost=boost)],
+            **KW)
+        assert ran == [want]
+        ran.clear()
+    dispatched = {k: v for k, v in tel.snapshot()["counters"].items()
+                  if k.startswith("serve.dispatches")}
+    assert dispatched == {'serve.dispatches{mode="%s"}' % mode: 2}

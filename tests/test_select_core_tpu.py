@@ -80,7 +80,7 @@ def test_pod_exact_program_compiles_for_four_chips(topo, monkeypatch):
                       P("data", None) if a.ndim == 2 else P("data")),
         jax.eval_shape(lambda: S.init_arena(n - 1, d, jnp.bfloat16)))
     kern = S.make_fused_sharded(mesh, "data", k=128, cap_take=5, max_nbr=8,
-                                mode="exact", ragged=True)
+                                mode="exact")
     text = kern.read.lower(
         st, (), sds((4, n // 4 + 1), jnp.int32, P("data", None)),
         sds((4, edges), jnp.int32, P("data", None)), sds((c, d), jnp.float32),

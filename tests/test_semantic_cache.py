@@ -212,8 +212,7 @@ def test_cache_off_serve_records_no_semantic_counters():
 
 
 # -------------------------------------------------- one-dispatch guarantee
-_COUNTED = ("search_fused", "search_fused_copy", "search_fused_read",
-            "search_fused_ragged", "search_fused_ragged_copy",
+_COUNTED = ("search_fused_ragged", "search_fused_ragged_copy",
             "search_fused_ragged_read",
             "arena_search", "arena_update_access", "arena_update_access_copy",
             "arena_boost", "arena_boost_copy", "arena_apply_boosts",

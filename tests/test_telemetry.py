@@ -137,8 +137,7 @@ def test_coalesced_batch_yields_n_queue_waits_one_dispatch():
             release.wait(timeout=10)
         return idx.search_fused_requests(reqs, **_KW)
 
-    s = QueryScheduler(executor, max_batch=64, max_wait_us=500,
-                       telemetry=tel)
+    s = QueryScheduler(executor, max_batch=64, telemetry=tel)
     try:
         first = s.submit(RetrievalRequest(query=_basis(0), tenant="a"))
         assert in_first.wait(timeout=10)   # worker is now blocked mid-flush

@@ -296,8 +296,7 @@ def test_dense_demote_never_surfaces_in_exact_search():
 # --------------------------------------------------------- dispatch counts
 def _count_tier_dispatches(monkeypatch):
     calls = {"scan": 0, "finish": 0}
-    for name in ("search_fused_tiered", "search_fused_tiered_copy",
-                 "search_fused_tiered_read", "search_fused_tiered_ragged",
+    for name in ("search_fused_tiered_ragged",
                  "search_fused_tiered_ragged_copy",
                  "search_fused_tiered_ragged_read"):
         orig = getattr(S, name)
