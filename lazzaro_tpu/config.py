@@ -155,7 +155,9 @@ class MemoryConfig:
     # lone request on an idle scheduler dispatches immediately, and
     # requests arriving while a dispatch is in flight coalesce into the
     # next one (the in-flight dispatch IS the batching window; a FULL
-    # window of pure reads is admitted over a dispatch of pure reads).
+    # window of pure reads is admitted over a dispatch of pure reads; a
+    # window that is not full is held, for at most half a dispatch's time,
+    # while callers the last demux released are on their way back).
     serve_batch_max: int = 64
     # Per-tenant admission control: at most this
     # many of one tenant's requests are admitted into a single dispatch

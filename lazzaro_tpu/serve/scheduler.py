@@ -17,7 +17,17 @@ the way Ragged Paged Attention coalesces ragged decode work on TPU:
 - there is no flush timer: the dispatch in flight is the batching window.
   Pending requests are admitted, at most ``max_batch`` of them, the moment
   the worker is free, so a lone request on an idle scheduler ships at
-  once and arrivals during a dispatch coalesce into the next;
+  once and arrivals during a dispatch coalesce into the next — except
+  that a free worker HOLDS a window that is not full while callers its
+  last demux released are still expected back (ISSUE 32): callers that
+  block in ``Future.result()`` re-submit within a millisecond or two of
+  their answers, and shipping the first of them alone costs the others a
+  whole dispatch. The hold ends when the window fills, when everyone
+  expected is back, or ``HOLD_FRACTION`` of one dispatch's time after
+  that demux, whichever is first; everything it reads (how long a
+  dispatch takes, who blocks, who comes back) the scheduler observes
+  itself, so callers that do not wait are never held for and callers
+  that do not return are held for once (see :class:`QueryScheduler`);
 - the executor pads the popped batch to a linear granularity bucket before
   dispatch (``utils.batching.pad_to_bucket``), so the number of distinct
   jit specializations stays bounded no matter what batch sizes arrive;
@@ -159,7 +169,37 @@ def _set_future(fut: Future, res) -> None:
         pass            # watchdog already failed it — late result discarded
 
 
+class _CallerFuture(Future):
+    """A request's future that knows its callers: the thread that
+    submitted it, and the thread blocked on it. At demux the scheduler
+    notes the threads that wait in ``result()`` — they are released by the
+    answer and, in a closed loop, on their way back. A caller that took
+    ``add_done_callback`` instead never counts."""
+
+    waiter = 0              # ident of the thread blocked in result(), or 0
+
+    def __init__(self, caller: int):
+        super().__init__()
+        self.caller = caller            # ident of the thread that submitted
+
+    def result(self, timeout=None):
+        self.waiter = threading.get_ident()
+        try:
+            return super().result(timeout)
+        finally:
+            self.waiter = 0
+
+
 _Item = Tuple[RetrievalRequest, Future, float]      # (request, future, enqueued)
+
+# The hold's bound, as a share of what a dispatch takes (the scheduler's own
+# running estimate): nobody waits longer than this past the demux that
+# released the callers being waited for. On the chip (fill.serve, PERF.md
+# section 6, PR 32) the executor call takes 14.7 ms, the demux of 64 answers
+# 1.1 ms, and the last of the 64 callers is back 3.6 ms after it (4.6 ms at
+# the 95th percentile): half a dispatch, 7.4 ms, leaves that room, and a
+# companion saves a whole dispatch, so the price is at most half of the gain.
+HOLD_FRACTION = 0.5
 
 
 @dataclass(eq=False)
@@ -169,13 +209,15 @@ class _Batch:
     items: List[_Item]
     overlapped: bool            # admitted while another was in flight
     seq: int                    # admission order: the profiler's step number
+    held_s: float = 0.0         # how long a free worker held its window open
 
     @property
     def reqs(self) -> List[RetrievalRequest]:
         return [req for req, _, _ in self.items]
 
 
-_PARK = object()        # _next_batch_locked: come back as a parked worker
+# _next_batch_locked: come back parked / come back through the hold
+_PARK, _HOLD = object(), object()
 
 
 class QueryScheduler:
@@ -192,9 +234,11 @@ class QueryScheduler:
     free, so the in-flight dispatch is the batching window: a lone
     request on an idle scheduler ships immediately (latency = dispatch
     time, there is no timer), and arrivals during a dispatch coalesce
-    into the next one — or (ISSUE 30) when exactly ONE
-    dispatch is in flight and the pending queue holds a FULL batch (what
-    ``_select_locked`` picks has ``max_batch`` requests) and
+    into the next one — unless (ISSUE 32) the window is NOT full and
+    callers released by a recent demux are still expected back: then the
+    free worker holds the window open (below) — or (ISSUE 30) when exactly
+    ONE dispatch is in flight and the pending queue holds a FULL batch
+    (what ``_select_locked`` picks has ``max_batch`` requests) and
     ``overlap_check`` says yes to both the batch in flight and the one to
     admit. A window that is full cannot grow: keeping it closed until the
     other dispatch returns only idles the device, so its host path (pack,
@@ -206,6 +250,59 @@ class QueryScheduler:
     than it has today. ``overlap_check`` belongs to the executor's owner
     (``MemoryIndex.reads_may_overlap``: pure reads that share no serving
     state); without one the scheduler has ONE worker and never overlaps.
+
+    The hold (ISSUE 32). Callers that block in ``result()`` come back
+    together: a demux of N answers is followed, a millisecond or two
+    later, by N submissions. Admitting the first s of them the instant
+    they arrive cuts the loop into (s, N - s), and every such split is a
+    stable cycle — when the N - s return, the s are in flight or already
+    waiting, and nothing about the age of the oldest pending request or
+    the time since the last arrival tells the two apart — so half of the
+    dispatches carry a fraction of the callers. What does tell them apart
+    is who was released and who is back, and that the scheduler sees:
+
+    - T, what a dispatch takes: the executor call's wall time, an estimate
+      that falls to a faster sample at once and rises by an eighth of a
+      slower one's excess (a compile or a stall never sets the bound);
+      the bound is ``HOLD_FRACTION`` * T past the last demux that released
+      a waiting caller;
+    - who waits: ``submit_many`` hands out :class:`_CallerFuture`, and at
+      demux the threads blocked in ``result()`` on the batch's futures are
+      the callers it releases — unless a thread has submitted other
+      requests that are still pending or in flight (a
+      ``search_memories_batch`` larger than a batch): that caller is not
+      on its way back, it is waiting for the rest; callbacks never count;
+    - who comes back: a released caller whose next submission arrives
+      inside the bound is a returner from then on — learned whether or not
+      a hold was in force, so there is no hold until returns have been
+      seen — and one that is still out when the bound ends is not, until
+      it returns inside a bound again: callers that have left, or that
+      think for longer than the bound, are held for once. It is kept per
+      caller and not as one share r of a batch, because a fleet is a mix:
+      the prompt callers of a batch are waited for and the thinkers beside
+      them are not (one r sits between the two, so it holds every window
+      the thinkers' answers open, to the bound, and cuts the prompt
+      callers' window short at r of them).
+
+    Expected back are the returners among the callers the recent demuxes
+    released that have not submitted since; nobody once the bound has
+    passed. While somebody is expected, nothing is in flight and the
+    window is not full, ``_try_admit_locked`` admits nothing and the free
+    worker waits on the condition (``_hold_locked``: a timed wait; the
+    submit path wakes it only when the window fills or the last expected
+    caller is back) and then admits as ever. An EMPTY window is held the
+    same way from the demux on when two or more callers are expected, so
+    that the first one back does not ship alone. From any split the loop
+    heals at the next demux: the released callers are expected, so the
+    pending ones wait for them. A lone request on an idle scheduler meets
+    no hold (nobody was released, or its own return is the one arrival
+    that was expected, and one caller has nobody to wait for), and a
+    fleet of more callers than a batch holds meets none either (two
+    batches' worth keep one dispatch in flight and a full window behind
+    it; where nothing is in flight for a moment, the window ships as it
+    is and the others make the next: holding it would only idle the
+    device); ``close()`` and ``flush()`` end a hold at once; a held
+    request is a pending request like any other.
 
     Per-tenant admission control (``tenant_max_inflight``) caps how many
     of one tenant's requests enter a single dispatch, walking the queue
@@ -257,7 +354,22 @@ class QueryScheduler:
         # in flight: woken only when a full batch waits behind ONE dispatch
         # (and on close), so a submit costs it nothing otherwise
         self._parked = threading.Condition(self._cond)
-        self._idle_held = False              # a worker is in its idle wait
+        # the one free worker is in its idle wait / in a hold: the other
+        # parks, and a submit wakes a holding worker only to end the hold
+        self._idle_held = False
+        self._holding = False
+        # what the hold reads (ISSUE 32; class docstring), all observed
+        self._dispatch_s = 0.0               # T: what a dispatch takes
+        # callers (thread idents) whose last release was followed by their
+        # next request inside the bound: the ones worth holding a window for
+        self._returners: set = set()
+        # callers the recent demuxes released that are not back yet -> when
+        # each one's bound ends (monotonic; oldest first): the returners
+        # among them (expected back), and the others (watched)
+        self._expected: Dict[int, float] = {}
+        self._watched: Dict[int, float] = {}
+        self._held_s = 0.0                   # held since the last admission
+        self._flushing = 0                   # flush() callers: they end a hold
         self._pending: List[_Item] = []
         self._pending_bytes = 0
         self._inflight_batches: List[_Batch] = []    # never more than two
@@ -297,7 +409,8 @@ class QueryScheduler:
         immediately with :class:`LoadShed` — the futures API is uniform,
         so callers see the typed error at ``.result()`` like any other
         failure."""
-        futures = [Future() for _ in requests]
+        caller = threading.get_ident()
+        futures: List[Future] = [_CallerFuture(caller) for _ in requests]
         now = time.time()
         if self.admission_check is not None and requests:
             try:
@@ -334,7 +447,12 @@ class QueryScheduler:
                 self._pending.append((req, fut, now))
             self._pending_bytes += nbytes
             self._ensure_workers_locked()
-            self._cond.notify()
+            self._note_return_locked(caller)
+            # a worker that holds its window open is woken only when the
+            # hold is over: a wake-up per submission would hand it the
+            # interpreter after the first caller is back
+            if not (self._holding and self._hold_left_locked() > 0):
+                self._cond.notify()
             if (self._inflight == 1
                     and len(self._pending) >= self.max_batch):
                 self._parked.notify()
@@ -376,12 +494,18 @@ class QueryScheduler:
 
     def _serve_loop(self) -> None:
         while True:
+            # a hold comes first, and is a span of its own: what the worker
+            # waits out for callers on their way back is not its idle time
+            with self._cond:
+                self._hold_locked()
             # the wait of a worker that would admit whatever came, up to
             # the batch being admitted: the window less these spans is the
             # time a worker was busy. A worker with nothing to admit while
             # a dispatch is in flight parks OUTSIDE the span.
             with self.telemetry.span("sched.idle"), self._cond:
                 batch = self._next_batch_locked(parked=False)
+            if batch is _HOLD:
+                continue
             if batch is _PARK:
                 with self._cond:
                     batch = self._next_batch_locked(parked=True)
@@ -412,11 +536,13 @@ class QueryScheduler:
         """Wait (caller holds the lock) until the admission rule lets this
         worker take a batch; None on a clean close. A worker in its idle
         wait (``parked=False``) that finds a dispatch in flight and
-        nothing it may admit over it — or the other worker idle already —
+        nothing it may admit over it — or the other worker free already —
         returns ``_PARK`` and comes back parked: it then sleeps until a
         full batch waits behind one dispatch. The worker that finishes a
         dispatch always looks at the queue itself, so a parked one is
-        never needed for anything else."""
+        never needed for anything else. One that finds what is pending
+        refused by the hold returns ``_HOLD`` and comes back through
+        ``_hold_locked``."""
         while True:
             batch = self._try_admit_locked()
             if batch is not None:
@@ -426,8 +552,10 @@ class QueryScheduler:
             if parked:
                 self._parked.wait()
                 continue
-            if self._inflight or self._idle_held:
+            if self._inflight or self._idle_held or self._holding:
                 return _PARK
+            if self._pending:       # nothing in flight: only the hold refuses
+                return _HOLD
             self._idle_held = True
             try:
                 self._cond.wait()
@@ -442,7 +570,10 @@ class QueryScheduler:
         overlapped = False
         if self._inflight == 0:
             # nothing in flight IS the flush signal: pending work admits
-            # immediately, a lone request never waits on a timer
+            # immediately, a lone request never waits on a timer — unless
+            # the window can still grow by callers known to be coming back
+            if self._hold_left_locked() > 0:
+                return None
             picked = self._select_locked()
         else:
             # one dispatch in flight: only a window that cannot grow, and
@@ -458,6 +589,99 @@ class QueryScheduler:
                 return None
             overlapped = True
         return self._admit_locked(*picked, overlapped)
+
+    def _hold_left_locked(self) -> float:
+        """The hold, as a predicate (class docstring, "The hold"; caller
+        holds the lock; it changes nothing but to forget callers whose
+        bound is over): for how many seconds more a free worker keeps its
+        window open, 0.0 = admit. It stands while nothing is in flight and
+        somebody is still expected back (so the bound past the last demux
+        that released such a caller still runs) whom the hold can bring
+        together with another request — an empty window is held for two
+        callers or more, so the first one back does not ship alone, but
+        not for ONE, who has nobody to wait for — and all of whom the
+        window ``_select_locked`` would pick still has room for: where it
+        has not (the window is full, or more callers wait than a batch
+        holds) the rest make the next window whatever this one does, and
+        waiting would only idle the device."""
+        if self._inflight or self._closed or self._flushing:
+            return 0.0
+        self._expire_locked()
+        expected = len(self._expected)
+        if not expected:
+            return 0.0
+        window = len(self._select_locked()[0])
+        if (window + expected > self.max_batch
+                or expected < max(1, 2 - window)):
+            return 0.0
+        return next(reversed(self._expected.values())) - time.monotonic()
+
+    def _hold_locked(self) -> None:
+        """The hold itself (caller holds the lock): while it stands, wait
+        on the condition — timed, so the bound ends it; a submission wakes
+        this worker only when it ends it sooner (``submit_many``), as
+        ``close()`` and ``flush()`` do. Opens its span, and counts as held
+        time, only if there is something to wait for."""
+        left = self._hold_left_locked()
+        if left <= 0 or self._idle_held or self._holding:
+            return
+        began = time.perf_counter()
+        self._holding = True
+        try:
+            with self.telemetry.span("sched.hold"):
+                while left > 0:
+                    self._cond.wait(left)
+                    left = self._hold_left_locked()
+        finally:
+            self._holding = False
+            self._held_s += time.perf_counter() - began
+
+    def _note_return_locked(self, caller: int) -> None:
+        """A submission by the thread ``caller``: if a recent demux
+        released it and its bound still runs, it is back, and known to
+        come back."""
+        self._expire_locked()
+        self._expected.pop(caller, None)
+        if self._watched.pop(caller, None) is not None:
+            self._returners.add(caller)
+
+    def _expire_locked(self) -> None:
+        """A caller still out when its bound has run out did not come
+        back inside it: no window is held for that caller again until it
+        has. (Each caller has its own bound's end, so a scheduler that is
+        never quiet — a demux every few milliseconds — forgets the callers
+        that left all the same.)"""
+        now = time.monotonic()
+        for out in (self._expected, self._watched):
+            while out:
+                caller = next(iter(out))
+                if out[caller] > now:
+                    break
+                del out[caller]
+                self._returners.discard(caller)
+
+    def _note_demux_locked(self, batch: "_Batch", took_s: float) -> None:
+        """A dispatch came back and its answers are about to be handed
+        out: ``took_s`` is T's next sample, and the threads blocked on the
+        batch's futures are released now — those that are not waiting for
+        more: a thread with requests of its own still pending or in the
+        other dispatch goes on waiting, it is not on its way back."""
+        t = self._dispatch_s
+        self._dispatch_s = (took_s if not t or took_s < t
+                            else t + (min(took_s, 2 * t) - t) / 8)
+        released = {fut.waiter for _, fut, _ in batch.items} - {0}
+        if not released:
+            return
+        released -= {fut.caller for _, fut, _ in self._pending}
+        for other in self._inflight_batches:
+            if other is not batch:
+                released -= {fut.caller for _, fut, _ in other.items}
+        until = time.monotonic() + HOLD_FRACTION * self._dispatch_s
+        for caller in released:
+            out = (self._expected if caller in self._returners
+                   else self._watched)
+            out.pop(caller, None)       # released again: last in line
+            out[caller] = until
 
     def _may_overlap(self, reqs) -> bool:
         try:
@@ -501,7 +725,8 @@ class QueryScheduler:
             self.requests_deferred += deferred
             self.telemetry.bump("serve.admission_deferred", deferred)
         self._dispatch_seq += 1
-        batch = _Batch(items, overlapped, self._dispatch_seq)
+        batch = _Batch(items, overlapped, self._dispatch_seq, self._held_s)
+        self._held_s = 0.0
         self._inflight_batches.append(batch)
         return batch
 
@@ -573,7 +798,9 @@ class QueryScheduler:
             # the whole executor call, on the thread that runs it: every
             # such span contains at least its own pass over the arena.
             with StepTraceAnnotation("lz.serve.batch", step_num=batch.seq):
+                began = time.perf_counter()
                 results = self._executor(reqs)
+                took_s = time.perf_counter() - began
         except Exception as e:                      # noqa: BLE001 — demuxed
             if timer is not None:
                 timer.cancel()
@@ -597,6 +824,7 @@ class QueryScheduler:
             self.batch_sizes.append(len(items))
             if len(self.batch_sizes) > 1024:
                 del self.batch_sizes[:512]
+            self._note_demux_locked(batch, took_s)
         self.telemetry.bump("serve.requests", len(items))
         self.telemetry.bump("serve.batches")
         # summed over the served requests, so it divides by serve.requests
@@ -607,6 +835,11 @@ class QueryScheduler:
         # a batch admitted while another was in flight (a bump of 0 is
         # dropped: a run that never overlaps has no such entry)
         self.telemetry.bump("serve.overlapped_batches", int(batch.overlapped))
+        # a batch admitted after a free worker held its window open for
+        # callers on their way back, and for how long (the same: no hold
+        # anywhere, no entry)
+        self.telemetry.bump("serve.held_batches", int(batch.held_s > 0))
+        self.telemetry.bump("serve.hold_us", int(batch.held_s * 1e6))
         self.telemetry.record("serve.batch_requests", len(items))
         # the callers' done-callbacks run here, on the worker thread
         with self.telemetry.span("sched.demux"):
@@ -624,12 +857,16 @@ class QueryScheduler:
         """Block until everything submitted so far has been executed."""
         deadline = time.time() + timeout
         with self._cond:
-            self._cond.notify()
-            while self._pending or self._inflight:
-                remaining = deadline - time.time()
-                if remaining <= 0:
-                    raise TimeoutError("QueryScheduler.flush timed out")
-                self._cond.wait(min(remaining, 0.05))
+            self._flushing += 1         # ends a hold, and lets none begin
+            self._cond.notify_all()
+            try:
+                while self._pending or self._inflight:
+                    remaining = deadline - time.time()
+                    if remaining <= 0:
+                        raise TimeoutError("QueryScheduler.flush timed out")
+                    self._cond.wait(min(remaining, 0.05))
+            finally:
+                self._flushing -= 1
 
     def close(self) -> None:
         with self._cond:

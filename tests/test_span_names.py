@@ -35,10 +35,12 @@ CONVERSATION = {
     "journal.commit": {"journal.io"},
 }
 DISPATCH = {
-    None: {"sched.idle", "sched.account", "index.pack", "index.stage",
-           "serve.exact", "index.decode", "sched.demux"},
+    None: {"sched.idle", "sched.hold", "sched.account", "index.pack",
+           "index.stage", "serve.exact", "index.decode", "sched.demux"},
     "serve.exact": {"dispatch.launch", "dispatch.readback"},
 }
+# a worker's two waits: which of them a dispatch opens is the callers' doing
+WAITS = {"sched.idle", "sched.hold"}
 # the names accepted metrics select by prefix (index.host_p50_ms.lat,
 # kernel.ingest_dev_ms): a new span under them would redefine the metric
 ACCEPTED = {"serve.exact", "ingest.dedup_fused"}
@@ -136,7 +138,8 @@ def test_one_dispatch_opens_at_most_twelve_spans(system, opened):
         time.sleep(0.005)
     mine = [e for e in opened if e[0] != "MainThread"]
     assert len({t for t, *_ in mine}) == 1              # the one worker
-    assert _tree(mine) == DISPATCH
+    # one sequential caller: released, back, nobody else expected — no hold
+    assert _tree(mine) == {**DISPATCH, None: DISPATCH[None] - {"sched.hold"}}
     assert len(mine) == 9 <= 12
     order = [n for _, n, _, _ in mine]
     assert order == ["sched.account", "index.pack", "index.stage", "serve.exact",
@@ -197,8 +200,61 @@ def test_overlapped_dispatch_opens_the_same_spans_on_the_other_worker(
         tree = {}
         for n, parent in spans:
             tree.setdefault(parent, set()).add(n)
-        assert tree == {None: DISPATCH[None] - {"sched.idle"},
+        assert tree == {None: DISPATCH[None] - WAITS,
                         "serve.exact": DISPATCH["serve.exact"]}, t
+
+
+def test_a_hold_is_a_top_level_span_of_the_worker_beside_its_idle_wait(
+        system, opened):
+    """ISSUE 32: two callers that wait, in step. Once both have been seen to
+    come back the worker holds its (empty) window open from the demux on:
+    ``sched.hold`` opens before ``sched.idle``, at the top level and never
+    under the dispatch, and the path of a dispatch keeps its names.
+    The executor takes 0.4 s a batch, so the callers have 0.2 s to return."""
+    from lazzaro_tpu.serve import QueryScheduler
+    _converse(system, "alice", 0)
+
+    def executor(reqs):
+        time.sleep(0.4)
+        return system._serve_requests(reqs)
+
+    req = RetrievalRequest(query=np.ones(D, np.float32), tenant="alice", k=5)
+    system._serve_requests([req] * 2)                   # warm: compiles
+    sched = QueryScheduler(executor, max_batch=4, telemetry=system.telemetry,
+                           name="lz-hold")
+
+    def caller():
+        for _ in range(8):
+            assert sched.submit(req).result(timeout=60).ids
+    threads = [threading.Thread(target=caller) for _ in range(2)]
+    try:
+        del opened[:]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        sched.flush(timeout=60)
+    finally:
+        sched.close()
+    tel = system.telemetry
+    assert tel.counter_total("serve.held_batches") >= 2
+    assert tel.counter_total("serve.hold_us") > 0
+    mine = [e for e in opened if e[0] == "lz-hold"]
+    assert _tree(mine) == DISPATCH
+    for _, name, parent, chain in mine:
+        if name in WAITS:
+            assert parent is None and chain == []
+    # a held batch runs the pinned path: the hold comes first, then the
+    # idle wait, which admits at once, and nothing else moved
+    order = [n for _, n, _, _ in mine]
+    at = order.index("sched.hold")
+    assert order[at - 8:at + 3] == [
+        "sched.account", "index.pack", "index.stage", "serve.exact",
+        "dispatch.launch", "dispatch.readback", "index.decode",
+        "sched.demux", "sched.hold", "sched.idle", "sched.account"]
+    # and no hold opens unless the worker waits in it
+    assert (tel.snapshot()["timers"]["sched.hold_ms"]["count"]
+            == order.count("sched.hold"))
 
 
 def test_no_new_name_falls_under_an_accepted_metrics_prefix():
