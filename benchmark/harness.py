@@ -413,6 +413,8 @@ def _serve_run(run, deploy, work, compiles, trace_dir, reference, cmp,
         if run.trace is not None:
             run.detail["spans"] = tracing.span_study(run.trace,
                                                      "lz.serve.batch")
+            run.detail["dispatch_device_ms"] = tracing.dispatch_study(
+                run.trace, "lz.serve.batch", "lz_select_scan")
     ms.close()
     del ms, submit, plan.requests
     gc.collect()

@@ -32,11 +32,13 @@ def compiles(run) -> float:
 
 
 def device_ms_per_span(run, prefix: str) -> Optional[float]:
-    """Mean device-busy milliseconds inside the host spans ``prefix*``."""
+    """Device-busy milliseconds inside the union of the host spans
+    ``prefix*``, over their number: what one dispatch keeps the device busy,
+    read once per dispatch whether dispatches overlap or not."""
     if run.trace is None:
         return None
-    per = tracing.device_ns_per_span(run.trace, prefix)
-    return sum(per) / len(per) / 1e6 if per else None
+    ns = tracing.device_ns_per_dispatch(run.trace, prefix)
+    return None if ns is None else ns / 1e6
 
 
 def span_minus_child_p50_ms(run, outer: str, inner_prefix: str

@@ -82,6 +82,7 @@ def main(argv=None) -> int:
                 "late_p95": round(d.get("late_ms", {}).get("p95", 0), 3),
                 "batch_p50": d.get("batch_requests", {}).get("p50"),
                 "dispatch_p50": round(d.get("dispatch_ms", {}).get("p50", 0), 3),
+                "dispatch_device_ms": d.get("dispatch_device_ms"),
                 "compared": {k: v["value"] for k, v in res["compared"].items()},
                 "failed": res["failed"], "attempted": res["attempted"],
             }), flush=True)

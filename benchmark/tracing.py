@@ -18,7 +18,7 @@ import glob
 import heapq
 import os
 import re
-from typing import Dict, Iterable, List, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 Event = Tuple[str, float, float]          # name, start_ns, dur_ns
 Interval = Tuple[float, float]            # start_ns, end_ns
@@ -161,14 +161,53 @@ def _first_device_busy(trace: dict, window: Interval) -> Busy:
 
 def device_ns_per_span(trace: dict, prefix: str) -> List[float]:
     """Device-busy nanoseconds inside each host span whose name starts with
-    ``prefix`` (first device plane; a one-chip dispatch ends in a readback,
-    so its operations run inside its span)."""
+    ``prefix`` (first device plane; a dispatch ends in a readback, so its
+    operations run inside its span — and, once two dispatches are open at
+    once, inside the other's too: the metrics read
+    ``device_ns_per_dispatch``, ``span_study`` this)."""
     window = window_of(trace)
     if not trace["devices"]:
         return []
     busy = _first_device_busy(trace, window)
     return [busy.inside(s, s + d) for n, s, d in clip(trace["spans"], window)
             if n.startswith(prefix)]
+
+
+def device_ns_per_dispatch(trace: dict, prefix: str) -> Optional[float]:
+    """Device-busy nanoseconds inside the UNION of the host spans whose name
+    starts with ``prefix``, over the number of those spans (first device
+    plane): a dispatch's device time, read once whether dispatches overlap
+    or not. Where the spans are disjoint the union is their sum, and this is
+    the mean of ``device_ns_per_span``; where two are open at once (PR 30)
+    each of those also counts part of the other's pass. None without a
+    device plane or such a span."""
+    window = window_of(trace)
+    spans = [e for e in clip(trace["spans"], window) if e[0].startswith(prefix)]
+    if not trace["devices"] or not spans:
+        return None
+    busy = _first_device_busy(trace, window)
+    return sum(busy.inside(a, b) for a, b in union(spans)) / len(spans)
+
+
+def dispatch_study(trace: dict, prefix: str, kernel: str) -> dict:
+    """Study aid: one trace's device milliseconds a ``prefix*`` span read
+    three ways — the mean over the spans of the busy time inside each
+    (``device_ns_per_span``), the busy time inside their union over their
+    number (``device_ns_per_dispatch``), and the first plane's operations
+    summed by name over that number, the ``kernel*`` ones apart from the
+    union of the rest."""
+    window = window_of(trace)
+    per = device_ns_per_span(trace, prefix)
+    if not per:
+        return {}
+    ops = clip(next(iter(trace["devices"].values())), window)
+    named = sum(d for name, _, d in ops if name.startswith(kernel))
+    rest = total(union(e for e in ops if not e[0].startswith(kernel)))
+    n = len(per)
+    return {"spans": n, "each_span_ms": sum(per) / n / 1e6,
+            "union_ms": device_ns_per_dispatch(trace, prefix) / 1e6,
+            "by_name_ms": {kernel: named / n / 1e6, "rest": rest / n / 1e6,
+                           "sum": (named + rest) / n / 1e6}}
 
 
 def top_ops(trace: dict, n: int = 10) -> List[List]:

@@ -1,11 +1,15 @@
 """What ``tests/benchmark`` holds a manifest, its cells and its metrics to, as
 functions of a checkout's root. The test files call them on the repository;
 ``test_benchmark_manifest.py`` calls every one again on a temporary checkout
-to which a four-chip cell of a sharded configuration was added with new files
-and appended entries alone, so "files and entries alone" is held to every test
-a later PR's cell will meet. The benchmark's code is the repository's in both
-cases; only ``BENCHMARK.json`` and the files it names are found under
-``root``."""
+to which three cells were added with new files and appended entries alone — a
+four-chip cell of a sharded configuration and a one-chip throughput cell of an
+int8 configuration among them — so "files and entries alone" is held to every
+test a later PR's cell will meet. What a single PR named of the manifest is
+here too (``named_entries``), with "at least" where the contract is "at
+least": an assertion written against the repository alone, with ``==`` on a
+list or a place in it, is what stopped PRs 30 and 32 from appending. The
+benchmark's code is the repository's in both cases; only ``BENCHMARK.json``
+and the files it names are found under ``root``."""
 
 import math
 import os
@@ -165,6 +169,98 @@ def per_layer_metric(e, root):
         assert e["unit"] == "%"
 
 
+# --------------------------- what PRs 29, 30 and 34 named: "at least", by name
+
+# the scheduler's entries, each as the PR that asked for it named it; where
+# in ``per_layer`` an entry stands is nobody's contract
+SCHEDULER_ENTRIES = {
+    name: {"name": name, "unit": unit, "better": better, "source": source,
+           "layer": "scheduler", "moves": moves, "workloads": [cell]}
+    for name, unit, better, source, moves, cell in [
+        ("sched.overlap_pct.qps", "%", "higher", "program_counter",
+         "search_qps", "fill.serve"),
+        ("sched.overlap_pct.pod", "%", "higher", "program_counter",
+         "search_qps", "pod.serve"),
+        ("sched.batch_requests_mean.qps", "count", "higher",
+         "program_counter", "search_qps", "fill.serve"),
+        ("sched.hold_pct.qps", "%", "higher", "program_counter",
+         "search_qps", "fill.serve"),
+        ("sched.hold_pct.lat", "%", "lower", "program_counter",
+         "search_p50_ms", "share.serve"),
+        ("sched.hold_p50_ms.qps", "ms", "lower", "program_span",
+         "search_qps", "fill.serve"),
+    ]}
+
+# PR 29's thirteen metrics of ``pod.serve`` and the five of them that read
+# the program's spans and counters; later PRs append others beside them
+POD_THIRTEEN = (
+    "sched.occupancy_pct.pod", "sched.lone_dispatch_pct.pod",
+    "dispatch.p50_ms.pod", "dispatch.launch_p50_ms.pod",
+    "dispatch.readback_p50_ms.pod", "index.stage_p50_ms.pod",
+    "kernel.serve_dev_ms.pod", "kernel.serve_roofline.pod",
+    "device.idle_pct.pod", "device.compiles.pod", "kernel.merge_dev_ms.pod",
+    "device.skew_pct.pod", "dispatch.copies.pod")
+POD_SPAN_FIVE = {
+    "sched.lone_dispatch_pct.pod", "dispatch.launch_p50_ms.pod",
+    "dispatch.readback_p50_ms.pod", "index.stage_p50_ms.pod",
+    "dispatch.copies.pod"}
+
+
+def entry(root, kind, name):
+    found = [e for e in harness.manifest(root)[kind] if e["name"] == name]
+    assert len(found) == 1, f"{kind} holds {len(found)} entries {name!r}"
+    return found[0]
+
+
+def scheduler_entry(name, root):
+    """The entry is what its PR named, is IN ``per_layer`` (anywhere), reads
+    the program's counters or spans and has its reader file."""
+    e = entry(root, "per_layer", name)
+    assert e == SCHEDULER_ENTRIES[name]
+    per_layer_metric(e, root)
+    assert e in span_metrics(root)
+    assert callable(harness.reader(name, root))
+    return e
+
+
+def throughput_cells(root):
+    """``search_qps`` is reported by AT LEAST ``fill.serve`` and
+    ``pod.serve`` (a later throughput cell appends itself), and by
+    ``pod.serve`` beside ``setup_s`` alone."""
+    cells = entry(root, "end_to_end", "search_qps")["workloads"]
+    assert {"fill.serve", "pod.serve"} <= set(cells)
+    assert len(set(cells)) == len(cells)
+    pod = harness.cell_files("pod.serve", root)[0]
+    assert [m["name"] for m in harness.metrics_of(pod, "end_to_end", root)] \
+        == ["search_qps", "setup_s"]
+
+
+def pod_metrics(root):
+    """``pod.serve``'s own per-layer metrics: AT LEAST PR 29's thirteen, by
+    name; every one ends in ``.pod``, moves ``search_qps``, has a reader and
+    names a layer another cell's metrics name too."""
+    m = harness.manifest(root)
+    mine = [e for e in m["per_layer"] if e.get("workloads") == ["pod.serve"]]
+    assert set(POD_THIRTEEN) <= {e["name"] for e in mine}
+    assert {e["moves"] for e in mine} == {"search_qps"}
+    assert all(e["name"].endswith(".pod") for e in mine)
+    layers = {e["layer"] for e in m["per_layer"]
+              if e.get("workloads") != ["pod.serve"]}
+    assert {e["layer"] for e in mine} <= layers          # no new layer name
+    for e in mine:
+        per_layer_metric(e, root)
+        assert callable(harness.reader(e["name"], root))
+    return mine
+
+
+def named_entries(root):
+    """What single PRs named of the manifest, held for every checkout."""
+    for name in SCHEDULER_ENTRIES:
+        scheduler_entry(name, root)
+    throughput_cells(root)
+    pod_metrics(root)
+
+
 def manifest_wide(root):
     """Every assertion above, on every entry of the root's manifest."""
     m = harness.manifest(root)
@@ -181,6 +277,7 @@ def manifest_wide(root):
     for e in m["per_layer"]:
         per_layer_metric(e, root)
     fourteen_in_manifest(root)
+    named_entries(root)
 
 
 # ------------------------------------------------------ a cell's debug runs

@@ -262,6 +262,60 @@ def test_roofline_share_divides_least_time_by_device_time():
     assert 90 < readers.serve_roofline_pct(run) < 100
 
 
+def _two_dispatches(second_start):
+    """One device plane, two passes of 100 ns back to back (100..200,
+    200..300), and two ``lz.serve.batch`` spans of 150 ns: the first from
+    60, the second from ``second_start``."""
+    return {"devices": {"/device:TPU:0": [("lz_select_scan.1", 100.0, 100.0),
+                                          ("lz_select_scan.1", 200.0, 100.0),
+                                          ("top_k.2", 300.0, 4.0)]},
+            "spans": [("bench.window", 0.0, 1000.0),
+                      ("lz.serve.batch", 60.0, 150.0),
+                      ("lz.serve.batch", second_start, 150.0),
+                      ("lz.index.stage", 62.0, 5.0)]}
+
+
+def test_overlapping_dispatches_are_read_once_per_dispatch():
+    # since PR 30 the second batch's span opens while the first's pass runs:
+    # 160..310 covers 40 ns of the first pass, 60..210 covers 10 of the second
+    trace = _two_dispatches(160.0)
+    each = tracing.device_ns_per_span(trace, "lz.serve.batch")
+    assert each == [110.0, 144.0]              # the shared 50 ns counted twice
+    assert sum(each) / 2 == 127.0
+    # busy inside the union 60..310 is 204 ns: 102 a dispatch, the by-name sum
+    assert tracing.device_ns_per_dispatch(trace, "lz.serve.batch") == 102.0
+    run = _fake_run(trace)
+    assert readers.device_ms_per_span(run, "lz.serve.batch") == 102e-6
+    study = tracing.dispatch_study(trace, "lz.serve.batch", "lz_select_scan")
+    assert study == {"spans": 2, "each_span_ms": 127e-6, "union_ms": 102e-6,
+                     "by_name_ms": {"lz_select_scan": 100e-6, "rest": 2e-6,
+                                    "sum": 102e-6}}
+    # the roofline share follows the new reading: never the doubled time
+    run.telemetry = _Tel({"serve.live_requests": 128, "serve.batches": 2})
+    need = files.load_module(run.cfg["demand"]).need(run.cfg, 64.0)
+    least = peaks.least_seconds(need, peaks.peaks_for("TPU v5 lite"))
+    assert readers.serve_roofline_pct(run) == pytest.approx(
+        100 * least["seconds"] / 102e-9, rel=1e-12)
+
+
+@pytest.mark.parametrize("second_start", [210.0, 250.0, 700.0])
+def test_disjoint_dispatches_read_as_they_always_did(second_start):
+    trace = _two_dispatches(second_start)
+    each = tracing.device_ns_per_span(trace, "lz.serve.batch")
+    assert tracing.device_ns_per_dispatch(trace, "lz.serve.batch") \
+        == sum(each) / len(each)
+    assert readers.device_ms_per_span(_fake_run(trace), "lz.serve.batch") \
+        == sum(each) / len(each) / 1e6
+
+
+def test_device_time_per_dispatch_on_the_recorded_traces_and_empty_cases():
+    assert tracing.device_ns_per_dispatch(TRACE, "lz.serve.batch") == 10.0
+    assert tracing.device_ns_per_dispatch(TRACE, "lz.ingest.") is None
+    no_plane = {"devices": {}, "spans": TRACE["spans"]}
+    assert tracing.device_ns_per_dispatch(no_plane, "lz.serve.batch") is None
+    assert tracing.dispatch_study(no_plane, "lz.serve.batch", "x") == {}
+
+
 # ------------------------------------------------------------------ demand
 
 def test_demand_of_both_configurations_by_hand():

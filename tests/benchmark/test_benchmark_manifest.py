@@ -1,9 +1,13 @@
 """BENCHMARK.json against the contract it is checked by, and the harness's
 promise that a later PR adds a cell with files and entries alone: every
 assertion is a function of a checkout's root (``contracts.py``), called here
-on the repository and again on a temporary checkout that two cells were added
-to, one of them on four chips over a sharded configuration."""
+on the repository and again on a temporary checkout that three cells were
+added to: one on four chips over a sharded configuration, and a one-chip
+closed-loop cell of an int8 configuration that reports ``search_qps`` with
+its metrics appended after the manifest's last entry — what the next
+``model_config`` PR brings."""
 
+import functools
 import json
 import os
 import shutil
@@ -149,9 +153,55 @@ def _add_pod_cell(root, m):
             e["workloads"].append("later.pod")
 
 
+def _add_q8_cell(root, m):
+    """What the next ``model_config`` PR appends: a ONE-chip closed-loop
+    throughput cell of a configuration with ``int8_serving``, with a
+    reference and a demand file of its own, reporting ``search_qps``, its
+    kernel's roofline share and a counter metric — all appended AFTER the
+    manifest's last entries."""
+    cfg = harness.load_json(os.path.join(root, "benchmark/configs/lme5m.json"))
+    cfg.update(name="later-int8", reference="benchmark/reference_two_stage.py",
+               demand="benchmark/demands/int8_two_stage.py")
+    cfg["memory_config"].update(int8_serving=True)
+    _write(root, "benchmark/configs/later-int8.json", json.dumps(cfg))
+    _write(root, "benchmark/reference_two_stage.py",
+           "from benchmark.reference import *  # noqa: F401,F403\n"
+           "MARK = 'two-stage'\n")
+    _write(root, "benchmark/demands/int8_two_stage.py",
+           "def need(cfg, batch):\n"
+           "    rows = cfg['memory_config']['initial_capacity'] + 1\n"
+           "    return {'bytes': rows * (cfg['dim'] + 9.0) + batch * 8 * "
+           "cfg['dim'] * 2, 'ops': 2.0 * batch * rows * cfg['dim'],\n"
+           "            'ops_peak': 'int8_ops_per_s'}\n")
+    readers = {
+        "kernel.serve_roofline.later": (
+            "device_trace", "kernels", "%", "higher",
+            "from benchmark.readers import serve_roofline_pct\n\n\n"
+            "def read(run):\n    return serve_roofline_pct(run)\n"),
+        "sched.batch_requests_mean.later": (
+            "program_counter", "scheduler", "count", "higher",
+            "from benchmark.span_metrics import counter_ratio\n\n\n"
+            "def read(run):\n    return counter_ratio(run, 'serve.requests',"
+            " 'serve.batches')\n"),
+    }
+    for name, (source, layer, unit, better, text) in readers.items():
+        _write(root, f"benchmark/metrics/{name}.py", text)
+        m["per_layer"].append({"name": name, "unit": unit, "better": better,
+                               "source": source, "layer": layer,
+                               "moves": "search_qps", "workloads": ["later.q8"]})
+    m["configs"].append({"name": "later-int8", "source": "s", "why": "w",
+                         "file": "benchmark/configs/later-int8.json",
+                         "reduced": ["tenants"]})
+    m["workloads"].append({"name": "later.q8", "config": "later-int8",
+                           "traffic": "serve-closed-128", "chips": 1, "why": "w"})
+    for e in m["end_to_end"]:
+        if e["name"] == "search_qps":
+            e["workloads"].append("later.q8")
+
+
 class Later:
     """A checkout as a later PR leaves it: the repository's manifest and
-    paths, and the two cells above added to them."""
+    paths, and the three cells above added to them."""
 
     def __init__(self, root):
         self.root = root
@@ -168,6 +218,7 @@ class Later:
         m = harness.manifest(root)
         _add_int8_cell(root, m)
         _add_pod_cell(root, m)
+        _add_q8_cell(root, m)
         _write(root, "BENCHMARK.json", json.dumps(m))
 
     def nothing_was_edited(self):
@@ -242,6 +293,63 @@ def test_a_later_pr_s_four_chip_cell_reports_the_span_metrics_it_has(later):
     mine = contracts.traced_debug_run_reports_span_metrics("later.pod",
                                                            later.root)
     assert mine == ["dispatch.readback_p50_ms.later", "sched.lone_dispatch_pct.later"]
+    later.nothing_was_edited()
+
+
+def test_a_later_pr_s_one_chip_int8_cell_lands_after_the_last_entries(later):
+    root = later.root
+    m = harness.manifest(root)
+    assert [e["name"] for e in m["per_layer"][-2:]] == [
+        "kernel.serve_roofline.later", "sched.batch_requests_mean.later"]
+    assert m["per_layer"][:len(M["per_layer"])] == M["per_layer"]
+    cell, cfg, mix = harness.cell_files("later.q8", root)
+    assert cell["chips"] == 1 == harness.mesh_chips(cfg)
+    assert mix["loop"] == "closed" and cfg["memory_config"]["int8_serving"]
+    assert files.load_module(cfg["reference"], root).MARK == "two-stage"
+    need = files.load_module(cfg["demand"], root).need(cfg, 64)
+    assert need["ops_peak"] == "int8_ops_per_s"
+    assert need["bytes"] < files.load_module(
+        "benchmark/demands/exact_scan.py", root).need(cfg, 64)["bytes"]
+    assert [x["name"] for x in harness.metrics_of(cell, "end_to_end", root)] == [
+        "search_qps", "setup_s"]
+    assert [x["name"] for x in harness.metrics_of(cell, "per_layer", root)] == [
+        "kernel.serve_roofline.later", "sched.batch_requests_mean.later"]
+    # the accepted cells report what they reported: no entry of theirs moved
+    for w in M["workloads"]:
+        for kind in ("end_to_end", "per_layer"):
+            assert [x["name"] for x in harness.metrics_of(w, kind, root)] \
+                == [x["name"] for x in harness.metrics_of(w, kind, ROOT)]
+    later.nothing_was_edited()
+
+
+def test_a_later_pr_s_one_chip_int8_cell_passes_the_per_cell_contracts(later):
+    contracts.cell_line("later.q8", later.root)
+    res = contracts.debug_run("later.q8", 34, later.root, traced=True)
+    assert res["correct"] is True
+    # the counter reads on any backend; the roofline share needs a device
+    # plane and is left out here, never reported as 0
+    assert set(res["metrics"]) == {"sched.batch_requests_mean.later"}
+    assert res["metrics"]["sched.batch_requests_mean.later"]["value"] > 1.0
+    later.nothing_was_edited()
+
+
+NAMED = {f"scheduler_entry:{name}": functools.partial(contracts.scheduler_entry, name)
+         for name in contracts.SCHEDULER_ENTRIES}
+NAMED.update(throughput_cells=contracts.throughput_cells,
+             pod_metrics=contracts.pod_metrics)
+
+
+@pytest.mark.parametrize("held", list(NAMED.values()), ids=list(NAMED))
+def test_what_single_prs_named_holds_here_and_in_a_later_checkout(later, held):
+    held(ROOT)
+    held(later.root)
+    later.nothing_was_edited()
+
+
+def test_a_later_pr_s_pod_cell_keeps_its_thirteen_and_its_five_span_metrics(later):
+    mine = contracts.traced_debug_run_reports_span_metrics("pod.serve",
+                                                           later.root)
+    assert set(mine) >= contracts.POD_SPAN_FIVE
     later.nothing_was_edited()
 
 
