@@ -1,0 +1,5 @@
+from benchmark.readers import idle_pct
+
+
+def read(run):
+    return idle_pct(run)

@@ -136,9 +136,13 @@ class MemoryConfig:
     # through the cross-request QueryScheduler so concurrent users share
     # dense device batches. Off = the classic 3-4 dispatch sequence.
     # With int8_serving on, the fused program streams the int8 shadow for
-    # a coarse top-(k + coarse_fetch_slack) and exactly rescores the
-    # survivors from the master (state.search_fused_quant_ragged) — still
-    # ONE dispatch. With ivf_serving > 0 and a published build, the coarse
+    # a coarse top-(serve_k_max + coarse_fetch_slack), selected while the
+    # codes stream (ops/pallas_topk.blocked_two_tier_q8; no [batch, rows]
+    # tile), and exactly rescores the survivors from the master
+    # (state.search_fused_quant_ragged) — still ONE dispatch. Resident: the
+    # codes and scales beside the master, 3 B a component where exact holds
+    # 2 (5M x 768: 11.5 GB of a 16 GB chip; PERF.md, cell fill.q8). With
+    # ivf_serving > 0 and a published build, the coarse
     # stage becomes the IVF centroid prefilter + member gather INSIDE the
     # same dispatch (state.search_fused_ivf_ragged; composes with int8 as
     # gathered-int8 coarse + exact rescore). Under a MESH the same

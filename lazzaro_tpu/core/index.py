@@ -501,6 +501,7 @@ class MemoryIndex:
         # combine crosses ICI (ops/topk.py make_sharded_int8_topk).
         self.int8_serving = bool(int8_serving)
         self._int8_shadow = None           # (q [N,d] i8, scale [N] f32)
+        self._shadow_quantize = None       # the mesh's build program
         self._int8_dirty = True
         # IVF coarse stage (ops/ivf.py): nprobe > 0 routes serving searches
         # through centroid prefilter + member gather. Rows added after a
@@ -2841,23 +2842,38 @@ class MemoryIndex:
             if (not self._int8_dirty and shadow is not None
                     and shadow[0].shape[0] == st.salience.shape[0]):
                 return shadow[0], shadow[1]
-        from lazzaro_tpu.ops.quant import quantize_rows
-        shadow = quantize_rows(self._emb_logical(st))
-        tm = self.tiering
-        if tm is not None and tm.cold_count:
-            # Cold rows hold ZEROS in the master (their exact bytes live
-            # in the host cold store), so a rebuild from ``emb`` would
-            # wipe their codes out of the coarse scan — patch them back
-            # from the store (codes travel with the demoted row).
-            rows, codes, scales = tm.snapshot_codes()
-            keep = rows < st.salience.shape[0]
-            if keep.any():
-                r = jnp.asarray(rows[keep].astype(np.int32))
-                shadow = (shadow[0].at[r].set(jnp.asarray(codes[keep])),
-                          shadow[1].at[r].set(jnp.asarray(scales[keep])))
-        if self.mesh is not None:
-            shadow = (jax.device_put(shadow[0], self._mat_sharding),
-                      jax.device_put(shadow[1], self._row_sharding))
+        from lazzaro_tpu.ops.quant import (quantize_arena,
+                                           quantize_arena_sharded)
+        tel = self.telemetry
+        with tel.span("index.shadow"):
+            # ONE program over the arena, blocked inside (ISSUE 36; under a
+            # mesh every chip quantizes its own rows); what a paged or
+            # tiered index adds is counted beside it
+            dispatches = 1 + (st.row_map is not None)
+            if self.mesh is None:
+                quantize = quantize_arena
+            else:
+                if self._shadow_quantize is None:
+                    self._shadow_quantize = quantize_arena_sharded(
+                        self.mesh, self.shard_axis)
+                quantize = self._shadow_quantize
+            shadow = quantize(self._emb_logical(st))
+            tm = self.tiering
+            if tm is not None and tm.cold_count:
+                # Cold rows hold ZEROS in the master (their exact bytes live
+                # in the host cold store), so a rebuild from ``emb`` would
+                # wipe their codes out of the coarse scan — patch them back
+                # from the store (codes travel with the demoted row).
+                rows, codes, scales = tm.snapshot_codes()
+                keep = rows < st.salience.shape[0]
+                if keep.any():
+                    r = jnp.asarray(rows[keep].astype(np.int32))
+                    shadow = (
+                        shadow[0].at[r].set(jnp.asarray(codes[keep])),
+                        shadow[1].at[r].set(jnp.asarray(scales[keep])))
+                    dispatches += 2
+        tel.bump("index.shadow_builds")
+        tel.bump("index.shadow_dispatches", dispatches)
         with self._state_lock:
             self._int8_shadow = shadow
             if self._state is st:
@@ -3438,18 +3454,25 @@ class MemoryIndex:
         return out
 
     def _note_select_core(self, mode: str, st) -> None:
-        """``serve.select{core}``: which form of the exact core this
-        dispatch runs (ISSUE 26) — ``blocked`` when a block tiles the pool
-        (each chip's slice of it under a mesh) and the selection follows
-        the stream, ``whole_pool`` when the pool is ONE block of the same
-        code (smaller than a block, or a row count no block divides)."""
-        if mode != "exact":
+        """``serve.select{core}``: which form of the select-while-scanning
+        core this dispatch runs (ISSUE 26) — ``blocked`` when a block tiles
+        the pool (each chip's slice of it under a mesh) and the selection
+        follows the stream, ``whole_pool`` when the pool is ONE block of the
+        same code (smaller than a block, or a row count no block divides);
+        the int8 family's coarse scan over the shadow (ISSUE 36) reads
+        ``blocked_q8`` / ``whole_pool_q8``."""
+        if mode not in ("exact", "quant"):
             return
-        from lazzaro_tpu.ops.pallas_topk import block_tiles
-        blocked = block_tiles(st.emb.shape[0] // self._n_parts,
-                              st.emb.shape[1], st.emb.dtype.itemsize)
+        from lazzaro_tpu.ops.pallas_topk import block_tiles, q8_block_tiles
+        d = st.emb.shape[1]
+        if mode == "exact":
+            blocked, tag = block_tiles(st.emb.shape[0] // self._n_parts, d,
+                                       st.emb.dtype.itemsize), ""
+        else:                       # the shadow lies in logical row space
+            blocked, tag = q8_block_tiles(
+                st.salience.shape[0] // self._n_parts, d), "_q8"
         self.telemetry.bump("serve.select", labels={
-            "core": "blocked" if blocked else "whole_pool"})
+            "core": ("blocked" if blocked else "whole_pool") + tag})
 
     def _note_serve_kernel(self, mode: str, statics: dict) -> None:
         """Track the distinct fused serving-kernel keys this index has

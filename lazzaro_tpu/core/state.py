@@ -2850,14 +2850,17 @@ def search_fused_ragged_read(state: ArenaState, csr_indptr: jax.Array,
 
 def _quant_two_tier(state: ArenaState, q8a: jax.Array, scale_a: jax.Array,
                     q_c: jax.Array, tenant_c: jax.Array, k: int, slack: int):
-    """Two-stage quantized two-tier core: int8 coarse scan over the shadow
-    (``q8a`` codes + ``scale_a`` per-row scales, ops/quant.py layout) for
-    BOTH retrieval tiers — super gate candidates and main ANN candidates
-    are different masks over the ONE int8 score matrix — then an exact
-    bf16/f32 rescore of the k+slack survivors via a gathered-row dot. The
-    slack absorbs the ~1e-2 int8 ranking error at the k boundary (ISSUE 3
-    satellite: config-driven, shared with the IVF over-fetch) so the exact
-    top-k can't lose a true member the coarse scan ranked at k+3.
+    """Two-stage quantized two-tier core: an int8 coarse scan over the shadow
+    (``q8a`` codes + ``scale_a`` per-row scales, ops/quant.py layout) that
+    SELECTS WHILE THE SHADOW STREAMS from HBM once (ISSUE 36;
+    ``ops/pallas_topk.blocked_two_tier_q8``, the int8 twin of the exact
+    family's core) for BOTH retrieval tiers — the super gate's coarse
+    top-(1 + slack) and the main tier's coarse top-(k + slack), each query
+    over its own tenant's rows — then an exact bf16/f32 rescore of exactly
+    those survivors via a gathered-row dot: no ``[C, rows]`` score tile,
+    nothing sorted at the arena's width. The slack absorbs the ~1e-2 int8
+    ranking error at the k boundary (ISSUE 3 satellite: config-driven, shared
+    with the IVF over-fetch): a true member ranked k+3 coarsely is kept.
 
     Shard-local by construction (the shadow row-shards like the master, and
     the rescore gather only touches local rows): single-chip callers pass
@@ -2867,31 +2870,26 @@ def _quant_two_tier(state: ArenaState, q8a: jax.Array, scale_a: jax.Array,
     VERDICT uses the exact rescored score — quantization error can only
     cost a gate candidate ranked below coarse position 1+slack, never flip
     the threshold comparison itself."""
+    from lazzaro_tpu.ops.pallas_topk import ROW_DEAD, blocked_two_tier_q8
     from lazzaro_tpu.ops.quant import quantize_rows
 
     n = _nrows(state)
     k_fetch = min(k + slack, n)
     g_fetch = min(1 + slack, n)
-    qn = normalize(q_c)                                   # [C, d] f32
-    qq, qs = quantize_rows(qn)
-    dots = jax.lax.dot_general(
-        qq, q8a, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)                 # [C, rows] i32
-    coarse = (dots.astype(jnp.float32)
-              * qs[:, None] * scale_a[None, :])
-    alive_t = state.alive[None, :] & (
-        state.tenant_id[None, :] == tenant_c[:, None])
-    sup = state.is_super[None, :]
-    cg_s, cg_r = jax.lax.top_k(
-        jnp.where(alive_t & sup, coarse, NEG_INF), g_fetch)
-    ca_s, ca_r = jax.lax.top_k(
-        jnp.where(alive_t & ~sup, coarse, NEG_INF), k_fetch)
-    # Same consumer-split hazard as _exact_two_tier: the coarse top-k
-    # feeds both the rescore gather and (via it) the readback — without
-    # the barrier XLA can duplicate the full-arena sorts.
+    with jax.named_scope("lz.norms"):
+        qn = normalize(q_c)                               # [C, d] f32
+        qq, qs = quantize_rows(qn)
+    with jax.named_scope("lz.scan_q8"):
+        # the shadow lies in LOGICAL row space, paged or not
+        ten = state.tenant_id.astype(jnp.int32)
+        row_main = jnp.where(state.alive & ~state.is_super, ten, ROW_DEAD)
+        row_gate = jnp.where(state.alive & state.is_super, ten, ROW_DEAD)
+    cg_s, cg_r, ca_s, ca_r = blocked_two_tier_q8(
+        q8a, scale_a, qq, qs, row_main, row_gate, tenant_c, k_fetch, g_fetch)
+    # Same consumer-split hazard as _exact_two_tier: the survivors feed the
+    # rescore gather and (via it) the readback; XLA could run the scan twice.
     cg_s, cg_r, ca_s, ca_r = jax.lax.optimization_barrier(
         (cg_s, cg_r, ca_s, ca_r))
-    qd = qn.astype(state.emb.dtype)
 
     def rescore(rows_c, coarse_s):
         g = state.emb[_phys(state, rows_c)]               # [C, kf, d]
@@ -2899,12 +2897,14 @@ def _quant_two_tier(state: ArenaState, q8a: jax.Array, scale_a: jax.Array,
                         preferred_element_type=jnp.float32)
         return jnp.where(coarse_s > NEG_INF / 2, ex, NEG_INF)
 
-    ann_ex = rescore(ca_r, ca_s)
-    ann_s, sel = jax.lax.top_k(ann_ex, k)
-    ann_r = jnp.take_along_axis(ca_r, sel, axis=1)
-    gate_ex = rescore(cg_r, cg_s)
-    g_s, g_sel = jax.lax.top_k(gate_ex, 1)
-    g_r = jnp.take_along_axis(cg_r, g_sel, axis=1)
+    with jax.named_scope("lz.rescore"):
+        qd = qn.astype(state.emb.dtype)
+        ann_ex = rescore(ca_r, ca_s)
+        ann_s, sel = jax.lax.top_k(ann_ex, k)
+        ann_r = jnp.take_along_axis(ca_r, sel, axis=1)
+        gate_ex = rescore(cg_r, cg_s)
+        g_s, g_sel = jax.lax.top_k(gate_ex, 1)
+        g_r = jnp.take_along_axis(cg_r, g_sel, axis=1)
     return g_s, g_r, ann_s, ann_r
 
 

@@ -353,3 +353,364 @@ def masked_topk(emb: jax.Array, mask: jax.Array, queries: jax.Array, k: int,
         jnp.full((n,), ROW_DEAD, jnp.int32),
         jnp.zeros((queries.shape[0],), jnp.int32), k, k_q, impl)
     return top_s, top_r
+
+
+# ------------------------------------------------------ the int8 coarse scan
+#
+# The int8 family's twin of the core above (ISSUE 36): the serving shadow
+# (``ops/quant.py``: int8 codes + one f32 scale a row) streams from HBM once,
+# ``block`` rows at a time; per block, on chip, the ``[C, block]`` int8 x int8
+# -> int32 tile on the MXU, the query's and the rows' scales in f32, the same
+# per-query tenant masks, and BOTH tiers' running sorted lists — the gate's
+# top-(1 + slack) and the main tier's coarse fetch (k + slack). No
+# ``[queries, rows]`` tile and no full-width top-k.
+#
+# A coarse fetch is wide (136 where an exact list holds a request's k), every
+# entry is one insertion, and an insertion's cost is the latency of its
+# cross-lane reductions, not their width. So the Pallas vehicle (1) sorts the
+# queries by tenant and drains a block per GROUP of eight queries (one f32
+# sublane tile) — a block wakes only the groups whose tenants have rows in it,
+# and queries of one tenant share a group; (2) cuts the group's tile into
+# ``panels`` column panels and takes the best remaining score of EVERY panel
+# in one step, so the reductions of a step overlap; (3) inserts by compare
+# and shift, with no reduction at all. Lists are ordered by (score, then
+# lower row), so panels may hand their candidates over in any order and equal
+# scores still resolve to the lowest row, at the fetch boundary too.
+
+_Q8_GROUP = 8
+# int8 operands tile 32 rows deep
+_Q8_QUERY_TILE = 32
+# rows a block of codes may have (12,288 x 768 B = 9.4 MB, double-buffered)
+_Q8_BLOCKS = (12288, 8192, 4096, 2048, 1024, 512)
+_Q8_BLOCK_BYTES = 12 * 1024 * 1024
+
+
+def q8_block_rows(n: int, d: int) -> int:
+    """Rows per block of the int8 scan over a shadow of ``n`` rows: the
+    largest of ``_Q8_BLOCKS`` that fits the VMEM budget and divides ``n``
+    into several blocks — or ``n`` itself (ONE whole-pool block)."""
+    for blk in _Q8_BLOCKS:
+        if blk * d <= _Q8_BLOCK_BYTES and blk < n and n % blk == 0:
+            return blk
+    return n
+
+
+def q8_block_tiles(n: int, d: int) -> bool:
+    """Whether a block tiles a shadow of ``n`` rows (the Pallas vehicle)."""
+    return q8_block_rows(n, d) < n
+
+
+def _q8_panels(block: int) -> int:
+    """Column panels a group's tile is drained in: each a whole number of
+    128-lane tiles. Four: a step costs its extraction (the tile's width) and
+    one insertion a panel, and the chip read 4 and 8 panels alike (PERF.md
+    section 6, PR 36) while 8 cost twice the program to trace and lower."""
+    return next(p for p in (4, 2, 1) if block % (p * 128) == 0)
+
+
+def _q8_scores(qq, qs, codes, scale_b):
+    """``[C, block]`` f32 coarse scores: the integer dot of the codes, times
+    the query's scale ([C, 1]), times the rows' ([1, block]) — the order
+    ``ops/quant.quantized_topk`` multiplies in."""
+    dots = jax.lax.dot_general(qq, codes, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+    return dots.astype(jnp.float32) * qs * scale_b
+
+
+def _q8_insert(r_s, r_r, m, row, lane, k: int, roll):
+    """``(m, row)`` ([Q, 1] each) into the lists sorted by (score down, row
+    up), where it beats the query's ``k``-th entry. Compare and shift: the
+    entries ahead of it stay, it lands behind them, the rest move one lane
+    on. Returns the lists and, per query, whether it entered."""
+    ahead = (r_s > m) | ((r_s == m) & (r_r < row))
+    act = ~ahead[:, k - 1:k] & (m > NEG / 2)     # NEG: nothing left
+    p_s, p_r = roll(r_s), roll(r_r)                # lane i holds entry i - 1
+    p_ahead = (lane == 0) | (p_s > m) | ((p_s == m) & (p_r < row))
+    ins_s = jnp.where(ahead, r_s, jnp.where(p_ahead, m, p_s))
+    ins_r = jnp.where(ahead, r_r, jnp.where(p_ahead, row, p_r))
+    return jnp.where(act, ins_s, r_s), jnp.where(act, ins_r, r_r), act
+
+
+def _q8_best(s, col):
+    """A tile's best remaining score per query and its column ([Q, 1] each,
+    lowest column on equal scores), and the tile with it taken out."""
+    m = jnp.max(s, axis=1, keepdims=True)
+    idx = jnp.min(jnp.where(s == m, col, s.shape[1]), axis=1, keepdims=True)
+    return m, idx, jnp.where(col == idx, NEG, s)
+
+
+def _scan_q8_jax(q8a, scale, qq, qs, row_main, row_gate, tenant_c,
+                 k_fetch: int, g_fetch: int, block: int, sentinel: int):
+    """The int8 core in plain JAX, one candidate a step. ``qs`` and
+    ``tenant_c`` are [C, 1]; the lists are as wide as their fetch."""
+    n = q8a.shape[0]
+    c = qq.shape[0]
+    nblocks = n // block
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, block), 1)
+    roll = functools.partial(jnp.roll, shift=1, axis=1)
+
+    def drain(s, base, r_s, r_r, k):
+        lane = jax.lax.broadcasted_iota(jnp.int32, r_s.shape, 1)
+
+        def body(cy):
+            s, r_s, r_r, _ = cy
+            m, idx, s = _q8_best(s, col)
+            r_s, r_r, act = _q8_insert(r_s, r_r, m, idx + base, lane, k, roll)
+            return s, r_s, r_r, _any(act)
+
+        out = jax.lax.while_loop(lambda cy: cy[3] > 0, body,
+                                 (s, r_s, r_r, jnp.int32(1)))
+        return out[1], out[2]
+
+    def one_block(b, carry):
+        r_s, r_r, g_s, g_r = carry
+        base = b * block
+        with jax.named_scope("lz.scan_q8"):
+            codes = jax.lax.dynamic_slice_in_dim(q8a, base, block, 0)
+            sc = jax.lax.dynamic_slice_in_dim(scale, base, block, 0)
+            rm = jax.lax.dynamic_slice_in_dim(row_main, base, block, 0)
+            rg = jax.lax.dynamic_slice_in_dim(row_gate, base, block, 0)
+            scores = _q8_scores(qq, qs, codes, sc[None, :])
+        with jax.named_scope("lz.topk"):
+            g_s, g_r = drain(jnp.where(rg[None, :] == tenant_c, scores, NEG),
+                             base, g_s, g_r, g_fetch)
+            r_s, r_r = drain(jnp.where(rm[None, :] == tenant_c, scores, NEG),
+                             base, r_s, r_r, k_fetch)
+        return r_s, r_r, g_s, g_r
+
+    init = (jnp.full((c, k_fetch), NEG, jnp.float32),
+            jnp.full((c, k_fetch), sentinel, jnp.int32),
+            jnp.full((c, g_fetch), NEG, jnp.float32),
+            jnp.full((c, g_fetch), sentinel, jnp.int32))
+    if nblocks == 1:
+        return one_block(0, init)
+    return jax.lax.fori_loop(0, nblocks, one_block, init)
+
+
+def _select_q8_kernel(block: int, k_fetch: int, g_fetch: int, kp: int,
+                      gp: int, sentinel: int):
+    panels = _q8_panels(block)
+
+    def kernel(hasg_ref, q_ref, qs_ref, tq_ref, codes_ref, sc_ref, rm_ref,
+               rg_ref, rs_ref, rr_ref, gs_ref, gr_ref, s_ref, woke_ref):
+        b = pl.program_id(0)
+        c = q_ref.shape[0]
+
+        @pl.when(b == 0)
+        def _():
+            rs_ref[...] = jnp.full((c, kp), NEG, jnp.float32)
+            rr_ref[...] = jnp.full((c, kp), sentinel, jnp.int32)
+            gs_ref[...] = jnp.full((c, gp), NEG, jnp.float32)
+            gr_ref[...] = jnp.full((c, gp), sentinel, jnp.int32)
+
+        scores = _q8_scores(q_ref[...], qs_ref[...], codes_ref[...],
+                            sc_ref[...])                   # [C, block]
+        tq = tq_ref[...]
+        base = b * block
+        roll = functools.partial(pltpu.roll, shift=1, axis=1)
+
+        def drain(rows, ls_ref, lr_ref, k, cuts):
+            """What the group ``rows`` of ``s_ref`` still wants of this
+            block, into its sorted lists: the best remaining score of each
+            of ``cuts`` column panels a step, until a step's candidates all
+            stay out."""
+            wide = block // cuts
+            col = jax.lax.broadcasted_iota(jnp.int32, (_Q8_GROUP, wide), 1)
+            lane = jax.lax.broadcasted_iota(
+                jnp.int32, (_Q8_GROUP, ls_ref.shape[1]), 1)
+
+            def step(cy):
+                r_s, r_r = ls_ref[rows, :], lr_ref[rows, :]
+                took = jnp.zeros((_Q8_GROUP, 1), bool)
+                for p in range(cuts):
+                    at = slice(p * wide, (p + 1) * wide)
+                    m, idx, rest = _q8_best(s_ref[rows, at], col)
+                    s_ref[rows, at] = rest
+                    r_s, r_r, act = _q8_insert(
+                        r_s, r_r, m, idx + (base + p * wide), lane, k, roll)
+                    took = took | act
+                ls_ref[rows, :] = r_s
+                lr_ref[rows, :] = r_r
+                return cy[0] + 1, _any(took)
+
+            jax.lax.while_loop(lambda cy: (cy[1] > 0) & (cy[0] < wide), step,
+                               (jnp.int32(0), jnp.int32(1)))
+
+        def tier(s, ls_ref, lr_ref, k, cuts):
+            """One tier's masked scores ``s`` into its lists: the groups the
+            block wakes, one by one. A block of other tenants' rows wakes
+            nobody."""
+            want = jnp.max(s, axis=1, keepdims=True) > ls_ref[:, k - 1:k]
+
+            @pl.when(_any(want) > 0)
+            def _():
+                s_ref[...] = s
+                for g in range(c // _Q8_GROUP):
+                    woke_ref[g] = _any(
+                        want[g * _Q8_GROUP:(g + 1) * _Q8_GROUP])
+
+                def group(g, _):
+                    @pl.when(woke_ref[g] > 0)
+                    def _():
+                        drain(pl.ds(pl.multiple_of(g * _Q8_GROUP, _Q8_GROUP),
+                                    _Q8_GROUP), ls_ref, lr_ref, k, cuts)
+                    return 0
+
+                jax.lax.fori_loop(0, c // _Q8_GROUP, group, 0)
+
+        @pl.when(hasg_ref[b] > 0)       # most blocks hold no super row, and
+        def _():                        # the gate's fetch is short: one panel
+            tier(jnp.where(rg_ref[...] == tq, scores, NEG), gs_ref, gr_ref,
+                 g_fetch, 1)
+
+        tier(jnp.where(rm_ref[...] == tq, scores, NEG), rs_ref, rr_ref,
+             k_fetch, panels)
+
+    return kernel
+
+
+def _scan_q8_pallas(q8a, scale, qq, qs, row_main, row_gate, tenant_c,
+                    k_fetch: int, g_fetch: int, kp: int, gp: int, block: int,
+                    sentinel: int, interpret: bool):
+    """The int8 core as one Pallas TPU kernel over the grid of blocks. Same
+    arguments and results as :func:`_scan_q8_jax` (lists ``kp`` / ``gp``
+    wide)."""
+    n, d = q8a.shape
+    c = qq.shape[0]
+    nblocks = n // block
+    has_gate = (row_gate.reshape(nblocks, block) != ROW_DEAD).any(
+        axis=1).astype(jnp.int32)
+    fixed = lambda b, *_: (0, 0)                           # noqa: E731
+    rows_of = lambda b, *_: (0, b)                         # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nblocks,),
+        in_specs=[
+            pl.BlockSpec((c, d), fixed),
+            pl.BlockSpec((c, 1), fixed),
+            pl.BlockSpec((c, 1), fixed),
+            pl.BlockSpec((block, d), lambda b, *_: (b, 0)),
+            pl.BlockSpec((1, block), rows_of),
+            pl.BlockSpec((1, block), rows_of),
+            pl.BlockSpec((1, block), rows_of),
+        ],
+        out_specs=[
+            pl.BlockSpec((c, kp), fixed),
+            pl.BlockSpec((c, kp), fixed),
+            pl.BlockSpec((c, gp), fixed),
+            pl.BlockSpec((c, gp), fixed),
+        ],
+        scratch_shapes=[pltpu.VMEM((c, block), jnp.float32),
+                        pltpu.SMEM((c // _Q8_GROUP,), jnp.int32)],
+    )
+    vmem = 2 * block * d + 8 * c * block * 4 + 8 * 1024 * 1024
+    return pl.pallas_call(
+        _select_q8_kernel(block, k_fetch, g_fetch, kp, gp, sentinel),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((c, kp), jnp.float32),
+            jax.ShapeDtypeStruct((c, kp), jnp.int32),
+            jax.ShapeDtypeStruct((c, gp), jnp.float32),
+            jax.ShapeDtypeStruct((c, gp), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=int(vmem)),
+        interpret=interpret,
+        name="lz_select_scan_q8",
+    )(has_gate, qq, qs, tenant_c, q8a, scale.reshape(1, n),
+      row_main.reshape(1, n), row_gate.reshape(1, n))
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_q8_for_tpu(n: int, d: int, c: int, k_fetch: int, g_fetch: int,
+                     kp: int, gp: int, block: int):
+    """:func:`_scan_q8_pallas` at one geometry, traced and lowered for the
+    TPU ONCE a process (``jax.export``) and called from every serving
+    program after that. A warm start finds its executables in the persistent
+    cache but still traces and lowers every program to ask for them — eight
+    batch buckets and two twins are sixteen, their queries padded to one of
+    two tiles — and this kernel's body is what made each of them slow to
+    lower (PERF.md section 6, PRs 35 and 36)."""
+    sds = jax.ShapeDtypeStruct
+    return jax.export.export(
+        jax.jit(functools.partial(
+            _scan_q8_pallas, k_fetch=k_fetch, g_fetch=g_fetch, kp=kp, gp=gp,
+            block=block, sentinel=n - 1, interpret=False)),
+        platforms=("tpu",))(
+            sds((n, d), jnp.int8), sds((n,), jnp.float32),
+            sds((c, d), jnp.int8), sds((c, 1), jnp.float32),
+            sds((n,), jnp.int32), sds((n,), jnp.int32),
+            sds((c, 1), jnp.int32)).call
+
+
+def blocked_two_tier_q8(q8a: jax.Array, scale: jax.Array, qq: jax.Array,
+                        qs: jax.Array, row_main: jax.Array,
+                        row_gate: jax.Array, tenant_c: jax.Array,
+                        k_fetch: int, g_fetch: int, impl: str = "auto"
+                        ) -> Tuple[jax.Array, jax.Array, jax.Array,
+                                   jax.Array]:
+    """The gate tier's ``g_fetch`` and the main tier's ``k_fetch`` best rows
+    of every query BY INT8 SCORE over its own tenant's rows, selected while
+    the shadow streams once.
+
+    ``q8a`` [n, d] i8 / ``scale`` [n] f32 (the shadow), ``qq`` [C, d] i8 /
+    ``qs`` [C] f32 (the unit queries' codes and scales), ``row_main`` /
+    ``row_gate`` [n] i32 and ``tenant_c`` [C] i32 as in
+    :func:`blocked_two_tier`. Returns ``(gate_s [C, g_fetch], gate_r,
+    ann_s [C, k_fetch], ann_r)``, each best first (equal scores: the lower
+    row first) with rows of ``q8a``; an empty slot is ``(NEG, n - 1)``.
+    ``impl`` as in :func:`blocked_two_tier`."""
+    n, d = q8a.shape
+    c = qq.shape[0]
+    sentinel = n - 1
+    block = q8_block_rows(n, d)
+    tiles = block < n
+    if impl == "pallas" and not tiles:
+        raise ValueError(f"no block tiles a pool of {n} rows")
+    use_pallas = impl == "pallas" or (impl == "auto" and on_tpu() and tiles)
+    if c > _MAX_QUERIES:
+        from lazzaro_tpu.ops.chunking import chunked_map_multi
+        return chunked_map_multi(
+            lambda q_p, s_p, t_p: blocked_two_tier_q8(
+                q8a, scale, q_p, s_p, row_main, row_gate, t_p, k_fetch,
+                g_fetch, impl),
+            (qq, qs, tenant_c), chunk=_MAX_QUERIES)
+    tenant_c = tenant_c.astype(jnp.int32)
+    if not use_pallas:
+        r_s, r_r, g_s, g_r = _scan_q8_jax(
+            q8a, scale, qq, qs[:, None], row_main, row_gate,
+            tenant_c[:, None], k_fetch, g_fetch, block, sentinel)
+        return g_s, g_r, r_s, r_r
+    # queries of one tenant side by side: they wake the same groups; the pad
+    # queries (no row answers to ROW_DEAD + 1) make up the int8 tile. The
+    # batch is small: its order is counted, not sorted, and both permutations
+    # are one-hot sums (exact; no sort and no row-by-row gather on the chip)
+    i = jnp.arange(c, dtype=jnp.int32)
+    before = (tenant_c[None, :] < tenant_c[:, None]) | (
+        (tenant_c[None, :] == tenant_c[:, None]) & (i[None, :] < i[:, None]))
+    place = jnp.sum(before, axis=1, dtype=jnp.int32)     # query i's new row
+    to_sorted = place[None, :] == i[:, None]             # [new row, query]
+
+    def permute(onehot, a):
+        wide = a.reshape(c, -1)
+        out = jnp.sum(jnp.where(onehot[:, :, None], wide[None, :, :], 0),
+                      axis=1, dtype=wide.dtype)
+        return out.reshape(a.shape)
+
+    pad = -c % _Q8_QUERY_TILE
+    kp, gp = -(-k_fetch // 128) * 128, -(-g_fetch // 128) * 128
+    if on_tpu():
+        scan = _scan_q8_for_tpu(n, d, c + pad, k_fetch, g_fetch, kp, gp,
+                                block)
+    else:                               # interpret mode: small shapes only
+        scan = functools.partial(
+            _scan_q8_pallas, k_fetch=k_fetch, g_fetch=g_fetch, kp=kp, gp=gp,
+            block=block, sentinel=sentinel, interpret=True)
+    r_s, r_r, g_s, g_r = scan(
+        q8a, scale, jnp.pad(permute(to_sorted, qq), ((0, pad), (0, 0))),
+        jnp.pad(permute(to_sorted, qs), (0, pad))[:, None], row_main,
+        row_gate, jnp.pad(permute(to_sorted, tenant_c), (0, pad),
+                          constant_values=ROW_DEAD + 1)[:, None])
+    back = to_sorted.T                                   # [query, new row]
+    return (permute(back, g_s[:c, :g_fetch]), permute(back, g_r[:c, :g_fetch]),
+            permute(back, r_s[:c, :k_fetch]), permute(back, r_r[:c, :k_fetch]))

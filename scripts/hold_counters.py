@@ -6,9 +6,12 @@ hold did in the timed window (ISSUE 32):
     python3 scripts/hold_counters.py <checkout> --workload fill.serve \\
         --seed 3200011 --seconds 40 --trace 0
 
-The three per-layer metrics ISSUE 32 names are not in the manifest (PERF.md
-section 7 says why and what the next ``benchmark`` PR appends), so the
-numbers PERF.md quotes for them were read this way: one ``HOLD_COUNTERS``
+The three per-layer metrics ISSUE 32 names ARE in the manifest since PR 34
+(``sched.batch_requests_mean.qps``, ``sched.hold_pct.qps`` / ``.lat``, beside
+``sched.hold_p50_ms.qps``), and ``tests/benchmark/test_hold_metric.py`` holds
+:func:`metrics` below and their readers to the same numbers. Before that, the
+numbers PERF.md quotes for them were read this way, and a checkout older than
+PR 34 — or one whose manifest lacks them — still is: one ``HOLD_COUNTERS``
 line on stderr with the program's counters as they stand when the window
 ends (``serve.requests``, ``serve.batches``, ``serve.held_batches``,
 ``serve.hold_us``, ``serve.lone_batches``, ``serve.overlapped_batches``),

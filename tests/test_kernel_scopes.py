@@ -97,3 +97,20 @@ def test_scopes_leave_the_results_alone():
     want = np.sort(scores[0])[::-1][:5]
     got = packed[0, 2:2 + 5].view(np.float32)
     np.testing.assert_array_equal(got, want)
+
+
+def test_int8_read_core_names_the_coarse_scan_and_the_rescore():
+    """ISSUE 36: the int8 family's program carries ``lz.scan_q8`` on the
+    coarse scan's operations and ``lz.rescore`` on the survivors' gather,
+    dot and top-k, beside the shared phases."""
+    from lazzaro_tpu.ops.quant import quantize_rows
+    idx = _index()
+    st, *rest = _serve_args(idx)
+    q8, scale = quantize_rows(st.emb)
+    low = S.search_fused_quant_ragged_read.lower(
+        st, q8, scale, *rest, jnp.full((Q,), 5, jnp.int32), jnp.float32(0.4),
+        k=8, slack=8, cap_take=5, max_nbr=4)
+    scopes = set(re.findall(r'op_name="[^"]*?(lz\.[a-z0-9_]+)',
+                            low.compile().as_text()))
+    assert scopes == {"lz.norms", "lz.scan_q8", "lz.topk", "lz.rescore",
+                      "lz.gate", "lz.pack"}

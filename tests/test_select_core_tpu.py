@@ -111,3 +111,98 @@ def test_pod_arena_is_constructed_in_its_shards_for_four_chips(topo):
     shard = (n // 4) * (d * 2 + 4 * 7 + 2)        # emb + seven words + two flags
     assert shard <= mem.output_size_in_bytes <= 1.01 * shard < 8e9
     assert mem.temp_size_in_bytes < 64 * 2**20
+
+
+# ------------------------------------------- the int8 coarse scan (ISSUE 36)
+
+@pytest.mark.parametrize("rows,batch", [
+    (5_001_216, 64),        # lme5m-int8, the full batch
+    (5_001_216, 8),         # lme5m-int8, the lone dispatch (padded to 32)
+    (135_168, 24),          # share131k's arena with int8_serving on
+])
+def test_int8_kernel_compiles_for_v5e(one_chip, rows, batch):
+    d, k_fetch, g_fetch = 768, 136, 9
+    block = PT.q8_block_rows(rows, d)
+    assert PT.q8_block_tiles(rows, d) and block == 12288
+    c = -(-batch // PT._Q8_QUERY_TILE) * PT._Q8_QUERY_TILE
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def core(q8a, scale, qq, qs, rm, rg, t):
+        return PT._scan_q8_pallas(q8a, scale, qq, qs, rm, rg, t, k_fetch,
+                                  g_fetch, kp=256, gp=128, block=block,
+                                  sentinel=rows - 1, interpret=False)
+
+    comp = jax.jit(core).lower(
+        sds((rows, d), jnp.int8), sds((rows,), jnp.float32),
+        sds((c, d), jnp.int8), sds((c, 1), jnp.float32),
+        sds((rows,), jnp.int32), sds((rows,), jnp.int32),
+        sds((c, 1), jnp.int32)).compile()
+    text = comp.as_text()
+    assert "tpu_custom_call" in text and "lz_select_scan_q8" in text
+    assert f"[{c},{rows}]" not in text
+
+
+def test_int8_serving_program_compiles_for_v5e_beside_the_master(
+        one_chip, monkeypatch):
+    """``search_fused_quant_ragged_read`` at ``lme5m-int8``'s geometry: the
+    kernel, the rescore's gather and the tail as ONE program whose operands
+    are the 11.5 GB a chip holds resident (master, codes, scales, columns)
+    and whose temporaries are megabytes — no ``[batch, rows]`` tile, no sort
+    at the arena's width."""
+    monkeypatch.setattr(PT, "on_tpu", lambda: True)
+    rows, d, c = 5_001_216, 768, 64
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    st = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: S.init_arena(rows - 1, d, jnp.bfloat16)))
+    comp = S.search_fused_quant_ragged_read.lower(
+        st, sds((rows, d), jnp.int8), sds((rows,), jnp.float32),
+        sds((rows + 1,), jnp.int32), sds((8192,), jnp.int32),
+        sds((c, d), jnp.float32), sds((c,), jnp.bool_), sds((c,), jnp.int32),
+        sds((c,), jnp.bool_), sds((c,), jnp.int32), sds((), jnp.float32),
+        k=128, slack=8, cap_take=5, max_nbr=8).compile()
+    text = comp.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"[{c},{rows}]" not in text
+    mem = comp.memory_analysis()
+    assert 11.4e9 < mem.argument_size_in_bytes < 11.7e9
+    assert mem.temp_size_in_bytes < 64 * 2**20
+
+
+def test_pod_int8_program_compiles_for_four_chips(topo, monkeypatch):
+    """``make_fused_sharded``'s quant mode on a 2x2 mesh at 10M rows (each
+    chip holds 2.5M rows of master AND codes, 5.8 GB): the shard-local
+    coarse scan is the same kernel inside the ``shard_map``, the rescore
+    gathers local rows, and the candidate merge is the ``all_gather``."""
+    monkeypatch.setattr(PT, "on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+    n, d, c, edges = 4 * 612 * 4096, 768, 64, 4096
+
+    def sds(shape, dt, spec=None):
+        spec = spec if spec is not None else P(*([None] * len(shape)))
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, spec))
+
+    st = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype,
+                      P("data", None) if a.ndim == 2 else P("data")),
+        jax.eval_shape(lambda: S.init_arena(n - 1, d, jnp.bfloat16)))
+    kern = S.make_fused_sharded(mesh, "data", k=128, cap_take=5, max_nbr=8,
+                                mode="quant", slack=8)
+    comp = kern.read.lower(
+        st, (sds((n, d), jnp.int8, P("data", None)),
+             sds((n,), jnp.float32, P("data"))),
+        sds((4, n // 4 + 1), jnp.int32, P("data", None)),
+        sds((4, edges), jnp.int32, P("data", None)), sds((c, d), jnp.float32),
+        sds((c,), jnp.bool_), sds((c,), jnp.int32), sds((c,), jnp.bool_),
+        sds((c,), jnp.int32), sds((c,), jnp.int32),
+        sds((), jnp.float32)).compile()
+    text = comp.as_text()
+    assert "lz_select_scan_q8" in text and "all-gather" in text
+    assert f"[{c},{n // 4}]" not in text
+    assert comp.memory_analysis().temp_size_in_bytes < 64 * 2**20

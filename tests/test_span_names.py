@@ -263,3 +263,61 @@ def test_no_new_name_falls_under_an_accepted_metrics_prefix():
     taken = [n for n in names - ACCEPTED
              if n.startswith(("serve.", "ingest."))]
     assert not taken
+
+
+# ----------------------------------------------- the int8 family (ISSUE 36)
+
+@pytest.fixture()
+def int8_system(tmp_path):
+    ms = MemorySystem(
+        enable_async=False, db_dir=str(tmp_path / "db"), verbose=False,
+        load_from_disk=False, llm_provider=QueueLLM(20),
+        embedding_provider=ClusteredEmb(), auto_prune=False,
+        max_buffer_size=10_000,
+        config=MemoryConfig(auto_consolidate=False, enable_hierarchy=False,
+                            int8_serving=True))
+    yield ms
+    ms.close()
+
+
+def test_int8_dispatch_opens_the_same_path_and_the_shadow_build_once(
+        int8_system, opened):
+    """An int8 dispatch runs the pinned path under ``serve.quant``; the
+    shadow's (re)build is the span ``index.shadow`` inside the launch that
+    found it dirty, with its timer and its two counters, and a dispatch on
+    a clean index opens none."""
+    system = int8_system
+    _converse(system, "alice", 0)
+    sched = system._ensure_scheduler()
+    req = RetrievalRequest(query=np.ones(D, np.float32), tenant="alice", k=5)
+    path = ["sched.account", "index.pack", "index.stage", "serve.quant",
+            "dispatch.launch", "index.shadow", "dispatch.readback",
+            "index.decode", "sched.demux"]
+    for build in (True, False):
+        time.sleep(0.05)        # the worker is back in its wait
+        del opened[:]
+        assert sched.submit(req).result(timeout=120).ids
+        deadline = time.time() + 10
+        while (not any(n == "sched.idle" for _, n, _, _ in opened)
+               and time.time() < deadline):
+            time.sleep(0.005)
+        mine = [e for e in opened if e[0] != "MainThread"]
+        want = [n for n in path if build or n != "index.shadow"]
+        assert [n for _, n, _, _ in mine] == want + ["sched.idle"]
+        tree = _tree(mine)
+        assert tree["serve.quant"] == {"dispatch.launch", "dispatch.readback"}
+        assert tree.get("dispatch.launch", set()) == (
+            {"index.shadow"} if build else set())
+    tel = system.telemetry
+    assert tel.counter_total("index.shadow_builds") == 1
+    assert tel.counter_total("index.shadow_dispatches") == 1
+    assert tel.snapshot()["timers"]["index.shadow_ms"]["count"] == 1
+    assert tel.counters['serve.dispatches{mode="quant"}'] == 2
+    # the int8 core's own label beside the exact core's
+    select = {k: v for k, v in tel.counters.items()
+              if k.startswith("serve.select")}
+    assert set(select) == {'serve.select{core="whole_pool_q8"}'}, select
+    assert sum(select.values()) == 2
+    # and the accepted metrics' prefixes still select what they selected
+    assert not [n for n in {"index.shadow"}
+                if n.startswith(("serve.", "ingest."))]
