@@ -36,9 +36,8 @@ from lazzaro_tpu.reliability.guard import (check_not_poisoned,
                                            run_guarded)
 from lazzaro_tpu.utils.batching import (LRUKernelCache, bucket_size,
                                         decode_topk, empty_results,
-                                        fetch_packed, next_pow2,
-                                        pad_to_bucket, pad_to_pow2,
-                                        unpack_retrieval)
+                                        fetch_packed, next_pow2, pad_to_pow2,
+                                        RequestCarrier, unpack_retrieval)
 from lazzaro_tpu.utils.telemetry import (default_registry, peak_bytes,
                                          record_device_counters)
 
@@ -373,9 +372,8 @@ class _StagedSharded(NamedTuple):
     operand but the state (and the tables made against it at the gate)."""
 
     kern: S.FusedShardedKernels
-    sargs: tuple                  # per-shard CSR, queries, per-query columns
-    read_extra: tuple             # the read twin's tail
-    boost_extra: Optional[tuple]  # the serve twins' tail; None: no boost asked
+    sargs: tuple                  # per-shard CSR, the request carrier
+    boosting: bool                # a request asked for boosts: serve twins
     sem_tail: tuple               # the semantic ring's operand, or ()
 
 
@@ -3177,7 +3175,13 @@ class MemoryIndex:
             # + ONE readback.
             route = self._serve_route(cap_take)
             mode, k_bucket, tiered = route.mode, route.k_bucket, route.tiered
-            q = np.zeros((nq, dim), np.float32)
+            # Batches pad to a LINEAR granularity bucket, not the next power
+            # of two: worst-case padded waste is granularity-1 slots instead
+            # of ~50% of the dispatch, with jit specializations still bounded.
+            # The carrier is the dispatch's ONE host operand (ISSUE 37): the
+            # loop writes each query's bits straight into it.
+            car = RequestCarrier(nq, dim, self.serve_pad_granularity)
+            q = car.q[:nq]
             valid = np.zeros((nq,), bool)
             tenants = np.full((nq,), -1, np.int32)
             gate_on = np.zeros((nq,), bool)
@@ -3201,53 +3205,42 @@ class MemoryIndex:
                                  k_bucket)
             if not valid.any():
                 return results
-            # Batches pad to a LINEAR granularity bucket, not the next power
-            # of two: worst-case padded waste is granularity-1 slots instead
-            # of ~50% of the dispatch, with jit specializations still bounded.
-            qp = pad_to_bucket(q, self.serve_pad_granularity)
-            pad_n = qp.shape[0]
+            pad_n = car.buf.shape[0]
         with tel.span("index.stage"):
             # Coalesce/pad inflation: padded kernel slots vs live requests.
             tel.bump("serve.live_requests", nq)
             tel.bump("serve.padded_slots", pad_n)
             tel.gauge("serve.batch_occupancy", nq / pad_n)
-
-            def padb(arr, fill=False, dt=bool):
-                out = np.full((pad_n,), fill, dt)
-                out[:nq] = arr
-                return out
-
             indptr, nbr = self._csr_for(st)
             tm = self.tiering
+            # Per-query k / cap / nprobe as int32 DATA next to the query
+            # batch. Pad rows carry 0 (their top-k masks fully dead; they
+            # were q_valid=False anyway). ``now`` is read here, a moment
+            # before a boosting launch takes _state_lock.
+            car.fill(valid=valid, tenant=tenants, gate_on=gate_on,
+                     boost_on=boost_on, k=k_arr, cap=cap_arr,
+                     super_gate=super_gate, acc_boost=acc_boost,
+                     nbr_boost=nbr_boost,
+                     now=(now if now is not None else time.time())
+                     - self.epoch)
+            boosting = bool(boost_on.any())
             if self.mesh is None:
-                args = (indptr, nbr, jnp.asarray(qp),
-                        jnp.asarray(padb(valid)),
-                        jnp.asarray(padb(tenants, -1, np.int32)),
-                        jnp.asarray(padb(gate_on)))
                 statics = dict(k=k_bucket, cap_take=min(cap_take, k_bucket),
                                max_nbr=max_nbr)
                 coarse_tabs = route.coarse_tabs
                 if coarse_tabs is not None:
-                    statics["nprobe"] = coarse_tabs[3]
+                    ceil_np = statics["nprobe"] = coarse_tabs[3]
                     statics["slack"] = self.coarse_slack
-                elif mode in ("quant", "tiered"):
-                    statics["slack"] = self.coarse_slack
-                # Per-query k / cap / nprobe as int32 DATA next to the query
-                # batch. Pad rows carry 0 (their top-k masks fully dead; they
-                # were q_valid=False anyway).
-                np.minimum(cap_arr, statics["cap_take"], out=cap_arr)
-                k_dev = jnp.asarray(padb(k_arr, 0, np.int32))
-                capq_dev = jnp.asarray(padb(cap_arr, 0, np.int32))
-                npq = ()              # the coarse families' probe-width column
-                if coarse_tabs is not None:
-                    ceil_np = coarse_tabs[3]
+                    # the coarse families' probe-width column
                     np_arr = np.zeros((nq,), np.int32)
                     for i, r in enumerate(reqs):
                         rn = getattr(r, "nprobe", None)
                         np_arr[i] = (min(max(int(rn), 1), ceil_np) if rn
                                      else ceil_np)
                     np_arr[~valid] = 0
-                    npq = (jnp.asarray(padb(np_arr, 0, np.int32)),)
+                    car.fill(nprobe=np_arr)
+                elif mode in ("quant", "tiered"):
+                    statics["slack"] = self.coarse_slack
                 if scan_chunk:
                     # Planner streaming-width override (ISSUE 11): the scan
                     # chunks the arena stream tighter — smaller [chunk, rows]
@@ -3271,13 +3264,12 @@ class MemoryIndex:
                 self._note_select_core(mode, st)
                 donated, copying, read = (getattr(S, name)
                                           for name in _SERVE_KERNELS[mode])
-                read_cols = (k_dev, *npq)
-                self._maybe_record_hbm(route, st, read, args, read_cols,
-                                       super_gate, statics)
+                args = (indptr, nbr, self._hand_requests(car, mode))
+                self._maybe_record_hbm(route, st, read, args, statics)
                 # Fault point "plan.oom" (ISSUE 11): an HBM allocation failure the
                 # admission plan missed; the wrapper answers with one replan.
                 faults.fire("plan.oom", mode=mode, batch=pad_n)
-                if sem_kw and not boost_on.any():
+                if sem_kw and not boosting:
                     # the read twins take the ring operand as a plain kwarg next
                     # to their statics; the boost branch passes it explicitly
                     # beside its donated state
@@ -3297,13 +3289,10 @@ class MemoryIndex:
                     win = k_bucket + (self.coarse_slack if tiered else 0)
                     if win <= semh.width:
                         sem_state = semh.tuple_for(fam)
-                # every host→device put of the distributed dispatch, as on
-                # one chip: ``dispatch.launch`` is the jitted call alone
+                # the distributed program's lookup, staged as on one chip
                 staged = self._stage_fused_sharded(
-                    st, indptr, nbr, qp, padb, valid, tenants, gate_on,
-                    boost_on, k_bucket, cap_take, max_nbr, super_gate,
-                    acc_boost, nbr_boost, now, fam, k_arr, cap_arr,
-                    sem=sem_state)
+                    st, indptr, nbr, car, k_bucket, cap_take, max_nbr, fam,
+                    boosting, sem=sem_state)
                 # Fault point "plan.oom" (ISSUE 11): models an HBM allocation
                 # failure the admission plan missed — recovery is ONE replan
                 # into split sub-dispatches through the copy twins.
@@ -3312,11 +3301,10 @@ class MemoryIndex:
             with tel.span("serve." + mode, timer="serve.dispatch_ms",
                           labels={"mode": mode}):
                 with tel.span("dispatch.launch"):
-                    if staged.boost_extra is None:
+                    if not staged.boosting:
                         packed = staged.kern.read(
                             st, self._sharded_tables(st, tiered),
-                            *staged.sargs, *staged.read_extra,
-                            *staged.sem_tail)
+                            *staged.sargs, *staged.sem_tail)
                     else:
                         # a live snapshot would trip the sole-owner gate,
                         # and this frame's reference is one
@@ -3364,17 +3352,10 @@ class MemoryIndex:
         with tel.span("serve." + mode, timer="serve.dispatch_ms",
                       labels={"mode": mode}):
             with tel.span("dispatch.launch"):
-                if boost_on.any():
+                if boosting:
                     del st  # a live snapshot would trip the sole-owner gate
-                    now_rel = ((now if now is not None else time.time())
-                               - self.epoch)
                     with self._state_lock:
                         cur = self._state
-                        scalars = (jnp.float32(now_rel),
-                                   jnp.float32(super_gate),
-                                   jnp.float32(acc_boost),
-                                   jnp.float32(nbr_boost))
-                        boost_dev = jnp.asarray(padb(boost_on))
                         # force_copy: a post-OOM replan always dispatches
                         # through the non-donating twin (ISSUE 11)
                         sole = (not force_copy
@@ -3389,11 +3370,9 @@ class MemoryIndex:
                         # copying twin, a consumed input raises typed
                         # ArenaPoisoned.
                         pre = self._serve_operands(route, cur)
-                        boost_args = (boost_dev, k_dev, capq_dev, *npq,
-                                      *scalars)
                         out = self._guarded(
-                            lambda fn: fn(cur, *pre, *args, *boost_args,
-                                          **sem_kw, **statics),
+                            lambda fn: fn(cur, *pre, *args, **sem_kw,
+                                          **statics),
                             donated, copying, sole, (cur,),
                             "serve_" + mode)
                         if sem_kw:
@@ -3404,8 +3383,7 @@ class MemoryIndex:
                         self.state = new_state
                 else:
                     packed = read(st, *self._serve_operands(route, st),
-                                  *args, *read_cols,
-                                  jnp.float32(super_gate), **statics)
+                                  *args, **statics)
                     if sem_kw:
                         sem_ring2, packed = packed
             with tel.span("dispatch.readback"):
@@ -3452,6 +3430,21 @@ class MemoryIndex:
                 np.asarray([min(int(r.k), cap) for r in reqs]),
                 sem_active=bool(sem_kw))
         return out
+
+    def _hand_requests(self, car: RequestCarrier, mode: str) -> np.ndarray:
+        """The ONE host→device transfer of a serving dispatch (ISSUE 37):
+        its request carrier, handed to the jitted call as the NumPy array
+        it is — the call's own argument handling makes the transfer (under
+        a mesh one to every chip, the carrier is replicated). On the chip's
+        host that is 0.16 ms a dispatch less than a ``jax.device_put``
+        before the call (PERF.md §6, PR 37), so ``lz.index.stage`` holds no
+        transfer and ``lz.dispatch.launch`` holds this one.
+        ``serve.h2d_puts{mode}`` counts these beside
+        ``serve.dispatches{mode}``: their ratio reads 1 while nobody adds a
+        second transfer (a CSR or shadow rebuild is not a request's, nor
+        are the cold rows of a tiered turn's bounded finish dispatch)."""
+        self.telemetry.bump("serve.h2d_puts", labels={"mode": mode})
+        return car.buf
 
     def _note_select_core(self, mode: str, st) -> None:
         """``serve.select{core}``: which form of the select-while-scanning
@@ -3564,7 +3557,7 @@ class MemoryIndex:
             return True
 
     def _maybe_record_hbm(self, route: _ServeRoute, st, read, args,
-                          read_cols, super_gate, statics) -> None:
+                          statics) -> None:
         """Record the ``memory_analysis()`` peak-HBM gauge for one fused
         serving geometry, once per (mode × k-bucket × cap/nbr) key —
         "Memory Safe Computations with XLA": compiled-program introspection
@@ -3580,7 +3573,6 @@ class MemoryIndex:
             return
         try:
             lowered = read.lower(st, *self._serve_operands(route, st), *args,
-                                 *read_cols, jnp.float32(super_gate),
                                  **statics)
             peak = peak_bytes(lowered.compile().memory_analysis())
         except Exception:   # noqa: BLE001 — observability must never serve 500s
@@ -3678,46 +3670,28 @@ class MemoryIndex:
             return (*self._int8_shadow_for(st), self.tiering.cold_mask_dev())
         return self._int8_shadow_for(st) if self.int8_serving else ()
 
-    def _stage_fused_sharded(self, st, indptr, nbr, qp, padb, valid,
-                             tenants, gate_on, boost_on, k_bucket, cap_take,
-                             max_nbr, super_gate, acc_boost, nbr_boost,
-                             now, mode, k_arr, cap_arr, *,
-                             sem=None) -> _StagedSharded:
+    def _stage_fused_sharded(self, st, indptr, nbr, car: RequestCarrier,
+                             k_bucket, cap_take, max_nbr, mode,
+                             boosting: bool, *, sem=None) -> _StagedSharded:
         """Everything the pod serving dispatch (ISSUE 5) needs before its
         launch, made under ``lz.index.stage`` as on one chip: the compiled
-        program for the batch's geometry and every host→device put.
-        ``indptr``/``nbr`` are the PER-SHARD CSR slices ``_csr_for`` builds
-        under a mesh; ``mode`` is the route's family (``exact`` / ``quant``
-        / ``tiered``), ``k_bucket`` the static ceiling; the per-query
-        (k, cap) columns ``k_arr``/``cap_arr`` ride as device data.
-        ``boost_extra`` is None for a batch that asked for no boost: it
-        takes the read twin."""
+        program for the batch's geometry and the request carrier ``car``,
+        the ONE host operand (``_hand_requests``). ``indptr``/``nbr``
+        are the PER-SHARD CSR slices ``_csr_for`` builds under a mesh;
+        ``mode`` is the route's family (``exact`` / ``quant`` / ``tiered``),
+        ``k_bucket`` the static ceiling. A batch that asked for no boost
+        (``boosting`` False) takes the read twin."""
         tiered = mode == "tiered"
         kern = self._fused_sharded_kernels(mode, k_bucket, cap_take,
                                            max_nbr, sem=sem is not None)
         sem_tail = () if sem is None else (sem,)
-        sargs = (indptr, nbr, jnp.asarray(qp), jnp.asarray(padb(valid)),
-                 jnp.asarray(padb(tenants, -1, np.int32)),
-                 jnp.asarray(padb(gate_on)))
-        boosting = bool(boost_on.any())
-        boost_extra = (jnp.asarray(padb(boost_on)),) if boosting else None
-        k_dev = jnp.asarray(padb(np.minimum(k_arr, k_bucket), 0, np.int32))
-        # the dense modes share the coarse modes' ABI; nprobe_q is inert here
-        npq_dev = jnp.asarray(np.zeros((qp.shape[0],), np.int32))
-        read_extra = (k_dev, npq_dev, jnp.float32(super_gate))
-        if boosting:
-            cap_s = min(cap_take, k_bucket)
-            capq_dev = jnp.asarray(padb(np.minimum(cap_arr, cap_s), 0,
-                                        np.int32))
-            boost_extra += (k_dev, capq_dev, npq_dev)
-            now_rel = (now if now is not None else time.time()) - self.epoch
-            boost_extra += (jnp.float32(now_rel), jnp.float32(super_gate),
-                            jnp.float32(acc_boost), jnp.float32(nbr_boost))
+        sargs = (indptr, nbr, self._hand_requests(car, "sharded_" + mode))
+        pad_n = car.buf.shape[0]
         # what sharded_topk_merge gathers: every shard's k_merge candidates
         # of every padded query
         self.telemetry.bump(
             "serve.merge_candidates",
-            self._n_parts * int(qp.shape[0])
+            self._n_parts * pad_n
             * (k_bucket + (self.coarse_slack if tiered else 0)),
             labels={"mode": "sharded_" + mode})
         if self.telemetry_hbm and self.telemetry.enabled:
@@ -3726,8 +3700,7 @@ class MemoryIndex:
                 try:
                     peak = peak_bytes(kern.read.lower(
                         st, self._sharded_tables(st, tiered), *sargs,
-                        *read_extra, *sem_tail
-                    ).compile().memory_analysis())
+                        *sem_tail).compile().memory_analysis())
                 except Exception:   # noqa: BLE001 — never fail the serve
                     peak = None
                 if peak is not None:
@@ -3736,11 +3709,11 @@ class MemoryIndex:
                         labels={"mode": f"sharded_{mode}",
                                 "k": str(k_bucket),
                                 "rows": str(st.salience.shape[0]),
-                                "batch": str(int(qp.shape[0])),
+                                "batch": str(pad_n),
                                 "mesh": f"{self._n_parts}x{self.shard_axis}"})
                     self.planner.observe_gauge(
                         Geometry(kind="serve", mode=f"sharded_{mode}",
-                                 batch=int(qp.shape[0]),
+                                 batch=pad_n,
                                  rows=int(st.salience.shape[0]), dim=self.dim,
                                  k=int(k_bucket),
                                  dtype_bytes=int(
@@ -3748,7 +3721,7 @@ class MemoryIndex:
                                  mesh_parts=self._n_parts,
                                  edge_cap=self.edge_state.capacity),
                         peak)
-        return _StagedSharded(kern, sargs, read_extra, boost_extra, sem_tail)
+        return _StagedSharded(kern, sargs, boosting, sem_tail)
 
     def _serve_fused_sharded(self, staged: _StagedSharded, mode: str,
                              tiered: bool, force_copy: bool):
@@ -3768,8 +3741,7 @@ class MemoryIndex:
                 self.telemetry.bump("serve.copy_dispatches",
                                     labels={"mode": mode})
             out = self._guarded(
-                lambda fn: fn(cur, tables, *staged.sargs,
-                              *staged.boost_extra, *staged.sem_tail),
+                lambda fn: fn(cur, tables, *staged.sargs, *staged.sem_tail),
                 staged.kern.serve, staged.kern.serve_copy, sole, (cur,),
                 "serve_sharded")
             new_state, packed = out[0], out[1:]

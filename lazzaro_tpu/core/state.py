@@ -59,6 +59,8 @@ from flax import struct
 from lazzaro_tpu.ops.backend import on_tpu
 from lazzaro_tpu.ops.chunking import (QUERY_CHUNK, chunked_map,
                                       chunked_map_multi)
+from lazzaro_tpu.utils.batching import (REQUEST_COLS, REQUEST_FIELDS,
+                                        REQUEST_SCALARS)
 
 NEG_INF = -1e30
 
@@ -1109,6 +1111,54 @@ def _bitcast_i32(x: jax.Array) -> jax.Array:
     counter is a denormal bit pattern as a float, and a TPU flushes
     denormals to zero when it moves floats."""
     return jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+
+
+class Requests(NamedTuple):
+    """What ``_unpack_requests`` reads out of one dispatch's carrier."""
+
+    q: jax.Array            # [Q, d] f32 padded query batch
+    q_valid: jax.Array      # [Q] bool (False for pad rows)
+    tenant: jax.Array       # [Q] i32 per-query tenant (-1 matches no row)
+    gate_on: jax.Array      # [Q] bool hierarchy gate enabled
+    boost_on: jax.Array     # [Q] bool apply device boosts for this query
+    k_q: jax.Array          # [Q] i32 per-query k (0 for pad rows)
+    cap_q: jax.Array        # [Q] i32 per-query retrieval cap
+    nprobe_q: jax.Array     # [Q] i32 per-query probe width (coarse families)
+    super_gate: jax.Array   # f32 scalars of the dispatch
+    now: jax.Array
+    acc_boost: jax.Array
+    nbr_boost: jax.Array
+
+
+def _unpack_requests(carrier: jax.Array, dim: int) -> Requests:
+    """The shared prologue of every fused serving program (ISSUE 37): the
+    ONE ``[Q, dim + REQUEST_COLS]`` int32 array a dispatch hands the device
+    (``utils.batching.RequestCarrier``, the host half) back into its
+    fields — a slice per column, a bitcast for the f32 ones (the query's
+    bits and the scalars arrive exactly as the host wrote them), which XLA
+    fuses into whatever reads the field first."""
+    with jax.named_scope("lz.unpack"):
+        if carrier.shape[1] != dim + REQUEST_COLS:
+            raise ValueError(f"request carrier of {carrier.shape[1]} columns "
+                             f"for dim {dim}")
+
+        def f32(x):
+            return jax.lax.bitcast_convert_type(x, jnp.float32)
+
+        def col(name):
+            return carrier[:, dim + REQUEST_FIELDS.index(name)]
+
+        def scalar(name):
+            return f32(carrier[0, dim + len(REQUEST_FIELDS)
+                               + REQUEST_SCALARS.index(name)])
+
+        return Requests(
+            q=f32(carrier[:, :dim]), q_valid=col("valid") != 0,
+            tenant=col("tenant"), gate_on=col("gate_on") != 0,
+            boost_on=col("boost_on") != 0, k_q=col("k"), cap_q=col("cap"),
+            nprobe_q=col("nprobe"), super_gate=scalar("super_gate"),
+            now=scalar("now"), acc_boost=scalar("acc_boost"),
+            nbr_boost=scalar("nbr_boost"))
 
 
 def _lifecycle_core(arena: ArenaState, edges: EdgeState, passes: jax.Array,
@@ -2652,17 +2702,7 @@ def _search_fused_ragged(
     state: ArenaState,
     csr_indptr: jax.Array,   # [cap+2] i32 neighbor-list offsets per row
     csr_nbr: jax.Array,      # [E_pad] i32 neighbor rows (bidirectional)
-    q: jax.Array,            # [Q, d] padded query batch
-    q_valid: jax.Array,      # [Q] bool (False for pad rows)
-    tenant: jax.Array,       # [Q] i32 per-query tenant (cross-tenant batch)
-    gate_on: jax.Array,      # [Q] bool hierarchy gate enabled
-    boost_on: jax.Array,     # [Q] bool apply device boosts for this query
-    k_q: jax.Array,          # [Q] i32 per-query k (0 for pad rows)
-    cap_q: jax.Array,        # [Q] i32 per-query retrieval cap
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
+    requests: jax.Array,     # [Q, d + REQUEST_COLS] i32: _unpack_requests
     k: int,                  # STATIC k ceiling (serve_k_max)
     cap_take: int,           # STATIC cap ceiling: top rows that get boosted
     max_nbr: int,
@@ -2681,12 +2721,13 @@ def _search_fused_ragged(
     (probe / early-out / substitution / ring writeback — see
     ``_semantic_scan_core``); when present the return gains the updated
     ring: ``(state, ring, packed)``."""
-    res = _search_fused_scan(state, csr_indptr, csr_nbr, q, q_valid, tenant,
-                             gate_on, boost_on, super_gate, k, cap_take,
-                             max_nbr, k_q=k_q, cap_q=cap_q,
+    r = _unpack_requests(requests, state.dim)
+    res = _search_fused_scan(state, csr_indptr, csr_nbr, r.q, r.q_valid,
+                             r.tenant, r.gate_on, r.boost_on, r.super_gate,
+                             k, cap_take, max_nbr, k_q=r.k_q, cap_q=r.cap_q,
                              scan_chunk=scan_chunk, sem=sem,
                              sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
+    return _sem_finish(state, res, sem, r.now, r.acc_boost, r.nbr_boost)
 
 
 def _boost_scatter(state: ArenaState, acc_rows: jax.Array,
@@ -2816,11 +2857,9 @@ search_fused_ragged, search_fused_ragged_copy = _donated_pair(
 @functools.partial(jax.jit, static_argnames=("k", "cap_take", "max_nbr",
                                              "scan_chunk", "sem_block"))
 def search_fused_ragged_read(state: ArenaState, csr_indptr: jax.Array,
-                             csr_nbr: jax.Array, q: jax.Array,
-                             q_valid: jax.Array, tenant: jax.Array,
-                             gate_on: jax.Array, k_q: jax.Array,
-                             super_gate: jax.Array, k: int, cap_take: int,
-                             max_nbr: int, scan_chunk: int = 0,
+                             csr_nbr: jax.Array, requests: jax.Array,
+                             k: int, cap_take: int, max_nbr: int,
+                             scan_chunk: int = 0,
                              sem=None, sem_block: int = 16) -> jax.Array:
     """Read-only twin of ``search_fused_ragged`` for batches where NO query
     wants boosts (pure ``search_memories`` fleets): same compute, per-query
@@ -2828,12 +2867,13 @@ def search_fused_ragged_read(state: ArenaState, csr_indptr: jax.Array,
     skipped entirely. With ``sem`` the semantic ring still rides (misses
     write back — read fleets warm the cache) and the return becomes
     ``(ring, packed)``."""
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    cap_q = jnp.zeros(q_valid.shape, jnp.int32)
+    r = _unpack_requests(requests, state.dim)
+    boost_off = jnp.zeros(r.q_valid.shape, bool)
+    cap_q = jnp.zeros(r.q_valid.shape, jnp.int32)
     res = _search_fused_scan(
-        state, csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_off,
-        super_gate, k, cap_take, max_nbr, k_q=k_q, cap_q=cap_q,
-        scan_chunk=scan_chunk, sem=sem, sem_block=sem_block)
+        state, csr_indptr, csr_nbr, r.q, r.q_valid, r.tenant, r.gate_on,
+        boost_off, r.super_gate, k, cap_take, max_nbr, k_q=r.k_q,
+        cap_q=cap_q, scan_chunk=scan_chunk, sem=sem, sem_block=sem_block)
     return _sem_finish_read(res, sem)
 
 
@@ -2949,17 +2989,7 @@ def _search_fused_quant_ragged(
     scale_a: jax.Array,      # [cap+1] f32 per-row scales
     csr_indptr: jax.Array,
     csr_nbr: jax.Array,
-    q: jax.Array,
-    q_valid: jax.Array,
-    tenant: jax.Array,
-    gate_on: jax.Array,
-    boost_on: jax.Array,
-    k_q: jax.Array,
-    cap_q: jax.Array,
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
+    requests: jax.Array,     # [Q, d + REQUEST_COLS] i32: _unpack_requests
     k: int,
     slack: int,
     cap_take: int,
@@ -2975,13 +3005,14 @@ def _search_fused_quant_ragged(
     shadow is a long-lived read-only replica (boost scatters touch
     salience/access/freshness, never the embeddings, so the codes stay
     valid)."""
+    r = _unpack_requests(requests, state.dim)
     res = _search_fused_quant_scan(state, q8a, scale_a, csr_indptr, csr_nbr,
-                                   q, q_valid, tenant, gate_on, boost_on,
-                                   super_gate, k, slack, cap_take, max_nbr,
-                                   k_q=k_q, cap_q=cap_q,
-                                   scan_chunk=scan_chunk, sem=sem,
-                                   sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
+                                   r.q, r.q_valid, r.tenant, r.gate_on,
+                                   r.boost_on, r.super_gate, k, slack,
+                                   cap_take, max_nbr, k_q=r.k_q,
+                                   cap_q=r.cap_q, scan_chunk=scan_chunk,
+                                   sem=sem, sem_block=sem_block)
+    return _sem_finish(state, res, sem, r.now, r.acc_boost, r.nbr_boost)
 
 
 search_fused_quant_ragged, search_fused_quant_ragged_copy = _donated_pair(
@@ -2996,10 +3027,8 @@ search_fused_quant_ragged, search_fused_quant_ragged_copy = _donated_pair(
 def search_fused_quant_ragged_read(state: ArenaState, q8a: jax.Array,
                                    scale_a: jax.Array,
                                    csr_indptr: jax.Array,
-                                   csr_nbr: jax.Array, q: jax.Array,
-                                   q_valid: jax.Array, tenant: jax.Array,
-                                   gate_on: jax.Array, k_q: jax.Array,
-                                   super_gate: jax.Array, k: int,
+                                   csr_nbr: jax.Array,
+                                   requests: jax.Array, k: int,
                                    slack: int, cap_take: int,
                                    max_nbr: int, scan_chunk: int = 0,
                                    sem=None,
@@ -3007,12 +3036,13 @@ def search_fused_quant_ragged_read(state: ArenaState, q8a: jax.Array,
     """Read-only twin of ``search_fused_quant_ragged`` (pure
     ``search_memories`` fleets in int8 mode): same coarse-scan +
     exact-rescore compute, no state mutation, no donation dance."""
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    cap_q = jnp.zeros(q_valid.shape, jnp.int32)
+    r = _unpack_requests(requests, state.dim)
+    boost_off = jnp.zeros(r.q_valid.shape, bool)
+    cap_q = jnp.zeros(r.q_valid.shape, jnp.int32)
     res = _search_fused_quant_scan(
-        state, q8a, scale_a, csr_indptr, csr_nbr, q, q_valid, tenant,
-        gate_on, boost_off, super_gate, k, slack, cap_take, max_nbr,
-        k_q=k_q, cap_q=cap_q, scan_chunk=scan_chunk, sem=sem,
+        state, q8a, scale_a, csr_indptr, csr_nbr, r.q, r.q_valid, r.tenant,
+        r.gate_on, boost_off, r.super_gate, k, slack, cap_take, max_nbr,
+        k_q=r.k_q, cap_q=cap_q, scan_chunk=scan_chunk, sem=sem,
         sem_block=sem_block)
     return _sem_finish_read(res, sem)
 
@@ -3237,17 +3267,7 @@ def _search_fused_tiered_ragged(
     cold: jax.Array,         # [cap+1] bool residency column (True = cold)
     csr_indptr: jax.Array,
     csr_nbr: jax.Array,
-    q: jax.Array,
-    q_valid: jax.Array,
-    tenant: jax.Array,
-    gate_on: jax.Array,
-    boost_on: jax.Array,
-    k_q: jax.Array,
-    cap_q: jax.Array,
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
+    requests: jax.Array,     # [Q, d + REQUEST_COLS] i32: _unpack_requests
     k: int,
     slack: int,
     cap_take: int,
@@ -3261,13 +3281,14 @@ def _search_fused_tiered_ragged(
     block is k+slack wide, each query's window masked at its own k_i +
     slack boundary. Hot-only queries boost in-kernel; cold-hit queries come
     back unboosted with their candidate window for the finish dispatch."""
+    r = _unpack_requests(requests, state.dim)
     res = _search_fused_tiered_scan(state, q8a, scale_a, cold, csr_indptr,
-                                    csr_nbr, q, q_valid, tenant, gate_on,
-                                    boost_on, super_gate, k, slack,
-                                    cap_take, max_nbr, k_q=k_q, cap_q=cap_q,
-                                    scan_chunk=scan_chunk, sem=sem,
-                                    sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
+                                    csr_nbr, r.q, r.q_valid, r.tenant,
+                                    r.gate_on, r.boost_on, r.super_gate, k,
+                                    slack, cap_take, max_nbr, k_q=r.k_q,
+                                    cap_q=r.cap_q, scan_chunk=scan_chunk,
+                                    sem=sem, sem_block=sem_block)
+    return _sem_finish(state, res, sem, r.now, r.acc_boost, r.nbr_boost)
 
 
 search_fused_tiered_ragged, search_fused_tiered_ragged_copy = _donated_pair(
@@ -3282,21 +3303,20 @@ search_fused_tiered_ragged, search_fused_tiered_ragged_copy = _donated_pair(
 def search_fused_tiered_ragged_read(state: ArenaState, q8a: jax.Array,
                                     scale_a: jax.Array, cold: jax.Array,
                                     csr_indptr: jax.Array,
-                                    csr_nbr: jax.Array, q: jax.Array,
-                                    q_valid: jax.Array, tenant: jax.Array,
-                                    gate_on: jax.Array, k_q: jax.Array,
-                                    super_gate: jax.Array, k: int,
+                                    csr_nbr: jax.Array,
+                                    requests: jax.Array, k: int,
                                     slack: int, cap_take: int,
                                     max_nbr: int,
                                     scan_chunk: int = 0, sem=None,
                                     sem_block: int = 16) -> jax.Array:
     """Read-only tiered twin (pure ``search_memories`` fleets)."""
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    cap_q = jnp.zeros(q_valid.shape, jnp.int32)
+    r = _unpack_requests(requests, state.dim)
+    boost_off = jnp.zeros(r.q_valid.shape, bool)
+    cap_q = jnp.zeros(r.q_valid.shape, jnp.int32)
     res = _search_fused_tiered_scan(
-        state, q8a, scale_a, cold, csr_indptr, csr_nbr, q, q_valid, tenant,
-        gate_on, boost_off, super_gate, k, slack, cap_take, max_nbr,
-        k_q=k_q, cap_q=cap_q, scan_chunk=scan_chunk, sem=sem,
+        state, q8a, scale_a, cold, csr_indptr, csr_nbr, r.q, r.q_valid,
+        r.tenant, r.gate_on, boost_off, r.super_gate, k, slack, cap_take,
+        max_nbr, k_q=r.k_q, cap_q=cap_q, scan_chunk=scan_chunk, sem=sem,
         sem_block=sem_block)
     return _sem_finish_read(res, sem)
 
@@ -3578,18 +3598,7 @@ def _search_fused_ivf_ragged(
     extras: jax.Array,       # [E] i32 residual + fresh + super rows, -1 pad
     csr_indptr: jax.Array,
     csr_nbr: jax.Array,
-    q: jax.Array,
-    q_valid: jax.Array,
-    tenant: jax.Array,
-    gate_on: jax.Array,
-    boost_on: jax.Array,
-    k_q: jax.Array,
-    cap_q: jax.Array,
-    nprobe_q: jax.Array,     # [Q] i32 per-query probe width (≤ nprobe)
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
+    requests: jax.Array,     # [Q, d + REQUEST_COLS] i32: _unpack_requests
     k: int,
     nprobe: int,             # STATIC probe ceiling (the build's width)
     slack: int,
@@ -3607,14 +3616,15 @@ def _search_fused_ivf_ragged(
     centroid/member/extras tables and the optional int8 shadow are
     long-lived read-only replicas (the boost scatter touches salience/
     access/freshness, never embeddings or routing)."""
+    r = _unpack_requests(requests, state.dim)
     res = _search_fused_ivf_scan(state, shadow, centroids, members, extras,
-                                 csr_indptr, csr_nbr, q, q_valid, tenant,
-                                 gate_on, boost_on, super_gate, k, nprobe,
-                                 slack, cap_take, max_nbr, k_q=k_q,
-                                 cap_q=cap_q, nprobe_q=nprobe_q,
-                                 scan_chunk=scan_chunk, sem=sem,
-                                 sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
+                                 csr_indptr, csr_nbr, r.q, r.q_valid,
+                                 r.tenant, r.gate_on, r.boost_on,
+                                 r.super_gate, k, nprobe, slack, cap_take,
+                                 max_nbr, k_q=r.k_q, cap_q=r.cap_q,
+                                 nprobe_q=r.nprobe_q, scan_chunk=scan_chunk,
+                                 sem=sem, sem_block=sem_block)
+    return _sem_finish(state, res, sem, r.now, r.acc_boost, r.nbr_boost)
 
 
 search_fused_ivf_ragged, search_fused_ivf_ragged_copy = _donated_pair(
@@ -3629,24 +3639,23 @@ search_fused_ivf_ragged, search_fused_ivf_ragged_copy = _donated_pair(
 def search_fused_ivf_ragged_read(state: ArenaState, shadow,
                                  centroids: jax.Array, members: jax.Array,
                                  extras: jax.Array, csr_indptr: jax.Array,
-                                 csr_nbr: jax.Array, q: jax.Array,
-                                 q_valid: jax.Array, tenant: jax.Array,
-                                 gate_on: jax.Array, k_q: jax.Array,
-                                 nprobe_q: jax.Array,
-                                 super_gate: jax.Array, k: int, nprobe: int,
+                                 csr_nbr: jax.Array, requests: jax.Array,
+                                 k: int, nprobe: int,
                                  slack: int, cap_take: int, max_nbr: int,
                                  scan_chunk: int = 0, sem=None,
                                  sem_block: int = 16) -> jax.Array:
     """Read-only twin of ``search_fused_ivf_ragged`` (pure
     ``search_memories`` fleets in IVF mode): same coarse prefilter +
     candidate scan, no state mutation, no donation dance."""
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    cap_q = jnp.zeros(q_valid.shape, jnp.int32)
+    r = _unpack_requests(requests, state.dim)
+    boost_off = jnp.zeros(r.q_valid.shape, bool)
+    cap_q = jnp.zeros(r.q_valid.shape, jnp.int32)
     res = _search_fused_ivf_scan(
-        state, shadow, centroids, members, extras, csr_indptr, csr_nbr, q,
-        q_valid, tenant, gate_on, boost_off, super_gate, k, nprobe, slack,
-        cap_take, max_nbr, k_q=k_q, cap_q=cap_q, nprobe_q=nprobe_q,
-        scan_chunk=scan_chunk, sem=sem, sem_block=sem_block)
+        state, shadow, centroids, members, extras, csr_indptr, csr_nbr,
+        r.q, r.q_valid, r.tenant, r.gate_on, boost_off, r.super_gate, k,
+        nprobe, slack, cap_take, max_nbr, k_q=r.k_q, cap_q=cap_q,
+        nprobe_q=r.nprobe_q, scan_chunk=scan_chunk, sem=sem,
+        sem_block=sem_block)
     return _sem_finish_read(res, sem)
 
 
@@ -3780,18 +3789,7 @@ def _search_fused_ivf_tiered_ragged(
     extras: jax.Array,
     csr_indptr: jax.Array,
     csr_nbr: jax.Array,
-    q: jax.Array,
-    q_valid: jax.Array,
-    tenant: jax.Array,
-    gate_on: jax.Array,
-    boost_on: jax.Array,
-    k_q: jax.Array,
-    cap_q: jax.Array,
-    nprobe_q: jax.Array,
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
+    requests: jax.Array,     # [Q, d + REQUEST_COLS] i32: _unpack_requests
     k: int,
     nprobe: int,
     slack: int,
@@ -3804,13 +3802,14 @@ def _search_fused_ivf_tiered_ragged(
     """ONE donated dispatch + ONE packed readback: IVF coarse stage for the
     hot tier, cold-masked int8 coarse for the demoted rows, tiered
     candidate window (k+slack wide) for the bounded finish."""
+    r = _unpack_requests(requests, state.dim)
     res = _search_fused_ivf_tiered_scan(
         state, q8a, scale_a, cold, centroids, members, extras,
-        csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_on,
-        super_gate, k, nprobe, slack, cap_take, max_nbr, k_q=k_q,
-        cap_q=cap_q, nprobe_q=nprobe_q, scan_chunk=scan_chunk, sem=sem,
-        sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
+        csr_indptr, csr_nbr, r.q, r.q_valid, r.tenant, r.gate_on,
+        r.boost_on, r.super_gate, k, nprobe, slack, cap_take, max_nbr,
+        k_q=r.k_q, cap_q=r.cap_q, nprobe_q=r.nprobe_q,
+        scan_chunk=scan_chunk, sem=sem, sem_block=sem_block)
+    return _sem_finish(state, res, sem, r.now, r.acc_boost, r.nbr_boost)
 
 
 search_fused_ivf_tiered_ragged, search_fused_ivf_tiered_ragged_copy = \
@@ -3826,19 +3825,18 @@ def search_fused_ivf_tiered_ragged_read(
         state: ArenaState, q8a: jax.Array, scale_a: jax.Array,
         cold: jax.Array, centroids: jax.Array, members: jax.Array,
         extras: jax.Array, csr_indptr: jax.Array, csr_nbr: jax.Array,
-        q: jax.Array, q_valid: jax.Array, tenant: jax.Array,
-        gate_on: jax.Array, k_q: jax.Array, nprobe_q: jax.Array,
-        super_gate: jax.Array, k: int, nprobe: int, slack: int,
+        requests: jax.Array, k: int, nprobe: int, slack: int,
         cap_take: int, max_nbr: int, scan_chunk: int = 0,
         sem=None, sem_block: int = 16) -> jax.Array:
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    cap_q = jnp.zeros(q_valid.shape, jnp.int32)
+    r = _unpack_requests(requests, state.dim)
+    boost_off = jnp.zeros(r.q_valid.shape, bool)
+    cap_q = jnp.zeros(r.q_valid.shape, jnp.int32)
     res = _search_fused_ivf_tiered_scan(
         state, q8a, scale_a, cold, centroids, members, extras,
-        csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_off,
-        super_gate, k, nprobe, slack, cap_take, max_nbr, k_q=k_q,
-        cap_q=cap_q, nprobe_q=nprobe_q, scan_chunk=scan_chunk, sem=sem,
-        sem_block=sem_block)
+        csr_indptr, csr_nbr, r.q, r.q_valid, r.tenant, r.gate_on,
+        boost_off, r.super_gate, k, nprobe, slack, cap_take, max_nbr,
+        k_q=r.k_q, cap_q=cap_q, nprobe_q=r.nprobe_q, scan_chunk=scan_chunk,
+        sem=sem, sem_block=sem_block)
     return _sem_finish_read(res, sem)
 
 
@@ -3994,18 +3992,7 @@ def _search_fused_pq_ragged(
     extras: jax.Array,       # [E] i32 residual + fresh + super rows, -1 pad
     csr_indptr: jax.Array,
     csr_nbr: jax.Array,
-    q: jax.Array,
-    q_valid: jax.Array,
-    tenant: jax.Array,
-    gate_on: jax.Array,
-    boost_on: jax.Array,
-    k_q: jax.Array,
-    cap_q: jax.Array,
-    nprobe_q: jax.Array,     # [Q] i32 per-query probe width (≤ nprobe)
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
+    requests: jax.Array,     # [Q, d + REQUEST_COLS] i32: _unpack_requests
     k: int,
     nprobe: int,             # STATIC probe ceiling (the build's width)
     slack: int,
@@ -4023,14 +4010,15 @@ def _search_fused_pq_ragged(
     codes slab, and coarse tables are long-lived read-only replicas (the
     boost scatter touches salience/access/freshness, never embeddings or
     codes)."""
+    r = _unpack_requests(requests, state.dim)
     res = _search_fused_pq_scan(state, book_cent, codes, centroids, members,
-                                extras, csr_indptr, csr_nbr, q, q_valid,
-                                tenant, gate_on, boost_on, super_gate, k,
-                                nprobe, slack, cap_take, max_nbr, k_q=k_q,
-                                cap_q=cap_q, nprobe_q=nprobe_q,
-                                scan_chunk=scan_chunk, sem=sem,
-                                sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
+                                extras, csr_indptr, csr_nbr, r.q, r.q_valid,
+                                r.tenant, r.gate_on, r.boost_on,
+                                r.super_gate, k, nprobe, slack, cap_take,
+                                max_nbr, k_q=r.k_q, cap_q=r.cap_q,
+                                nprobe_q=r.nprobe_q, scan_chunk=scan_chunk,
+                                sem=sem, sem_block=sem_block)
+    return _sem_finish(state, res, sem, r.now, r.acc_boost, r.nbr_boost)
 
 
 search_fused_pq_ragged, search_fused_pq_ragged_copy = _donated_pair(
@@ -4046,23 +4034,21 @@ def search_fused_pq_ragged_read(state: ArenaState, book_cent: jax.Array,
                                 codes: jax.Array, centroids: jax.Array,
                                 members: jax.Array, extras: jax.Array,
                                 csr_indptr: jax.Array, csr_nbr: jax.Array,
-                                q: jax.Array, q_valid: jax.Array,
-                                tenant: jax.Array, gate_on: jax.Array,
-                                k_q: jax.Array, nprobe_q: jax.Array,
-                                super_gate: jax.Array, k: int, nprobe: int,
+                                requests: jax.Array, k: int, nprobe: int,
                                 slack: int, cap_take: int, max_nbr: int,
                                 scan_chunk: int = 0, sem=None,
                                 sem_block: int = 16) -> jax.Array:
     """Read-only twin of ``search_fused_pq_ragged`` (pure
     ``search_memories`` fleets in PQ mode): same ADC scan + exact rescore,
     no state mutation, no donation dance."""
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    cap_q = jnp.zeros(q_valid.shape, jnp.int32)
+    r = _unpack_requests(requests, state.dim)
+    boost_off = jnp.zeros(r.q_valid.shape, bool)
+    cap_q = jnp.zeros(r.q_valid.shape, jnp.int32)
     res = _search_fused_pq_scan(
         state, book_cent, codes, centroids, members, extras, csr_indptr,
-        csr_nbr, q, q_valid, tenant, gate_on, boost_off, super_gate, k,
-        nprobe, slack, cap_take, max_nbr, k_q=k_q, cap_q=cap_q,
-        nprobe_q=nprobe_q, scan_chunk=scan_chunk, sem=sem,
+        csr_nbr, r.q, r.q_valid, r.tenant, r.gate_on, boost_off,
+        r.super_gate, k, nprobe, slack, cap_take, max_nbr, k_q=r.k_q,
+        cap_q=cap_q, nprobe_q=r.nprobe_q, scan_chunk=scan_chunk, sem=sem,
         sem_block=sem_block)
     return _sem_finish_read(res, sem)
 
@@ -4190,18 +4176,7 @@ def _search_fused_pq_tiered_ragged(
     extras: jax.Array,
     csr_indptr: jax.Array,
     csr_nbr: jax.Array,
-    q: jax.Array,
-    q_valid: jax.Array,
-    tenant: jax.Array,
-    gate_on: jax.Array,
-    boost_on: jax.Array,
-    k_q: jax.Array,
-    cap_q: jax.Array,
-    nprobe_q: jax.Array,
-    now: jax.Array,
-    super_gate: jax.Array,
-    acc_boost: jax.Array,
-    nbr_boost: jax.Array,
+    requests: jax.Array,     # [Q, d + REQUEST_COLS] i32: _unpack_requests
     k: int,
     nprobe: int,
     slack: int,
@@ -4214,13 +4189,14 @@ def _search_fused_pq_tiered_ragged(
     """ONE donated dispatch + ONE packed readback: IVF member gather for
     the hot tier, cold-masked ADC coarse for the demoted rows, tiered
     candidate window (k+slack wide) for the bounded finish."""
+    r = _unpack_requests(requests, state.dim)
     res = _search_fused_pq_tiered_scan(
         state, book_cent, codes, cold, centroids, members, extras,
-        csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_on,
-        super_gate, k, nprobe, slack, cap_take, max_nbr, k_q=k_q,
-        cap_q=cap_q, nprobe_q=nprobe_q, scan_chunk=scan_chunk,
-        sem=sem, sem_block=sem_block)
-    return _sem_finish(state, res, sem, now, acc_boost, nbr_boost)
+        csr_indptr, csr_nbr, r.q, r.q_valid, r.tenant, r.gate_on,
+        r.boost_on, r.super_gate, k, nprobe, slack, cap_take, max_nbr,
+        k_q=r.k_q, cap_q=r.cap_q, nprobe_q=r.nprobe_q,
+        scan_chunk=scan_chunk, sem=sem, sem_block=sem_block)
+    return _sem_finish(state, res, sem, r.now, r.acc_boost, r.nbr_boost)
 
 
 search_fused_pq_tiered_ragged, search_fused_pq_tiered_ragged_copy = \
@@ -4236,19 +4212,18 @@ def search_fused_pq_tiered_ragged_read(
         state: ArenaState, book_cent: jax.Array, codes: jax.Array,
         cold: jax.Array, centroids: jax.Array, members: jax.Array,
         extras: jax.Array, csr_indptr: jax.Array, csr_nbr: jax.Array,
-        q: jax.Array, q_valid: jax.Array, tenant: jax.Array,
-        gate_on: jax.Array, k_q: jax.Array, nprobe_q: jax.Array,
-        super_gate: jax.Array, k: int, nprobe: int, slack: int,
+        requests: jax.Array, k: int, nprobe: int, slack: int,
         cap_take: int, max_nbr: int, scan_chunk: int = 0,
         sem=None, sem_block: int = 16) -> jax.Array:
-    boost_off = jnp.zeros(q_valid.shape, bool)
-    cap_q = jnp.zeros(q_valid.shape, jnp.int32)
+    r = _unpack_requests(requests, state.dim)
+    boost_off = jnp.zeros(r.q_valid.shape, bool)
+    cap_q = jnp.zeros(r.q_valid.shape, jnp.int32)
     res = _search_fused_pq_tiered_scan(
         state, book_cent, codes, cold, centroids, members, extras,
-        csr_indptr, csr_nbr, q, q_valid, tenant, gate_on, boost_off,
-        super_gate, k, nprobe, slack, cap_take, max_nbr, k_q=k_q,
-        cap_q=cap_q, nprobe_q=nprobe_q, scan_chunk=scan_chunk, sem=sem,
-        sem_block=sem_block)
+        csr_indptr, csr_nbr, r.q, r.q_valid, r.tenant, r.gate_on,
+        boost_off, r.super_gate, k, nprobe, slack, cap_take, max_nbr,
+        k_q=r.k_q, cap_q=cap_q, nprobe_q=r.nprobe_q, scan_chunk=scan_chunk,
+        sem=sem, sem_block=sem_block)
     return _sem_finish_read(res, sem)
 
 
@@ -4327,22 +4302,21 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
 
     Call signatures (tables is the mode's tuple above, ``()`` for exact):
 
-    ``serve(state, tables, csr_indptr [n,L+1], csr_nbr [n,E], q [Q,d],
-    q_valid [Q], tenant [Q], gate_on [Q], boost_on [Q], k_q [Q], cap_q [Q],
-    nprobe_q [Q], now, super_gate, acc_boost, nbr_boost) -> (state, packed
-    [Q, 3+2k])`` — donates the state (ONE distributed dispatch, shard-local
-    boost scatters in place); ``serve_copy`` is the non-donating twin;
-    ``read(state, tables, csr_indptr, csr_nbr, q, q_valid, tenant, gate_on,
-    k_q, nprobe_q, super_gate) -> packed`` skips the mutation entirely.
+    ``serve(state, tables, csr_indptr [n,L+1], csr_nbr [n,E], requests
+    [Q, d + REQUEST_COLS]) -> (state, packed [Q, 3+2k])`` — donates the
+    state (ONE distributed dispatch, shard-local boost scatters in place);
+    ``serve_copy`` is the non-donating twin; ``read``, the same operands
+    ``-> packed``, skips the mutation entirely. ``requests`` is the
+    dispatch's ONE request carrier (``_unpack_requests``), REPLICATED: the
+    one host operand reaches every chip.
 
-    ``k`` / ``cap_take`` / ``nprobe`` are static CEILINGS; ``k_q`` /
-    ``cap_q`` / ``nprobe_q`` are replicated [Q] i32 columns carrying each
-    query's own shape, so ONE compiled distributed program serves any mix
-    of request shapes (the shard-local scans and the all_gather merge run
-    to the ceiling; each query masks at its own boundaries,
-    ``ops.topk.sharded_topk_merge`` applying the k mask at the merge).
-    ``nprobe_q`` is accepted and ignored by the dense modes so every mode
-    shares one ABI.
+    ``k`` / ``cap_take`` / ``nprobe`` are static CEILINGS; the carrier's
+    ``k`` / ``cap`` / ``nprobe`` columns carry each query's own shape, so
+    ONE compiled distributed program serves any mix of request shapes (the
+    shard-local scans and the all_gather merge run to the ceiling; each
+    query masks at its own boundaries, ``ops.topk.sharded_topk_merge``
+    applying the k mask at the merge). The dense modes ignore the probe
+    column, so every mode shares one ABI.
 
     The per-shard CSR carries each chip's OWN rows' neighbor lists with
     GLOBAL neighbor ids; Q is bounded by the scheduler's padded batch
@@ -4547,9 +4521,11 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
         return (gate_s, gate_r, ann_s, ann_r, fast, n_dup,
                 cold_any & ~hit, hit, sem_col, ring2)
 
-    def _serve_local(arena, tables, indptr2, nbr2, q, q_valid, tenant,
-                     gate_on, boost_on, k_q, cap_q, nprobe_q, now,
-                     super_gate, acc_boost, nbr_boost, sem_state=None):
+    def _serve_local(arena, tables, indptr2, nbr2, requests,
+                     sem_state=None):
+        (q, q_valid, tenant, gate_on, boost_on, k_q, cap_q, nprobe_q,
+         super_gate, now, acc_boost, nbr_boost) = _unpack_requests(
+             requests, arena.emb.shape[1])
         merged = _scan_merge(arena, tables, q, tenant, k_q, nprobe_q)
         if sem_state is None:
             gate_s, gate_r, ann_s, ann_r, n_dup, cold_any = merged
@@ -4575,8 +4551,11 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
                                  sem=sem_col)
         return arena, ring2, packed
 
-    def _read_local(arena, tables, indptr2, nbr2, q, q_valid, tenant,
-                    gate_on, k_q, nprobe_q, super_gate, sem_state=None):
+    def _read_local(arena, tables, indptr2, nbr2, requests,
+                    sem_state=None):
+        r = _unpack_requests(requests, arena.emb.shape[1])
+        q, q_valid, tenant, gate_on = r.q, r.q_valid, r.tenant, r.gate_on
+        k_q, nprobe_q, super_gate = r.k_q, r.nprobe_q, r.super_gate
         merged = _scan_merge(arena, tables, q, tenant, k_q, nprobe_q)
         if sem_state is None:
             gate_s, gate_r, ann_s, ann_r, n_dup, _cold = merged
@@ -4606,8 +4585,9 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
         "pq": (P(None, None, None), P(axis, None), P(None, None),
                P(axis, None, None), P(axis, None)),
     }[mode]
+    # arena, tables, the per-shard CSR, and the REPLICATED request carrier
     common = (state_specs, tables_specs, P(axis, None), P(axis, None),
-              P(None, None), P(None), P(None), P(None))
+              P(None, None))
     # Semantic ring (ISSUE 20): REPLICATED on every chip — the probe /
     # substitute / writeback are replicated arithmetic after the merge.
     ring_specs = SemanticRing(
@@ -4618,15 +4598,11 @@ def make_fused_sharded(mesh, axis: str, *, k: int, cap_take: int,
     serve_out = ((state_specs, ring_specs, P(None, None)) if sem
                  else (state_specs, P(None, None)))
     read_out = (ring_specs, P(None, None)) if sem else P(None, None)
-    # + (boost_on, k_q, cap_q, nprobe_q) replicated per-query columns
     mapped_serve = shard_map(
-        _serve_local, mesh=mesh,
-        in_specs=common + (P(None), P(None), P(None), P(None),
-                           P(), P(), P(), P()) + sem_in,
+        _serve_local, mesh=mesh, in_specs=common + sem_in,
         out_specs=serve_out, check_vma=False)
     mapped_read = shard_map(
-        _read_local, mesh=mesh,
-        in_specs=common + (P(None), P(None), P()) + sem_in,
+        _read_local, mesh=mesh, in_specs=common + sem_in,
         out_specs=read_out, check_vma=False)
     return FusedShardedKernels(
         serve=jax.jit(mapped_serve, donate_argnums=(0,)),

@@ -61,9 +61,8 @@ from lazzaro_tpu.reliability.guard import (check_not_poisoned,
                                            run_guarded)
 from lazzaro_tpu.utils.batching import (LRUKernelCache, bucket_size,
                                         decode_topk, empty_results,
-                                        fetch_packed, next_pow2,
-                                        pad_to_bucket, pad_to_pow2,
-                                        unpack_retrieval)
+                                        fetch_packed, next_pow2, pad_to_pow2,
+                                        RequestCarrier, unpack_retrieval)
 from lazzaro_tpu.utils.telemetry import (default_registry, peak_bytes,
                                          record_device_counters)
 
@@ -1611,7 +1610,10 @@ class ShardedMemoryIndex:
                 k_bucket = int(min(max(self.serve_k_max, cap_s, 1),
                                    self.capacity))
                 cap_s = min(self.cap_take, k_bucket)
-            q = np.zeros((nq, dim), np.float32)
+            # the fused dispatch's ONE host operand (ISSUE 37): the loop
+            # writes each query's bits straight into it
+            car = RequestCarrier(nq, dim, self.serve_pad_granularity)
+            q = car.q[:nq]
             valid = np.zeros((nq,), bool)
             tids = np.full((nq,), -1, np.int32)
             gate_on = np.zeros((nq,), bool)
@@ -1643,20 +1645,14 @@ class ShardedMemoryIndex:
             # Fused batches bucket LINEARLY (granularity slots of worst-case
             # padding), the classic path to the next power of two (~50%
             # worst case).
-            qp = (pad_to_bucket(q, self.serve_pad_granularity) if fused
-                  else pad_to_pow2(q))
-            pad_n = qp.shape[0]
+            qp = None if fused else pad_to_pow2(q)
+            pad_n = car.buf.shape[0] if fused else qp.shape[0]
         # Coalesce/pad inflation: padded kernel slots vs live requests.
         tel.bump("serve.live_requests", nq)
         tel.bump("serve.padded_slots", pad_n)
         tel.gauge("serve.batch_occupancy", nq / pad_n)
 
-        def padb(arr, fill=False, dt=bool):
-            out = np.full((pad_n,), fill, dt)
-            out[:nq] = arr
-            return out
-
-        if not self.serve_fused:
+        if not fused:
             return self._serve_classic(reqs, results, valid, qp, tids,
                                        k_bucket)
 
@@ -1704,25 +1700,24 @@ class ShardedMemoryIndex:
                                        scan_chunk=scan_chunk,
                                        sem=sem_state is not None)
             csr_i, csr_n = self._csr_sharded()
-            args = (tables, csr_i, csr_n, jnp.asarray(qp),
-                    jnp.asarray(padb(valid)),
-                    jnp.asarray(padb(tids, -1, np.int32)),
-                    jnp.asarray(padb(gate_on)))
-            # per-query columns (replicated over the mesh): k, retrieval
-            # cap, and — for the IVF modes — probe width
-            k_dev = jnp.asarray(padb(k_arr, 0, np.int32))
-            capq_dev = jnp.asarray(padb(cap_arr, 0, np.int32))
-            np_arr = np.zeros((nq,), np.int32)
+            # per-query columns (replicated over the mesh with the carrier):
+            # k, retrieval cap, and — for the IVF modes — probe width
+            car.fill(valid=valid, tenant=tids, gate_on=gate_on,
+                     boost_on=boost_on, k=k_arr, cap=cap_arr,
+                     super_gate=self.super_gate, acc_boost=self.acc_boost,
+                     nbr_boost=self.nbr_boost, now=time.time() - self.epoch)
             if ivf_tabs is not None:
+                np_arr = np.zeros((nq,), np.int32)
                 for i, r in enumerate(reqs):
                     rn = getattr(r, "nprobe", None)
                     np_arr[i] = (min(max(int(rn), 1), nprobe) if rn
                                  else nprobe)
                 np_arr[~valid] = 0
-            npq_dev = jnp.asarray(padb(np_arr, 0, np.int32))
-            read_extra = (k_dev, npq_dev, jnp.float32(self.super_gate))
-            self._maybe_record_hbm(mode, kern, args, k_bucket,
-                                   read_extra + sem_tail)
+                car.fill(nprobe=np_arr)
+            # the ONE host operand: the call makes the transfer (ISSUE 37)
+            tel.bump("serve.h2d_puts", labels={"mode": "pod"})
+            args = (tables, csr_i, csr_n, car.buf)
+            self._maybe_record_hbm(mode, kern, args, k_bucket, sem_tail)
             # Fault point "plan.oom" (ISSUE 11): an HBM allocation failure the
             # admission plan missed; serve_requests answers with one replan.
             faults.fire("plan.oom", mode=f"pod_{mode}", batch=pad_n)
@@ -1730,20 +1725,13 @@ class ShardedMemoryIndex:
                       labels={"mode": "pod_" + mode}):
             with tel.span("dispatch.launch"):
                 if boost_on.any():
-                    now_rel = time.time() - self.epoch
                     with self._state_lock:
                         cur = self._arena
                         sole = (not force_copy
                                 and sys.getrefcount(cur) <= self._SOLE_REFS)
-                        boost_extra = (jnp.asarray(padb(boost_on)), k_dev,
-                                       capq_dev, npq_dev)
                         out = self._guarded(
                             lambda fn: self._dispatch(
-                                fn, cur, *args, *boost_extra,
-                                jnp.float32(now_rel),
-                                jnp.float32(self.super_gate),
-                                jnp.float32(self.acc_boost),
-                                jnp.float32(self.nbr_boost), *sem_tail),
+                                fn, cur, *args, *sem_tail),
                             kern.serve, kern.serve_copy, sole, (cur,),
                             "serve_pod")
                         if sem_state is not None:
@@ -1754,7 +1742,7 @@ class ShardedMemoryIndex:
                         self.state = new_state
                 else:
                     out = self._dispatch(kern.read, self.state, *args,
-                                         *read_extra, *sem_tail)
+                                         *sem_tail)
                     if sem_state is not None:
                         sem_ring2, packed = out
                     else:
@@ -1804,7 +1792,7 @@ class ShardedMemoryIndex:
         return results
 
     def _maybe_record_hbm(self, mode: str, kern, args, k_bucket,
-                          read_extra) -> None:
+                          sem_tail) -> None:
         """Opt-in peak-HBM gauge for one pod serving geometry (AOT lower +
         ``memory_analysis()`` of the read twin; one extra compile, zero
         extra dispatches)."""
@@ -1816,7 +1804,7 @@ class ShardedMemoryIndex:
         self._hbm_recorded.add(key)
         try:
             peak = peak_bytes(kern.read.lower(
-                self.state, *args, *read_extra
+                self.state, *args, *sem_tail
             ).compile().memory_analysis())
         except Exception:   # noqa: BLE001 — never fail the serve
             return
@@ -1829,10 +1817,7 @@ class ShardedMemoryIndex:
                 labels["pq"] = "true"
             if self.replica_groups > 1:
                 labels["groups"] = str(self.replica_groups)
-            # the sem operand is the one TUPLE in the read tail (the
-            # base extras are device scalars/arrays)
-            sem_on = (self._sem_host is not None and bool(read_extra)
-                      and isinstance(read_extra[-1], tuple))
+            sem_on = self._sem_host is not None and bool(sem_tail)
             if sem_on:
                 # ring geometry for check_hbm_budget.py's semantic-cache
                 # sweep (ISSUE 20): resident ring + [batch, slots] probe

@@ -94,6 +94,57 @@ def pad_to_bucket(arr: np.ndarray, granularity: int) -> np.ndarray:
     return np.concatenate([arr, pad])
 
 
+# Columns of the request carrier after its ``dim`` query columns (ISSUE 37):
+# one int32 column a per-request field, then one a scalar (f32 bits, read
+# from row 0 — a column each, so a one-row bucket carries them too).
+REQUEST_FIELDS = ("valid", "tenant", "gate_on", "boost_on", "k", "cap",
+                  "nprobe")
+REQUEST_SCALARS = ("super_gate", "now", "acc_boost", "nbr_boost")
+REQUEST_COLS = len(REQUEST_FIELDS) + len(REQUEST_SCALARS)
+
+
+class RequestCarrier:
+    """Host half of ``core.state._unpack_requests``: everything one serving
+    dispatch sends the device about its requests, as ONE
+    ``[bucket, dim + REQUEST_COLS]`` int32 array (``buf``) and so one
+    host→device transfer. ``q`` is the first ``dim`` columns viewed as
+    float32 — the request loop writes each query's bits straight into the
+    carrier —, ``fill`` writes per-request columns (pad rows keep valid / k /
+    cap / nprobe 0 and tenant −1) and the dispatch's scalars by name.
+    int32 for the reason the packed readback's carrier is: every f32 pattern
+    survives an integer lane, while not every integer pattern (a tenant of
+    −1 is a NaN, a small k a denormal) is promised to survive a float one."""
+
+    def __init__(self, n: int, dim: int, granularity: int):
+        self.n, self.dim = int(n), int(dim)
+        self.buf = np.zeros((bucket_size(n, granularity),
+                             self.dim + REQUEST_COLS), np.int32)
+        self.buf[:, self.dim + REQUEST_FIELDS.index("tenant")] = -1
+        self.q = self.buf[:, :self.dim].view(np.float32)
+
+    def fill(self, **cols) -> None:
+        """By name: a ``REQUEST_FIELDS`` column from the ``n`` live
+        requests' values (bool or integer), a ``REQUEST_SCALARS`` scalar
+        from a float."""
+        tail = self.buf[0, self.dim + len(REQUEST_FIELDS):].view(np.float32)
+        for name, value in cols.items():
+            if name in REQUEST_SCALARS:
+                tail[REQUEST_SCALARS.index(name)] = value
+            else:
+                self.buf[:self.n,
+                         self.dim + REQUEST_FIELDS.index(name)] = value
+
+    @classmethod
+    def of(cls, q, *, granularity: int = 1, **cols) -> "RequestCarrier":
+        """A carrier from whole columns — how a test or a script states a
+        batch; fields left out stay at their pad values (scalars 0)."""
+        q = np.asarray(q, np.float32)
+        car = cls(q.shape[0], q.shape[1], granularity)
+        car.q[:car.n] = q
+        car.fill(**cols)
+        return car
+
+
 class LRUKernelCache:
     """Tiny LRU map bounding a compiled-kernel cache (ISSUE 7 satellite):
     before ragged serving, per-(mode × k-bucket) keys grew without bound

@@ -5,6 +5,12 @@ and ``lme5m``'s arenas, and ``make_fused_sharded``'s exact program on a 2x2
 mesh at ``lme20m-mesh4``'s size — 18 modules, Mosaic payload and its source
 lines included. Two checkouts whose lines agree cannot differ on the device
 in an exact cell (PRs 31 and 36 showed their exact cells unmoved this way).
+Each line ends with the hash of the Mosaic kernels alone (``lz_select_scan``
+as the chip's compiler reads it: the payload's module printed WITHOUT its
+source locations, which name the callers' lines in ``core/state.py`` too):
+where a PR changes what surrounds the kernel (PR 37: the request carrier and
+its prologue) the first hash differs and the last has to agree. A checkout
+from before PR 37 is lowered with its own request operands.
 
     ln -sfn <checkout> /tmp/co && python3 scripts/exact_stablehlo.py /tmp/co
 
@@ -15,8 +21,12 @@ number here is a device number."""
 
 from __future__ import annotations
 
+import base64
 import hashlib
+import inspect
+import json
 import os
+import re
 import sys
 
 
@@ -39,9 +49,38 @@ def main(checkout: str) -> int:
     one = SingleDeviceSharding(topo.devices[0])
     d = 768
 
+    def kernel_asm(config: str) -> str:
+        """One ``tpu_custom_call``'s Mosaic module, locations stripped."""
+        from jax._src.interpreters import mlir as jax_mlir
+        from jax._src.lib.mlir import ir
+        body = json.loads(config.replace("\\22", '"'))[
+            "custom_call_config"]["body"]
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            return ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+                enable_debug_info=False)
+
     def show(name, c, text):
+        mosaic = re.findall(
+            r'@tpu_custom_call\(.*?backend_config = "((?:[^"\\]|\\.)*)"', text)
         print(name, c, hashlib.sha256(text.encode()).hexdigest(), len(text),
-              "tpu_custom_call" in text)
+              len(mosaic), hashlib.sha256(
+                  "".join(map(kernel_asm, mosaic)).encode()).hexdigest())
+
+    # PR 37: the request operands are ONE int32 carrier
+    carrier = "requests" in inspect.signature(
+        S.search_fused_ragged_read).parameters
+
+    def requests(sds, c, pod=False):
+        if carrier:
+            from lazzaro_tpu.utils.batching import REQUEST_COLS
+            return (sds((c, d + REQUEST_COLS), jnp.int32),)
+        cols = (sds((c, d), jnp.float32), sds((c,), jnp.bool_),
+                sds((c,), jnp.int32), sds((c,), jnp.bool_),
+                sds((c,), jnp.int32))
+        return cols + ((sds((c,), jnp.int32),) if pod else ()) + (
+            sds((), jnp.float32),)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one)
@@ -53,10 +92,7 @@ def main(checkout: str) -> int:
         for c in range(8, 65, 8):
             show(cell, c, S.search_fused_ragged_read.lower(
                 st, sds((rows + 1,), jnp.int32), sds((8192,), jnp.int32),
-                sds((c, d), jnp.float32), sds((c,), jnp.bool_),
-                sds((c,), jnp.int32), sds((c,), jnp.bool_),
-                sds((c,), jnp.int32), sds((), jnp.float32), k=128,
-                cap_take=5, max_nbr=8).as_text())
+                *requests(sds, c), k=128, cap_take=5, max_nbr=8).as_text())
 
     mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
     n, edges = 4 * 1221 * 4096, 4096
@@ -76,9 +112,7 @@ def main(checkout: str) -> int:
         show("lme20m-mesh4", c, kern.read.lower(
             st, (), ms((4, n // 4 + 1), jnp.int32, P("data", None)),
             ms((4, edges), jnp.int32, P("data", None)),
-            ms((c, d), jnp.float32), ms((c,), jnp.bool_), ms((c,), jnp.int32),
-            ms((c,), jnp.bool_), ms((c,), jnp.int32), ms((c,), jnp.int32),
-            ms((), jnp.float32)).as_text())
+            *requests(ms, c, pod=True)).as_text())
     return 0
 
 

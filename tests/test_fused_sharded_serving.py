@@ -25,7 +25,7 @@ from lazzaro_tpu.core.index import build_host_csr, split_csr
 from lazzaro_tpu.parallel.index import ShardedMemoryIndex
 from lazzaro_tpu.parallel.mesh import make_mesh, shard_stacked
 from lazzaro_tpu.serve import QueryScheduler, RetrievalRequest
-from lazzaro_tpu.utils.batching import unpack_retrieval
+from lazzaro_tpu.utils.batching import RequestCarrier, unpack_retrieval
 
 D = 16
 CAP = 127          # cap+1 = 128 divides both mesh shapes
@@ -81,15 +81,17 @@ def _shard_csr(indptr, nbr, mesh):
     return jax.device_put(ish, stk), jax.device_put(nsh, stk)
 
 
-_TAIL = (jnp.float32(1000.0), jnp.float32(0.4), jnp.float32(0.05),
-         jnp.float32(0.02))
-
-
-def _cols(q=8, nprobe=0):
-    """Uniform per-query columns (k_q, cap_q, nprobe_q): every query asks
-    the static ceilings, on both sides of a comparison."""
-    return (jnp.full((q,), K, jnp.int32), jnp.full((q,), CT, jnp.int32),
-            jnp.full((q,), nprobe, jnp.int32))
+def _requests(qv, q_valid, tq, gate_on, boost_on=None, nprobe=0):
+    """The dispatch's request carrier (ISSUE 37), the same array on both
+    sides of a comparison: uniform per-query columns (every query asks the
+    static ceilings) and the boost scalars."""
+    n = len(qv)
+    return jnp.asarray(RequestCarrier.of(
+        qv, valid=q_valid, tenant=tq, gate_on=gate_on,
+        boost_on=np.zeros((n,), bool) if boost_on is None else boost_on,
+        k=np.full((n,), K), cap=np.full((n,), CT),
+        nprobe=np.full((n,), nprobe), now=1000.0, super_gate=0.4,
+        acc_boost=0.05, nbr_boost=0.02).buf)
 
 # A shard-local scan and the whole-arena scan reduce the same products in a
 # different order, so cosines differ in the last bits of a UNIT-scale f32
@@ -117,18 +119,14 @@ def test_exact_mode_bit_identical_to_single_chip(n_dev):
     for bit, scores to ``_SCORE_ATOL``."""
     mesh = _mesh(n_dev)
     st, emb, indptr, nbr = _arena()
-    qv, q_valid, tq, gate_on, boost_on = _queries()
-    args = (jnp.asarray(qv), jnp.asarray(q_valid), jnp.asarray(tq),
-            jnp.asarray(gate_on), jnp.asarray(boost_on))
-    k_q, cap_q, np_q = _cols()
+    reqs = _requests(*_queries())
     st1, p1 = S.search_fused_ragged_copy(
-        st, jnp.asarray(indptr), jnp.asarray(nbr), *args, k_q, cap_q,
-        *_TAIL, k=K, cap_take=CT, max_nbr=MN)
+        st, jnp.asarray(indptr), jnp.asarray(nbr), reqs, k=K, cap_take=CT,
+        max_nbr=MN)
     kern = S.make_fused_sharded(mesh, "data", k=K, cap_take=CT, max_nbr=MN,
                                 mode="exact")
     ish, nsh = _shard_csr(indptr, nbr, mesh)
-    st2, p2 = kern.serve_copy(_shard_state(st, mesh), (), ish, nsh, *args,
-                              k_q, cap_q, np_q, *_TAIL)
+    st2, p2 = kern.serve_copy(_shard_state(st, mesh), (), ish, nsh, reqs)
     _assert_packed_parity(p1, p2)
     for col in ("salience", "access_count", "last_accessed"):
         np.testing.assert_array_equal(np.asarray(getattr(st1, col)),
@@ -138,20 +136,16 @@ def test_exact_mode_bit_identical_to_single_chip(n_dev):
 def test_read_twin_matches_and_mutates_nothing():
     mesh = _mesh(4)
     st, emb, indptr, nbr = _arena()
-    qv, q_valid, tq, gate_on, _ = _queries()
-    k_q, _, np_q = _cols()
+    reqs = _requests(*_queries()[:4])
     r1 = S.search_fused_ragged_read(
-        st, jnp.asarray(indptr), jnp.asarray(nbr), jnp.asarray(qv),
-        jnp.asarray(q_valid), jnp.asarray(tq), jnp.asarray(gate_on), k_q,
-        jnp.float32(0.4), k=K, cap_take=CT, max_nbr=MN)
+        st, jnp.asarray(indptr), jnp.asarray(nbr), reqs, k=K, cap_take=CT,
+        max_nbr=MN)
     kern = S.make_fused_sharded(mesh, "data", k=K, cap_take=CT, max_nbr=MN,
                                 mode="exact")
     ish, nsh = _shard_csr(indptr, nbr, mesh)
     st_sh = _shard_state(st, mesh)
     sal_before = np.asarray(st_sh.salience)
-    r2 = kern.read(st_sh, (), ish, nsh, jnp.asarray(qv),
-                   jnp.asarray(q_valid), jnp.asarray(tq),
-                   jnp.asarray(gate_on), k_q, np_q, jnp.float32(0.4))
+    r2 = kern.read(st_sh, (), ish, nsh, reqs)
     _assert_packed_parity(r1, r2)
     np.testing.assert_array_equal(sal_before, np.asarray(st_sh.salience))
 
@@ -164,15 +158,12 @@ def test_quant_mode_parity_exhaustive_slack():
 
     mesh = _mesh(4)
     st, emb, indptr, nbr = _arena()
-    qv, q_valid, tq, gate_on, boost_on = _queries()
+    reqs = _requests(*_queries())
     q8, scale = quantize_rows(st.emb)
     slack = CAP + 1
-    args = (jnp.asarray(qv), jnp.asarray(q_valid), jnp.asarray(tq),
-            jnp.asarray(gate_on), jnp.asarray(boost_on))
-    k_q, cap_q, np_q = _cols()
     st1, p1 = S.search_fused_quant_ragged_copy(
-        st, q8, scale, jnp.asarray(indptr), jnp.asarray(nbr), *args, k_q,
-        cap_q, *_TAIL, k=K, slack=slack, cap_take=CT, max_nbr=MN)
+        st, q8, scale, jnp.asarray(indptr), jnp.asarray(nbr), reqs, k=K,
+        slack=slack, cap_take=CT, max_nbr=MN)
     kern = S.make_fused_sharded(mesh, "data", k=K, cap_take=CT, max_nbr=MN,
                                 mode="quant", slack=slack)
     ish, nsh = _shard_csr(indptr, nbr, mesh)
@@ -181,7 +172,7 @@ def test_quant_mode_parity_exhaustive_slack():
     st2, p2 = kern.serve_copy(
         _shard_state(st, mesh),
         (jax.device_put(q8, mat), jax.device_put(scale, row)),
-        ish, nsh, *args, k_q, cap_q, np_q, *_TAIL)
+        ish, nsh, reqs)
     np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
     for col in ("salience", "access_count", "last_accessed"):
         np.testing.assert_array_equal(np.asarray(getattr(st1, col)),
@@ -197,17 +188,14 @@ def test_ivf_mode_parity_full_probe():
 
     mesh = _mesh(4)
     st, emb, indptr, nbr = _arena()
-    qv, q_valid, tq, gate_on, boost_on = _queries()
     ivf = IVF.build_ivf(st.emb, np.asarray(st.alive), n_clusters=8, iters=4)
     sup_rows = np.flatnonzero(np.asarray(st.is_super)).tolist()
     extras = IVF.pack_extras(np.asarray(ivf.residual), [], sup_rows)
     nprobe = ivf.n_clusters
-    args = (jnp.asarray(qv), jnp.asarray(q_valid), jnp.asarray(tq),
-            jnp.asarray(gate_on), jnp.asarray(boost_on)) + _cols(
-                nprobe=nprobe) + _TAIL
+    reqs = _requests(*_queries(), nprobe=nprobe)
     st1, p1 = S.search_fused_ivf_ragged_copy(
         st, None, ivf.centroids, ivf.members, jnp.asarray(extras),
-        jnp.asarray(indptr), jnp.asarray(nbr), *args,
+        jnp.asarray(indptr), jnp.asarray(nbr), reqs,
         k=K, nprobe=nprobe, slack=8, cap_take=CT, max_nbr=MN)
     part = (CAP + 1) // 4
     mem_sh, ext_sh = IVF.shard_serve_tables(np.asarray(ivf.members), extras,
@@ -220,7 +208,7 @@ def test_ivf_mode_parity_full_probe():
         _shard_state(st, mesh),
         (jax.device_put(ivf.centroids, NamedSharding(mesh, P())),
          jax.device_put(mem_sh, stk), jax.device_put(ext_sh, stk)),
-        ish, nsh, *args)
+        ish, nsh, reqs)
     p1, p2 = np.asarray(p1), np.asarray(p2)
     np.testing.assert_allclose(p1[:, 0], p2[:, 0], atol=1e-6)   # gate score
     np.testing.assert_array_equal(p1[:, -1], p2[:, -1])         # fast bit

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from lazzaro_tpu.core.index import MemoryIndex
 from lazzaro_tpu.ops import quant as Q
+from lazzaro_tpu.utils.batching import REQUEST_COLS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -111,14 +112,14 @@ import hashlib
 import jax, jax.numpy as jnp
 from lazzaro_tpu.core import state as S
 from lazzaro_tpu.ops import pallas_topk as PT
+from lazzaro_tpu.utils.batching import REQUEST_COLS
 PT.on_tpu = lambda: True            # the TPU's vehicle: the Pallas kernel
 rows, d, c = 8192, 64, 16           # the benchmark's debug geometry
 sds = jax.ShapeDtypeStruct
 st = jax.eval_shape(lambda: S.init_arena(rows - 1, d, jnp.bfloat16))
 args = (st, sds((rows, d), jnp.int8), sds((rows,), jnp.float32),
         sds((rows + 1,), jnp.int32), sds((1024,), jnp.int32),
-        sds((c, d), jnp.float32), sds((c,), jnp.bool_), sds((c,), jnp.int32),
-        sds((c,), jnp.bool_), sds((c,), jnp.int32), sds((), jnp.float32))
+        sds((c, d + REQUEST_COLS), jnp.int32))
 text = S.search_fused_quant_ragged_read.trace(
     *args, k=128, slack=8, cap_take=5, max_nbr=8).lower(
         lowering_platforms=("tpu",)).as_text()
@@ -175,9 +176,8 @@ def test_every_bucket_traces_the_kernel_once_a_query_tile(monkeypatch):
         traced = S.search_fused_quant_ragged_read.trace(
             st, sds((rows, d), jnp.int8), sds((rows,), jnp.float32),
             sds((rows + 1,), jnp.int32), sds((1024,), jnp.int32),
-            sds((c, d), jnp.float32), sds((c,), jnp.bool_),
-            sds((c,), jnp.int32), sds((c,), jnp.bool_), sds((c,), jnp.int32),
-            sds((), jnp.float32), k=128, slack=8, cap_take=5, max_nbr=8)
+            sds((c, d + REQUEST_COLS), jnp.int32), k=128, slack=8,
+            cap_take=5, max_nbr=8)
         sizes.append(_eqns(traced.jaxpr.jaxpr))
     assert len(built) == 2, built       # queries padded to 32 and to 64
     assert max(sizes) < 1200, sizes

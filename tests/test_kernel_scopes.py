@@ -11,6 +11,7 @@ import pytest
 
 from lazzaro_tpu.core import state as S
 from lazzaro_tpu.core.index import MemoryIndex
+from lazzaro_tpu.utils.batching import RequestCarrier
 
 D, Q = 16, 8
 
@@ -25,13 +26,17 @@ def _index():
     return idx
 
 
-def _serve_args(idx):
+def _serve_args(idx, boost=False):
+    """State, CSR and the request carrier (ISSUE 37) of Q live queries of
+    tenant "a": k = cap = 5, the gate off."""
     st = idx.state
     indptr, nbr = idx._csr_for(st)
-    tid = idx._tenants["a"]
-    return (st, indptr, nbr, jnp.ones((Q, D), jnp.float32),
-            jnp.ones((Q,), bool), jnp.full((Q,), tid, jnp.int32),
-            jnp.zeros((Q,), bool))
+    n = np.ones((Q,), np.int32)
+    car = RequestCarrier.of(
+        np.ones((Q, D), np.float32), valid=n, tenant=n * idx._tenants["a"],
+        boost_on=n * boost, k=5 * n, cap=5 * n, now=1.0, super_gate=0.4,
+        acc_boost=0.05, nbr_boost=0.02)
+    return st, indptr, nbr, jnp.asarray(car.buf)
 
 
 def _scopes(hlo_text):
@@ -41,21 +46,18 @@ def _scopes(hlo_text):
 def test_read_core_names_its_phases():
     idx = _index()
     args = _serve_args(idx)
-    low = S.search_fused_ragged_read.lower(
-        *args, jnp.full((Q,), 5, jnp.int32), jnp.float32(0.4),
-        k=8, cap_take=5, max_nbr=4)
+    low = S.search_fused_ragged_read.lower(*args, k=8, cap_take=5,
+                                           max_nbr=4)
     # the read twin drops the neighbour gather (nothing reads it)
     assert _scopes(low.compile().as_text()) == {
-        "lz.norms", "lz.scan", "lz.topk", "lz.gate", "lz.pack"}
+        "lz.unpack", "lz.norms", "lz.scan", "lz.topk", "lz.gate", "lz.pack"}
 
 
 def test_boosting_twin_of_the_same_core_names_the_csr_gather_too():
     idx = _index()
-    args = _serve_args(idx)
-    low = S.search_fused_ragged_copy.lower(
-        *args, jnp.ones((Q,), bool), jnp.full((Q,), 5, jnp.int32),
-        jnp.full((Q,), 5, jnp.int32), jnp.float32(1.0), jnp.float32(0.4),
-        jnp.float32(0.05), jnp.float32(0.02), k=8, cap_take=5, max_nbr=4)
+    args = _serve_args(idx, boost=True)
+    low = S.search_fused_ragged_copy.lower(*args, k=8, cap_take=5,
+                                           max_nbr=4)
     assert _scopes(low.compile().as_text()) >= {
         "lz.norms", "lz.scan", "lz.topk", "lz.gate", "lz.csr", "lz.pack"}
 
@@ -86,13 +88,13 @@ def test_fused_dedup_ingest_names_its_phases(monkeypatch):
 def test_scopes_leave_the_results_alone():
     """Same inputs, same packed readback as a scan written without scopes."""
     idx = _index()
-    st, indptr, nbr, q, valid, tenant, gate = _serve_args(idx)
+    st, indptr, nbr, reqs = _serve_args(idx)
     packed = np.asarray(S.search_fused_ragged_read(
-        st, indptr, nbr, q, valid, tenant, gate, jnp.full((Q,), 5, jnp.int32),
-        jnp.float32(0.4), k=8, cap_take=5, max_nbr=4))
-    qn = S.normalize(q).astype(st.emb.dtype)
+        st, indptr, nbr, reqs, k=8, cap_take=5, max_nbr=4))
+    qn = S.normalize(jnp.ones((Q, D), jnp.float32)).astype(st.emb.dtype)
     scores = np.array(S.nt_dot(qn, st.emb), np.float32)
-    live = np.asarray(st.alive) & (np.asarray(st.tenant_id) == int(tenant[0]))
+    live = (np.asarray(st.alive)
+            & (np.asarray(st.tenant_id) == idx._tenants["a"]))
     scores[:, ~live] = -np.inf
     want = np.sort(scores[0])[::-1][:5]
     got = packed[0, 2:2 + 5].view(np.float32)
@@ -108,9 +110,8 @@ def test_int8_read_core_names_the_coarse_scan_and_the_rescore():
     st, *rest = _serve_args(idx)
     q8, scale = quantize_rows(st.emb)
     low = S.search_fused_quant_ragged_read.lower(
-        st, q8, scale, *rest, jnp.full((Q,), 5, jnp.int32), jnp.float32(0.4),
-        k=8, slack=8, cap_take=5, max_nbr=4)
+        st, q8, scale, *rest, k=8, slack=8, cap_take=5, max_nbr=4)
     scopes = set(re.findall(r'op_name="[^"]*?(lz\.[a-z0-9_]+)',
                             low.compile().as_text()))
-    assert scopes == {"lz.norms", "lz.scan_q8", "lz.topk", "lz.rescore",
-                      "lz.gate", "lz.pack"}
+    assert scopes == {"lz.unpack", "lz.norms", "lz.scan_q8", "lz.topk",
+                      "lz.rescore", "lz.gate", "lz.pack"}

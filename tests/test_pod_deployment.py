@@ -290,10 +290,10 @@ def test_merge_candidates_count_what_the_merge_gathers(system):
             == N * padded * k_merge)
 
 
-# ------------------------------------------ (d) the puts lie under index.stage
+# ------------------- (d) the stage span holds the staging, and no put (PR 37)
 
-def test_sharded_dispatch_stages_its_puts_before_the_launch(system,
-                                                           monkeypatch):
+def test_sharded_dispatch_puts_nothing_beside_its_carrier(system,
+                                                          monkeypatch):
     log = []
 
     class Recorded(T.Span):
@@ -309,14 +309,21 @@ def test_sharded_dispatch_stages_its_puts_before_the_launch(system,
     time.sleep(0.05)
     monkeypatch.setattr(T, "Span", Recorded)
     puts = []
-    real = jnp.asarray
 
-    def spy(x, *a, **kw):
-        if isinstance(x, np.ndarray):
-            span = T.current_span()
-            puts.append(span.name if span else None)
-        return real(x, *a, **kw)
-    monkeypatch.setattr("lazzaro_tpu.core.index.jnp.asarray", spy)
+    def spy(real):
+        def put(x, *a, **kw):
+            if isinstance(x, np.ndarray):
+                span = T.current_span()
+                puts.append(span.name if span else None)
+            return real(x, *a, **kw)
+        return put
+    monkeypatch.setattr("lazzaro_tpu.core.index.jnp.asarray",
+                        spy(jnp.asarray))
+    monkeypatch.setattr("lazzaro_tpu.core.index.jax.device_put",
+                        spy(jax.device_put))
+    tel = system.telemetry
+    before = (tel.counter_total("serve.h2d_puts"),
+              tel.counter_total("serve.dispatches"))
     assert sched.submit(req).result(timeout=120).ids[0] == "t2:f7"
     deadline = time.time() + 10
     while (not any(n == "sched.idle" for _, n, _ in log)
@@ -332,5 +339,8 @@ def test_sharded_dispatch_stages_its_puts_before_the_launch(system,
     assert parents["dispatch.launch"] == "serve.sharded_exact"
     assert parents["dispatch.readback"] == "serve.sharded_exact"
     assert parents["index.stage"] == parents["serve.sharded_exact"] is None
-    # every host->device put of the dispatch was made under index.stage
-    assert len(puts) >= 5 and set(puts) == {"index.stage"}
+    # the parent made nine puts under index.stage; the dispatch's requests
+    # now cross as ONE carrier, handed to the launch as the host array it is
+    assert puts == []
+    assert (tel.counter_total("serve.h2d_puts") - before[0]
+            == tel.counter_total("serve.dispatches") - before[1] == 1)

@@ -457,8 +457,7 @@ _LEAD = {                      # a family's operands between arena and CSR
     "pq_tiered": ("book_cent", "codes", "cold", "centroids", "members",
                   "extras"),
 }
-_BATCH = ("csr_indptr", "csr_nbr", "q", "q_valid", "tenant", "gate_on")
-_SCALARS = ("now", "super_gate", "acc_boost", "nbr_boost")
+_BATCH = ("csr_indptr", "csr_nbr", "requests")   # ONE carrier (ISSUE 37)
 _TAIL = ("scan_chunk", "sem", "sem_block")
 
 
@@ -483,18 +482,14 @@ def test_serving_has_one_shape_census():
     for fam, (donated, copying, read) in _SERVE_KERNELS.items():
         assert (copying, read) == (donated + "_copy", donated + "_read")
         coarse = fam.startswith(("ivf", "pq"))
-        cols = ("k_q", "cap_q") + (("nprobe_q",) if coarse else ())
         statics = (("k",) + (("nprobe",) if coarse else ())
                    + (("slack",) if fam != "exact" else ())
                    + ("cap_take", "max_nbr"))
-        want = (("state",) + _LEAD[fam] + _BATCH + ("boost_on",) + cols
-                + _SCALARS + statics + _TAIL)
-        assert params(getattr(S, donated)) == want, fam
-        assert params(getattr(S, copying)) == want, fam
-        # the read twin: no boost column, no cap column, the gate alone
-        assert params(getattr(S, read)) == (
-            ("state",) + _LEAD[fam] + _BATCH + cols[:1] + cols[2:]
-            + ("super_gate",) + statics + _TAIL), fam
+        # all three twins take the same operands: the request fields ride
+        # the carrier, and the read twin leaves boost and cap unread
+        want = ("state",) + _LEAD[fam] + _BATCH + statics + _TAIL
+        for name in (donated, copying, read):
+            assert params(getattr(S, name)) == want, name
     assert "ragged" not in params(S.make_fused_sharded)
     # (the options' names are spelt in pieces: the tree is grepped for them)
     assert not {"continuous", "max_wait" + "_us"} & set(

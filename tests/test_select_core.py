@@ -17,6 +17,7 @@ import pytest
 
 from lazzaro_tpu.core import state as S
 from lazzaro_tpu.ops import pallas_topk as PT
+from lazzaro_tpu.utils.batching import REQUEST_COLS
 
 NEG = np.float32(S.NEG_INF)
 BLK = PT.SELECT_BLOCK
@@ -290,10 +291,9 @@ def _compiled_read(idx, c):
     st = idx.state
     indptr, nbr = idx._csr_for(st)
     return S.search_fused_ragged_read.lower(
-        st, indptr, nbr, jnp.ones((c, idx.dim), jnp.float32),
-        jnp.ones((c,), bool), jnp.zeros((c,), jnp.int32),
-        jnp.zeros((c,), bool), jnp.full((c,), 5, jnp.int32),
-        jnp.float32(0.4), k=128, cap_take=5, max_nbr=4).compile()
+        st, indptr, nbr,
+        jax.ShapeDtypeStruct((c, idx.dim + REQUEST_COLS), jnp.int32),
+        k=128, cap_take=5, max_nbr=4).compile()
 
 
 def test_compiled_serving_program_holds_no_score_tile():

@@ -14,6 +14,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 from lazzaro_tpu.core import state as S
 from lazzaro_tpu.ops import pallas_topk as PT
+from lazzaro_tpu.utils.batching import REQUEST_COLS
 
 
 @pytest.fixture(scope="module")
@@ -83,10 +84,8 @@ def test_pod_exact_program_compiles_for_four_chips(topo, monkeypatch):
                                 mode="exact")
     text = kern.read.lower(
         st, (), sds((4, n // 4 + 1), jnp.int32, P("data", None)),
-        sds((4, edges), jnp.int32, P("data", None)), sds((c, d), jnp.float32),
-        sds((c,), jnp.bool_), sds((c,), jnp.int32), sds((c,), jnp.bool_),
-        sds((c,), jnp.int32), sds((c,), jnp.int32),
-        sds((), jnp.float32)).compile().as_text()
+        sds((4, edges), jnp.int32, P("data", None)),
+        sds((c, d + REQUEST_COLS), jnp.int32)).compile().as_text()
     assert "tpu_custom_call" in text and "all-gather" in text
     assert f"f32[{c},{n // 4}]" not in text
 
@@ -163,8 +162,7 @@ def test_int8_serving_program_compiles_for_v5e_beside_the_master(
     comp = S.search_fused_quant_ragged_read.lower(
         st, sds((rows, d), jnp.int8), sds((rows,), jnp.float32),
         sds((rows + 1,), jnp.int32), sds((8192,), jnp.int32),
-        sds((c, d), jnp.float32), sds((c,), jnp.bool_), sds((c,), jnp.int32),
-        sds((c,), jnp.bool_), sds((c,), jnp.int32), sds((), jnp.float32),
+        sds((c, d + REQUEST_COLS), jnp.int32),
         k=128, slack=8, cap_take=5, max_nbr=8).compile()
     text = comp.as_text()
     assert text.count("tpu_custom_call") == 1
@@ -198,10 +196,8 @@ def test_pod_int8_program_compiles_for_four_chips(topo, monkeypatch):
         st, (sds((n, d), jnp.int8, P("data", None)),
              sds((n,), jnp.float32, P("data"))),
         sds((4, n // 4 + 1), jnp.int32, P("data", None)),
-        sds((4, edges), jnp.int32, P("data", None)), sds((c, d), jnp.float32),
-        sds((c,), jnp.bool_), sds((c,), jnp.int32), sds((c,), jnp.bool_),
-        sds((c,), jnp.int32), sds((c,), jnp.int32),
-        sds((), jnp.float32)).compile()
+        sds((4, edges), jnp.int32, P("data", None)),
+        sds((c, d + REQUEST_COLS), jnp.int32)).compile()
     text = comp.as_text()
     assert "lz_select_scan_q8" in text and "all-gather" in text
     assert f"[{c},{n // 4}]" not in text

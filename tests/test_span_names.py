@@ -321,3 +321,82 @@ def test_int8_dispatch_opens_the_same_path_and_the_shadow_build_once(
     # and the accepted metrics' prefixes still select what they selected
     assert not [n for n in {"index.shadow"}
                 if n.startswith(("serve.", "ingest."))]
+
+
+# ------------------------------------- the way in is one transfer (ISSUE 37)
+
+@pytest.mark.parametrize("fam,boost", [
+    ("exact", False), ("exact", True), ("quant", False), ("quant", True),
+    ("mesh_exact", False), ("mesh_exact", True)])
+def test_a_dispatch_stages_its_requests_in_one_transfer(monkeypatch, fam,
+                                                        boost):
+    """``serve.h2d_puts{mode}`` == ``serve.dispatches{mode}`` after a read,
+    a boosting, an int8 and a mesh batch — and the counter tells the truth:
+    the warm dispatch hands its program ONE host operand, the carrier, and
+    between pack and readback puts nothing on the device beside it (no
+    ``jax.device_put`` / ``jnp.asarray`` of host data)."""
+    import jax
+    import jax.numpy as jnp
+    from lazzaro_tpu.core import state as S
+    from lazzaro_tpu.core.index import _SERVE_KERNELS
+    from tests.test_request_carrier import routed_index, served
+
+    host = (np.ndarray, np.generic, int, float, bool)
+    tel = T.Telemetry()
+    idx, emb = routed_index(fam, telemetry=tel)
+    served(idx, emb, boost)                 # warm: compiles, builds the CSR
+    puts, handed = [], []
+    for mod, name in ((jax, "device_put"), (jnp, "asarray")):
+        real = getattr(mod, name)
+
+        def counted(x, *a, __real=real, **kw):
+            if isinstance(x, host):
+                puts.append(np.shape(x))
+            return __real(x, *a, **kw)
+
+        monkeypatch.setattr(mod, name, counted)
+
+    def spy(fn):            # the positional operands are the dynamic ones
+        def call(*a, **kw):
+            handed.append([np.shape(x) for x in jax.tree_util.tree_leaves(a)
+                           if isinstance(x, host)])
+            return fn(*a, **kw)
+        return call
+
+    if idx.mesh is None:
+        for name in _SERVE_KERNELS[fam]:
+            monkeypatch.setattr(S, name, spy(getattr(S, name)))
+    else:
+        real_kernels = idx._fused_sharded_kernels
+        monkeypatch.setattr(
+            idx, "_fused_sharded_kernels",
+            lambda *a, **kw: S.FusedShardedKernels(
+                *map(spy, real_kernels(*a, **kw))))
+    out = served(idx, emb, boost)
+    monkeypatch.undo()
+    assert any(r["ids"] for r in out["results"])
+    assert handed == [[(8, 32 + 11)]], handed   # seven requests: bucket 8
+    assert puts == [], puts
+    mode = fam.replace("mesh_", "sharded_")
+    assert (tel.counters[f'serve.h2d_puts{{mode="{mode}"}}']
+            == tel.counters[f'serve.dispatches{{mode="{mode}"}}'] == 2)
+    assert (tel.counter_total("serve.h2d_puts")
+            == tel.counter_total("serve.dispatches") == 2)
+
+
+def test_the_pod_index_stages_one_transfer_too():
+    from lazzaro_tpu.parallel.index import ShardedMemoryIndex
+    from lazzaro_tpu.parallel.mesh import make_mesh
+    import jax
+
+    tel = T.Telemetry()
+    mesh = make_mesh(("data",), (4,), devices=jax.devices()[:4])
+    si = ShardedMemoryIndex(mesh, dim=D, capacity=255, telemetry=tel)
+    emb = np.random.default_rng(0).standard_normal((40, D)).astype(np.float32)
+    si.add([f"n{i}" for i in range(40)], emb, "u0")
+    for boost in (False, True):
+        res = si.serve_requests([RetrievalRequest(
+            query=emb[i], tenant="u0", k=5, boost=boost) for i in range(3)])
+        assert all(r.ids for r in res)
+    assert (tel.counters['serve.h2d_puts{mode="pod"}']
+            == tel.counters['serve.dispatches{mode="pod"}'] == 2)
