@@ -119,6 +119,55 @@ def query_vectors(seed2, tenant, j, n, salt, *, dim: int):
 
 
 # --------------------------------------------------------------------------
+# The graph a configuration names: data, like the rows, made from them.
+# --------------------------------------------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("nearest",))
+def nearest_facts(rows, gate, *, nearest: int):
+    """Each fact's ``nearest`` most similar OTHER facts of its tenant, best
+    first: (fact [n, nearest] i32, -1 where the cosine is not above ``gate``;
+    cosine [n, nearest] f32) of one tenant's stored rows ``[n, dim]`` f32.
+    The same compiled call on the same device returns the same bits, so
+    set-up installs these edges and the reference makes them again."""
+    n = rows.shape[0]
+    cos = jnp.matmul(rows, rows.T, precision=jax.lax.Precision.HIGHEST)
+    cos = jnp.where(jnp.eye(n, dtype=bool), -jnp.inf, cos)
+    best, fact = jax.lax.top_k(cos, min(nearest, n))
+    return jnp.where(best > gate, fact, -1).astype(jnp.int32), best
+
+
+def tenant_edges(rows: np.ndarray, graph: dict) -> List[Tuple[int, int, float]]:
+    """(fact, fact, weight) edges of one tenant whose stored rows are
+    ``rows`` [n, dim] f32, each ordered pair once, as the ingest path links
+    a conversation's facts:
+    the chain j -> j + 1 at ``chain_weight``, and from each fact to its
+    ``nearest`` most similar facts above ``gate`` at ``weight_scale`` x
+    cosine. The configuration's ``graph`` group names the four."""
+    n = rows.shape[0]
+    edges = {(j, j + 1): float(graph["chain_weight"]) for j in range(n - 1)}
+    fact, cos = nearest_facts(jnp.asarray(rows), jnp.float32(graph["gate"]),
+                              nearest=int(graph["nearest"]))
+    fact, cos = np.asarray(fact), np.asarray(cos)
+    scale = float(graph["weight_scale"])
+    for j, r in zip(*np.nonzero(fact >= 0)):
+        # a pair the chain already joins keeps the chain's edge
+        edges.setdefault((int(j), int(fact[j, r])), scale * float(cos[j, r]))
+    return [(a, b, w) for (a, b), w in edges.items()]
+
+
+def neighbour_lists(n: int, edges: Sequence[Tuple[int, int, float]]
+                    ) -> List[np.ndarray]:
+    """[n] arrays: the facts joined to each fact by an edge in either
+    direction, one entry an edge key (a pair linked both ways is listed
+    twice, as the program's adjacency lists it)."""
+    out: List[List[int]] = [[] for _ in range(n)]
+    for a, b, _ in edges:
+        out[a].append(b)
+        out[b].append(a)
+    return [np.asarray(x, np.int64) for x in out]
+
+
+# --------------------------------------------------------------------------
 # Host generator and stand-in providers (conversation API path).
 # --------------------------------------------------------------------------
 

@@ -18,7 +18,8 @@ from __future__ import annotations
 import functools
 import math
 import os
-from typing import List, Optional, Sequence, Tuple
+import time
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -211,6 +212,42 @@ def install_rows(ms: MemorySystem, cfg: dict, seed: int, rows: int,
     return starts
 
 
+def install_graph(ms: MemorySystem, cfg: dict, starts: np.ndarray,
+                  tenant_first: int, rows_of: Callable[[int], np.ndarray]
+                  ) -> int:
+    """The edges the configuration's ``graph`` group names
+    (``corpus.tenant_edges`` of each tenant's rows, ``rows_of(t)``), through
+    ``MemoryIndex.add_edges``, tenant by tenant. Returns how many were
+    installed; the configured edge arena has to hold them as it is."""
+    idx = ms.index
+    want = 0
+    for t in range(len(starts) - 1):
+        name = corpus.tenant_name(tenant_first + t)
+        edges = corpus.tenant_edges(rows_of(t), cfg["graph"])
+        idx.add_edges([(f"{name}:f{a}", f"{name}:f{b}", w)
+                       for a, b, w in edges], name)
+        want += len(edges)
+    if len(idx.edge_slots) != want or want > ms.config.max_edges:
+        raise RuntimeError(
+            f"the graph has {want} edges, the index holds "
+            f"{len(idx.edge_slots)} and memory_config.max_edges is "
+            f"{ms.config.max_edges}")
+    return want
+
+
+def index_clock(ms: MemorySystem) -> float:
+    """Seconds on the clock the index stamps ``last_accessed`` with."""
+    return time.time() - ms.index.epoch
+
+
+def read_state(ms: MemorySystem) -> Dict[str, np.ndarray]:
+    """What the window's boosting reads left: the three columns a boost
+    writes, whole, by arena row."""
+    st = ms.index.state
+    return {name: np.asarray(getattr(st, name))
+            for name in ("access_count", "salience", "last_accessed")}
+
+
 def warm_serving(ms: MemorySystem, cfg: dict) -> None:
     """Every padded batch the scheduler can dispatch up to its maximum (the
     open and closed loops both see batches of any size), and no other."""
@@ -232,11 +269,15 @@ def run_conversation(ms: MemorySystem, tenant: int, conv: int = 0) -> None:
     ms.end_conversation()
 
 
-def make_requests(queries: np.ndarray, tenants: Sequence[int], k: int
-                  ) -> List[RetrievalRequest]:
-    """The scheduler's own request type, one per query row."""
+def make_requests(queries: np.ndarray, tenants: Sequence[int], k: int,
+                  boost: Optional[np.ndarray] = None) -> List[RetrievalRequest]:
+    """The scheduler's own request type, one per query row; where ``boost``
+    marks a row, a ``chat`` retrieval (``boost=True``): the read that bumps
+    access and neighbour salience in the dispatch that serves it."""
     names = {int(t): corpus.tenant_name(t) for t in np.unique(tenants)}
-    return [RetrievalRequest(query=queries[i], tenant=names[int(t)], k=k)
+    flags = [False] * len(tenants) if boost is None else boost.tolist()
+    return [RetrievalRequest(query=queries[i], tenant=names[int(t)], k=k,
+                             boost=flags[i])
             for i, t in enumerate(tenants)]
 
 

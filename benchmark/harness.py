@@ -181,8 +181,18 @@ class ServePlan:
                 dim=cfg["dim"])))
         self.queries = np.concatenate(q)
         self.k = int(mix["k"])
+        # a mix may say what share of its requests are ``chat`` retrievals,
+        # reads that write: that many of them, marked from a stream of their
+        # own. A mix without the key makes the plan it made before the key.
+        self.boost: Optional[np.ndarray] = None
+        if "boost_share" in mix:
+            share = float(mix["boost_share"])
+            if not 0.0 <= share <= 1.0:
+                raise ValueError(f"boost_share {share} is no share")
+            self.boost = np.arange(n) < int(round(share * n))
+            np.random.default_rng([int(seed), 0xB0057]).shuffle(self.boost)
         self.requests = make_requests(self.queries, self.tenant + tenant_first,
-                                      self.k)
+                                      self.k, self.boost)
         # the answers compared after the window, drawn from the seed now:
         # up to check_per_tenant requests of check_tenants tenants
         self.keep = np.zeros(n, bool)
@@ -198,6 +208,7 @@ class ServePlan:
                 want -= 1
                 if not want:
                     break
+        self.check_tenants = sorted(picked)
 
 
 # --------------------------------------------------------------------------
@@ -234,18 +245,60 @@ def tenant_rows(cfg, seed, starts, tenant_first, t) -> np.ndarray:
     return np.concatenate(parts)
 
 
+def boost_constants(cfg: dict, mix: dict, salience0: float) -> dict:
+    """What a boost adds, as the replay assumes it: the mix's ``boost`` group
+    restates the program's documented defaults, a field the configuration's
+    ``memory_config`` names wins, and ``salience0`` is what set-up installed."""
+    names = ("retrieval_cap", "access_salience_boost",
+             "neighbor_salience_boost", "serve_max_nbr")
+    return {"salience0": salience0,
+            **{n: cfg["memory_config"].get(n, mix["boost"][n]) for n in names}}
+
+
+def check_state(reference, cmp, cfg, plan, state, rows, t, lo, hi):
+    """Tenant ``t``'s rows ``[lo, hi)`` of the state read back after the
+    window against the replay of EVERY boosting request of ``t`` the window
+    completed (``state["done"]``; a closed loop sends one more than once)."""
+    done = state["done"]
+    reqs, times = np.unique(done[plan.tenant[done] == t], return_counts=True)
+    boost = state["boost"]
+    near = None
+    if "graph" in cfg:
+        near = corpus.neighbour_lists(rows.shape[0],
+                                      corpus.tenant_edges(rows, cfg["graph"]))
+    want = reference.boost_bounds(
+        rows, np.ones(rows.shape[0], bool), plan.queries[reqs], times,
+        min(plan.k, boost["retrieval_cap"]), cfg["dtype"],
+        cfg["limits"]["score_gap"], near, boost["serve_max_nbr"])
+    cmp.state(f"tenant {t}", {n: c[lo:hi] for n, c in state["columns"].items()},
+              want, boost, state["window"])
+
+
 def check_serving(reference, cmp, cfg, plan, samples, seed, starts,
-                  tenant_first, control: Optional[str]):
+                  tenant_first, control: Optional[str],
+                  state: Optional[dict] = None):
     """The kept answers of the timed requests against the reference, tenant
-    by tenant."""
+    by tenant; under a mix that boosts also the ``state`` the window left
+    (``columns``, ``window``, ``boost``), for the tenants drawn for the
+    check."""
     from benchmark.deploy import parse_node_id
 
     by_tenant: Dict[int, List[int]] = {}
     for i in sorted(samples.answers):
         by_tenant.setdefault(int(plan.tenant[i]), []).append(i)
+    if state is not None:
+        for t in plan.check_tenants:
+            by_tenant.setdefault(t, [])
+        done = samples.request_of[samples.ok]
+        state = {**state, "done": done[plan.boost[done]]}
     k = plan.k
     for t, reqs in sorted(by_tenant.items()):
         rows = tenant_rows(cfg, seed, starts, tenant_first, t)
+        if state is not None:
+            check_state(reference, cmp, cfg, plan, state, rows, t,
+                        int(starts[t]), int(starts[t + 1]))
+        if not reqs:
+            continue
         live = np.ones(rows.shape[0], bool)
         q = plan.queries[reqs]
         variants = reference.query_variants(rows, live, q, k, cfg["dtype"])
@@ -320,7 +373,12 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     work = tempfile.mkdtemp(prefix="lzbench-")
     trace_dir = os.path.join(work, "trace")
     reference = load_module(cfg["reference"], root)
-    cmp = reference.Comparison(cfg["limits"])
+    limits = dict(cfg["limits"])
+    if "boost_share" in mix:
+        # the state its reads leave is compared under a mix that boosts, and
+        # only there: the mix brings that number's limit
+        limits["state_errors"] = mix["limits"]["state_errors"]
+    cmp = reference.Comparison(limits)
     try:
         loop = mix["loop"]
         if loop in ("open", "closed"):
@@ -366,6 +424,11 @@ def _serve_run(run, deploy, work, compiles, trace_dir, reference, cmp,
     phases.done("import+system")
     starts = deploy.install_rows(ms, cfg, seed, cfg["rows"], 0, cfg["tenants"])
     phases.done("rows")
+    if "graph" in cfg:
+        edges = deploy.install_graph(
+            ms, cfg, starts, 0,
+            lambda t: tenant_rows(cfg, seed, starts, 0, t))
+        phases.done(f"graph of {edges} edges")
     deploy.warm_serving(ms, cfg)
     phases.done("warm-up")
     plan = ServePlan(cfg, mix, seed, run.seconds, starts, 0,
@@ -383,6 +446,7 @@ def _serve_run(run, deploy, work, compiles, trace_dir, reference, cmp,
     gc_before = [g["collections"] for g in gc.get_stats()]
     pauses = _watch_gc() if run.want_detail else None
     run.setup_s = time.perf_counter() - t_start
+    opened = deploy.index_clock(ms)
     _start_trace(run, trace_dir)
     if mix["loop"] == "open":
         samples = loadgen.run_open(submit, plan.requests, plan.due,
@@ -396,6 +460,11 @@ def _serve_run(run, deploy, work, compiles, trace_dir, reference, cmp,
     device = deploy.device_info(run.cell["chips"])
     swallowed = deploy.counters(ms)
     arena_rows = int(ms.index.state.salience.shape[0])
+    state = None
+    if plan.boost is not None:
+        state = {"columns": deploy.read_state(ms),
+                 "window": (opened, deploy.index_clock(ms)),
+                 "boost": boost_constants(cfg, mix, deploy.SALIENCE)}
     gc.unfreeze()
 
     ok = samples.ok
@@ -422,7 +491,7 @@ def _serve_run(run, deploy, work, compiles, trace_dir, reference, cmp,
     cmp.swallowed = sum(swallowed.values())
     cmp.unanswered = run.failed
     check_serving(reference, cmp, cfg, plan, samples, seed, starts, 0,
-                  control)
+                  control, state)
     say(f"arena {arena_rows} rows x {cfg['dim']} {cfg['dtype']}; "
         f"{run.attempted} requests, {run.failed} failed, "
         f"{cmp.answers} compared; swallowed {swallowed}")
@@ -482,6 +551,11 @@ def _serve_detail(run: Run, samples, ok, gc_before) -> dict:
         "batch_size_counts": np.bincount(sizes).tolist() if len(sizes) else [],
         "latency_ms_all": [round(float(x), 4) for x in lat],
         "due_s_all": [round(float(x), 5) for x in due_rel],
+        "counters": {name: run.counter(name) for name in (
+            "serve.requests", "serve.batches", "serve.dispatches",
+            "serve.overlapped_batches", "serve.held_batches",
+            "serve.copy_dispatches", "serve.h2d_puts", "device.boost_rows",
+            "device.nbr_boost_rows")},
         "gc_collections_in_window": [
             g["collections"] - b for g, b in zip(gc.get_stats(), gc_before)],
         "window_s": run.window_s,

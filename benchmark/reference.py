@@ -10,6 +10,18 @@ f32 where XLA's excess precision does so: see ``Comparison.answer``.
 ``int8_answers`` is the control: the same reference computed one precision
 below the configuration's (bf16 -> int8 codes, per-row scale, exact integer
 accumulation), put in the program's place. It has to come out NOT correct.
+
+Reads that write (``chat`` retrievals, a mix's ``boost_share``): a boosting
+request adds 1 to ``access_count`` and ``access_salience_boost`` to the
+salience of its ``retrieval_cap`` best served rows, and
+``neighbor_salience_boost`` to the salience of every graph neighbour of those
+rows that is not itself among them (once a request, however many of its rows
+touch it); salience is capped at 1.0 and a touched row takes the time of its
+dispatch as ``last_accessed``. Increments are positive and the cap monotone,
+so the state a window leaves depends on how often each row was boosted and
+not on the order: ``boost_bounds`` counts that from the reference's own exact
+top-k of every completed request, and ``Comparison.state`` holds the rows the
+program reads back to those counts (``state_errors``).
 """
 
 from __future__ import annotations
@@ -72,6 +84,65 @@ def int8_answers(rows: np.ndarray, live: np.ndarray, queries: np.ndarray,
     return out
 
 
+def boost_bounds(rows: np.ndarray, live: np.ndarray, queries: np.ndarray,
+                 times: np.ndarray, k: int, dtype: str, tol: float,
+                 neighbours: Optional[List[np.ndarray]] = None,
+                 max_neighbours: int = 0, block: int = 2048
+                 ) -> Dict[str, np.ndarray]:
+    """How often each of one tenant's rows was boosted by ``queries``
+    (boosting requests of that tenant, request i sent ``times[i]`` times, each
+    taking its ``k`` best rows): ``acc_lo``/``acc_hi`` [n] the fewest and the
+    most access boosts a row can have, ``nbr_lo``/``nbr_hi`` the same for
+    neighbour boosts, ``requests`` the boosting requests and ``taken`` the
+    rows each takes. The two differ only for a row within ``tol`` of the
+    boundary of a request's top-k, at the query rounded to the arena's dtype
+    or kept in f32 (``query_variants``): there the program may rightly serve
+    its neighbour in rank. ``neighbours[j]`` are the rows joined to row j by
+    an edge, in either direction; a boost reaches the first
+    ``max_neighbours`` of them, and which come first is the program's own
+    order, so a list longer than that is refused: the configuration's graph
+    has to stay under it."""
+    n = rows.shape[0]
+    out = {name: np.zeros(n, np.int64)
+           for name in ("acc_lo", "acc_hi", "nbr_lo", "nbr_hi")}
+    take = min(int(k), int(live.sum()))
+    out["requests"], out["taken"] = int(np.sum(times)), take
+    if not take or not len(queries):
+        return out
+    adj = None
+    if neighbours is not None:
+        longest = max((len(ns) for ns in neighbours), default=0)
+        if longest > max_neighbours:
+            raise ValueError(f"a row has {longest} neighbours, a boost "
+                             f"reaches {max_neighbours}")
+        adj = np.zeros((n, n), bool)
+        for j, ns in enumerate(neighbours):
+            adj[j, np.asarray(ns, np.int64)] = True
+        adj |= adj.T
+        np.fill_diagonal(adj, False)
+    rows32 = rows.astype(np.float32)
+    for a in range(0, len(queries), block):
+        q, m = queries[a:a + block], np.asarray(times[a:a + block], np.int64)
+        sure = np.ones((len(q), n), bool)
+        may = np.zeros((len(q), n), bool)
+        for qv in (stored(q, dtype), unit(q)):
+            s = np.where(live[None, :], qv @ rows32.T, -np.inf)
+            best = -np.sort(-s, axis=1)
+            kth = best[:, take - 1:take]
+            nxt = best[:, take:take + 1] if n > take else np.full_like(kth, -np.inf)
+            sure &= s > nxt + tol
+            may |= s >= kth - tol
+        out["acc_lo"] += m @ sure
+        out["acc_hi"] += m @ may
+        if adj is not None:
+            # a neighbour of a taken row, not itself taken
+            near_sure = (sure.astype(np.int32) @ adj > 0) & ~may & live[None, :]
+            near_may = (may.astype(np.int32) @ adj > 0) & ~sure & live[None, :]
+            out["nbr_lo"] += m @ near_sure
+            out["nbr_hi"] += m @ near_may
+    return out
+
+
 class Comparison:
     """The numbers ``correct`` rests on, each held to a limit of its own.
 
@@ -84,19 +155,26 @@ class Comparison:
     unanswered     requests of the sample that returned no answer at all
     swallowed      failures the program retried or swallowed during the
                    window (its reliability counters), set by the harness
+    state_errors   rows whose access count, salience or last access is not
+                   what the boosting requests of the window leave, and
+                   tenants whose boosts do not add up (``state``). Compared
+                   only where the limits name it: under a mix that boosts.
     """
 
     NAMES = ("score_gap", "rank_errors", "foreign_ids", "count_errors",
              "unanswered", "swallowed")
+    OPTIONAL = ("state_errors",)
 
     def __init__(self, limits: Dict[str, float]):
         missing = [n for n in self.NAMES if n not in limits]
         if missing:
             raise ValueError(f"limits lack {missing}")
-        self.limits = {n: float(limits[n]) for n in self.NAMES}
+        self.names = self.NAMES + tuple(n for n in self.OPTIONAL if n in limits)
+        self.limits = {n: float(limits[n]) for n in self.names}
         self.score_gap = 0.0
         self.rank_errors = self.foreign_ids = 0
         self.count_errors = self.unanswered = self.swallowed = 0
+        self.state_errors = 0
         self.answers = 0
         self.first_fault: Optional[str] = None
 
@@ -163,9 +241,56 @@ class Comparison:
         if fault:
             self._fault(fault)
 
+    def state(self, label: str, got: Dict[str, np.ndarray],
+              want: Dict[str, np.ndarray], boost: Dict[str, float],
+              window: Tuple[float, float]) -> None:
+        """One tenant's rows as the program holds them after the window
+        (``got``: ``access_count``, ``salience``, ``last_accessed``, a row
+        each) against ``boost_bounds``' counts (``want``). ``boost`` gives
+        the installed salience (``salience0``) and the two increments;
+        ``window`` the index's clock when the window opened and when the
+        state was read. A row counts once, whatever is wrong with it; a
+        tenant whose access counts do not add up to its boosting requests
+        times the rows each takes counts once more."""
+        count = np.asarray(got["access_count"], np.int64)
+        sal = np.asarray(got["salience"], np.float64)
+        seen = np.asarray(got["last_accessed"], np.float64)
+        eps, slack = 1e-5, 0.01
+
+        def salience(acc, nbr):
+            return np.minimum(1.0, boost["salience0"]
+                              + acc * boost["access_salience_boost"]
+                              + nbr * boost["neighbor_salience_boost"])
+
+        bad_count = (count < want["acc_lo"]) | (count > want["acc_hi"])
+        acc = np.clip(count, want["acc_lo"], want["acc_hi"])
+        bad_sal = ((sal < salience(acc, want["nbr_lo"]) - eps)
+                   | (sal > salience(acc, want["nbr_hi"]) + eps))
+        inside = (seen >= window[0] - slack) & (seen <= window[1] + slack)
+        touched = (count > 0) | (want["acc_lo"] + want["nbr_lo"] > 0)
+        never = want["acc_hi"] + want["nbr_hi"] == 0
+        bad_seen = np.where(touched, ~inside,
+                            (seen != 0.0) & (never | ~inside))
+        bad = bad_count | bad_sal | bad_seen
+        self.state_errors += int(bad.sum())
+        if bad.any():
+            j = int(np.argmax(bad))
+            self._fault(
+                f"{label}: row {j} holds access_count {count[j]} salience "
+                f"{sal[j]:.6f} last_accessed {seen[j]:.3f}; the replay "
+                f"{want['acc_lo'][j]}-{want['acc_hi'][j]} access boosts, "
+                f"{want['nbr_lo'][j]}-{want['nbr_hi'][j]} neighbour boosts, "
+                f"window {window[0]:.3f}-{window[1]:.3f}")
+        total = want["requests"] * want["taken"]
+        if int(count.sum()) != total:
+            self.state_errors += 1
+            self._fault(f"{label}: access counts add up to {int(count.sum())}"
+                        f", its {want['requests']} boosting requests x "
+                        f"{want['taken']} rows to {total}")
+
     def numbers(self) -> Dict[str, Dict[str, float]]:
         return {n: {"value": float(getattr(self, n)), "limit": self.limits[n]}
-                for n in self.NAMES}
+                for n in self.names}
 
     @property
     def correct(self) -> bool:

@@ -199,9 +199,51 @@ def _add_q8_cell(root, m):
             e["workloads"].append("later.q8")
 
 
+def _add_graph_cell(root, m):
+    """What the next ``model_config`` PR fills: a configuration that names
+    its ``graph`` (edges installed after the rows), a closed-loop mix whose
+    every request is a ``chat`` retrieval, and per-layer metrics that are
+    two-line files of their families — files and appended entries alone."""
+    cfg = harness.load_json(os.path.join(root, "benchmark/configs/share131k.json"))
+    cfg.update(name="later-graph",
+               graph={"chain_weight": 0.5, "nearest": 3, "gate": 0.5,
+                      "weight_scale": 0.8})
+    cfg["assumed"]["graph"] = "MemoryConfig's chain_link_weight, " \
+        "cross_link_top_k, link_gate, link_weight_scale (the defaults)"
+    cfg["memory_config"].update(max_edges=1048576)
+    _write(root, "benchmark/configs/later-graph.json", json.dumps(cfg))
+    mix = harness.load_json(os.path.join(root, "benchmark/mixes/serve-closed-128.json"))
+    chat = harness.load_json(os.path.join(root, "benchmark/mixes/chat-open-zipf.json"))
+    mix.update(name="chat-closed-128", boost_share=1.0, boost=chat["boost"],
+               limits=chat["limits"])
+    _write(root, "benchmark/mixes/chat-closed-128.json", json.dumps(mix))
+    family = "from benchmark.families import reader_for\n\n" \
+             "read = reader_for(__file__)\n"
+    for name, unit, better, source, layer in [
+            ("sched.overlap_pct.graph", "%", "higher", "program_counter",
+             "scheduler"),
+            ("dispatch.boost_rows_per_req.graph", "count", "higher",
+             "program_counter", "dispatch"),
+            ("device.compiles.graph", "count", "lower", "program_counter",
+             "device")]:
+        _write(root, f"benchmark/metrics/{name}.py", family)
+        m["per_layer"].append({"name": name, "unit": unit, "better": better,
+                               "source": source, "layer": layer,
+                               "moves": "search_qps",
+                               "workloads": ["later.graph"]})
+    m["configs"].append({"name": "later-graph", "source": "s", "why": "w",
+                         "file": "benchmark/configs/later-graph.json",
+                         "reduced": ["rows", "tenants"]})
+    m["workloads"].append({"name": "later.graph", "config": "later-graph",
+                           "traffic": "chat-closed-128", "chips": 1, "why": "w"})
+    for e in m["end_to_end"]:
+        if e["name"] == "search_qps":
+            e["workloads"].append("later.graph")
+
+
 class Later:
     """A checkout as a later PR leaves it: the repository's manifest and
-    paths, and the three cells above added to them."""
+    paths, and the cells above added to them."""
 
     def __init__(self, root):
         self.root = root
@@ -219,6 +261,7 @@ class Later:
         _add_int8_cell(root, m)
         _add_pod_cell(root, m)
         _add_q8_cell(root, m)
+        _add_graph_cell(root, m)
         _write(root, "BENCHMARK.json", json.dumps(m))
 
     def nothing_was_edited(self):
@@ -299,8 +342,10 @@ def test_a_later_pr_s_four_chip_cell_reports_the_span_metrics_it_has(later):
 def test_a_later_pr_s_one_chip_int8_cell_lands_after_the_last_entries(later):
     root = later.root
     m = harness.manifest(root)
-    assert [e["name"] for e in m["per_layer"][-2:]] == [
-        "kernel.serve_roofline.later", "sched.batch_requests_mean.later"]
+    added = [e["name"] for e in m["per_layer"][len(M["per_layer"]):]]
+    at = added.index("kernel.serve_roofline.later")
+    assert added[at:at + 2] == ["kernel.serve_roofline.later",
+                                "sched.batch_requests_mean.later"]
     assert m["per_layer"][:len(M["per_layer"])] == M["per_layer"]
     cell, cfg, mix = harness.cell_files("later.q8", root)
     assert cell["chips"] == 1 == harness.mesh_chips(cfg)
@@ -330,6 +375,45 @@ def test_a_later_pr_s_one_chip_int8_cell_passes_the_per_cell_contracts(later):
     # plane and is left out here, never reported as 0
     assert set(res["metrics"]) == {"sched.batch_requests_mean.later"}
     assert res["metrics"]["sched.batch_requests_mean.later"]["value"] > 1.0
+    later.nothing_was_edited()
+
+
+# ------------------------------------------ a configuration names its graph
+
+def test_a_later_pr_s_graph_cell_installs_its_edges_and_boosts_neighbours(later):
+    import faults
+    from benchmark import corpus
+    root = later.root
+    cell, cfg, mix = harness.cell_files("later.graph", root, debug=True)
+    assert cfg["graph"]["nearest"] == 3 and mix["boost_share"] == 1.0
+    seen = {}
+
+    def look(ms):
+        seen.update(edges=len(ms.index.edge_slots),
+                    held=ms.config.max_edges)
+    contracts.cell_line("later.graph", root)
+    res = contracts.debug_run("later.graph", 35, root, traced=True, sabotage=look)
+    assert res["correct"] is True and res["failed"] == 0 < res["attempted"]
+    assert res["compared"]["state_errors"] == {"value": 0.0, "limit": 0.0}
+    # the chain alone is rows - tenants edges; the nearest facts add theirs
+    assert cfg["rows"] - cfg["tenants"] < seen["edges"] <= seen["held"]
+    m = {k: v["value"] for k, v in res["metrics"].items()}
+    assert m["dispatch.boost_rows_per_req.graph"] == 5.0
+    assert m["sched.overlap_pct.graph"] == 0.0        # what ROADMAP A4 is judged on
+    assert m["device.compiles.graph"] == 0.0
+    # the neighbour boosts were compared: without its edges the program
+    # boosts the served rows alone, and only the state says so
+    bad = contracts.debug_run("later.graph", 35, root,
+                              sabotage=faults.edges_dropped)
+    assert bad["correct"] is False
+    assert bad["compared"]["state_errors"]["value"] > 0
+    assert all(v["value"] <= v["limit"] for n, v in bad["compared"].items()
+               if n != "state_errors")
+    # and the graph is data: the same edges from the same rows, every time
+    rows = harness.tenant_rows(cfg, 35, corpus.tenant_starts(
+        cfg["rows"], cfg["tenants"]), 0, 3)
+    assert corpus.tenant_edges(rows, cfg["graph"]) == \
+        corpus.tenant_edges(rows, cfg["graph"])
     later.nothing_was_edited()
 
 

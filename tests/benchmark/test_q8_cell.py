@@ -50,8 +50,9 @@ def test_cell_is_named_as_the_issue_names_it():
     # what a caller waits, not requests a second: the driver holds a new
     # cell's spread to the bound in the PARENT's units, and 8% of the
     # parent's 380.8 req/s is 0.44% of this program's own level (PERF.md §6)
+    # (later cells append themselves: the list starts as PR 36 left it)
     assert contracts.entry(ROOT, "end_to_end", "search_p50_ms")[
-        "workloads"] == ["share.serve", CELL]
+        "workloads"][:2] == ["share.serve", CELL]
     assert [m["name"] for m in harness.metrics_of(cell, "end_to_end", ROOT)] \
         == ["search_p50_ms", "setup_s"]
     # the tail spreads 6% over six seeds here, the median 1.4% (PERF.md §2)
