@@ -2905,6 +2905,10 @@ class MemoryIndex:
         edge-topology change. The dirty flag is cleared BEFORE the build,
         so a writer racing past us re-dirties and the next serve rebuilds."""
         n = st.salience.shape[0]
+        tel = self.telemetry
+        # every call counts: a window without a build reads 0 builds of
+        # this many look-ups, not "a program that does not count"
+        tel.bump("index.csr_lookups")
         # two read dispatches may come together: one builds, the other
         # finds its build
         with self._serve_shared_lock:
@@ -2912,19 +2916,23 @@ class MemoryIndex:
             if cache is not None and not self._csr_dirty and cache[0] == n:
                 return cache[1], cache[2]
             self._csr_dirty = False
-            indptr, nbr = build_host_csr(list(self.edge_slots.keys()),
-                                         self.id_to_row, n,
-                                         min_pad=self._csr_pad_hwm)
-            self._csr_pad_hwm = nbr.shape[0]
-            if self.mesh is not None:
-                # pod path: per-shard CSR slices for the distributed fused
-                # kernel, placed so each chip holds its own rows' lists
-                from lazzaro_tpu.parallel.mesh import shard_stacked
-                sh = shard_stacked(self.mesh, self.shard_axis)
-                dev = tuple(jax.device_put(a, sh)
-                            for a in split_csr(indptr, nbr, self._n_parts))
-            else:
-                dev = (jnp.asarray(indptr), jnp.asarray(nbr))
+            with tel.span("index.csr"):
+                keys = list(self.edge_slots.keys())
+                indptr, nbr = build_host_csr(keys, self.id_to_row, n,
+                                             min_pad=self._csr_pad_hwm)
+                self._csr_pad_hwm = nbr.shape[0]
+                if self.mesh is not None:
+                    # pod path: per-shard CSR slices for the distributed
+                    # fused kernel, placed so each chip holds its own rows'
+                    # lists
+                    from lazzaro_tpu.parallel.mesh import shard_stacked
+                    sh = shard_stacked(self.mesh, self.shard_axis)
+                    dev = tuple(jax.device_put(a, sh) for a in
+                                split_csr(indptr, nbr, self._n_parts))
+                else:
+                    dev = (jnp.asarray(indptr), jnp.asarray(nbr))
+            tel.bump("index.csr_builds")
+            tel.bump("index.csr_edges", len(keys))
             self._csr_cache = (n, dev[0], dev[1])
             return dev
 
@@ -4271,50 +4279,54 @@ class MemoryIndex:
         (+0.1 capped, co+1); new ones inserted. A key repeated WITHIN the
         batch inserts once then reinforces (the scatter accumulates duplicate
         slots), matching what sequential singleton calls would do."""
-        now = (now if now is not None else time.time()) - self.epoch
-        new, existing = [], []
-        pending = set()
-        for src, tgt, w in triples:
-            if src not in self.id_to_row or tgt not in self.id_to_row:
-                continue
-            key = (src, tgt)
-            if key in self.edge_slots:
-                existing.append(self.edge_slots[key])
-            elif key in pending:
-                existing.append(key)        # slot resolved after the insert
-            else:
-                pending.add(key)
-                new.append((key, w))
-        if new:
-            slots = self._alloc_edge_slots(len(new))
-            for (key, _), slot in zip(new, slots):
-                self.edge_slots[key] = slot
-            self._csr_dirty = True
-            cap = self.edge_state.capacity
-            padded = S.pad_rows(np.asarray(slots, np.int32), cap)
-            b = len(padded)
-            src_r = np.full((b,), -1, np.int32)
-            tgt_r = np.full((b,), -1, np.int32)
-            w = np.zeros((b,), np.float32)
-            live = np.zeros((b,), bool)
-            for i, ((s_id, t_id), wt) in enumerate(new):
-                src_r[i] = self.id_to_row[s_id]
-                tgt_r[i] = self.id_to_row[t_id]
-                w[i] = wt
-                live[i] = True
-            self._apply_edges(
-                S.edges_add, S.edges_add_copy,
-                jnp.asarray(padded), jnp.asarray(src_r),
-                jnp.asarray(tgt_r), jnp.asarray(w),
-                jnp.ones((b,), jnp.int32), jnp.float32(now),
-                jnp.int32(self.tenant_id(tenant)), jnp.asarray(live))
-        if existing:
-            slots = [self.edge_slots[s] if isinstance(s, tuple) else s
-                     for s in existing]
-            padded = S.pad_rows(np.asarray(slots, np.int32), self.edge_state.capacity)
-            self._apply_edges(
-                S.edges_reinforce, S.edges_reinforce_copy,
-                jnp.asarray(padded), jnp.float32(reinforce), jnp.float32(now))
+        with self.telemetry.span("index.edges"):
+            now = (now if now is not None else time.time()) - self.epoch
+            new, existing = [], []
+            pending = set()
+            for src, tgt, w in triples:
+                if src not in self.id_to_row or tgt not in self.id_to_row:
+                    continue
+                key = (src, tgt)
+                if key in self.edge_slots:
+                    existing.append(self.edge_slots[key])
+                elif key in pending:
+                    existing.append(key)    # slot resolved after the insert
+                else:
+                    pending.add(key)
+                    new.append((key, w))
+            if new:
+                slots = self._alloc_edge_slots(len(new))
+                for (key, _), slot in zip(new, slots):
+                    self.edge_slots[key] = slot
+                self._csr_dirty = True
+                self.telemetry.bump("index.edges_added", len(new))
+                cap = self.edge_state.capacity
+                padded = S.pad_rows(np.asarray(slots, np.int32), cap)
+                b = len(padded)
+                src_r = np.full((b,), -1, np.int32)
+                tgt_r = np.full((b,), -1, np.int32)
+                w = np.zeros((b,), np.float32)
+                live = np.zeros((b,), bool)
+                for i, ((s_id, t_id), wt) in enumerate(new):
+                    src_r[i] = self.id_to_row[s_id]
+                    tgt_r[i] = self.id_to_row[t_id]
+                    w[i] = wt
+                    live[i] = True
+                self._apply_edges(
+                    S.edges_add, S.edges_add_copy,
+                    jnp.asarray(padded), jnp.asarray(src_r),
+                    jnp.asarray(tgt_r), jnp.asarray(w),
+                    jnp.ones((b,), jnp.int32), jnp.float32(now),
+                    jnp.int32(self.tenant_id(tenant)), jnp.asarray(live))
+            if existing:
+                slots = [self.edge_slots[s] if isinstance(s, tuple) else s
+                         for s in existing]
+                padded = S.pad_rows(np.asarray(slots, np.int32),
+                                    self.edge_state.capacity)
+                self._apply_edges(
+                    S.edges_reinforce, S.edges_reinforce_copy,
+                    jnp.asarray(padded), jnp.float32(reinforce),
+                    jnp.float32(now))
 
     def prune_edges(self, tenant: str, threshold: float) -> List[Tuple[str, str]]:
         """Drop the tenant's weak edges; host cleanup is O(pruned) via the

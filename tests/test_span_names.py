@@ -39,6 +39,9 @@ DISPATCH = {
            "index.stage", "serve.exact", "index.decode", "sched.demux"},
     "serve.exact": {"dispatch.launch", "dispatch.readback"},
 }
+# opened only by the dispatch that finds the edge topology dirty (ISSUE 39):
+# the CSR's build, inside ``index.stage``
+BUILDS = {"index.csr"}
 # a worker's two waits: which of them a dispatch opens is the callers' doing
 WAITS = {"sched.idle", "sched.hold"}
 # the names accepted metrics select by prefix (index.host_p50_ms.lat,
@@ -290,9 +293,9 @@ def test_int8_dispatch_opens_the_same_path_and_the_shadow_build_once(
     _converse(system, "alice", 0)
     sched = system._ensure_scheduler()
     req = RetrievalRequest(query=np.ones(D, np.float32), tenant="alice", k=5)
-    path = ["sched.account", "index.pack", "index.stage", "serve.quant",
-            "dispatch.launch", "index.shadow", "dispatch.readback",
-            "index.decode", "sched.demux"]
+    path = ["sched.account", "index.pack", "index.stage", "index.csr",
+            "serve.quant", "dispatch.launch", "index.shadow",
+            "dispatch.readback", "index.decode", "sched.demux"]
     for build in (True, False):
         time.sleep(0.05)        # the worker is back in its wait
         del opened[:]
@@ -302,9 +305,11 @@ def test_int8_dispatch_opens_the_same_path_and_the_shadow_build_once(
                and time.time() < deadline):
             time.sleep(0.005)
         mine = [e for e in opened if e[0] != "MainThread"]
-        want = [n for n in path if build or n != "index.shadow"]
+        want = [n for n in path
+                if build or n not in BUILDS | {"index.shadow"}]
         assert [n for _, n, _, _ in mine] == want + ["sched.idle"]
         tree = _tree(mine)
+        assert tree.get("index.stage", set()) == (BUILDS if build else set())
         assert tree["serve.quant"] == {"dispatch.launch", "dispatch.readback"}
         assert tree.get("dispatch.launch", set()) == (
             {"index.shadow"} if build else set())
@@ -319,7 +324,7 @@ def test_int8_dispatch_opens_the_same_path_and_the_shadow_build_once(
     assert set(select) == {'serve.select{core="whole_pool_q8"}'}, select
     assert sum(select.values()) == 2
     # and the accepted metrics' prefixes still select what they selected
-    assert not [n for n in {"index.shadow"}
+    assert not [n for n in BUILDS | {"index.shadow", "index.edges"}
                 if n.startswith(("serve.", "ingest."))]
 
 
