@@ -2339,11 +2339,20 @@ Example: {"preferences": "User prefers Python for data science.", "knowledge_dom
             # queued boosts must land before _sync_from_arena pulls rows,
             # or boosted host copies get overwritten with stale values
             self._flush_pending_boosts_locked()
-            if self._supports_incremental and self._store_synced:
-                self._save_incremental()
-            else:
+            if not self._supports_incremental:
                 self._save_full()
-            self._last_version = self.store.get_latest_version()
+                self._last_version = self.store.get_latest_version()
+                return
+            # a segmented store commits the save's writes as one: VERSION
+            # rises once, after the last of them — or not at all when
+            # nothing was dirty, and then _last_version stands
+            with self.store.commit() as commit:
+                if self._store_synced:
+                    self._save_incremental()
+                else:
+                    self._save_full()
+            if commit.version is not None:
+                self._last_version = commit.version
 
     def _save_incremental(self) -> None:
         self._sync_from_arena(node_ids=set(self._dirty_nodes),

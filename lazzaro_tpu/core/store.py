@@ -7,9 +7,14 @@ Same Store protocol (11 methods), same role split:
   (``core.index.MemoryIndex``). ``search_nodes`` is still implemented (exact
   top-k over durable rows) for protocol parity and store-only consumers.
 - The store is the system of record across restarts AND the multi-process
-  sync channel: every write bumps a version counter persisted via atomic
+  sync channel: every COMMIT bumps a version counter persisted via atomic
   rename, so dashboard-style readers can poll ``get_latest_version`` exactly
   like the reference polls LanceDB table versions (vector_store.py:150-156).
+  A commit is one write call (``add_nodes``, ``delete_edges``,
+  ``save_profile``, ``compact`` ...) or, inside ``with store.commit():``,
+  the whole group: the counter is read once and written once, after the
+  group's last rename, and only if something landed. It is read from the
+  file at every commit — other processes write it too.
 
 Write path is LSM-lite so bulk graphs stay cheap to mutate: each
 ``add_nodes``/``delete_nodes`` call appends one small *delta segment* parquet
@@ -32,11 +37,13 @@ decay in closed form on reload instead of rewriting every row per sweep.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import tempfile
 import threading
 import time
+import types
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -131,6 +138,11 @@ class ArrowStore:
                           else default_registry())
         os.makedirs(db_dir, exist_ok=True)
         self._lock = threading.Lock()
+        # the open commit scope of each thread (``commit``)
+        self._scope = threading.local()
+        # (kind, user) -> json.dumps of the sidecar content this instance
+        # last wrote or read (``_holds``)
+        self._sidecars: Dict[Any, str] = {}
         self._closed = False
 
     # ------------------------------------------------------------- plumbing
@@ -181,9 +193,43 @@ class ArrowStore:
             except FileNotFoundError:
                 pass
 
-    def _bump_version(self) -> None:
+    def _bump_version(self) -> int:
         v = self.get_latest_version() + 1
         self._write(self._version_path(), str(v).encode())
+        self.telemetry.bump("store.commits")
+        return v
+
+    def _landed(self) -> None:
+        """Every write funnel's last step, under the lock: the open commit
+        scope of this thread takes note, or the call is a commit itself."""
+        mark = getattr(self._scope, "mark", None)
+        if mark is None:
+            self._bump_version()
+        else:
+            mark.landed = True
+
+    @contextlib.contextmanager
+    def commit(self):
+        """Group this thread's writes into ONE commit: each still lands
+        (temp file, rename) before its call returns, and ``VERSION`` is read
+        and written once on the way out — after the last rename, also when
+        a later write raised, and not at all if nothing landed. Yields the
+        scope's mark: ``landed`` (a write did) and ``version`` (the new
+        number; None while open, and when nothing landed). A scope opened
+        inside another belongs to it."""
+        mark = getattr(self._scope, "mark", None)
+        if mark is not None:
+            yield mark
+            return
+        mark = self._scope.mark = types.SimpleNamespace(landed=False,
+                                                         version=None)
+        try:
+            yield mark
+        finally:
+            self._scope.mark = None
+            if mark.landed:
+                with self._lock:
+                    mark.version = self._bump_version()
 
     def get_latest_version(self) -> int:
         with self._io("read_version"):
@@ -352,22 +398,37 @@ class ArrowStore:
         self._write(os.path.join(self.db_dir, name), _table_bytes(rows_table))
         man["segments"].append(name)
         man["gen"] = gen
+        self._row_counts(man, {name: rows_table.num_rows})
         self._store_manifest(table, user_id, man)
         self._maybe_compact(table, user_id, man)
-        self._bump_version()
+        self._landed()
 
-    def _maybe_compact(self, table: str, user_id: str, man: Dict[str, Any]) -> None:
-        def rows_of(name):
+    def _row_counts(self, man: Dict[str, Any],
+                    fresh: Optional[Dict[str, int]] = None) -> None:
+        """``man["rows"]``: the row count of each file the manifest lists,
+        written with it by whoever wrote the file (``fresh``: the one just
+        written). A name it lacks (a legacy manifest, or one an older
+        process wrote) is asked of the file's footer here, so the next
+        manifest written is complete."""
+        known = {**(man.get("rows") or {}), **(fresh or {})}
+        rows = {}
+        for name in ([man["base"]] if man.get("base") else []) + man["segments"]:
+            if name in known:
+                rows[name] = known[name]
+                continue
             with self._io("read_meta"):
                 try:
-                    return pq.read_metadata(
+                    rows[name] = pq.read_metadata(
                         os.path.join(self.db_dir, name)).num_rows
                 except FileNotFoundError:
-                    return 0
+                    rows[name] = 0
+        man["rows"] = rows
 
+    def _maybe_compact(self, table: str, user_id: str, man: Dict[str, Any]) -> None:
+        rows = man["rows"]          # complete: _append_segment just wrote it
         segs = man["segments"]
-        seg_rows = sum(rows_of(name) for name in segs)
-        base_rows = rows_of(man["base"]) if man.get("base") else 0
+        seg_rows = sum(rows[name] for name in segs)
+        base_rows = rows[man["base"]] if man.get("base") else 0
         # Amortized (LSM-style): rewrite the base only once the deltas are a
         # meaningful fraction of it, so total compaction IO stays O(N log N).
         if seg_rows >= max(_COMPACT_MIN_ROWS, base_rows // 2):
@@ -399,6 +460,7 @@ class ArrowStore:
         self._write(os.path.join(self.db_dir, name), _table_bytes(t))
         man["segments"] = [name]
         man["gen"] = gen
+        self._row_counts(man, {name: t.num_rows})
         self._store_manifest(table, user_id, man)
         for old_name in old:
             self._unlink(os.path.join(self.db_dir, old_name))
@@ -408,11 +470,12 @@ class ArrowStore:
         old = ([man["base"]] if man.get("base") else []) + man["segments"]
         gen = int(man["gen"]) + 1
         if merged is None or merged.num_rows == 0:
-            new_man = {"base": None, "segments": [], "gen": gen}
+            new_man = {"base": None, "segments": [], "gen": gen, "rows": {}}
         else:
             name = f"{os.path.basename(self._stem(table, user_id))}.base-{gen:06d}.parquet"
             self._write(os.path.join(self.db_dir, name), _table_bytes(merged))
-            new_man = {"base": name, "segments": [], "gen": gen}
+            new_man = {"base": name, "segments": [], "gen": gen,
+                       "rows": {name: merged.num_rows}}
         self._store_manifest(table, user_id, new_man)
         for name in old:
             self._unlink(os.path.join(self.db_dir, name))
@@ -424,10 +487,13 @@ class ArrowStore:
                 man = self._load_manifest(table, user_id)
                 if man is not None:
                     self._compact(table, user_id, man)
-            self._bump_version()
+            self._landed()
 
     def _drop_all(self, table: str, user_id: str) -> None:
-        """Delete-all parity (reference vector_store.py:143-145)."""
+        """Delete-all parity (reference vector_store.py:143-145). The user's
+        sidecars are written anew by whatever save follows."""
+        for kind in ("profile", "sys_meta"):
+            self._sidecars.pop((kind, user_id), None)
         man = self._load_manifest(table, user_id)
         if man is not None:
             for name in ([man["base"]] if man.get("base") else []) + man["segments"]:
@@ -621,7 +687,7 @@ class ArrowStore:
                 # Parity: empty list deletes ALL the user's rows
                 # (reference vector_store.py:143-145).
                 self._drop_all("nodes", user_id)
-                self._bump_version()
+                self._landed()
                 return
             if self._load_manifest("nodes", user_id) is None:
                 return
@@ -688,7 +754,7 @@ class ArrowStore:
         with self._lock:
             if not edge_ids:
                 self._drop_all("edges", user_id)
-                self._bump_version()
+                self._landed()
                 return
             if self._load_manifest("edges", user_id) is None:
                 return
@@ -698,29 +764,59 @@ class ArrowStore:
             self._append_segment("edges", user_id, t)
 
     # --------------------------------------------------------------- profile
+    def _holds(self, kind: str, user_id: str, content: str) -> bool:
+        """Whether the user's ``kind`` sidecar already holds ``content``
+        (``json.dumps`` of what a save is handed): this instance last wrote
+        or read exactly that, and the user has one writer. Then the save is
+        no file operation. Caller holds the lock."""
+        if self._sidecars.get((kind, user_id)) != content:
+            return False
+        self.telemetry.bump("store.writes_skipped", labels={"kind": kind})
+        return True
+
+    def _remember(self, kind: str, user_id: str, loaded) -> None:
+        """What a load found in the file; None (absent, torn): nothing."""
+        if loaded is None:
+            self._sidecars.pop((kind, user_id), None)
+        else:
+            self._sidecars[(kind, user_id)] = json.dumps(loaded)
+
     def save_profile(self, profile: Dict[str, Any], user_id: str = "default") -> None:
+        """``updated_at`` is when the profile last CHANGED: saving the
+        ``data`` the file holds again writes nothing."""
+        data = json.dumps(profile)
         with self._lock:
+            if self._holds("profile", user_id, data):
+                return
             payload = json.dumps({"user_id": user_id, "data": profile,
                                   "updated_at": time.time()}).encode()
             self._write(self._stem("profiles", user_id) + ".json", payload)
-            self._bump_version()
+            self._sidecars[("profile", user_id)] = data
+            self._landed()
 
     def load_profile(self, user_id: str = "default") -> Optional[Dict[str, Any]]:
         prof = self._read_json(self._stem("profiles", user_id) + ".json")
-        return prof.get("data") if prof is not None else None
+        data = prof.get("data") if prof is not None else None
+        self._remember("profile", user_id, data)
+        return data
 
     # -------------------------------------------------------------- sys meta
     def save_sys_meta(self, meta: Dict[str, Any], user_id: str = "default") -> None:
         """Small orchestrator-owned sidecar (decay-pass counter, node counter).
         Presence of this method is how the orchestrator detects that the
         store supports incremental persistence."""
+        data = json.dumps(meta)
         with self._lock:
+            if self._holds("sys_meta", user_id, data):
+                return
             self._write(self._stem("sysmeta", user_id) + ".json",
-                        json.dumps(meta).encode())
-            self._bump_version()
+                        data.encode())
+            self._sidecars[("sys_meta", user_id)] = data
+            self._landed()
 
     def load_sys_meta(self, user_id: str = "default") -> Dict[str, Any]:
         meta = self._read_json(self._stem("sysmeta", user_id) + ".json")
+        self._remember("sys_meta", user_id, meta)
         return meta if meta is not None else {}
 
     # ------------------------------------------------------------------ misc

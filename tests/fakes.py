@@ -51,3 +51,9 @@ class MockLLM:
 def extraction_response(facts) -> str:
     """Build a canned fact-extraction JSON payload."""
     return json.dumps({"memories": facts})
+
+
+def file_ops(tel):
+    """``store.file_ops{op}`` of a registry as {op: count}."""
+    return {k.split("op=")[1].strip('"}'): v for k, v in tel.counters.items()
+            if k.startswith("store.file_ops")}

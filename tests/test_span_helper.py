@@ -223,10 +223,12 @@ def test_store_funnels_bump_once_per_call(tmp_path):
     tel.reset()
     store.add_nodes([{"id": "n1", "content": "c", "embedding": [1.0, 0.0]}],
                     user_id="u")
-    # manifest read (none yet), segment + manifest + version written, the
-    # segment's metadata read for the compaction decision, the version read
+    # manifest read (none yet), segment + manifest (with the segment's row
+    # count: no footer is read for the compaction decision) + version
+    # written, the version read
     assert _ops(tel) == {'{op="read_manifest"}': 1, '{op="write"}': 3,
-                         '{op="read_meta"}': 1, '{op="read_version"}': 1}
+                         '{op="read_version"}': 1}
+    assert tel.counters["store.commits"] == 1
     tel.reset()
     assert [n["id"] for n in store.get_nodes("u")] == ["n1"]
     assert _ops(tel) == {'{op="read_manifest"}': 1, '{op="read_table"}': 1}
@@ -234,6 +236,116 @@ def test_store_funnels_bump_once_per_call(tmp_path):
     store.delete_nodes([], user_id="u")
     assert _ops(tel)['{op="unlink"}'] == 3     # segment, manifest, legacy file
     assert tel.timer_count("store.io_ms") == sum(_ops(tel).values())
+
+
+def _written(store, monkeypatch):
+    """The base names ``store`` writes from here on, in order."""
+    names, write = [], store._write
+
+    def record(path, data):
+        names.append(os.path.basename(path))
+        write(path, data)
+
+    monkeypatch.setattr(store, "_write", record)
+    return names
+
+
+def test_commit_scope_bumps_the_version_once_after_the_last_write(
+        tmp_path, monkeypatch):
+    tel = Telemetry()
+    store = ArrowStore(str(tmp_path / "db"), telemetry=tel)
+    names = _written(store, monkeypatch)
+    with store.commit() as commit:
+        store.add_nodes([{"id": "n1", "content": "c", "embedding": [1.0]}],
+                        user_id="u")
+        store.save_profile({"data": {}}, user_id="u")
+        store.save_sys_meta({"decay_pass": 1}, user_id="u")
+        assert commit.version is None and "VERSION" not in names
+    # segment, manifest, two sidecars, THEN the version: one read, one write
+    assert names[-1] == "VERSION" and names.count("VERSION") == 1
+    assert _ops(tel) == {'{op="read_manifest"}': 1, '{op="write"}': 5,
+                         '{op="read_version"}': 1}
+    assert commit.version == store.get_latest_version() == 1
+    assert tel.counters["store.commits"] == 1
+    # a funnel outside any scope is a commit of its own again
+    store.save_sys_meta({"decay_pass": 2}, user_id="u")
+    assert store.get_latest_version() == 2
+    assert tel.counters["store.commits"] == 2
+
+
+def test_commit_scope_in_which_nothing_landed_is_no_operation(tmp_path):
+    tel = Telemetry()
+    store = ArrowStore(str(tmp_path / "db"), telemetry=tel)
+    store.save_sys_meta({"decay_pass": 1}, user_id="u")
+    tel.reset()
+    with store.commit() as commit:
+        store.add_nodes([], user_id="u")                   # no rows
+        store.delete_edges(["e"], user_id="u")             # no such table
+        store.save_sys_meta({"decay_pass": 1}, user_id="u")   # held already
+    assert _ops(tel) == {'{op="read_manifest"}': 1}
+    assert commit.version is None and "store.commits" not in tel.counters
+    assert store.get_latest_version() == 1
+
+
+def test_commit_scope_whose_second_write_raises_bumps_for_the_first(tmp_path):
+    store = ArrowStore(str(tmp_path / "db"))
+    with pytest.raises(TypeError):
+        with store.commit() as commit:
+            store.save_sys_meta({"decay_pass": 1}, user_id="u")
+            store.save_sys_meta({"decay_pass": object()}, user_id="u")
+    # the first write is on disk, so a poller has to hear of it
+    assert commit.version == store.get_latest_version() == 1
+    assert store.load_sys_meta("u") == {"decay_pass": 1}
+    # and the scope is closed: the next funnel commits by itself
+    store.save_sys_meta({"decay_pass": 2}, user_id="u")
+    assert store.get_latest_version() == 2
+
+
+def test_second_store_sees_the_version_rise_by_one_a_commit(tmp_path):
+    writer = ArrowStore(str(tmp_path / "db"))
+    poller = ArrowStore(str(tmp_path / "db"))
+    for i in range(1, 4):
+        with writer.commit():
+            writer.add_nodes([{"id": f"n{i}", "content": "c",
+                               "embedding": [1.0]}], user_id="u")
+            writer.save_sys_meta({"decay_pass": i}, user_id="u")
+        assert poller.get_latest_version() == i
+    # the counter is the FILE's: the other instance's commit counts on
+    with poller.commit() as commit:
+        poller.save_profile({"data": {}}, user_id="v")
+    assert commit.version == writer.get_latest_version() == 4
+
+
+def test_commit_scopes_of_many_threads_lose_no_bump(tmp_path):
+    """A scope is its thread's own; the bump is read-modify-write under the
+    store's lock, so N commits leave VERSION at N and no two share one."""
+    store = ArrowStore(str(tmp_path / "db"))
+    versions, errors = [], []
+
+    def writer(t):
+        try:
+            for i in range(5):
+                with store.commit() as commit:
+                    store.save_sys_meta({"decay_pass": i}, user_id=f"u{t}")
+                    store.save_profile({"data": {"i": i}}, user_id=f"u{t}")
+                versions.append(commit.version)
+        except Exception as e:                  # pragma: no cover
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=writer, args=(t,))
+                   for t in range(16)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(th.is_alive() for th in threads)
+    assert sorted(versions) == list(range(1, 81))
+    assert store.get_latest_version() == 80
 
 
 @pytest.mark.parametrize("native", [True, False])

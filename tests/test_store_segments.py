@@ -11,6 +11,9 @@ import pyarrow.parquet as pq
 import pytest
 
 from lazzaro_tpu.core.store import ArrowStore
+from lazzaro_tpu.utils.telemetry import Telemetry
+
+from tests.fakes import file_ops
 
 
 @pytest.fixture()
@@ -227,3 +230,102 @@ def test_columnar_bulk_insert_matches_dict_path(tmp_path):
     rows = {r["id"]: r for r in store.get_nodes()}
     assert rows["d"]["content"] == "four v2" and rows["d"]["salience"] == 0.9
     store.close()
+
+
+# ------------------------------------------------ row counts in the manifest
+def _footers(store, man):
+    """What the files themselves say: {name: rows} over the listed files."""
+    names = ([man["base"]] if man.get("base") else []) + man["segments"]
+    return {n: pq.read_metadata(os.path.join(store.db_dir, n)).num_rows
+            for n in names}
+
+
+def test_manifest_carries_row_counts_after_append_fold_and_compact(tmp_path):
+    store = ArrowStore(str(tmp_path / "db"), telemetry=Telemetry())
+    store.add_nodes([_node(i) for i in range(7)])
+    store.add_nodes([_node(7)])
+    man = _segments(store)
+    assert list(man["rows"].values()) == [7, 1]            # append
+    store.compact()
+    man = _segments(store)
+    assert man["rows"] == {man["base"]: 8}                 # compact
+    for i in range(16):                                    # the 16th folds
+        store.add_nodes([_node(100 + i)])
+    man = _segments(store)
+    assert len(man["segments"]) == 1                       # fold
+    assert man["rows"] == {man["base"]: 8, man["segments"][0]: 16}
+    assert man["rows"] == _footers(store, man)
+    store.delete_nodes([f"node_{i}" for i in range(8)])    # tombstones count
+    man = _segments(store)
+    assert man["rows"] == _footers(store, man)
+    assert "read_meta" not in file_ops(store.telemetry)    # nobody asked a footer
+    store.delete_nodes([])
+    store.add_nodes([_node(1)])
+    store.delete_nodes(["node_1"])
+    store.compact()                                        # nothing is left
+    assert _segments(store) == {"base": None, "segments": [], "gen": 3,
+                                "rows": {}}
+
+
+@pytest.mark.parametrize("batches", [
+    [3000, 3000],                    # row-heavy deltas: _COMPACT_MIN_ROWS
+    [10000, 4500, 600],              # ... or half the base, if that is more
+    [1] * 33,                        # tiny deltas: _COMPACT_MAX_SEGMENTS
+    [4095, 1],                       # the row threshold to the row
+], ids=["min_rows", "half_base", "max_segments", "edge"])
+def test_compaction_decision_is_the_one_the_footers_give(store, batches):
+    from lazzaro_tpu.core.store import (_COMPACT_MAX_SEGMENTS,
+                                        _COMPACT_MIN_ROWS)
+    seen, nxt = set(), 0
+    for n in batches:
+        before = (_segments(store) if os.path.exists(
+            store._manifest_path("nodes", "default"))
+            else {"base": None, "segments": [], "gen": 0})
+        foot = _footers(store, before)
+        seg_rows = sum(foot[s] for s in before["segments"]) + n
+        base_rows = foot[before["base"]] if before["base"] else 0
+        if seg_rows >= max(_COMPACT_MIN_ROWS, base_rows // 2):
+            want = "compact"
+        elif len(before["segments"]) + 1 >= _COMPACT_MAX_SEGMENTS:
+            want = "fold"
+        else:
+            want = "append"
+        store.add_nodes([_node(i, dim=1) for i in range(nxt, nxt + n)])
+        nxt += n
+        after = _segments(store)
+        got = ("compact" if after["segments"] == [] else
+               "fold" if after["gen"] == before["gen"] + 2 else "append")
+        assert got == want, (n, before, after)
+        assert after["rows"] == _footers(store, after)
+        seen.add(got)
+    assert len(seen) > 1                 # the case drove a threshold
+    assert len(store.get_nodes()) == nxt
+
+
+def test_manifest_without_row_counts_is_read_once_and_completed(tmp_path):
+    store = ArrowStore(str(tmp_path / "db"), telemetry=Telemetry())
+    store.add_nodes([_node(i) for i in range(5)])
+    store.compact()
+    store.add_nodes([_node(5), _node(6)])
+    man = _segments(store)
+    counts = man.pop("rows")
+    # as an older process left it: the files listed, no counts
+    with open(store._manifest_path("nodes", "default"), "w") as f:
+        json.dump(man, f)
+    store.telemetry.reset()
+    store.add_nodes([_node(7)])
+    assert file_ops(store.telemetry)["read_meta"] == 2         # the base and the segment
+    done = _segments(store)
+    assert done["rows"] == {**counts, done["segments"][-1]: 1}
+    store.add_nodes([_node(8)])                  # complete: no footer again
+    assert file_ops(store.telemetry)["read_meta"] == 2
+    # an older process appends a name without its count: that one is asked
+    done = _segments(store)
+    done["rows"].pop(done["segments"][-1])
+    with open(store._manifest_path("nodes", "default"), "w") as f:
+        json.dump(done, f)
+    store.add_nodes([_node(9)])
+    assert file_ops(store.telemetry)["read_meta"] == 3
+    man = _segments(store)
+    assert man["rows"] == _footers(store, man)
+    assert len(store.get_nodes()) == 10
