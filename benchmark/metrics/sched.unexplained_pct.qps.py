@@ -1,0 +1,9 @@
+from benchmark.stage_metrics import unexplained_pct
+
+
+def read(run):
+    """Share of the harness's own mean latency that the request's stages the
+    scheduler stamps (queue, account, executor call, demux wait, wake-up) do
+    not add up to: what the layer map still leaves out of a waiting caller's
+    latency on the chip-filling arena."""
+    return unexplained_pct(run)

@@ -1272,15 +1272,23 @@ class ShardedMemoryIndex:
     def _csr_sharded(self):
         """Per-shard CSR slices of the host edge map (each chip's own
         rows' neighbor lists, global neighbor ids), re-uploaded only after
-        an edge-topology change."""
+        an edge-topology change. Span and counters are the one-chip
+        index's (``MemoryIndex._csr_for``): ``lz.index.csr`` only when it
+        builds, ``index.csr_lookups`` on every call."""
+        tel = self.telemetry
+        tel.bump("index.csr_lookups")
         if self._csr_cache is not None and not self._csr_dirty:
             return self._csr_cache
         self._csr_dirty = False
-        indptr, nbr = build_host_csr(list(self.edges.keys()),
-                                     self.id_to_row, self.capacity + 1)
-        ish, nsh = split_csr(indptr, nbr, self.n_parts)
-        self._csr_cache = (jax.device_put(ish, self._stacked),
-                           jax.device_put(nsh, self._stacked))
+        with tel.span("index.csr"):
+            keys = list(self.edges.keys())
+            indptr, nbr = build_host_csr(keys, self.id_to_row,
+                                         self.capacity + 1)
+            ish, nsh = split_csr(indptr, nbr, self.n_parts)
+            self._csr_cache = (jax.device_put(ish, self._stacked),
+                               jax.device_put(nsh, self._stacked))
+        tel.bump("index.csr_builds")
+        tel.bump("index.csr_edges", len(keys))
         return self._csr_cache
 
     def _int8_shadow_for(self):

@@ -169,28 +169,59 @@ def _set_future(fut: Future, res) -> None:
         pass            # watchdog already failed it — late result discarded
 
 
+# The one clock of a request's stamps (ISSUE 41): the spans' own
+# (``perf_counter``), in whole nanoseconds. Monotonic: wall time stays where
+# a wall time is meant (the breaker's cooldown, a request's ``now``).
+_clock_ns = time.perf_counter_ns
+
+
 class _CallerFuture(Future):
     """A request's future that knows its callers: the thread that
     submitted it, and the thread blocked on it. At demux the scheduler
     notes the threads that wait in ``result()`` — they are released by the
     answer and, in a closed loop, on their way back. A caller that took
-    ``add_done_callback`` instead never counts."""
+    ``add_done_callback`` instead never counts.
+
+    It also carries the request's last two stamps (ISSUE 41; class
+    docstring of :class:`QueryScheduler`, "A request's stages"): the
+    worker writes ``t_set`` just before it sets the answer, and the thread
+    that waited in ``result()`` reads the clock when it runs again and
+    leaves its wake-up in the scheduler's ledger (``_woke``: thread ident
+    → its running ``(wake_ns, wakes, t_woke)``) — two dict operations and
+    no lock on the caller's thread; the scheduler folds the entry at that
+    thread's next submission. A done-callback that reads its answer runs
+    on the worker, inside the demux: it was not woken, and counts nowhere."""
 
     waiter = 0              # ident of the thread blocked in result(), or 0
+    t_set = 0               # when the worker set the answer; 0 = not stamped
 
-    def __init__(self, caller: int):
+    def __init__(self, caller: int, sched: "QueryScheduler"):
         super().__init__()
         self.caller = caller            # ident of the thread that submitted
+        self.sched = sched
 
     def result(self, timeout=None):
-        self.waiter = threading.get_ident()
+        self.waiter = ident = threading.get_ident()
         try:
-            return super().result(timeout)
+            res = super().result(timeout)
         finally:
             self.waiter = 0
+        t_set = self.t_set
+        if t_set:                       # a served answer, read for the first time
+            self.t_set = 0
+            sched = self.sched
+            if ident not in sched._worker_idents:
+                now = _clock_ns()
+                # pop, then store: whoever pops an entry owns it, so an
+                # expiry on another thread in between neither loses nor
+                # doubles a wake-up
+                woke = sched._woke
+                ns, n, _ = woke.pop(ident, None) or (0, 0, 0)
+                woke[ident] = (ns + now - t_set, n + 1, now)
+        return res
 
 
-_Item = Tuple[RetrievalRequest, Future, float]      # (request, future, enqueued)
+_Item = Tuple[RetrievalRequest, Future, int]    # (request, future, t_submit ns)
 
 # The hold's bound, as a share of what a dispatch takes (the scheduler's own
 # running estimate): nobody waits longer than this past the demux that
@@ -304,6 +335,38 @@ class QueryScheduler:
     device); ``close()`` and ``flush()`` end a hold at once; a held
     request is a pending request like any other.
 
+    A request's stages (ISSUE 41). Every served request is stamped on ONE
+    monotonic clock (``_clock_ns``, the spans' ``perf_counter``) where it
+    changes hands: ``t_submit`` (``submit_many``, before the lock),
+    ``t_flush`` (the start of ``_account``), ``t_exec0`` / ``t_exec1``
+    (around the executor call), ``t_set`` (just before its answer is set
+    in the demux loop), ``t_woke`` (in ``_CallerFuture.result``, when the
+    thread that waited runs again) and ``t_back`` (that thread's next
+    ``submit_many``). The differences are summed over ALL served requests
+    into unlabelled counters, in microseconds, bumped once a served batch
+    beside ``serve.requests``: ``serve.queue_wait_us`` (flush − submit),
+    ``serve.account_us`` (exec0 − flush), ``serve.exec_us`` (exec1 −
+    exec0; the first three are the batch's, times its requests),
+    ``serve.demux_wait_us`` (set − exec1: the bookkeeping after the call
+    and the callbacks of the requests ahead in the batch),
+    ``serve.wake_us`` / ``serve.wakes`` (woke − set, and their number:
+    only threads that waited in ``result()``; a callback counts nowhere)
+    and ``serve.return_us`` / ``serve.returns`` (back − woke: the caller's
+    own time, counted only while the caller's bound of ``_watched`` /
+    ``_expected`` still runs). So for every request served through
+    ``result()``, woke − submit = queue + account + exec + demux_wait +
+    wake, and for a callback request set − submit is the same without the
+    wake: what a user's own clock reads beyond that sum, no layer of the
+    program explains. The last two pairs begin on one thread and end on
+    another, so no span can hold them: the caller's thread leaves its
+    wake-up in a ledger (``_woke``: its ident → ``(wake_ns, wakes,
+    t_woke)``; two dict operations, no lock), the same thread's next
+    submission folds it under the lock it takes anyway (an expiry folds
+    the wake-up of a caller that did not come back, ``close()`` what is
+    left), and the worker bumps what was folded with the next batch's
+    counters. A failed or timed-out batch counts in no stage; with the
+    registry switched off nothing is stamped or stored.
+
     Per-tenant admission control (``tenant_max_inflight``) caps how many
     of one tenant's requests enter a single dispatch, walking the queue
     oldest-first so over-cap requests keep their place for the next batch
@@ -369,6 +432,13 @@ class QueryScheduler:
         self._expected: Dict[int, float] = {}
         self._watched: Dict[int, float] = {}
         self._held_s = 0.0                   # held since the last admission
+        # a request's way back (ISSUE 41; class docstring, "A request's
+        # stages"): the ledger the callers' threads write their wake-ups
+        # into, and what submissions (and expiries) have folded out of it
+        # since the last served batch — [wake ns, wakes, return ns, returns]
+        self._woke: Dict[int, Tuple[int, int, int]] = {}
+        self._way_back = [0, 0, 0, 0]
+        self._worker_idents: set = set()     # their reads are no wake-ups
         self._flushing = 0                   # flush() callers: they end a hold
         self._pending: List[_Item] = []
         self._pending_bytes = 0
@@ -410,8 +480,9 @@ class QueryScheduler:
         so callers see the typed error at ``.result()`` like any other
         failure."""
         caller = threading.get_ident()
-        futures: List[Future] = [_CallerFuture(caller) for _ in requests]
-        now = time.time()
+        futures: List[Future] = [_CallerFuture(caller, self)
+                                 for _ in requests]
+        now = _clock_ns()
         if self.admission_check is not None and requests:
             try:
                 self.admission_check(list(requests))
@@ -447,7 +518,7 @@ class QueryScheduler:
                 self._pending.append((req, fut, now))
             self._pending_bytes += nbytes
             self._ensure_workers_locked()
-            self._note_return_locked(caller)
+            self._fold_locked(caller, now, self._note_return_locked(caller))
             # a worker that holds its window open is woken only when the
             # hold is over: a wake-up per submission would hand it the
             # interpreter after the first caller is back
@@ -477,6 +548,7 @@ class QueryScheduler:
         batch's futures (inside ``_serve_loop``) and restarts the loop —
         pending requests stay queued and are served after the restart.
         Only a clean close exits."""
+        self._worker_idents.add(threading.get_ident())
         while True:
             try:
                 self._serve_loop()
@@ -636,14 +708,32 @@ class QueryScheduler:
             self._holding = False
             self._held_s += time.perf_counter() - began
 
-    def _note_return_locked(self, caller: int) -> None:
+    def _note_return_locked(self, caller: int) -> bool:
         """A submission by the thread ``caller``: if a recent demux
-        released it and its bound still runs, it is back, and known to
-        come back."""
+        released it and its bound still runs, it is back (True), and known
+        to come back."""
         self._expire_locked()
-        self._expected.pop(caller, None)
+        back = self._expected.pop(caller, None) is not None
         if self._watched.pop(caller, None) is not None:
             self._returners.add(caller)
+            back = True
+        return back
+
+    def _fold_locked(self, caller: int, now: int = 0,
+                     back: bool = False) -> None:
+        """Take the thread ``caller``'s wake-ups out of the ledger: their
+        time and number and, if it is ``back`` inside its bound at ``now``,
+        its return — from its last wake-up to this submission."""
+        entry = self._woke.pop(caller, None)
+        if entry is None:
+            return
+        wake_ns, wakes, t_woke = entry
+        tally = self._way_back
+        tally[0] += wake_ns
+        tally[1] += wakes
+        if back:
+            tally[2] += now - t_woke
+            tally[3] += 1
 
     def _expire_locked(self) -> None:
         """A caller still out when its bound has run out did not come
@@ -659,6 +749,7 @@ class QueryScheduler:
                     break
                 del out[caller]
                 self._returners.discard(caller)
+                self._fold_locked(caller)   # it woke; it is not on its way back
 
     def _note_demux_locked(self, batch: "_Batch", took_s: float) -> None:
         """A dispatch came back and its answers are about to be handed
@@ -749,22 +840,23 @@ class QueryScheduler:
         return dataclasses.replace(req, cap_take=cap, nprobe=npr)
 
     def _account(self, batch: _Batch):
-        """(requests to dispatch, their summed queue wait in seconds, the
-        armed watchdog timer or None, its timed-out flag)."""
+        """(requests to dispatch, ``t_flush`` and the requests' summed queue
+        wait up to it in nanoseconds, the armed watchdog timer or None, its
+        timed-out flag)."""
         items = batch.items
         reqs = batch.reqs
-        flush_t = time.time()
-        waited_s = 0.0
-        for req, _, enq in items:
-            waited_s += flush_t - enq
+        t_flush = _clock_ns()
+        waited_ns = 0
+        for req, _, t_submit in items:
+            waited_ns += t_flush - t_submit
             # a ring of ``window`` samples PER TENANT series (256 tenants,
             # then "~other"): a series that overflows keeps its newest
             # samples only, so percentiles of this timer lean to the
             # window's end — serve.queue_wait_us is the whole sum
             self.telemetry.record("serve.queue_wait_ms",
-                                  (flush_t - enq) * 1e3,
+                                  (t_flush - t_submit) / 1e6,
                                   labels={"tenant": req.tenant})
-        if self.breaker is not None and self.breaker.degraded(flush_t):
+        if self.breaker is not None and self.breaker.degraded():
             reqs = [self._degrade(r) for r in reqs]
             self.telemetry.bump("reliability.degraded_requests", len(reqs))
         timer = None
@@ -784,23 +876,23 @@ class QueryScheduler:
             timer = threading.Timer(self.dispatch_timeout_s, _deadline)
             timer.daemon = True
             timer.start()
-        return reqs, waited_s, timer, timed_out
+        return reqs, t_flush, waited_ns, timer, timed_out
 
     def _execute(self, batch: _Batch) -> None:
         items = batch.items
         # the worker's own bookkeeping before the dispatch: one labelled
         # queue-wait sample per request, the breaker, the watchdog
         with self.telemetry.span("sched.account"):
-            reqs, waited_s, timer, timed_out = self._account(batch)
+            reqs, t_flush, waited_ns, timer, timed_out = self._account(batch)
         try:
             # one mega-batch == one profiler step, so TPU captures line up
             # with the host spans batch-for-batch. ONE annotation around
             # the whole executor call, on the thread that runs it: every
             # such span contains at least its own pass over the arena.
             with StepTraceAnnotation("lz.serve.batch", step_num=batch.seq):
-                began = time.perf_counter()
+                t_exec0 = _clock_ns()
                 results = self._executor(reqs)
-                took_s = time.perf_counter() - began
+                t_exec1 = _clock_ns()
         except Exception as e:                      # noqa: BLE001 — demuxed
             if timer is not None:
                 timer.cancel()
@@ -824,11 +916,19 @@ class QueryScheduler:
             self.batch_sizes.append(len(items))
             if len(self.batch_sizes) > 1024:
                 del self.batch_sizes[:512]
-            self._note_demux_locked(batch, took_s)
-        self.telemetry.bump("serve.requests", len(items))
+            self._note_demux_locked(batch, (t_exec1 - t_exec0) / 1e9)
+            way_back, self._way_back = self._way_back, [0, 0, 0, 0]
+        n = len(items)
+        self.telemetry.bump("serve.requests", n)
         self.telemetry.bump("serve.batches")
-        # summed over the served requests, so it divides by serve.requests
-        self.telemetry.bump("serve.queue_wait_us", int(waited_s * 1e6))
+        # a request's stages, each summed over the served requests, so it
+        # divides by serve.requests (class docstring, "A request's stages")
+        self.telemetry.bump("serve.queue_wait_us", waited_ns // 1000)
+        self.telemetry.bump("serve.account_us", n * (t_exec0 - t_flush) // 1000)
+        self.telemetry.bump("serve.exec_us", n * (t_exec1 - t_exec0) // 1000)
+        # the way back of the requests served BEFORE this batch, as the
+        # callers' submissions folded it since the last one
+        self._bump_way_back(way_back)
         if len(items) == 1:
             # a whole dispatch (a full arena pass) for one answer
             self.telemetry.bump("serve.lone_batches")
@@ -841,10 +941,24 @@ class QueryScheduler:
         self.telemetry.bump("serve.held_batches", int(batch.held_s > 0))
         self.telemetry.bump("serve.hold_us", int(batch.held_s * 1e6))
         self.telemetry.record("serve.batch_requests", len(items))
-        # the callers' done-callbacks run here, on the worker thread
+        # the callers' done-callbacks run here, on the worker thread: an
+        # answer waits for the callbacks of those ahead of it in the batch
+        stamped = self.telemetry.enabled
+        demux_wait_ns = 0
         with self.telemetry.span("sched.demux"):
             for (_, fut, _), res in zip(items, results):
+                if stamped:
+                    fut.t_set = t_set = _clock_ns()
+                    demux_wait_ns += t_set - t_exec1
                 _set_future(fut, res)
+        self.telemetry.bump("serve.demux_wait_us", demux_wait_ns // 1000)
+
+    def _bump_way_back(self, tally) -> None:
+        wake_ns, wakes, return_ns, returns = tally
+        self.telemetry.bump("serve.wake_us", wake_ns // 1000)
+        self.telemetry.bump("serve.wakes", wakes)
+        self.telemetry.bump("serve.return_us", return_ns // 1000)
+        self.telemetry.bump("serve.returns", returns)
 
     def load(self) -> int:
         """Instantaneous queue depth + in-flight dispatches — the
@@ -855,13 +969,13 @@ class QueryScheduler:
     # ----------------------------------------------------------- lifecycle
     def flush(self, timeout: float = 30.0) -> None:
         """Block until everything submitted so far has been executed."""
-        deadline = time.time() + timeout
+        deadline = time.monotonic() + timeout
         with self._cond:
             self._flushing += 1         # ends a hold, and lets none begin
             self._cond.notify_all()
             try:
                 while self._pending or self._inflight:
-                    remaining = deadline - time.time()
+                    remaining = deadline - time.monotonic()
                     if remaining <= 0:
                         raise TimeoutError("QueryScheduler.flush timed out")
                     self._cond.wait(min(remaining, 0.05))
@@ -878,6 +992,12 @@ class QueryScheduler:
             workers = [w for w in self._workers if w is not None]
         for worker in workers:
             worker.join(timeout=30.0)
+        # the wake-ups no later submission folded: the last batches' callers
+        with self._cond:
+            for caller in list(self._woke):
+                self._fold_locked(caller)
+            way_back, self._way_back = self._way_back, [0, 0, 0, 0]
+        self._bump_way_back(way_back)
 
     def stats(self) -> dict:
         with self._cond:

@@ -47,6 +47,13 @@ WAITS = {"sched.idle", "sched.hold"}
 # the names accepted metrics select by prefix (index.host_p50_ms.lat,
 # kernel.ingest_dev_ms): a new span under them would redefine the metric
 ACCEPTED = {"serve.exact", "ingest.dedup_fused"}
+# a request's stages (ISSUE 41): unlabelled sums over every served request,
+# bumped once a served batch — the first three by any dispatch, the last
+# four only for callers that waited in ``result()`` (and, the last two,
+# came back inside their bound)
+STAGES = {"serve.account_us", "serve.exec_us", "serve.demux_wait_us"}
+WAY_BACK = {"serve.wake_us", "serve.wakes", "serve.return_us",
+            "serve.returns"}
 SUMMED = ("journal.turn", "journal.sync", "journal.append", "journal.commit",
           "journal.setup", "store.save", "store.load", "store.add")
 
@@ -150,6 +157,11 @@ def test_one_dispatch_opens_at_most_twelve_spans(system, opened):
                      "sched.demux", "sched.idle"]
     assert system.telemetry.counter_total("serve.lone_batches") == 2
     assert system.telemetry.counter_total("serve.queue_wait_us") > 0
+    # a request's stages: both dispatches stamped; the first answer's
+    # wake-up was folded by the second submission and rode its counters,
+    # the second's waits for the next batch (or ``close()``)
+    counters = system.telemetry.counters
+    assert STAGES <= set(counters) and counters["serve.wakes"] == 1
     # nothing overlapped: the counter has no entry at all (a bump of 0)
     assert "serve.overlapped_batches" not in system.telemetry.counters
 
@@ -258,6 +270,46 @@ def test_a_hold_is_a_top_level_span_of_the_worker_beside_its_idle_wait(
     # and no hold opens unless the worker waits in it
     assert (tel.snapshot()["timers"]["sched.hold_ms"]["count"]
             == order.count("sched.hold"))
+    # callers that wait and come back inside their bound: all seven of a
+    # request's stage counters, unlabelled, and every answer one wake-up
+    assert STAGES | WAY_BACK <= set(tel.counters)
+    assert tel.counters["serve.wakes"] == tel.counters["serve.requests"] == 16
+    assert 1 <= tel.counters["serve.returns"] <= 14
+
+
+def test_the_pod_index_builds_its_csr_under_the_one_chip_index_s_span(opened):
+    """``ShardedMemoryIndex`` builds its per-shard CSR under ``index.csr``,
+    inside the ``index.stage`` of the dispatch that found the topology
+    dirty, with the one-chip index's three counters; a dispatch on a clean
+    topology opens none and counts a look-up."""
+    import jax
+    from lazzaro_tpu.parallel.index import ShardedMemoryIndex
+    from lazzaro_tpu.parallel.mesh import make_mesh
+
+    tel = T.Telemetry()
+    mesh = make_mesh(("data",), (4,), devices=jax.devices()[:4])
+    si = ShardedMemoryIndex(mesh, dim=D, capacity=255, telemetry=tel)
+    emb = np.random.default_rng(0).standard_normal((40, D)).astype(np.float32)
+    si.add([f"n{i}" for i in range(40)], emb, "u0")
+    si.add_edges([(f"n{i}", f"n{i + 1}", 0.5) for i in range(39)])
+    reqs = [RetrievalRequest(query=emb[i], tenant="u0", k=5, boost=True)
+            for i in range(3)]
+    def serve():
+        del opened[:]
+        assert all(r.ids for r in si.serve_requests(reqs))
+        return [(n, parent) for _, n, parent, _ in opened if n == "index.csr"]
+
+    def counted():
+        return tuple(tel.counter_total("index.csr_" + n)
+                     for n in ("builds", "edges", "lookups"))
+
+    assert serve() == [("index.csr", "index.stage")]    # the first one builds
+    assert counted() == (1, 39, 1)
+    assert serve() == [] and counted() == (1, 39, 2)    # a clean topology
+    si.add_edges([("n0", "n7", 0.3)])                   # dirties it
+    assert serve() == [("index.csr", "index.stage")]
+    assert counted() == (2, 79, 3)
+    assert tel.snapshot()["timers"]["index.csr_ms"]["count"] == 2
 
 
 def test_no_new_name_falls_under_an_accepted_metrics_prefix():
