@@ -9,7 +9,10 @@ retrievals — across callers, threads, and tenants — into padded mega-batches
 the way Ragged Paged Attention coalesces ragged decode work on TPU:
 
 - callers ``submit()`` a :class:`RetrievalRequest` and block on the returned
-  future; the scheduler's workers own the device dispatch. One dispatch
+  handle (``result()`` / ``add_done_callback`` as on a
+  ``concurrent.futures.Future``; since ISSUE 42 it is one lock and not a
+  ``Future``: :class:`_CallerFuture`); the scheduler's workers own the
+  device dispatch. One dispatch
   is in flight at a time — which keeps the donated state mutation
   single-writer — except that a FULL pending batch of pure reads is
   admitted over an in-flight batch of pure reads (at most two in flight,
@@ -20,7 +23,7 @@ the way Ragged Paged Attention coalesces ragged decode work on TPU:
   once and arrivals during a dispatch coalesce into the next — except
   that a free worker HOLDS a window that is not full while callers its
   last demux released are still expected back (ISSUE 32): callers that
-  block in ``Future.result()`` re-submit within a millisecond or two of
+  block in ``result()`` re-submit within a millisecond or two of
   their answers, and shipping the first of them alone costs the others a
   whole dispatch. The hold ends when the window fills, when everyone
   expected is back, or ``HOLD_FRACTION`` of one dispatch's time after
@@ -91,8 +94,11 @@ import dataclasses
 import logging
 import threading
 import time
-from concurrent.futures import Future, InvalidStateError
+from _thread import allocate_lock as _allocate_lock
+from concurrent.futures import CancelledError
+from concurrent.futures import TimeoutError as FutureTimeout
 from dataclasses import dataclass, field
+from threading import get_ident as _get_ident
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -149,38 +155,49 @@ class RetrievalResult:
 Executor = Callable[[List[RetrievalRequest]], List[RetrievalResult]]
 
 
-def _fail_future(fut: Future, err: BaseException) -> None:
-    """Set an exception, tolerating a future that already resolved (the
-    watchdog and the late dispatch race by design)."""
-    if fut.cancelled():
-        return
-    try:
-        fut.set_exception(err)
-    except InvalidStateError:
-        pass
-
-
-def _set_future(fut: Future, res) -> None:
-    if fut.cancelled():
-        return
-    try:
-        fut.set_result(res)
-    except InvalidStateError:
-        pass            # watchdog already failed it — late result discarded
-
-
 # The one clock of a request's stamps (ISSUE 41): the spans' own
 # (``perf_counter``), in whole nanoseconds. Monotonic: wall time stays where
 # a wall time is meant (the breaker's cooldown, a request's ``now``).
 _clock_ns = time.perf_counter_ns
 
 
-class _CallerFuture(Future):
-    """A request's future that knows its callers: the thread that
-    submitted it, and the thread blocked on it. At demux the scheduler
-    notes the threads that wait in ``result()`` — they are released by the
-    answer and, in a closed loop, on their way back. A caller that took
-    ``add_done_callback`` instead never counts.
+class _CallerFuture:
+    """A request's handle: ONE lock, taken when the request is made and
+    released by whoever resolves it (ISSUE 42). It is NOT a
+    ``concurrent.futures.Future`` — no ``Condition``, no ``RLock``, no lock
+    allocated per wait — because the 64 answers of a batch change hands
+    under the one interpreter lock that paces a host-bound cell, and every
+    bytecode of the hand-over is paid 64 times a cycle.
+
+    What it keeps of the ``Future`` protocol, for the callers: ``result`` /
+    ``exception`` (``timeout`` raises ``concurrent.futures.TimeoutError``,
+    a cancelled handle ``CancelledError``, a failed one its typed error),
+    ``done``, ``cancelled``, ``cancel`` (True on a pending handle, whose
+    late answer is then discarded), ``running`` (never: the scheduler marks
+    no request running, so a request can be cancelled until it is
+    answered), ``add_done_callback`` (run with the handle on the thread
+    that resolves it, in registration order; at once, on the registering
+    thread, when it is resolved already; a raising callback is logged and
+    the others still run) and free attributes. What it does not: there is
+    no ``set_result`` / ``set_exception`` (only this module resolves a
+    handle: ``_set_future`` / ``_fail_future``), and ``concurrent.futures
+    .wait`` / ``as_completed``, which reach into a ``Future``'s condition,
+    raise ``TypeError`` at once (nothing in the repo hands them one).
+
+    Resolution is atomic, and first come first served: the worker's demux,
+    a watchdog's ``Timer`` thread and a caller's ``cancel`` race as meant.
+    Whoever deletes ``_open`` — one C call, so exactly one thread finds it —
+    owns the handle: it stores the outcome, releases the lock and runs the
+    callbacks; the others learn that they lost and change nothing. A
+    pending ``result()`` is one blocking ``acquire`` in C and the
+    ``release`` that lets a second reader through; a resolved one touches
+    no lock.
+
+    It knows its callers: the thread that submitted it, and the thread
+    blocked on it. At demux the scheduler notes the threads that wait in
+    ``result()`` — they are released by the answer and, in a closed loop,
+    on their way back. A caller that took ``add_done_callback`` instead
+    never counts.
 
     It also carries the request's last two stamps (ISSUE 41; class
     docstring of :class:`QueryScheduler`, "A request's stages"): the
@@ -194,20 +211,60 @@ class _CallerFuture(Future):
 
     waiter = 0              # ident of the thread blocked in result(), or 0
     t_set = 0               # when the worker set the answer; 0 = not stamped
+    _outcome = None         # (result, error); error is CancelledError itself
+                            # (the class) on a cancelled handle
 
     def __init__(self, caller: int, sched: "QueryScheduler"):
-        super().__init__()
         self.caller = caller            # ident of the thread that submitted
         self.sched = sched
+        self._open = True               # deleted by the one that resolves it
+        self._callbacks = []
+        lock = self._lock = _allocate_lock()
+        lock.acquire()
+
+    def _resolve(self, result, error) -> bool:
+        """Claim → store → release → callbacks. False, and nothing
+        changed, if somebody else resolved it first."""
+        try:
+            del self._open
+        except AttributeError:
+            return False
+        self._outcome = (result, error)
+        self._lock.release()
+        callbacks = self._callbacks
+        while callbacks:
+            try:
+                fn = callbacks.pop(0)
+            except IndexError:      # its registrant took the last one back
+                break
+            self._call(fn)
+        return True
+
+    def _call(self, fn) -> None:
+        try:
+            fn(self)
+        except Exception:       # noqa: BLE001 — as Future: logged
+            logger.exception("exception calling callback for %r", self)
+
+    def _wait(self, timeout):
+        """Block until resolved: the outcome, or the futures' timeout."""
+        if not self._lock.acquire(
+                True, -1 if timeout is None else max(timeout, 0)):
+            raise FutureTimeout()
+        self._lock.release()            # the next reader's turn
+        return self._outcome
 
     def result(self, timeout=None):
-        self.waiter = ident = threading.get_ident()
-        try:
-            res = super().result(timeout)
-        finally:
-            self.waiter = 0
+        ident = _get_ident()
+        outcome = self._outcome
+        if outcome is None:
+            self.waiter = ident
+            try:
+                outcome = self._wait(timeout)
+            finally:
+                self.waiter = 0
         t_set = self.t_set
-        if t_set:                       # a served answer, read for the first time
+        if t_set:               # a served answer, read for the first time
             self.t_set = 0
             sched = self.sched
             if ident not in sched._worker_idents:
@@ -218,10 +275,65 @@ class _CallerFuture(Future):
                 woke = sched._woke
                 ns, n, _ = woke.pop(ident, None) or (0, 0, 0)
                 woke[ident] = (ns + now - t_set, n + 1, now)
-        return res
+        res, err = outcome
+        if err is None:
+            return res
+        try:
+            raise err                   # the class, for a cancelled handle
+        finally:
+            self = outcome = err = None     # no cycle through the traceback
+
+    def exception(self, timeout=None):
+        err = (self._outcome or self._wait(timeout))[1]
+        if err is CancelledError:
+            raise CancelledError()
+        return err
+
+    def done(self) -> bool:
+        return self._outcome is not None
+
+    def cancelled(self) -> bool:
+        outcome = self._outcome
+        return outcome is not None and outcome[1] is CancelledError
+
+    def running(self) -> bool:
+        return False
+
+    def cancel(self) -> bool:
+        return self._resolve(None, CancelledError) or self.cancelled()
+
+    def add_done_callback(self, fn) -> None:
+        callbacks = self._callbacks
+        callbacks.append(fn)
+        if self._outcome is not None:
+            # resolved before, or meanwhile: whoever takes ``fn`` out of
+            # the list runs it — the resolver, or this thread, never both
+            try:
+                callbacks.remove(fn)
+            except ValueError:
+                return
+            self._call(fn)
+
+    @property
+    def _condition(self):
+        raise TypeError(
+            "a request's handle is not a concurrent.futures.Future: wait "
+            "on it with result() / exception() or add_done_callback()")
 
 
-_Item = Tuple[RetrievalRequest, Future, int]    # (request, future, t_submit ns)
+def _fail_future(fut: _CallerFuture, err: BaseException) -> None:
+    """Set an exception, tolerating a handle that already resolved (the
+    watchdog and the late dispatch race by design; a cancelled one stays
+    cancelled)."""
+    fut._resolve(None, err)
+
+
+def _set_future(fut: _CallerFuture, res) -> None:
+    # False: the watchdog already failed it — late result discarded
+    fut._resolve(res, None)
+
+
+_Item = Tuple[RetrievalRequest, _CallerFuture, int]     # (.., .., t_submit ns)
 
 # The hold's bound, as a share of what a dispatch takes (the scheduler's own
 # running estimate): nobody waits longer than this past the demux that
@@ -342,7 +454,14 @@ class QueryScheduler:
     (around the executor call), ``t_set`` (just before its answer is set
     in the demux loop), ``t_woke`` (in ``_CallerFuture.result``, when the
     thread that waited runs again) and ``t_back`` (that thread's next
-    ``submit_many``). The differences are summed over ALL served requests
+    ``submit_many``). What changes hands is the request's handle
+    (:class:`_CallerFuture`; ISSUE 42): one lock, taken in ``submit_many``
+    and released by the demux loop right after ``t_set`` is written — a
+    waiting caller is one blocking ``acquire`` in C, woken by that release,
+    with no ``Condition``, no ``RLock`` and no lock allocated per wait in
+    between; the first thread to claim a handle (the worker, a watchdog, a
+    ``cancel``) resolves it and the others change nothing. The stamps did
+    not move. The differences are summed over ALL served requests
     into unlabelled counters, in microseconds, bumped once a served batch
     beside ``serve.requests``: ``serve.queue_wait_us`` (flush − submit),
     ``serve.account_us`` (exec0 − flush), ``serve.exec_us`` (exec1 −
@@ -468,11 +587,11 @@ class QueryScheduler:
         return len(self._inflight_batches)
 
     # ------------------------------------------------------------- submit
-    def submit(self, request: RetrievalRequest) -> "Future[RetrievalResult]":
+    def submit(self, request: RetrievalRequest) -> _CallerFuture:
         return self.submit_many([request])[0]
 
     def submit_many(self, requests: Sequence[RetrievalRequest]
-                    ) -> List["Future[RetrievalResult]"]:
+                    ) -> List[_CallerFuture]:
         """Enqueue a group atomically (a ``search_memories_batch`` fleet
         stays contiguous, so it lands in as few flushes as possible).
         Under admission overload the whole group's futures fail
@@ -480,8 +599,7 @@ class QueryScheduler:
         so callers see the typed error at ``.result()`` like any other
         failure."""
         caller = threading.get_ident()
-        futures: List[Future] = [_CallerFuture(caller, self)
-                                 for _ in requests]
+        futures = [_CallerFuture(caller, self) for _ in requests]
         now = _clock_ns()
         if self.admission_check is not None and requests:
             try:
@@ -1096,17 +1214,17 @@ class ReplicaRouter:
                             labels={"group": str(g)})
         return g
 
-    def submit(self, request: RetrievalRequest) -> "Future[RetrievalResult]":
+    def submit(self, request: RetrievalRequest) -> _CallerFuture:
         return self.schedulers[self.route(request)].submit(request)
 
     def submit_many(self, requests: Sequence[RetrievalRequest]
-                    ) -> List["Future[RetrievalResult]"]:
+                    ) -> List[_CallerFuture]:
         """Route a group of requests; each sub-group stays contiguous on
         its scheduler (the atomic-group property per group)."""
         by_group: Dict[int, List[int]] = {}
         for i, req in enumerate(requests):
             by_group.setdefault(self.route(req), []).append(i)
-        futures: List[Optional[Future]] = [None] * len(requests)
+        futures: List[Optional[_CallerFuture]] = [None] * len(requests)
         for g, idxs in by_group.items():
             got = self.schedulers[g].submit_many(
                 [requests[i] for i in idxs])
