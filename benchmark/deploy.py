@@ -15,6 +15,7 @@ key nothing here touches a mesh.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 import os
@@ -32,8 +33,9 @@ from lazzaro_tpu.config import MemoryConfig
 from lazzaro_tpu.core import state as S
 from lazzaro_tpu.parallel.mesh import make_mesh
 from lazzaro_tpu.serve.scheduler import RetrievalRequest
-from lazzaro_tpu.utils.batching import bucket_size
+from lazzaro_tpu.utils.batching import bucket_size, next_pow2
 from lazzaro_tpu.utils.compile_cache import place_compile_cache
+from lazzaro_tpu.utils.telemetry import Telemetry
 
 from benchmark import corpus
 
@@ -261,6 +263,49 @@ def warm_serving(ms: MemorySystem, cfg: dict) -> None:
         tenant=next(iter(ms.index.tenant_nodes)), k=cfg["k"])).result()
 
 
+def _with_privates(index, *names: str):
+    """``index``, once it is seen to have the private attributes that set-up
+    is about to write: the program has no public warm-up of these (PERF.md,
+    Open questions), so a rename there has to fail here, by name, and not
+    turn the override into a new attribute nothing reads."""
+    missing = [n for n in names if not hasattr(index, n)]
+    if missing:
+        raise AttributeError(
+            f"{type(index).__name__} has no {', '.join(missing)}: the "
+            "benchmark's set-up writes it (deploy.presize_csr / "
+            "deploy.beside_a_reader) and has to follow the program's rename")
+    return index
+
+
+def presize_csr(ms: MemorySystem) -> None:
+    """The serving programs take the CSR's neighbour array padded to a power
+    of two that only ever grows (``MemoryIndex._csr_pad_hwm``), and recompile
+    each time it doubles. A deployment that has been written to for a while
+    sits at its high-water mark; set-up puts a fresh one there before it
+    warms the serving programs: the pad that its edge arena (``max_edges``
+    keys, listed in both directions) can ever need. Under a writer nothing
+    then compiles inside a window (PERF.md, Open questions)."""
+    idx = _with_privates(ms.index, "_csr_pad_hwm", "_csr_dirty")
+    idx._csr_pad_hwm = max(idx._csr_pad_hwm, next_pow2(2 * ms.config.max_edges))
+    idx._csr_dirty = True
+
+
+@contextlib.contextmanager
+def beside_a_reader(ms: MemorySystem):
+    """Inside, every donation gate of the index finds a second owner, as it
+    does while a reader's dispatch holds the arena: a writer takes its
+    COPYING twins. For warm-up alone, so that those programs are compiled
+    before a window in which readers run beside the writer. (The gate
+    counts references against ``MemoryIndex._SOLE_REFS``; the program has
+    no switch that forces it.)"""
+    idx = _with_privates(ms.index, "_SOLE_REFS")
+    idx._SOLE_REFS = -1
+    try:
+        yield
+    finally:
+        del idx._SOLE_REFS
+
+
 def run_conversation(ms: MemorySystem, tenant: int, conv: int = 0) -> None:
     """The conversation API, as a user's session drives it."""
     ms.switch_user(corpus.tenant_name(tenant))
@@ -304,6 +349,18 @@ def read_back(ms: MemorySystem, tenant: int, facts: Sequence[int],
                         or (-1, -1))
         by_vec.append((hits, list(res.scores)))
     return nodes, by_text, by_vec
+
+
+def telemetry_copy(ms: MemorySystem) -> Telemetry:
+    """The program's registry as it stands: timers and counters copied, so
+    that what runs later (the writer's read-back goes through the scheduler
+    too) stays out of the metrics of the window."""
+    src = ms.telemetry
+    out = Telemetry(window=src.window)
+    for key, values in list(src.timers.items()):
+        out.timers[key].extend(values)
+    out.counters.update(dict(src.counters))
+    return out
 
 
 def counters(ms: MemorySystem) -> dict:

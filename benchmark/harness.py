@@ -2,7 +2,7 @@
 
 Driven by data: a cell is an entry of ``BENCHMARK.json``'s ``workloads``
 naming a configuration (its ``file``) and a traffic mix
-(``benchmark/mixes/<traffic>.json``); a metric is an entry naming its reader
+(``benchmark/mixes/<traffic>.json``, whose ``loop`` picks one of ``LOOPS``); a metric is an entry naming its reader
 (``benchmark/metrics/<name>.py``, one function ``read(run)``). The
 configuration names its plain reference and its demand function by path and
 carries the program's ``MemoryConfig`` fields verbatim. Nothing below
@@ -11,6 +11,7 @@ branches on a cell's, a configuration's or a metric's name.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import gc
 import math
@@ -110,11 +111,16 @@ class Run:
         self.cell, self.cfg, self.mix, self.root = cell, cfg, mix, root
         self.seed, self.seconds, self.traced = seed, seconds, traced
         self.setup_s = 0.0
-        self.window_s = 0.0                 # real length of the window
+        # real length of the window; where a writer runs, the writer's: the
+        # window's start to the end of its last conversation
+        self.window_s = 0.0
         self.latency_ms = np.zeros(0)       # due -> done, finished requests
         self.late_ms = np.zeros(0)          # due -> sent (open loop)
+        self.due_s = np.zeros(0)            # window start -> due, the same
         self.completed_in_window = 0        # finished inside ``seconds``
         self.conversation_s = np.zeros(0)
+        # [n, 2] window start -> a conversation's start and end (mixed loop)
+        self.conversation_spans = np.zeros((0, 2))
         self.memories_acked = 0
         self.telemetry = None               # the system's Telemetry
         self.trace: Optional[dict] = None
@@ -374,22 +380,19 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     trace_dir = os.path.join(work, "trace")
     reference = load_module(cfg["reference"], root)
     limits = dict(cfg["limits"])
-    if "boost_share" in mix:
+    reads = mix.get("readers") or mix       # a mixed mix's readers: an open mix
+    if "boost_share" in reads:
         # the state its reads leave is compared under a mix that boosts, and
         # only there: the mix brings that number's limit
-        limits["state_errors"] = mix["limits"]["state_errors"]
+        limits["state_errors"] = reads["limits"]["state_errors"]
     cmp = reference.Comparison(limits)
     try:
-        loop = mix["loop"]
-        if loop in ("open", "closed"):
-            result = _serve_run(run, deploy, work, compiles, trace_dir,
-                                reference, cmp, t_start, control, sabotage)
-        elif loop == "conversations":
-            result = _ingest_run(run, deploy, work, compiles, trace_dir,
-                                 reference, cmp, t_start, control, sabotage)
-        else:
-            raise ValueError(f"mix {mix['name']!r} names no loop the "
-                             f"generator has: {loop!r}")
+        if mix["loop"] not in LOOPS:
+            raise ValueError(
+                f"mix {mix['name']!r} names no loop the generator has: "
+                f"{mix['loop']!r} is none of {', '.join(LOOPS)}")
+        result = LOOPS[mix["loop"]](run, deploy, work, compiles, trace_dir,
+                                    reference, cmp, t_start, control, sabotage)
     finally:
         shutil.rmtree(work, ignore_errors=True)
     say(f"compile cache: {cache_dir}; compilations this process: "
@@ -414,6 +417,20 @@ def _freeze() -> None:
     window."""
     gc.collect()
     gc.freeze()
+
+
+def _note_reads(run: Run, samples) -> np.ndarray:
+    """What the window's requests leave on ``run``; returns which finished."""
+    ok = samples.ok
+    run.attempted += len(ok)
+    run.failed += int((~ok).sum())
+    run.latency_ms = (samples.done - samples.due)[ok] * 1e3
+    run.late_ms = (samples.sent - samples.due)[ok] * 1e3
+    run.due_s = (samples.due - samples.t0)[ok]
+    run.window_s = samples.t1 - samples.t0
+    inside = ok & (samples.done <= samples.t0 + run.seconds)
+    run.completed_in_window = int(inside.sum())
+    return ok
 
 
 def _serve_run(run, deploy, work, compiles, trace_dir, reference, cmp,
@@ -467,14 +484,7 @@ def _serve_run(run, deploy, work, compiles, trace_dir, reference, cmp,
                  "boost": boost_constants(cfg, mix, deploy.SALIENCE)}
     gc.unfreeze()
 
-    ok = samples.ok
-    run.attempted = len(ok)
-    run.failed = int((~ok).sum())
-    run.latency_ms = (samples.done - samples.due)[ok] * 1e3
-    run.late_ms = (samples.sent - samples.due)[ok] * 1e3
-    run.window_s = samples.t1 - samples.t0
-    inside = ok & (samples.done <= samples.t0 + run.seconds)
-    run.completed_in_window = int(inside.sum())
+    ok = _note_reads(run, samples)
     if run.want_detail:
         gc.callbacks.remove(pauses.hook)
         run.detail = _serve_detail(run, samples, ok, gc_before)
@@ -562,39 +572,134 @@ def _serve_detail(run: Run, samples, ok, gc_before) -> dict:
     }
 
 
+class Writer:
+    """The one writer's side of a window, from a group of parameters (an
+    ``ingest-conv100`` mix, or a mixed mix's ``writer``): its providers, its
+    tenants in the seed's order, and after the window the read-back of
+    acknowledged memories and its comparison with the reference."""
+
+    def __init__(self, cfg: dict, params: dict, seed: int, other=None):
+        self.cfg, self.p, self.seed = cfg, params, seed
+        self.facts = params["facts_per_conversation"]
+        self.first = params["first_fact"]
+        # (first fact, facts, corpus size) of a tenant's conversation: the
+        # window's and warm-up's own, or what ``other(t)`` says of a tenant
+        # stocked through the API
+        own = (self.first, self.facts, cfg["facts_per_tenant"])
+        self.shape_of = lambda t: (other(t) if other else None) or own
+        self.emb = corpus.SeededEmbedder(seed, cfg["dim"], cfg["dup_every"],
+                                         lambda t: self.shape_of(t)[2])
+        self.llm = corpus.SeededLLM(self.shape_of)
+        t0, tn = params["window_tenants"]
+        self.order = np.random.default_rng([int(seed), 0x1A6E57]).permutation(
+            np.arange(t0, t0 + tn))
+
+    def warm(self, deploy, ms, last_inside=contextlib.nullcontext) -> None:
+        """The warm-up conversations; the last of them inside the context
+        ``last_inside()`` makes."""
+        w0, wn = self.p["warm_tenants"]
+        for t in range(w0, w0 + wn - 1):
+            deploy.run_conversation(ms, t)
+        with last_inside():
+            deploy.run_conversation(ms, w0 + wn - 1)
+
+    def note(self, run: Run, log) -> List[int]:
+        """What the writer's log leaves on ``run``; returns the tenants
+        whose conversation was acknowledged."""
+        for e in log.errors:
+            say(f"write failed: {e}")
+        if log.ran_out:
+            say(f"the writer filled the deployment's free rows "
+                f"({len(self.order)} conversations) in "
+                f"{log.t1 - log.t0:.2f}s of {run.seconds}s: it stopped there")
+        secs = np.asarray(log.seconds)
+        good = np.isfinite(secs)
+        run.attempted += len(secs)
+        run.failed += int((~good).sum())
+        run.conversation_s = secs[good]
+        run.memories_acked = int(good.sum()) * self.facts
+        # what the writer's rate divides by, whatever the loop: ALL its time,
+        # from the window's start to the end of its last conversation
+        run.window_s = log.t1 - log.t0
+        return [t for t, g in zip(log.tenants, good) if g]
+
+    def read_back(self, deploy, ms, done: List[int]) -> list:
+        """Acknowledged memories, through the program's own API."""
+        rng = np.random.default_rng([int(self.seed), 0xC0FFEE])
+        chosen = list(dict.fromkeys(
+            done[-1:] + [int(t) for t in rng.permutation(done)]
+        ))[:self.p["check_tenants"]]
+        js_all = np.arange(self.first, self.first + self.facts)
+        dups = [int(j) for j in
+                js_all[corpus.is_dup(js_all, self.cfg["dup_every"])]]
+        out = []
+        for t in chosen:
+            probes = list(dict.fromkeys(
+                dups + [j - 1 for j in dups if j - 1 >= self.first]
+                + [int(j) for j in rng.permutation(js_all)]
+            ))[:self.p["check_facts"]]
+            out.append((t, probes) + deploy.read_back(
+                ms, t, probes, self.emb.corpus(t)[probes], int(self.p["k"])))
+        return out
+
+    def compare(self, reference, cmp, readback: list,
+                control: Optional[str]) -> None:
+        cfg, emb, k = self.cfg, self.emb, int(self.p["k"])
+        first, facts = self.first, self.facts
+        js_all = np.arange(first, first + facts)
+        live = np.zeros(cfg["facts_per_tenant"], bool)
+        live[first:first + facts] = ~corpus.is_dup(js_all, cfg["dup_every"])
+        for t, probes, nodes, by_text, by_vec in readback:
+            rows = reference.stored(emb.corpus(t), cfg["dtype"])
+            q = emb.corpus(t)[probes]
+            # as in check_serving: the single-request programs keep the f32
+            # query, the batched ones round it to the arena's dtype
+            variants = reference.query_variants(rows, live, q, k, cfg["dtype"])
+            if nodes != int(live.sum()):
+                cmp.count_errors += 1
+                cmp._fault(f"tenant {t} holds {nodes} nodes after {facts} "
+                           f"facts, the reference {int(live.sum())}")
+            ctl = (reference.int8_answers(rows, live, q, k)
+                   if control is not None else None)
+            for n, j in enumerate(probes):
+                label = f"tenant {t} fact {j}"
+                for hits, scores in ((by_text[n], None), by_vec[n]):
+                    if ctl is not None:
+                        if scores is None:
+                            continue
+                        idx, sc = ctl[n]
+                    else:
+                        idx, sc = [], ([] if scores is not None else None)
+                        for r, (who, fact) in enumerate(hits):
+                            if who != t:
+                                cmp.foreign(label, f"fact {who}.{fact}")
+                                continue
+                            idx.append(fact)
+                            if scores is not None:
+                                sc.append(scores[r])
+                    cmp.answer(label, idx, sc, [tuple(v[n] for v in var)
+                                                for var in variants], live)
+
+
 def _ingest_run(run, deploy, work, compiles, trace_dir, reference, cmp,
                 t_start, control, sabotage) -> dict:
     cfg, mix, seed = run.cfg, run.mix, run.seed
-    facts, first = mix["facts_per_conversation"], mix["first_fact"]
-    n_tenant = cfg["facts_per_tenant"]
-    dup_every = cfg["dup_every"]
     pre = mix["prefill"]
     stock = range(pre["tenant_first"], pre["tenant_first"] + pre["tenants"])
-
-    def shape_of(t):        # (first fact, facts, corpus size) of t's talk
-        return ((0, pre["facts"], pre["facts"]) if t in stock
-                else (first, facts, n_tenant))
-
-    emb = corpus.SeededEmbedder(seed, cfg["dim"], dup_every,
-                                lambda t: shape_of(t)[2])
-    llm = corpus.SeededLLM(shape_of)
+    writer = Writer(cfg, mix, seed, lambda t: (
+        (0, pre["facts"], pre["facts"]) if t in stock else None))
     phases = Phases(t_start)
-    ms = deploy.build_system(cfg, work, emb, llm)
+    ms = deploy.build_system(cfg, work, writer.emb, writer.llm)
     phases.done("import+system")
     # the deployment's stock: large conversations through the API itself,
     # so that index, buffer and store all hold it when the window opens
     for t in stock:
         deploy.run_conversation(ms, t)
     phases.done(f"stock of {pre['tenants']} x {pre['facts']} facts")
-    w0, wn = mix["warm_tenants"]
-    for t in range(w0, w0 + wn):
-        deploy.run_conversation(ms, t)
+    writer.warm(deploy, ms)
     phases.done("warm-up conversations")
     deploy.warm_serving(ms, cfg)
     phases.done("warm-up serving")
-    t0_, tn = mix["window_tenants"]
-    order = np.random.default_rng([int(seed), 0x1A6E57]).permutation(
-        np.arange(t0_, t0_ + tn))
     if sabotage is not None:
         sabotage(ms)
     capacity = ms.index.capacity
@@ -607,45 +712,15 @@ def _ingest_run(run, deploy, work, compiles, trace_dir, reference, cmp,
     run.setup_s = time.perf_counter() - t_start
     _start_trace(run, trace_dir)
     log = loadgen.run_conversations(
-        lambda t: deploy.run_conversation(ms, t), order, run.seconds,
+        lambda t: deploy.run_conversation(ms, t), writer.order, run.seconds,
         TraceAnnotation)
     _stop_trace(run, trace_dir)
     run.compiles_in_window = compiles.count - mark
     device = deploy.device_info(run.cell["chips"])
     gc.unfreeze()
-    if ms.index.capacity != capacity:
-        raise RuntimeError("the arena grew inside the window: the cell is "
-                           "sized so that it never has to")
-    for e in log.errors:
-        say(f"write failed: {e}")
-    if log.ran_out:
-        say(f"the writer filled the deployment's free rows ({tn} "
-            f"conversations) in {log.t1 - log.t0:.2f}s of {run.seconds}s: "
-            "the window closed there")
-    secs = np.asarray(log.seconds)
-    good = np.isfinite(secs)
-    run.attempted = len(secs)
-    run.failed = int((~good).sum())
-    run.conversation_s = secs[good]
-    run.memories_acked = int(good.sum()) * facts
-    run.window_s = log.t1 - log.t0
-
-    # read-back of acknowledged memories, through the program's own API
-    done = [t for t, g in zip(log.tenants, good) if g]
-    rng = np.random.default_rng([int(seed), 0xC0FFEE])
-    chosen = list(dict.fromkeys(
-        done[-1:] + [int(t) for t in rng.permutation(done)]
-    ))[:mix["check_tenants"]]
-    js_all = np.arange(first, first + facts)
-    dups = [int(j) for j in js_all[corpus.is_dup(js_all, dup_every)]]
-    k = int(mix["k"])
-    readback = []
-    for t in chosen:
-        probes = list(dict.fromkeys(
-            dups + [j - 1 for j in dups if j - 1 >= first]
-            + [int(j) for j in rng.permutation(js_all)]))[:mix["check_facts"]]
-        readback.append((t, probes) + deploy.read_back(
-            ms, t, probes, emb.corpus(t)[probes], k))
+    _same_capacity(ms, capacity)
+    done = writer.note(run, log)
+    readback = writer.read_back(deploy, ms, done)
     swallowed = deploy.counters(ms)
     ms.close()
     del ms
@@ -653,45 +728,167 @@ def _ingest_run(run, deploy, work, compiles, trace_dir, reference, cmp,
 
     cmp.swallowed = sum(swallowed.values())
     cmp.unanswered = run.failed
-    live = np.zeros(n_tenant, bool)
-    live[first:first + facts] = ~corpus.is_dup(js_all, dup_every)
-    for t, probes, nodes, by_text, by_vec in readback:
-        rows = reference.stored(emb.corpus(t), cfg["dtype"])
-        q = emb.corpus(t)[probes]
-        # as in check_serving: the single-request programs keep the f32
-        # query, the batched ones round it to the arena's dtype
-        variants = reference.query_variants(rows, live, q, k, cfg["dtype"])
-        if nodes != int(live.sum()):
-            cmp.count_errors += 1
-            cmp._fault(f"tenant {t} holds {nodes} nodes after {facts} "
-                       f"facts, the reference {int(live.sum())}")
-        ctl = (reference.int8_answers(rows, live, q, k)
-               if control is not None else None)
-        for n, j in enumerate(probes):
-            label = f"tenant {t} fact {j}"
-            for hits, scores in ((by_text[n], None), by_vec[n]):
-                if ctl is not None:
-                    if scores is None:
-                        continue
-                    idx, sc = ctl[n]
-                else:
-                    idx, sc = [], ([] if scores is not None else None)
-                    for r, (who, fact) in enumerate(hits):
-                        if who != t:
-                            cmp.foreign(label, f"fact {who}.{fact}")
-                            continue
-                        idx.append(fact)
-                        if scores is not None:
-                            sc.append(scores[r])
-                cmp.answer(label, idx, sc, [tuple(v[n] for v in var)
-                                            for var in variants], live)
+    writer.compare(reference, cmp, readback, control)
+    _say_writes(run, writer, done, readback, cmp, swallowed)
+    return finish(run, cmp, device)
+
+
+def _same_capacity(ms, capacity: int) -> None:
+    if ms.index.capacity != capacity:
+        raise RuntimeError("the arena grew inside the window: the cell is "
+                           "sized so that it never has to")
+
+
+def _say_writes(run, writer, done, readback, cmp, swallowed) -> None:
     drift = [round(1e3 * float(np.median(part)), 1)
              for part in np.array_split(run.conversation_s, 4) if len(part)]
-    say(f"{len(done)} conversations x {facts} facts in {run.window_s:.2f}s "
-        f"(median ms by quarter of the window: {drift}); "
+    say(f"{len(done)} conversations x {writer.facts} facts in "
+        f"{run.window_s:.2f}s (median ms by quarter of the window: {drift}); "
         f"{len(readback)} tenants read back, {cmp.answers} answers compared; "
         f"swallowed {swallowed}")
+
+
+def mixed_groups(cfg: dict, mix: dict) -> Tuple[dict, Optional[dict],
+                                                 Optional[dict]]:
+    """(stock, readers, writer) of a mixed mix: ``stock`` ``{"rows",
+    "tenants"}`` installed from the device, ``readers`` an open mix's
+    parameters over the stock's tenants, ``writer`` an ingest mix's over
+    tenants that are not of the stock. Either of the two may be absent (or
+    None), not both."""
+    stock, readers, writer = mix["stock"], mix.get("readers"), mix.get("writer")
+    if readers is None and writer is None:
+        raise ValueError(f"mixed mix {mix['name']!r} has neither 'readers' "
+                         "nor 'writer': a window needs one of the two")
+    block = cfg["fill_block_rows"]
+    if stock["rows"] % block or not 0 < stock["rows"] <= cfg["rows"]:
+        raise ValueError(
+            f"mixed mix {mix['name']!r}: stock.rows {stock['rows']} is no "
+            f"multiple of the configuration's fill_block_rows {block} inside "
+            f"its {cfg['rows']} rows")
+    if writer is not None:
+        (w0, wn), (a0, an) = writer["window_tenants"], writer["warm_tenants"]
+        mine = set(range(w0, w0 + wn)) | set(range(a0, a0 + an))
+        if min(mine) < stock["tenants"] or len(mine) != wn + an:
+            raise ValueError(
+                f"mixed mix {mix['name']!r}: the writer's window_tenants and "
+                f"warm_tenants have to be distinct tenants outside the "
+                f"stock's {stock['tenants']}")
+    return stock, readers, writer
+
+
+def _mixed_run(run, deploy, work, compiles, trace_dir, reference, cmp,
+               t_start, control, sabotage) -> dict:
+    """Readers beside a writer over an installed stock: ``_serve_run``'s
+    set-up, plan and comparison over the stock's tenants, ``_ingest_run``'s
+    writer and read-back over tenants of its own, in one window."""
+    cfg, mix, seed = run.cfg, run.mix, run.seed
+    stock, readers, wparams = mixed_groups(cfg, mix)
+    # with a writer the system has the seeded providers, as ``_ingest_run``
+    # builds it; without one nothing ever calls a provider
+    writer = Writer(cfg, wparams, seed) if wparams is not None else None
+    phases = Phases(t_start)
+    ms = deploy.build_system(cfg, work, *((writer.emb, writer.llm)
+                                          if writer else ()))
+    phases.done("import+system")
+    starts = deploy.install_rows(ms, cfg, seed, stock["rows"], 0,
+                                 stock["tenants"])
+    phases.done(f"stock of {stock['rows']} rows")
+    if "graph" in cfg:
+        edges = deploy.install_graph(
+            ms, cfg, starts, 0,
+            lambda t: tenant_rows(cfg, seed, starts, 0, t))
+        phases.done(f"graph of {edges} edges")
+    if writer is not None:
+        # the last warm conversation runs as the window's will, beside a
+        # reader: the writer's copying twins are compiled here too, and the
+        # serving programs below over a CSR pad that edges cannot outgrow
+        writer.warm(deploy, ms, lambda: deploy.beside_a_reader(ms))
+        deploy.presize_csr(ms)
+        phases.done("warm-up conversations")
+    deploy.warm_serving(ms, cfg)
+    phases.done("warm-up serving")
+    plan = None
+    if readers is not None:
+        plan = ServePlan(dict(cfg, tenants=stock["tenants"]),
+                         dict(readers, loop="open"), seed, run.seconds, starts,
+                         0, deploy.make_requests)
+        phases.done("requests")
+    if sabotage is not None:
+        sabotage(ms)
+    capacity = ms.index.capacity
+    _freeze()
+    phases.done("collect+freeze")
+    phases.report()
+    ms.telemetry.reset()
+    mark = compiles.count
+    pauses = _watch_gc() if run.want_detail else None
+    gc_before = [g["collections"] for g in gc.get_stats()]
+    run.setup_s = time.perf_counter() - t_start
+    opened = deploy.index_clock(ms)
+    _start_trace(run, trace_dir)
+    samples, log = loadgen.run_mixed(
+        None if plan is None else (ms.query_scheduler.submit, plan.requests,
+                                   plan.due, plan.keep, readers["drain_s"]),
+        None if writer is None else (
+            lambda t: deploy.run_conversation(ms, t), writer.order),
+        run.seconds, TraceAnnotation)
+    _stop_trace(run, trace_dir)
+    run.compiles_in_window = compiles.count - mark
+    run.telemetry = deploy.telemetry_copy(ms)     # before the read-back's reads
+    device = deploy.device_info(run.cell["chips"])
+    state = None
+    if plan is not None and plan.boost is not None:
+        state = {"columns": deploy.read_state(ms),
+                 "window": (opened, deploy.index_clock(ms)),
+                 "boost": boost_constants(cfg, readers, deploy.SALIENCE)}
+    gc.unfreeze()
+    _same_capacity(ms, capacity)
+
+    if samples is not None:
+        ok = _note_reads(run, samples)
+        if run.want_detail:
+            run.detail = _serve_detail(run, samples, ok, gc_before)
+    done, readback = [], []
+    if log is not None:
+        done = writer.note(run, log)
+        at = np.asarray(log.starts) - log.t0
+        run.conversation_spans = np.stack(
+            [at, at + np.nan_to_num(np.asarray(log.seconds))], axis=1)
+        readback = writer.read_back(deploy, ms, done)
+    if run.want_detail:
+        gc.callbacks.remove(pauses.hook)
+        run.detail = dict(run.detail or {}, gc_pauses_ms=pauses.longest(),
+                          **_mixed_detail(run))
+    swallowed = deploy.counters(ms)
+    arena_rows = int(ms.index.state.salience.shape[0])
+    ms.close()
+    del ms
+    if plan is not None:
+        del plan.requests
+    gc.collect()
+
+    cmp.swallowed = sum(swallowed.values())
+    cmp.unanswered = run.failed
+    if samples is not None:
+        check_serving(reference, cmp, cfg, plan, samples, seed, starts, 0,
+                      control, state)
+        say(f"arena {arena_rows} rows x {cfg['dim']} {cfg['dtype']}, "
+            f"{stock['rows']} of them the stock's; {len(samples.ok)} requests, "
+            f"{int((~samples.ok).sum())} failed")
+    if log is not None:
+        writer.compare(reference, cmp, readback, control)
+        _say_writes(run, writer, done, readback, cmp, swallowed)
     return finish(run, cmp, device)
+
+
+def _mixed_detail(run: Run) -> dict:
+    """Study aid: where the writer's conversations lay in the window."""
+    return {"conversation_spans_s": run.conversation_spans.round(5).tolist()}
+
+
+LOOPS: Dict[str, Callable] = {
+    "open": _serve_run, "closed": _serve_run,
+    "conversations": _ingest_run, "mixed": _mixed_run}
 
 
 def finish(run: Run, cmp, device: dict) -> dict:

@@ -1,6 +1,6 @@
 """The one general load generator. A traffic mix is a data file; its ``loop``
-field picks one of three loops (open, closed, conversations) and its other
-fields are their parameters. Nothing here knows a cell's name.
+field picks one of four loops (open, closed, conversations, mixed) and its
+other fields are their parameters. Nothing here knows a cell's name.
 
 Every seed draws from the same population: the multiset of tenants (Zipf by
 largest remainder, tenant 0 hottest), the multiset of inter-arrival gaps
@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Callable, List, Sequence
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -71,15 +71,13 @@ class Samples:
         self.t0 = self.t1 = 0.0          # window start / end
 
 
-def run_open(submit: Callable, requests: Sequence, due_rel: np.ndarray,
-             keep: np.ndarray, drain_s: float, annotate: Callable,
-             clock: Callable = time.perf_counter,
-             sleep: Callable = time.sleep) -> Samples:
-    """Open loop: request i is sent when ``due_rel[i]`` has passed, late or
-    not, and its latency counts from the time it was DUE. (``clock`` and
-    ``sleep`` are the host's; the tests put a clock of their own there.)"""
+def _send(out: Samples, submit: Callable, requests: Sequence,
+          due: np.ndarray, keep: np.ndarray, clock: Callable,
+          sleep: Callable) -> threading.Event:
+    """The open loop's sender: request i goes out when ``due[i]`` has passed,
+    late or not; ``out`` takes its stamps. Returns the event that is set
+    when the last answer is back."""
     n = len(requests)
-    out = Samples(n)
     done, ok, answers = out.done, out.ok, out.answers
     returned = [0]                  # written by the one thread that answers
     all_back = threading.Event()
@@ -96,20 +94,31 @@ def run_open(submit: Callable, requests: Sequence, due_rel: np.ndarray,
             all_back.set()
 
     sent = out.sent
+    for i in range(n):
+        wait = due[i] - clock()
+        if wait > 0:
+            sleep(wait)
+        sent[i] = clock()
+        fut = submit(requests[i])
+        fut.bench_i = i
+        fut.add_done_callback(stamp)
+    out.due = due
+    return all_back
+
+
+def run_open(submit: Callable, requests: Sequence, due_rel: np.ndarray,
+             keep: np.ndarray, drain_s: float, annotate: Callable,
+             clock: Callable = time.perf_counter,
+             sleep: Callable = time.sleep) -> Samples:
+    """Open loop: request i is sent when ``due_rel[i]`` has passed, late or
+    not, and its latency counts from the time it was DUE. (``clock`` and
+    ``sleep`` are the host's; the tests put a clock of their own there.)"""
+    out = Samples(len(requests))
     with annotate("bench.window"):
-        t0 = clock()
-        due = due_rel + t0
-        for i in range(n):
-            wait = due[i] - clock()
-            if wait > 0:
-                sleep(wait)
-            sent[i] = clock()
-            fut = submit(requests[i])
-            fut.bench_i = i
-            fut.add_done_callback(stamp)
-        all_back.wait(timeout=drain_s)
-        t1 = clock()
-    out.due, out.t0, out.t1 = due, t0, t1
+        out.t0 = clock()
+        _send(out, submit, requests, due_rel + out.t0, keep, clock,
+              sleep).wait(timeout=drain_s)
+        out.t1 = clock()
     return out
 
 
@@ -171,10 +180,37 @@ def run_closed(submit: Callable, requests: Sequence, clients: int,
 class ConversationLog:
     def __init__(self):
         self.tenants: List[int] = []
+        self.starts: List[float] = []       # on the window's clock
         self.seconds: List[float] = []
         self.errors: List[str] = []
         self.t0 = self.t1 = 0.0
         self.ran_out = False        # the tenants ended before the seconds
+
+
+def _converse(log: ConversationLog, converse: Callable,
+              tenants: Sequence[int], t_end: float, annotate: Callable,
+              clock: Callable) -> None:
+    """The one writer: a conversation per tenant, in order and back to back,
+    none started once ``t_end`` has passed; ``log`` takes each one's start
+    and length."""
+    for t in tenants:
+        a = clock()
+        if a >= t_end:
+            break
+        log.starts.append(a)
+        with annotate("bench.conversation"):
+            try:
+                converse(int(t))
+            except Exception as e:      # noqa: BLE001 — a failed write
+                log.errors.append(f"tenant {t}: {e!r}")
+                log.tenants.append(int(t))
+                log.seconds.append(float("nan"))
+                continue
+        log.tenants.append(int(t))
+        log.seconds.append(clock() - a)
+    else:
+        log.ran_out = True
+    log.t1 = clock()
 
 
 def run_conversations(converse: Callable, tenants: Sequence[int],
@@ -188,22 +224,41 @@ def run_conversations(converse: Callable, tenants: Sequence[int],
     log = ConversationLog()
     with annotate("bench.window"):
         log.t0 = clock()
-        t_end = log.t0 + seconds
-        for t in tenants:
-            a = clock()
-            if a >= t_end:
-                break
-            with annotate("bench.conversation"):
-                try:
-                    converse(int(t))
-                except Exception as e:      # noqa: BLE001 — a failed write
-                    log.errors.append(f"tenant {t}: {e!r}")
-                    log.tenants.append(int(t))
-                    log.seconds.append(float("nan"))
-                    continue
-            log.tenants.append(int(t))
-            log.seconds.append(clock() - a)
-        else:
-            log.ran_out = True
-        log.t1 = clock()
+        _converse(log, converse, tenants, log.t0 + seconds, annotate, clock)
     return log
+
+
+def run_mixed(reads: Optional[tuple], writes: Optional[tuple], seconds: float,
+              annotate: Callable, clock: Callable = time.perf_counter,
+              sleep: Callable = time.sleep
+              ) -> Tuple[Optional[Samples], Optional[ConversationLog]]:
+    """Readers beside a writer, in ONE window from one ``t0``: ``reads`` is
+    the open loop's ``(submit, requests, due_rel, keep, drain_s)``, sent from
+    this thread as ``run_open`` sends them; ``writes`` is the writer's
+    ``(converse, tenants)``, one thread of its own that walks its tenants as
+    ``run_conversations`` does and starts none once ``seconds`` have passed
+    (or the tenants have ended: ``ran_out`` closes the writer, never the
+    readers). Either may be None. The window closes when the last answer is
+    back and the last conversation started has ended; the writer's own time
+    runs from ``t0`` to the end of its last conversation (``log.t1``)."""
+    samples = log = thread = None
+    with annotate("bench.window"):
+        t0 = clock()
+        if writes is not None:
+            converse, tenants = writes
+            log = ConversationLog()
+            log.t0 = t0
+            thread = threading.Thread(
+                target=_converse, name="bench-writer", daemon=True,
+                args=(log, converse, tenants, t0 + seconds, annotate, clock))
+            thread.start()
+        if reads is not None:
+            submit, requests, due_rel, keep, drain_s = reads
+            samples = Samples(len(requests))
+            samples.t0 = t0
+            _send(samples, submit, requests, due_rel + t0, keep, clock,
+                  sleep).wait(timeout=drain_s)
+            samples.t1 = clock()
+        if thread is not None:
+            thread.join()
+    return samples, log

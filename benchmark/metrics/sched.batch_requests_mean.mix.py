@@ -1,0 +1,3 @@
+from benchmark.families import reader_for
+
+read = reader_for(__file__)

@@ -57,3 +57,24 @@ def edges_dropped(ms):
     """The graph forgotten: boosts reach the served rows and no neighbour."""
     ms.index.edge_slots.clear()
     ms.index._csr_dirty = True
+
+
+def fact_dropped(ms):
+    """The write path loses a fact: every conversation's first extracted
+    fact is dropped before the ingest, after it was acknowledged."""
+    real = ms._ingest_facts_dedup_fused
+    ms._ingest_facts_dedup_fused = lambda staged: real(staged[1:])
+
+
+def window_row_served(ms):
+    """A row written beside the readers is served to them: the first hit of
+    every answer gives way to the newest row the index holds — a warm-up or
+    window tenant's, never one of the installed stock's."""
+    def newest(real, reqs, kw):
+        out = real(reqs, **kw)
+        nid = next(reversed(ms.index.row_to_id.values()))
+        for r in out:
+            if r.ids:
+                r.ids = [nid] + list(r.ids[1:])
+        return out
+    _wrap(ms, newest)
