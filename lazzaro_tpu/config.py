@@ -125,7 +125,9 @@ class MemoryConfig:
     # scatters — so write throughput scales with the mesh like read
     # throughput has since PR 5. Off = let GSPMD partition the plain jit
     # kernel (correct, but re-replicates candidate tensors chip-to-chip
-    # every batch; debug/fallback). No effect without a mesh.
+    # every batch — and, since the scan selects block by block (ISSUE 45),
+    # the arena's blocks too: its Pallas kernel has no GSPMD partitioning
+    # rule; debug/fallback, small arenas only). No effect without a mesh.
     ingest_sharded: bool = True
 
     # --- serving path (lazzaro_tpu/serve) ----------------------------------

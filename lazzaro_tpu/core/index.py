@@ -1727,6 +1727,7 @@ class MemoryIndex:
             host = host[:-S.PAGE_INGEST_TAIL]
         ctr = host[3 * n_modes:]
         self.telemetry.bump("ingest.dispatches", labels={"kind": kind})
+        self._note_ingest_select()
         self.telemetry.bump("ingest.links_accepted", int(ctr[1][0, 0]))
         self.telemetry.bump("ingest.pool_slots_used", int(ctr[2][0, 0]))
         if ivf_fresh:
@@ -1749,6 +1750,8 @@ class MemoryIndex:
                 for j in range(k_eff):
                     p = int(ps[bi, j])
                     s = float(sc[bi, j])
+                    # a slot no candidate filled holds (NEG_INF, capacity):
+                    # the score says so before the row is looked up
                     cid = (self.row_to_id.get(int(cd[bi, j]))
                            if s > S.NEG_INF / 2 else None)
                     if cid is not None:
@@ -2038,6 +2041,7 @@ class MemoryIndex:
         ctr = host[3 + 3 * n_modes:]
         self.telemetry.bump("ingest.dispatches",
                             labels={"kind": kind})
+        self._note_ingest_select()
         self.telemetry.bump("ingest.dedup_hits",
                             int((host[0][:n, 0] > 0).sum()))
         self.telemetry.bump("ingest.links_accepted", int(ctr[1][0, 0]))
@@ -2116,6 +2120,8 @@ class MemoryIndex:
                 for j in range(k_eff):
                     p = int(ps[bi, j])
                     s = float(sc[bi, j])
+                    # a slot no candidate filled holds (NEG_INF, capacity):
+                    # the score says so before the row is looked up
                     cid = (self.row_to_id.get(int(cd[bi, j]))
                            if s > S.NEG_INF / 2 else None)
                     if cid is not None and not dup[bi]:
@@ -3475,6 +3481,20 @@ class MemoryIndex:
         self.telemetry.bump("serve.select", labels={
             "core": ("blocked" if blocked else "whole_pool") + tag})
 
+    def _note_ingest_select(self) -> None:
+        """``ingest.select{core}``: ``serve.select``'s write-path twin
+        (ISSUE 45), bumped once a fused ingest dispatch — which form of the
+        select-while-scanning link scan it runs: ``blocked`` when a block
+        tiles the pool (each chip's slice of it under the sharded program),
+        ``whole_pool`` when the pool is ONE block of the same code."""
+        from lazzaro_tpu.ops.pallas_topk import block_tiles
+        emb = self.state.emb
+        parts = self._n_parts if self.ingest_sharded else 1
+        blocked = block_tiles(emb.shape[0] // parts, emb.shape[1],
+                              emb.dtype.itemsize)
+        self.telemetry.bump("ingest.select", labels={
+            "core": "blocked" if blocked else "whole_pool"})
+
     def _note_serve_kernel(self, mode: str, statics: dict) -> None:
         """Track the distinct fused serving-kernel keys this index has
         dispatched — exactly ONE per mode while the k/cap/nprobe ceilings
@@ -4133,10 +4153,10 @@ class MemoryIndex:
 
         The consolidation pipeline needs both the same-shard (mode 1) and
         the any-shard (mode 0) candidate sets per conversation. Both modes
-        are masks over the SAME query×arena score matrix, so ONE fused
-        kernel streams the arena from HBM once and re-masks per mode
-        (``arena_link_candidates_multi``) — at 1M rows the matmul is the
-        whole cost, so two modes for the price of one — and all four
+        are masks over the SAME block of query×arena scores, so ONE fused
+        kernel streams the arena from HBM once and selects per mode while
+        it does (``arena_link_candidates_multi``) — at 1M rows the stream
+        is the whole cost, so two modes for the price of one — and all four
         output arrays come back in one packed readback: one host round
         trip per conversation total."""
         rows = [self.id_to_row[i] for i in new_ids if i in self.id_to_row]
@@ -4169,8 +4189,8 @@ class MemoryIndex:
                         shard_mode: int = 0) -> Dict[str, List[Tuple[str, float]]]:
         """Per new node: top-k (existing_id, cosine) candidates — the
         single-mode view of ``link_candidates_multi`` (same ONE dispatch +
-        ONE readback; the kernel streams [512, capacity] f32 tiles via
-        lax.map, the HBM high-water mark at 1M rows)."""
+        ONE readback; the kernel holds one block's scores, no
+        [rows, capacity] tile)."""
         return self.link_candidates_multi(new_ids, tenant, k,
                                           (shard_mode,))[shard_mode]
 

@@ -893,41 +893,20 @@ def _arena_link_candidates_multi(
     # 0: any shard, 1: same shard only, -1: other shards only
 ) -> Tuple[jax.Array, ...]:
     """For each new node, top-k most similar existing nodes (excluding self
-    and other new rows), for SEVERAL shard modes in one pass. One batched
-    matmul replaces reference hot loops #2/#3 (``memory_system.py:797-836``
-    within-shard, ``:838-891`` cross-shard) — and because every mode is just
-    a different mask over the SAME score matrix, the arena is streamed from
-    HBM once and the [C, cap+1] scores are re-masked per mode: two modes
-    cost one matmul, not two (the matmul dominates the top-k).
-
-    Batches past QUERY_CHUNK stream through ``lax.map`` in [512, cap+1] f32
-    tiles INSIDE this one dispatch — the tile bounds HBM at 1M rows, and a
-    whole-conversation link batch costs ONE host round trip (a host-side
-    chunk loop would pay one per 512 rows). Returns ``(scores, rows)`` pairs
-    flattened in ``shard_modes`` order."""
-    lmask = state.alive & (state.tenant_id == tenant) & ~state.is_super
-    # exclude the new rows themselves from candidates
+    and other new rows), for SEVERAL shard modes in one pass. One scan
+    replaces reference hot loops #2/#3 (``memory_system.py:797-836``
+    within-shard, ``:838-891`` cross-shard): the fused ingest's
+    select-while-scanning core without its probe tier
+    (``_ingest_scan_core``) — every mode is a mask over the same block's
+    scores, so the arena streams from HBM once whatever the modes, and a
+    whole-conversation link batch costs ONE host round trip. Returns
+    ``(scores, rows)`` pairs flattened in ``shard_modes`` order; a slot no
+    candidate fills holds ``(NEG_INF, capacity)``."""
     excl = jnp.zeros((_nrows(state),), bool).at[excl_rows].set(True)
-    mask = _pool_mask(state, lmask & ~excl)       # pool-space scan mask
-    shard_pool = _pool_col(state, state.shard_id)
-
-    def chunk(rows_c):
-        q = state.emb[_phys(state, rows_c)]       # [C, d]
-        scores = nt_dot(q, state.emb)             # [C, pool]
-        same = None
-        outs = []
-        for sm in shard_modes:
-            full_mask = mask[None, :]
-            if sm != 0:
-                if same is None:
-                    same = (state.shard_id[rows_c][:, None]
-                            == shard_pool[None, :])
-                full_mask = full_mask & (same if sm == 1 else ~same)
-            s, r = jax.lax.top_k(jnp.where(full_mask, scores, NEG_INF), k)
-            outs.extend((s, _pool_to_logical(state, r)))
-        return tuple(outs)
-
-    return chunked_map(chunk, new_rows)
+    return _ingest_scan_core(
+        state, state.emb[_phys(state, new_rows)], state.shard_id[new_rows],
+        jnp.zeros_like(excl), excl, tenant, k, tuple(shard_modes),
+        with_probe=False)
 
 
 arena_link_candidates_multi = jax.jit(
@@ -1632,15 +1611,17 @@ def _ingest_scan_core(state: ArenaState, qd: jax.Array, q_shard: jax.Array,
                       probe_excl: jax.Array, link_excl: jax.Array,
                       tenant: jax.Array, k: int,
                       shard_modes: Tuple[int, ...],
-                      chunk: int = QUERY_CHUNK,
                       with_probe: bool = True):
     """The whole-arena ingest scan: dedup-probe top-1 plus the per-mode
-    link top-k over ONE score matrix — the probe and every link mode are
-    just different masks, so the arena streams from HBM once per ingest
-    batch (the pre-refactor kernel paid two full matmuls: probe, then the
-    post-add link scan; the exclusion mask makes the pre-add scan
-    equivalent — the batch's own rows are excluded as candidates either
-    way, and no other row's embedding changes between the two points).
+    link top-k, SELECTED WHILE THE POOL STREAMS from HBM once (ISSUE 45;
+    ``ops/pallas_topk.blocked_link_scan``, the write path's twin of
+    ``_exact_two_tier``'s core): the probe and every link mode are masks
+    over one block's scores, and the probe's running top-1 and each mode's
+    running top-k stay on chip across the blocks, so no ``[facts, rows]``
+    score tile exists. The pre-add scan is equivalent to the probe followed
+    by a post-add link scan — the batch's own rows are excluded as
+    candidates either way, and no other row's embedding changes between the
+    two points.
 
     ``qd`` is each fact's normalized arena-dtype embedding (exactly the
     bytes the node scatter stores, so scores match a post-add gather of
@@ -1651,38 +1632,24 @@ def _ingest_scan_core(state: ArenaState, qd: jax.Array, q_shard: jax.Array,
     silently eat a fact). ``link_excl`` additionally masks the batch's
     own rows out of the link candidates. Shard-local by construction:
     single-chip callers pass the whole arena, the sharded program passes
-    each chip's local slice with localized exclusion masks — and, because
-    a chip's slice is n× narrower, an n×-wider ``chunk`` at the SAME
-    [chunk × rows] f32 tile budget (fewer, denser gemms; chunking never
-    changes any per-row output, so parity is unaffected). Returns the
-    flat tuple ``(p_s [B,1], p_r [B,1], s_mode, r_mode, ...)``;
-    ``with_probe=False`` (the non-dedup sharded program) skips the probe
-    group — the link modes alone, post-add semantics — and then
-    ``probe_excl`` only shapes the link mask."""
+    each chip's local slice with localized exclusion masks; a paged pool
+    scans in pool space and maps the survivors back. Returns the flat tuple
+    ``(p_s [B,1], p_r [B,1], s_mode, r_mode, ...)``; a slot no candidate
+    fills holds ``(NEG_INF, capacity)``. ``with_probe=False`` (the non-dedup
+    sharded program) skips the probe group — the link modes alone, post-add
+    semantics — and then ``probe_excl`` only shapes the link mask."""
+    from lazzaro_tpu.ops.pallas_topk import ROW_DEAD, blocked_link_scan
+
     pmask = _pool_mask(state, state.alive & (state.tenant_id == tenant)
                        & ~state.is_super & ~probe_excl)
     lmask = pmask & ~_pool_mask(state, link_excl)
-    shard_pool = _pool_col(state, state.shard_id)
-
-    def body(q_c, qs_c):
-        scores = nt_dot(q_c, state.emb)               # [C, pool rows] f32
-        outs = []
-        if with_probe:
-            s, r = jax.lax.top_k(
-                jnp.where(pmask[None, :], scores, NEG_INF), 1)
-            outs.extend((s, _pool_to_logical(state, r)))
-        same = None
-        for sm in shard_modes:
-            m = lmask[None, :]
-            if sm != 0:
-                if same is None:
-                    same = qs_c[:, None] == shard_pool[None, :]
-                m = m & (same if sm == 1 else ~same)
-            s, r = jax.lax.top_k(jnp.where(m, scores, NEG_INF), k)
-            outs.extend((s, _pool_to_logical(state, r)))
-        return tuple(outs)
-
-    return chunked_map_multi(body, (qd, q_shard), chunk=chunk)
+    shard_pool = _pool_col(state, state.shard_id).astype(jnp.int32)
+    flat = blocked_link_scan(
+        state.emb, qd, jnp.where(lmask, shard_pool, ROW_DEAD), q_shard, k,
+        shard_modes,
+        jnp.where(pmask, 0, ROW_DEAD) if with_probe else None)
+    return tuple(_pool_to_logical(state, a) if i % 2 else a
+                 for i, a in enumerate(flat))
 
 
 def _dedup_resolve(qf: jax.Array, rows: jax.Array, valid: jax.Array,
@@ -1784,8 +1751,8 @@ def _ingest_dedup_fused(
                                                   valid)
         page_tail = (pops, ptable.free_top, p_over.astype(jnp.int32))
 
-    # ONE whole-arena score matrix feeds BOTH the pre-add dedup probe and
-    # the per-mode link scans (_ingest_scan_core): the probe sees the same
+    # ONE pass over the arena feeds BOTH the pre-add dedup probe and the
+    # per-mode link scans (_ingest_scan_core): the probe sees the same
     # visibility the classic host probe has (its batch insert also lands
     # after the probe), and the link candidates exclude the batch's own
     # rows — so the pre-add scan is exactly the post-add-with-exclusion
@@ -1862,7 +1829,7 @@ ingest_dedup_fused, ingest_dedup_fused_copy = _donated_pair(
 #   slots, link pool) is replicated.
 # - Each chip runs the SAME shard-local scan core the single-chip kernel
 #   traces (``_ingest_scan_core`` — dedup-probe top-1 + per-mode link top-k
-#   over one local score matrix), and the ONLY cross-chip traffic is ONE
+#   selected in one pass over the local rows), and the ONLY cross-chip traffic is ONE
 #   all_gather merging probe + every link mode's candidates in a single
 #   grouped combine (``ops.topk.sharded_grouped_topk_merge``).
 # - The dedup resolve, gate verdicts, and prefix-sum pool compaction are
@@ -2105,12 +2072,8 @@ def make_ingest_fused_sharded(mesh, axis: str, *, k: int,
         probe_excl = jnp.arange(local_n) == (cap - row_base)
         link_excl = (jnp.zeros((local_n,), bool).at[rows_l].set(True)
                      | probe_excl)
-        # each chip's slice is n× narrower than the whole arena, so the
-        # scan streams n×-wider query chunks at the SAME f32 tile budget
-        # the single-chip QUERY_CHUNK bounds — fewer, denser gemms
         flat = _ingest_scan_core(arena, qd, shard_id_v, probe_excl,
-                                 link_excl, tenant, k_l, shard_modes,
-                                 chunk=min(QUERY_CHUNK * n_shards, 4096))
+                                 link_excl, tenant, k_l, shard_modes)
         # ONE all_gather merges the probe AND every link mode's local
         # candidates (grouped combine; candidate ids globalized first, so
         # masked/garbage entries route to the global sentinel row) — and
@@ -2212,7 +2175,6 @@ def make_ingest_fused_sharded(mesh, axis: str, *, k: int,
         flat = _ingest_scan_core(arena, qd, shard_id_v,
                                  jnp.zeros((local_n,), bool), link_excl,
                                  tenant, k_l, shard_modes,
-                                 chunk=min(QUERY_CHUNK * n_shards, 4096),
                                  with_probe=False)
         cat_s = [flat[2 * g] for g in range(n_modes)]
         cat_i = [_globalize_rows(flat[2 * g + 1], flat[2 * g], shard,

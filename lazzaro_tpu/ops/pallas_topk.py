@@ -27,6 +27,10 @@ block b, everything else in VMEM) and a plain-JAX ``fori_loop`` over
 ``dynamic_slice``d blocks (every other backend, and any pool the block does
 not tile: then ONE whole-pool block of the same code). PERF.md §6 (PR 26)
 holds the chip measurements that chose between them.
+
+Two more cores share these steps and nothing else, each a body of its own
+further down: the int8 coarse scan (``blocked_two_tier_q8``, ISSUE 36) and
+the fused ingest's link scan (``blocked_link_scan``, ISSUE 45).
 """
 
 from __future__ import annotations
@@ -353,6 +357,249 @@ def masked_topk(emb: jax.Array, mask: jax.Array, queries: jax.Array, k: int,
         jnp.full((n,), ROW_DEAD, jnp.int32),
         jnp.zeros((queries.shape[0],), jnp.int32), k, k_q, impl)
     return top_s, top_r
+
+
+# ------------------------------------------------- the write path's link scan
+#
+# The fused ingest's twin of the core above (ISSUE 45): a conversation's facts
+# against every row of the pool, ONE pass, several fixed-``k`` tiers — the
+# dedup probe's top-1 (the gate tier's step) and one sorted top-``k`` list PER
+# link mode — all held on chip across the blocks. A separate body, not the
+# serving kernel with a flag: that one's list is ragged (``k_c`` and ``kmax``
+# are device data, one main tier); here ``k`` is static and small and the
+# tiers are several. They share the step functions and nothing else.
+#
+# The tiers mask on two per-row int32 columns: ``row_probe`` (0 where the
+# probe may read the row, else ``ROW_DEAD``) and ``row_link`` (the row's shard
+# where a link may read it, else ``ROW_DEAD``); a fact's key is its shard.
+# Every link row is a probe row, so ONE per-block flag (any probe row in the
+# block) lets a block that holds no candidate skip the matmul and the masks;
+# the block is still streamed.
+
+
+def _link_mask(sm: int, row_link, q_shard):
+    """One link mode's ``[C, block]`` mask from the rows' key column
+    ([1, block]) and the facts' shards ([C, 1]): 1 the fact's own shard, 0
+    any shard, anything else another shard."""
+    rows = jnp.broadcast_to(row_link, (q_shard.shape[0], row_link.shape[1]))
+    if sm == 1:
+        return rows == q_shard
+    live = rows != ROW_DEAD
+    return live if sm == 0 else live & (rows != q_shard)
+
+
+def _probe_key(k_c):
+    """The facts' key in the probe tier ([C, 1]): 0, and for the pad facts
+    the kernel adds (they ask nothing) a key no row carries."""
+    return jnp.where(k_c > 0, 0, ROW_DEAD + 1)
+
+
+def _link_scan_jax(emb, qn, row_probe, row_link, q_shard, k_c, k: int,
+                   modes: Tuple[int, ...], kp: int, block: int,
+                   sentinel: int):
+    """The link scan in plain JAX. ``q_shard`` / ``k_c`` are [C, 1];
+    ``row_probe`` None drops the probe tier. Returns ``(g_s, g_r, r_s_mode,
+    r_r_mode, ...)``: the probe's [C, 1] pair, then a [C, kp] pair a mode.
+
+    Off the kernel a tier takes a block's best ``k`` with ONE ``lax.top_k``
+    and merges them behind its running list with another (both keep the
+    lower index ahead on equal scores, and earlier blocks hold lower rows),
+    and the probe its best with a ``top_k`` of 1: the kernel's lists, found
+    the way XLA is quick at — ``_select_step``'s insertion loop cost the CPU
+    four times the dense scan it replaced at the benchmark's debug geometry
+    (PERF.md section 6, PR 45)."""
+    n = emb.shape[0]
+    c = qn.shape[0]
+    nblocks = n // block
+    flag_col = row_link if row_probe is None else row_probe
+    has = (flag_col.reshape(nblocks, block) != ROW_DEAD).any(axis=1)
+    asks = k_c > 0
+
+    def one_block(b, carry):
+        g_s, g_r = carry[:2]
+        base = b * block
+        scores = nt_dot(qn, jax.lax.dynamic_slice_in_dim(emb, base, block, 0))
+        if row_probe is not None:
+            rp = jax.lax.dynamic_slice_in_dim(row_probe, base, block, 0)
+            mg, ig = jax.lax.top_k(
+                jnp.where(rp[None, :] == _probe_key(k_c), scores, NEG), 1)
+            upd = mg > g_s          # strictly better: ties keep the lower row
+            g_s, g_r = jnp.where(upd, mg, g_s), jnp.where(upd, ig + base, g_r)
+        rl = jax.lax.dynamic_slice_in_dim(row_link, base, block, 0)[None, :]
+        out = [g_s, g_r]
+        for t, sm in enumerate(modes):
+            s = jnp.where(_link_mask(sm, rl, q_shard) & asks, scores, NEG)
+            b_s, b_i = jax.lax.top_k(s, min(kp, block))
+            b_r = jnp.where(b_s > NEG / 2, b_i + base, sentinel)
+            both_s = jnp.concatenate([carry[2 + 2 * t], b_s], axis=1)
+            both_r = jnp.concatenate([carry[3 + 2 * t], b_r], axis=1)
+            r_s, at = jax.lax.top_k(both_s, kp)
+            out.extend((r_s, jnp.take_along_axis(both_r, at, axis=1)))
+        return tuple(out)
+
+    init = (jnp.full((c, 1), NEG, jnp.float32),
+            jnp.full((c, 1), sentinel, jnp.int32)) + (
+        jnp.full((c, kp), NEG, jnp.float32),
+        jnp.full((c, kp), sentinel, jnp.int32)) * len(modes)
+    if nblocks == 1:
+        return one_block(0, init)
+    return jax.lax.fori_loop(
+        0, nblocks, lambda b, carry: jax.lax.cond(
+            has[b], functools.partial(one_block, b), lambda cy: cy, carry),
+        init)
+
+
+def _link_kernel(block: int, kp: int, k: int, modes: Tuple[int, ...],
+                 with_probe: bool, sentinel: int):
+    def kernel(has_ref, q_ref, kq_ref, sq_ref, emb_ref, *refs):
+        refs = list(refs)
+        rp_ref = refs.pop(0) if with_probe else None
+        rl_ref, gs_ref, gr_ref, *lists, s_ref = refs
+        b = pl.program_id(0)
+        c = q_ref.shape[0]
+
+        @pl.when(b == 0)
+        def _():
+            gs_ref[...] = jnp.full((c, 1), NEG, jnp.float32)
+            gr_ref[...] = jnp.full((c, 1), sentinel, jnp.int32)
+            for t in range(len(modes)):
+                lists[2 * t][...] = jnp.full((c, kp), NEG, jnp.float32)
+                lists[2 * t + 1][...] = jnp.full((c, kp), sentinel,
+                                                 jnp.int32)
+
+        @pl.when(has_ref[b] > 0)        # most blocks hold no candidate row
+        def _():
+            scores = jax.lax.dot_general(
+                q_ref[...], emb_ref[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)        # [C, block]
+            kq = kq_ref[...]
+            base = b * block
+            col = jax.lax.broadcasted_iota(jnp.int32, (c, block), 1)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (c, kp), 1)
+            roll = functools.partial(pltpu.roll, shift=1, axis=1)
+            if with_probe:
+                g_s, g_r = _gate_step(scores, rp_ref[...], _probe_key(kq),
+                                      col, base, gs_ref[...], gr_ref[...])
+                gs_ref[...] = g_s
+                gr_ref[...] = g_r
+            for t, sm in enumerate(modes):
+                rs_ref, rr_ref = lists[2 * t], lists[2 * t + 1]
+                s = jnp.where(_link_mask(sm, rl_ref[...], sq_ref[...]),
+                              scores, NEG)
+                s_ref[...] = s
+                m = jnp.max(s, axis=1, keepdims=True)
+                go = _any(_wants(m, rs_ref[...], lane, kq))
+
+                def body(cy, rs_ref=rs_ref, rr_ref=rr_ref):
+                    t_, m, _ = cy
+                    s, m, r_s, r_r, go = _select_step(
+                        s_ref[...], m, col, lane, base, rs_ref[...],
+                        rr_ref[...], kq, roll)
+                    s_ref[...] = s
+                    rs_ref[...] = r_s
+                    rr_ref[...] = r_r
+                    return t_ + 1, m, go
+
+                jax.lax.while_loop(lambda cy: (cy[2] > 0) & (cy[0] < k),
+                                   body, (jnp.int32(0), m, go))
+
+    return kernel
+
+
+def _link_scan_pallas(emb, qn, row_probe, row_link, q_shard, k_c, k: int,
+                      modes: Tuple[int, ...], kp: int, block: int,
+                      sentinel: int, interpret: bool):
+    """The link scan as one Pallas TPU kernel over the grid of blocks. Same
+    arguments and results as :func:`_link_scan_jax`."""
+    n, d = emb.shape
+    c = qn.shape[0]
+    nblocks = n // block
+    with_probe = row_probe is not None
+    cols = ([row_probe] if with_probe else []) + [row_link]
+    has = (cols[0].reshape(nblocks, block) != ROW_DEAD).any(
+        axis=1).astype(jnp.int32)
+    fixed = lambda b, *_: (0, 0)                           # noqa: E731
+    rows_of = lambda b, *_: (0, b)                         # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nblocks,),
+        in_specs=[
+            pl.BlockSpec((c, d), fixed),
+            pl.BlockSpec((c, 1), fixed),
+            pl.BlockSpec((c, 1), fixed),
+            pl.BlockSpec((block, d), lambda b, *_: (b, 0)),
+        ] + [pl.BlockSpec((1, block), rows_of)] * len(cols),
+        out_specs=[pl.BlockSpec((c, 1), fixed)] * 2
+        + [pl.BlockSpec((c, kp), fixed)] * (2 * len(modes)),
+        scratch_shapes=[pltpu.VMEM((c, block), jnp.float32)],
+    )
+    vmem = (2 * block * d * emb.dtype.itemsize + 8 * c * block * 4
+            + 8 * 1024 * 1024)
+    return tuple(pl.pallas_call(
+        _link_kernel(block, kp, k, modes, with_probe, sentinel),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((c, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((c, 1), jnp.int32)]
+        + [jax.ShapeDtypeStruct((c, kp), jnp.float32),
+           jax.ShapeDtypeStruct((c, kp), jnp.int32)] * len(modes),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=int(vmem)),
+        interpret=interpret,
+        name="lz_link_scan",
+    )(has, qn, k_c, q_shard, emb, *(a.reshape(1, n) for a in cols)))
+
+
+def blocked_link_scan(emb: jax.Array, qn: jax.Array, row_link: jax.Array,
+                      q_shard: jax.Array, k: int,
+                      shard_modes: Tuple[int, ...],
+                      row_probe: Optional[jax.Array] = None,
+                      impl: str = "auto") -> Tuple[jax.Array, ...]:
+    """The fused ingest's scan: per fact the dedup probe's top-1 and, for
+    each entry of ``shard_modes``, a top-``k`` of link candidates, selected
+    while the pool streams once.
+
+    ``emb`` [n, d] (rows L2-normalized), ``qn`` [C, d] (the facts,
+    normalized, in ``emb``'s dtype), ``row_link`` [n] i32 (the row's shard
+    where a link may read it, ``ROW_DEAD`` where none may), ``q_shard`` [C]
+    i32, ``row_probe`` [n] i32 (0 where the probe may read the row, else
+    ``ROW_DEAD``; every link row must be a probe row; None: no probe tier).
+    A mode is 1 (rows of the fact's own shard), 0 (any shard) or anything
+    else (another shard). Returns the flat tuple ``(probe_s [C, 1], probe_r
+    [C, 1], s_mode [C, k], r_mode [C, k], ...)`` — without the first pair
+    when ``row_probe`` is None — with POOL rows, best first, equal scores
+    the lower row first; a slot no candidate fills is ``(NEG, n - 1)``.
+    ``impl`` as in :func:`blocked_two_tier`."""
+    n, d = emb.shape
+    c = qn.shape[0]
+    block = select_block_rows(n, d, emb.dtype.itemsize)
+    tiles = block < n
+    if impl == "pallas" and not tiles:
+        raise ValueError(f"no block tiles a pool of {n} rows")
+    use_pallas = impl == "pallas" or (impl == "auto" and on_tpu() and tiles)
+    shard_modes = tuple(shard_modes)
+    if c > _MAX_QUERIES:
+        from lazzaro_tpu.ops.chunking import chunked_map_multi
+        return chunked_map_multi(
+            lambda q_p, s_p: blocked_link_scan(
+                emb, q_p, row_link, s_p, k, shard_modes, row_probe, impl),
+            (qn, q_shard), chunk=_MAX_QUERIES)
+    # the kernel's tiles: bf16 facts are 16 deep, the lists 128 lanes wide;
+    # a pad fact asks nothing (k 0)
+    cp = -(-c // 16) * 16 if use_pallas else c
+    kp = -(-k // 128) * 128 if use_pallas else k
+    pad = cp - c
+    args = (emb, jnp.pad(qn, ((0, pad), (0, 0))), row_probe, row_link,
+            jnp.pad(q_shard.astype(jnp.int32), (0, pad))[:, None],
+            jnp.pad(jnp.full((c,), k, jnp.int32), (0, pad))[:, None],
+            k, shard_modes, kp, block, n - 1)
+    if use_pallas:
+        flat = _link_scan_pallas(*args, interpret=not on_tpu())
+    else:
+        flat = _link_scan_jax(*args)
+    lists = tuple(a[:c, :k] for a in flat[2:])
+    if row_probe is None:
+        return lists
+    return (flat[0][:c], flat[1][:c]) + lists
 
 
 # ------------------------------------------------------ the int8 coarse scan

@@ -70,6 +70,8 @@ DISPATCH_WORKSPACE_BYTES = 2 << 20
 # as literals for the same reason as the chunks above.
 SELECT_BLOCK = 4096
 _SELECT_BLOCK_BYTES = 8 * 1024 * 1024
+# facts the ingest's link scan holds on chip at once (_MAX_QUERIES there)
+LINK_SCAN_FACTS = 128
 
 
 def select_block_rows(n: int, d: int, itemsize: int) -> int:
@@ -295,10 +297,17 @@ class CostModel:
             cands = max(1, g.nprobe or 4) * m + g.k
             tile = chunk * cands * (g.dim + 2) * 4
         elif fam == "ingest":
-            # the multi-mode link/dedup scan streams [chunk, rows] f32
-            # once (PR 9 single-stream refactor) + candidate triples
-            tile = chunk * (scan_rows_pc + 1) * 4 \
-                + chunk * max(1, g.link_k) * 3 * 4 * 2
+            # the blocked link scan (ISSUE 45): no [facts, rows] tile —
+            # a piece of the batch's facts on chip against ONE block's
+            # scores with their masked copy and
+            # compare workspace, the two per-row key columns the tiers
+            # mask on, and the candidate triples. A pool no block tiles
+            # is one whole-pool block.
+            block = select_block_rows(scan_rows_pc, g.dim,
+                                      g.dtype_bytes)
+            tile = min(g.batch, LINK_SCAN_FACTS) * block * 4 * 3
+            tile += (scan_rows_pc + 1) * 4 * 2
+            tile += g.batch * max(1, g.link_k) * 3 * 4 * 2
             if g.ivf:
                 # the [batch, C] assignment tile, the [C, d] centroid
                 # update workspace (sums + proposal), and the batch-wide

@@ -30,6 +30,29 @@ import re
 import sys
 
 
+def mosaic_hash(text: str):
+    """``(count, sha256)`` of the Mosaic kernels in a lowered module's text:
+    every ``tpu_custom_call``'s payload, printed WITHOUT its source
+    locations (``tests/test_select_core_tpu.py`` holds the serving kernels
+    to the hashes frozen there)."""
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    def kernel_asm(config: str) -> str:
+        body = json.loads(config.replace("\\22", '"'))[
+            "custom_call_config"]["body"]
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            return ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+                enable_debug_info=False)
+
+    mosaic = re.findall(
+        r'@tpu_custom_call\(.*?backend_config = "((?:[^"\\]|\\.)*)"', text)
+    return len(mosaic), hashlib.sha256(
+        "".join(map(kernel_asm, mosaic)).encode()).hexdigest()
+
+
 def main(checkout: str) -> int:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     sys.path.insert(0, checkout)
@@ -49,24 +72,10 @@ def main(checkout: str) -> int:
     one = SingleDeviceSharding(topo.devices[0])
     d = 768
 
-    def kernel_asm(config: str) -> str:
-        """One ``tpu_custom_call``'s Mosaic module, locations stripped."""
-        from jax._src.interpreters import mlir as jax_mlir
-        from jax._src.lib.mlir import ir
-        body = json.loads(config.replace("\\22", '"'))[
-            "custom_call_config"]["body"]
-        ctx = jax_mlir.make_ir_context()
-        ctx.allow_unregistered_dialects = True
-        with ctx:
-            return ir.Module.parse(base64.b64decode(body)).operation.get_asm(
-                enable_debug_info=False)
-
     def show(name, c, text):
-        mosaic = re.findall(
-            r'@tpu_custom_call\(.*?backend_config = "((?:[^"\\]|\\.)*)"', text)
+        count, kernels = mosaic_hash(text)
         print(name, c, hashlib.sha256(text.encode()).hexdigest(), len(text),
-              len(mosaic), hashlib.sha256(
-                  "".join(map(kernel_asm, mosaic)).encode()).hexdigest())
+              count, kernels)
 
     # PR 37: the request operands are ONE int32 carrier
     carrier = "requests" in inspect.signature(

@@ -115,3 +115,52 @@ def test_int8_read_core_names_the_coarse_scan_and_the_rescore():
                             low.compile().as_text()))
     assert scopes == {"lz.unpack", "lz.norms", "lz.scan_q8", "lz.topk",
                       "lz.rescore", "lz.gate", "lz.pack"}
+
+
+@pytest.mark.parametrize("cap,core", [(3 * 512 - 1, "blocked"),
+                                      (64, "whole_pool")])
+def test_ingest_select_counter_names_the_core(cap, core):
+    """``ingest.select{core}`` (ISSUE 45), once a fused ingest dispatch —
+    the dedup program and the plain one: ``blocked`` where a block tiles the
+    pool, ``whole_pool`` where the pool is one block; in ``prometheus()``."""
+    from lazzaro_tpu.utils.telemetry import Telemetry
+
+    idx = MemoryIndex(dim=D, capacity=cap, edge_capacity=255,
+                      telemetry=Telemetry())
+    rng = np.random.default_rng(5)
+    emb = rng.standard_normal((12, D)).astype(np.float32)
+    pending = idx.ingest_batch_dedup(
+        emb[:6], [0.5] * 6, [0.0] * 6, ["semantic"] * 6, ["default"] * 6,
+        tenant="a", dedup_gate=0.95, now=0.0)
+    idx.commit_ingest_dedup(pending, [f"n{i}" for i in range(6)])
+    idx.ingest_batch([f"m{i}" for i in range(6)], emb[6:], [0.5] * 6,
+                     [0.0] * 6, ["semantic"] * 6, ["default"] * 6, "a",
+                     now=0.0)
+    tel = idx.telemetry
+    assert tel.counter_total("ingest.select") == 2
+    lines = [ln for ln in tel.prometheus().splitlines()
+             if ln.startswith("lazzaro_ingest_select_total")]
+    assert lines == [f'lazzaro_ingest_select_total{{core="{core}"}} 2']
+
+
+def test_classic_link_scan_is_the_same_core_without_its_probe():
+    """``arena_link_candidates_multi`` (the plain fused ingest's post-add
+    scan, and the classic path's) at a pool three blocks tile: no
+    ``[facts, rows]`` buffer in the compiled program, the new rows excluded,
+    and with one shard both modes give the same lists."""
+    idx = MemoryIndex(dim=D, capacity=3 * 512 - 1, edge_capacity=255)
+    rng = np.random.default_rng(3)
+    idx.add([f"n{i}" for i in range(12)],
+            rng.standard_normal((12, D)).astype(np.float32), [0.5] * 12,
+            [0.0] * 12, ["semantic"] * 12, ["default"] * 12, "a")
+    st = idx.state
+    rows = jnp.asarray(S.pad_rows(np.asarray([0, 1], np.int32), st.capacity))
+    args = (st, rows, rows, jnp.int32(idx._tenants["a"]), 3, (1, 0))
+    text = S.arena_link_candidates_multi.lower(*args).compile().as_text()
+    assert f"[{len(rows)},512]" in text                # one block's tile
+    assert f"[{len(rows)},{st.emb.shape[0]}]" not in text
+    s1, r1, s0, r0 = (np.asarray(a)
+                      for a in S.arena_link_candidates_multi(*args))
+    assert ((r1[:2] >= 2) & (r1[:2] < 12)).all()
+    assert (s1[:2] > S.NEG_INF / 2).all()
+    np.testing.assert_array_equal(r1[:2], r0[:2])

@@ -202,3 +202,120 @@ def test_pod_int8_program_compiles_for_four_chips(topo, monkeypatch):
     assert "lz_select_scan_q8" in text and "all-gather" in text
     assert f"[{c},{n // 4}]" not in text
     assert comp.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+# --------------------------------------- the write path's link scan (ISSUE 45)
+
+def _described_ingest(one_chip, rows, b, edges=1_048_575):
+    """``ingest_dedup_fused``'s operands at ``rows`` x 768 bf16, described."""
+    d, k = 768, 3
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    st, es = (jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
+                                     jax.eval_shape(make))
+              for make in (lambda: S.init_arena(rows - 1, d, jnp.bfloat16),
+                           lambda: S.init_edges(edges)))
+    i32, f32 = jnp.int32, jnp.float32
+    per_fact = (sds((b,), i32), sds((b, d), f32), sds((b,), f32),
+                sds((b,), f32), sds((b,), i32), sds((b,), i32),
+                sds((b,), i32), sds((b,), jnp.bool_), sds((b,), i32),
+                sds((b,), i32), sds((2 * b * k + 1,), i32))
+    scalars = (sds((), i32), sds((), f32), sds((), i32)) + (sds((), f32),) * 5
+    return (st, es, None, None, None, None) + per_fact + scalars
+
+
+@pytest.mark.parametrize("rows,b", [
+    (5_001_216, 128),       # lme5m-live: a 100-fact conversation
+    (135_168, 128),         # share131k's arena: 33 blocks
+    (5_001_216, 256),       # a batch the scan takes in two pieces
+])
+def test_fused_ingest_compiles_for_v5e_and_holds_no_score_tile(
+        one_chip, monkeypatch, rows, b):
+    """The write-path twin of
+    ``test_compiled_serving_program_holds_no_score_tile``, at the cells'
+    real shapes: ``ingest_dedup_fused`` (modes (1, 0), k = 3) lowers for a
+    described v5e with ``lz_link_scan`` as its one Mosaic kernel and no
+    ``[facts, rows]`` buffer of any type; what it holds besides the donated
+    arena is megabytes."""
+    import re
+
+    monkeypatch.setattr(PT, "on_tpu", lambda: True)
+    comp = S.ingest_dedup_fused.lower(
+        *_described_ingest(one_chip, rows, b), k=3,
+        shard_modes=(1, 0)).compile()
+    text = comp.as_text()
+    assert text.count("tpu_custom_call") == 1 and "lz_link_scan" in text
+    for facts in {b, min(b, PT._MAX_QUERIES)}:
+        assert not re.search(rf"\[{facts},{rows}\]|\[{rows},{facts}\]", text)
+    assert comp.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+@pytest.mark.parametrize("rows,c,want", [
+    (135_168, 8, "fb68f94e94b78515"), (135_168, 64, "c7db72de159e6899"),
+    (5_001_216, 8, "aa1567dae3196e6a"), (5_001_216, 64, "b776dfd99eafd386"),
+])
+def test_serving_kernel_is_the_one_pr_44_left(one_chip, monkeypatch, rows, c,
+                                              want):
+    """``lz_select_scan`` as the chip's compiler reads it, source locations
+    stripped (``scripts/exact_stablehlo.py``'s last column, frozen from
+    commit d42b5f3): the write path's kernel shares its step functions, and
+    nothing of the serving programs' Mosaic modules may move with it."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "exact_stablehlo", os.path.join(os.path.dirname(__file__), os.pardir,
+                                        "scripts", "exact_stablehlo.py"))
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    monkeypatch.setattr(PT, "on_tpu", lambda: True)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    st = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: S.init_arena(rows - 1, 768, jnp.bfloat16)))
+    text = S.search_fused_ragged_read.lower(
+        st, sds((rows + 1,), jnp.int32), sds((8192,), jnp.int32),
+        sds((c, 768 + REQUEST_COLS), jnp.int32),
+        k=128, cap_take=5, max_nbr=8).as_text()
+    count, kernels = script.mosaic_hash(text)
+    assert count == 1 and kernels.startswith(want)
+
+
+def test_pod_ingest_program_compiles_for_four_chips(topo, monkeypatch):
+    """``make_ingest_fused_sharded``'s dedup program on a 2x2 mesh at 20M
+    rows (each chip's slice is ``lme5m``'s pool): the link scan's kernel
+    sits inside the ``shard_map``, the candidate merge is the one
+    ``all_gather``, and no chip holds a ``[facts, its rows]`` buffer."""
+    monkeypatch.setattr(PT, "on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+    n, d, b, k, edges = 4 * 1221 * 4096, 768, 128, 3, 4 * 262_144
+
+    def sds(shape, dt, spec=None):
+        spec = spec if spec is not None else P(*([None] * len(shape)))
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def rows_of(make):
+        return jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype,
+                          P("data", None) if a.ndim == 2 else P("data")),
+            jax.eval_shape(make))
+
+    i32, f32 = jnp.int32, jnp.float32
+    per_fact = (sds((b,), i32), sds((b, d), f32), sds((b,), f32),
+                sds((b,), f32), sds((b,), i32), sds((b,), i32),
+                sds((b,), i32), sds((b,), jnp.bool_), sds((b,), i32),
+                sds((b,), i32), sds((2 * b * k + 1,), i32))
+    scalars = (sds((), i32), sds((), f32), sds((), i32)) + (sds((), f32),) * 5
+    kern = S.make_ingest_fused_sharded(mesh, "data", k=k, shard_modes=(1, 0))
+    text = kern.ingest.lower(
+        rows_of(lambda: S.init_arena(n - 1, d, jnp.bfloat16)),
+        rows_of(lambda: S.init_edges(edges - 1)), *per_fact,
+        *scalars).compile().as_text()
+    assert "lz_link_scan" in text and "all-gather" in text
+    assert f"[{b},{n // 4}]" not in text and f"[{n // 4},{b}]" not in text
